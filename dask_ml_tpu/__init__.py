@@ -26,6 +26,10 @@ sklearn/dask-ml-parity namespaces (import as ``dask_ml_tpu.<name>``):
 
 __version__ = "0.1.0"
 
+from .config import ensure_compile_cache as _ensure_compile_cache
+
+_ensure_compile_cache()
+
 __all__ = [
     "cluster", "compose", "config", "datasets", "decomposition",
     "ensemble", "feature_extraction", "impute", "linear_model", "metrics",

@@ -217,12 +217,6 @@ class Config:
     # hang into a typed StreamSyncTimeout instead of wedging the fit
     # forever. 0 = no deadline
     stream_sync_timeout_s: float = 600.0
-    # persistent XLA compilation cache directory ("" = off): repeated
-    # runs skip warm-up compiles for programs whose shapes/backends
-    # match a cached entry (applies process-wide on first streamed fit
-    # or serving warmup after the knob is set; every plans.ProgramPlan
-    # build arms it too)
-    compile_cache_dir: str = ""
     # -- execution plans (dask_ml_tpu/plans/) -----------------------------
     # process-wide plan build cache: two ProgramPlan builds with an
     # identical spec (name, cache key, donation, static axes) return
@@ -552,45 +546,36 @@ def fit_dtype_info(override=None) -> dict:
     return {"fit_dtype": dt, "fit_dtype_source": src}
 
 
-_compile_cache_applied: str | None = None
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def ensure_compile_cache() -> bool:
-    """Apply ``config.compile_cache_dir`` to jax's persistent
-    compilation cache (idempotent per directory value; process-wide, as
-    the cache itself is). Returns True when a cache directory is
-    active. Called from the streamed-fit entry (BlockStream) and
-    ``serving.warmup()`` — warmup still compiles the full
-    (method, bucket) grid, but a second process/run with the same knob
-    replays those compiles from disk instead of XLA.
+def ensure_compile_cache() -> str:
+    """Place jax's persistent compilation cache; returns the directory
+    in effect. Called ONCE, from the package's import (before anything
+    can compile — jax latches the cache at its first compile).
 
-    The thresholds are zeroed so even sub-second streamed-block
-    programs are cached: the dispatch-bound hot loops this repo cares
-    about are exactly the ones whose many small compiles add up."""
-    global _compile_cache_applied
-    d = get_config().compile_cache_dir
-    if not d:
-        return False
-    if _compile_cache_applied == d:
-        return True
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache lives there
+    and no directory is set in code (jax reads the variable itself);
+    otherwise it lives at ``<checkout>/.jax_cache`` — a FIXED path,
+    because the path is part of the cache key and a directory that
+    moves never hits.
+
+    The thresholds are zeroed either way so even sub-second
+    streamed-block programs are cached: the dispatch-bound hot loops
+    this repo cares about are exactly the ones whose many small
+    compiles add up."""
     import jax
 
-    try:
+    d = os.environ.get(COMPILE_CACHE_ENV)
+    if not d:
+        d = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
+        )
         jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # jax latches the cache backend at the FIRST compile: a process
-        # that already compiled anything before this knob was applied
-        # holds an initialized no-op cache and silently ignores the new
-        # directory — reset so the next compile re-initializes against it
-        from jax._src import compilation_cache as _cc
-
-        if getattr(_cc, "_cache_initialized", False):
-            _cc.reset_cache()
-    except Exception:
-        return False  # jax build without the cache knobs: run uncached
-    _compile_cache_applied = d
-    return True
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return d
 
 
 def get_config() -> Config:

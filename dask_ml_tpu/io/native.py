@@ -1,47 +1,85 @@
-"""ctypes bindings for the native data loader (native/fast_loader.cpp).
+"""ctypes bindings for the native helpers (native/*.cpp): the
+multithreaded CSV parser and the readahead block reader that feeds
+``parallel/streaming.BlockStream``.
 
-Compiled on demand with g++ (the image has the toolchain but no
-pybind11 — SURVEY.md environment notes); falls back to numpy text parsing
-when compilation is unavailable. The loader feeds
-``parallel/streaming.BlockStream`` — parse into pinned host memory, then
-stream blocks to the mesh.
+Each library is compiled on first use with g++ (the image has the
+toolchain but no pybind11) from the source IN THIS TREE into
+``native/_build/<name>-<sha256 of source + flags>.so``: a binary is only
+ever loaded under the hash of the source it was built from, so a stale
+or foreign ``.so`` lying in the tree can never be picked up, and an
+edited source rebuilds whatever the mtimes say. A host without g++ has
+no native helpers (``native_available()`` is False and callers take
+their numpy path, on record); a compile or dlopen that FAILS raises.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
+import tempfile
 import threading
 
 import numpy as np
 
 _lock = threading.Lock()
-_lib = None
-_lib_failed = False
+_libs: dict = {}
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-_SRC = os.path.join(_ROOT, "native", "fast_loader.cpp")
-_SO = os.path.join(_ROOT, "native", "_fast_loader.so")
+_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "native")
+_CXX = "g++"
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17", "-pthread")
 
 
-def _build_and_load(src, so, configure):
-    """Shared compile-if-stale + dlopen + symbol-config flow for every
-    native helper; returns the configured library or None. A prebuilt
-    .so next to a MISSING source still loads (no getmtime on a path
-    that isn't there)."""
-    if not os.path.exists(so) or (
-        os.path.exists(src) and os.path.getmtime(so) < os.path.getmtime(src)
-    ):
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-             "-o", so, src],
-            check=True, capture_output=True,
-        )
-    lib = ctypes.CDLL(so)
-    configure(lib)
-    return lib
+def native_available() -> bool:
+    """Whether this host can build the native helpers at all."""
+    return shutil.which(_CXX) is not None
+
+
+def _build_and_load(name, configure):
+    """The configured library for ``native/<name>.cpp``, compiled into
+    its content-hashed path when that path does not exist yet; None on
+    a host without a C++ compiler. Build and dlopen errors propagate."""
+    if not native_available():
+        return None
+    with _lock:
+        lib = _libs.get(name)
+        if lib is not None:
+            return lib
+        src = os.path.join(_NATIVE_DIR, name + ".cpp")
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(
+                f.read() + " ".join(_CXXFLAGS).encode()
+            ).hexdigest()[:16]
+        build_dir = os.path.join(_NATIVE_DIR, "_build")
+        so = os.path.join(build_dir, f"{name}-{digest}.so")
+        if not os.path.exists(so):
+            os.makedirs(build_dir, exist_ok=True)
+            # compile beside the target and rename: a concurrent builder
+            # (two processes importing at once) never sees a half-written
+            # library under the final name
+            fd, tmp = tempfile.mkstemp(suffix=".so", dir=build_dir)
+            os.close(fd)
+            try:
+                proc = subprocess.run(
+                    [_CXX, *_CXXFLAGS, "-o", tmp, src],
+                    capture_output=True, text=True,
+                )
+                if proc.returncode != 0:
+                    raise RuntimeError(
+                        f"building {src} failed "
+                        f"(exit {proc.returncode}):\n{proc.stderr}"
+                    )
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+        lib = ctypes.CDLL(so)
+        configure(lib)
+        _libs[name] = lib
+        return lib
 
 
 def _configure_fast_loader(lib):
@@ -57,21 +95,15 @@ def _configure_fast_loader(lib):
 
 
 def load_library():
-    """The compiled library, building it if needed; None if unavailable."""
-    global _lib, _lib_failed
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        try:
-            _lib = _build_and_load(_SRC, _SO, _configure_fast_loader)
-        except Exception:
-            _lib_failed = True
-        return _lib
+    """The CSV parser library (built on first use); None on a host
+    without a C++ compiler."""
+    return _build_and_load("fast_loader", _configure_fast_loader)
 
 
 def read_csv_f32(path, n_threads=None) -> np.ndarray:
     """Parse a numeric CSV (comma/space/tab separated, no header) into a
-    float32 array with the native multithreaded parser; numpy fallback."""
+    float32 array with the native multithreaded parser (numpy's text
+    parser on a host without a C++ compiler)."""
     path = os.path.abspath(path)
     lib = load_library()
     if lib is None:
@@ -104,12 +136,6 @@ def read_csv_sharded(path, mesh=None, n_threads=None):
 
 # -- native block reader (native/block_reader.cpp) --------------------------
 
-_SRC_BR = os.path.join(_ROOT, "native", "block_reader.cpp")
-_SO_BR = os.path.join(_ROOT, "native", "_block_reader.so")
-_lib_br = None
-_lib_br_failed = False
-
-
 def _configure_block_reader(lib):
     lib.br_open.restype = ctypes.c_void_p
     lib.br_open.argtypes = [
@@ -123,17 +149,9 @@ def _configure_block_reader(lib):
 
 
 def load_block_reader():
-    """The threaded-readahead reader library; None if unavailable."""
-    global _lib_br, _lib_br_failed
-    with _lock:
-        if _lib_br is not None or _lib_br_failed:
-            return _lib_br
-        try:
-            _lib_br = _build_and_load(_SRC_BR, _SO_BR,
-                                      _configure_block_reader)
-        except Exception:
-            _lib_br_failed = True
-        return _lib_br
+    """The threaded-readahead reader library (built on first use); None
+    on a host without a C++ compiler."""
+    return _build_and_load("block_reader", _configure_block_reader)
 
 
 class NativeBlockReader:

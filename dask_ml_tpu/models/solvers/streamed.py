@@ -286,13 +286,15 @@ def _sb_reducer_sharded(kind, family, intercept, n_classes, mesh,
     per-shard slab height, not the global block), produces local raw
     sums from ONE VMEM pass, and the existing single psum per
     super-block merges them — the per-chip kernel speed of the fused
-    flavor composed with the data mesh. The replication checker is
-    disabled on the fused trace only (pallas_call has no replication
-    rule); the unfused program is byte-identical to the pre-feature
-    one."""
+    flavor composed with the data mesh.
+
+    Both flavors run with ``check_vma=False``: the XLA body autodiffs
+    the REPLICATED ``beta`` and then psums the local sums itself; under
+    ``check_vma=True`` jax would already have psummed that gradient
+    (the transpose of the implicit ``pvary``) and the explicit psum
+    would make it D times too large."""
     from jax.sharding import PartitionSpec as P
 
-    from ..._compat import shard_map
     from ...parallel.mesh import DATA_AXIS, data_shard_spec as spec_of
 
     if fused:
@@ -353,11 +355,11 @@ def _sb_reducer_sharded(kind, family, intercept, n_classes, mesh,
         else:
             xs_spec = spec_of(Xs, 1)
             ys_spec = spec_of(ys, 1)
-        f = shard_map(
-            body, mesh,
+        f = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(), P(), xs_spec, ys_spec, P(DATA_AXIS, None)),
             out_specs=P(),
-            check_vma=False if fused else None,
+            check_vma=False,
         )
         return f(acc, beta, Xs, ys, counts)
 
@@ -392,7 +394,6 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
     jaxpr-byte-identical."""
     from jax.sharding import PartitionSpec as P
 
-    from ..._compat import shard_map
     from ...parallel.mesh import DATA_AXIS, MODEL_AXIS
 
     fam = get_family(family)
@@ -419,8 +420,7 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
     def _gather_feat(t, axis):
         # per-feature slices -> the full-width array, replicated over
         # "model": scatter this shard's tile into a zero full-width
-        # buffer and psum (adding zeros — exact), which the replication
-        # checker can statically infer (an all_gather it cannot)
+        # buffer and psum (adding zeros — exact)
         mi = jax.lax.axis_index(MODEL_AXIS)
         dm = t.shape[axis]
         full = t.shape[:axis] + (dm * model_shards,) + t.shape[axis + 1:]
@@ -617,10 +617,11 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
         else:
             xs_spec = _x_spec(Xs, 1)
             ys_spec = _y_spec(ys, 1)
-        f = shard_map(
-            body, mesh,
+        f = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(), P(), xs_spec, ys_spec, P(DATA_AXIS, None)),
             out_specs=P(),
+            check_vma=False,
         )
         return f(acc, beta, Xs, ys, counts)
 
@@ -843,7 +844,6 @@ def _sb_reducer_sparse(kind, family, intercept, n_classes, n_rows,
 
     from jax.sharding import PartitionSpec as P
 
-    from ..._compat import shard_map
     from ...parallel.mesh import DATA_AXIS
 
     sums = _sparse_reducer_sums(kind, family, intercept, n_classes,
@@ -869,12 +869,13 @@ def _sb_reducer_sparse(kind, family, intercept, n_classes, n_rows,
 
     @partial(jax.jit, donate_argnums=(0,))
     def run(acc, beta, data, cols, rows, ys, counts):
-        f = shard_map(
-            body, mesh,
+        f = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(), P(), P(None, DATA_AXIS), P(None, DATA_AXIS),
                       P(None, DATA_AXIS), P(None, DATA_AXIS),
                       P(DATA_AXIS, None)),
             out_specs=P(),
+            check_vma=False,
         )
         return f(acc, beta, data, cols, rows, ys, counts)
 

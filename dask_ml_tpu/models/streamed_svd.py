@@ -146,7 +146,6 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
     ``_sb_reducer_feature_sharded`` structure."""
     from jax.sharding import PartitionSpec as P
 
-    from .._compat import shard_map
     from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
 
     M = int(model_shards)
@@ -169,8 +168,7 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
     def _scatter_feat(t):
         # feature-tile -> replicated full width: scatter into a zero
         # (d, ...) buffer at this device's offset, psum over "model"
-        # (exact — adds zeros — and the replication checker infers the
-        # psum output replicated, unlike all_gather)
+        # (exact — adds zeros)
         mi = jax.lax.axis_index(MODEL_AXIS)
         dm = t.shape[0]
         full = (dm * M,) + t.shape[1:]
@@ -224,10 +222,11 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
             unrolled = isinstance(Xs, (tuple, list))
             xs_spec = (tuple(_x_spec(a, 0) for a in Xs) if unrolled
                        else _x_spec(Xs, 1))
-            f = shard_map(
-                body, mesh,
+            f = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(P(), P(), xs_spec, P(DATA_AXIS, None)),
                 out_specs=P(),
+                check_vma=False,
             )
             return f(acc, shift, Xs, counts)
     else:
@@ -276,10 +275,11 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
             unrolled = isinstance(Xs, (tuple, list))
             xs_spec = (tuple(_x_spec(a, 0) for a in Xs) if unrolled
                        else _x_spec(Xs, 1))
-            f = shard_map(
-                body, mesh,
+            f = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(P(), P(), P(), xs_spec, P(DATA_AXIS, None)),
                 out_specs=P(),
+                check_vma=False,
             )
             return f(acc, mean, omega, Xs, counts)
 

@@ -22,9 +22,9 @@ equivalent is this package (grown from the flat per-step logger in
   (``config.watchdog_timeout_s``): spans open past their deadline dump
   all-thread tracebacks + device memory gauges + the open-span stack to
   the trace sink without touching the fit;
-- ``_peak``     — the peak-FLOPs table (datasheet TPU peaks / measured
-  matmul fallback) the report's measured MFU and bench.py's analytic
-  MFU both divide by;
+- ``_peak``     — the peak-FLOPs table (published peaks by exact
+  ``device_kind``; an unknown device is an error) the report's measured
+  MFU and bench.py's analytic MFU both divide by;
 - ``export``    — span JSONL -> Chrome-trace/Perfetto JSON
   (``report ... --perfetto out.json``);
 - ``report``    — ``python -m dask_ml_tpu.observability.report
@@ -119,9 +119,7 @@ from ._metrics import (
     active_logger,
     emit_jit_step,
     fit_logger,
-    jit_callbacks_supported,
     profile_trace,
-    reset_jit_callbacks_probe,
     start_profiler_server,
     timed,
 )
@@ -225,7 +223,6 @@ __all__ = [
     "fit_logger",
     "install_recompile_tracking",
     "incidents_data",
-    "jit_callbacks_supported",
     "load_bundles",
     "load_capture",
     "log_counters",
@@ -263,7 +260,6 @@ __all__ = [
     "record_superblock_donation",
     "record_transfer",
     "record_zero_copy",
-    "reset_jit_callbacks_probe",
     "span",
     "start_profiler_server",
     "stop_engine",

@@ -1,69 +1,50 @@
 """Peak-FLOPs table: the ONE denominator every MFU number divides by.
 
-Grown out of ``bench.py`` (which now imports it) so the report CLI's
-measured per-span MFU and the benchmark's analytic MFU are computed
-against the same peak: datasheet bf16 matmul peaks for known TPU
-generations, a measured large-matmul peak everywhere else (the only
-honest option on CPU fallback).
+Published per-chip peaks keyed by the exact ``device_kind`` string jax
+reports. A device that is not in the table is an error, never a default
+and never a number measured on the spot: a "peak" timed with a matmul on
+whatever backend happens to be active (XLA:CPU included) would put a
+host figure under a device metric's name. Off-chip callers report
+"not measured".
 """
 
 from __future__ import annotations
 
-import time
-
-# bf16 datasheet peaks per chip (TFLOP/s) by device_kind substring. The
-# MXU runs f32-input matmuls at bf16-pass rate under default precision,
-# so the bf16 peak is the honest denominator for BOTH dtypes (using it
-# for f32 yields a conservative MFU, never an inflated one).
-DATASHEET_PEAKS = {
-    "v6": 918e12,       # Trillium / v6e
-    "v5p": 459e12,
-    "v5 lite": 197e12,  # v5e reports device_kind "TPU v5 lite"
-    "v5e": 197e12,
-    "v4": 275e12,
-    "v3": 123e12,
-    "v2": 45e12,
+# device_kind -> (bf16 matmul FLOP/s per chip, source). The MXU runs
+# f32-input matmuls at bf16-pass rate under default precision, so the
+# bf16 peak is the denominator for BOTH dtypes (using it for f32 yields
+# a conservative MFU, never an inflated one).
+PEAKS = {
+    "TPU v5 lite": (
+        197e12,
+        "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16 per chip",
+    ),
 }
 
-_cached_peak = None
+
+class UnknownDeviceError(LookupError):
+    """``device_kind`` has no row in :data:`PEAKS`."""
 
 
-def resolve_peak(matmul_dim=None, use_cache=True) -> dict:
-    """Per-chip peak matmul FLOP/s: datasheet when the device_kind is
-    known, else MEASURED with a large square matmul. Returns
-    ``{"flops", "source", "device_kind"}``. The measured path is cached
-    per process (it burns a few GFLOPs); pass ``use_cache=False`` to
-    re-measure."""
-    global _cached_peak
-    if use_cache and matmul_dim is None and _cached_peak is not None:
-        return dict(_cached_peak)
+def peak_for(device_kind: str) -> dict:
+    """``{"flops", "source", "device_kind"}`` for one exact
+    ``device_kind``; raises :class:`UnknownDeviceError` otherwise."""
+    try:
+        flops, source = PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peak for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}. Add a row with its source to "
+            "observability/_peak.py::PEAKS — a peak is never estimated"
+        ) from None
+    return {"flops": flops, "source": source, "device_kind": device_kind}
+
+
+def resolve_peak() -> dict:
+    """The peak of the device jax runs on (``jax.devices()[0]``)."""
     import jax
 
-    backend = jax.default_backend()
-    kind = getattr(jax.devices()[0], "device_kind", backend) or backend
-    if backend == "tpu":
-        for sub, peak in DATASHEET_PEAKS.items():
-            if sub in kind.lower():
-                out = {"flops": peak, "source": "datasheet",
-                       "device_kind": kind}
-                _cached_peak = dict(out)
-                return out
-    import jax.numpy as jnp
-
-    m = matmul_dim or (4096 if backend == "tpu" else 1024)
-    a = jnp.ones((m, m), jnp.bfloat16 if backend == "tpu" else jnp.float32)
-    f = jax.jit(lambda x: x @ x)
-    jax.block_until_ready(f(a))  # compile
-    reps = 3
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        a = jax.block_until_ready(f(a))
-    dt = time.perf_counter() - t0
-    out = {"flops": 2.0 * m ** 3 * reps / dt, "source": "measured",
-           "device_kind": kind}
-    if matmul_dim is None:
-        _cached_peak = dict(out)
-    return out
+    return peak_for(jax.devices()[0].device_kind)
 
 
 def mfu_fields(model_flops, elapsed, n_chips, peak) -> dict:

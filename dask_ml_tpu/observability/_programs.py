@@ -168,10 +168,7 @@ def _shape_key(args, kwargs):
 
 
 def _cost_dict(compiled):
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-        ca = ca[0] if ca else {}
-    return ca or {}
+    return compiled.cost_analysis() or {}
 
 
 def _analyze(name: str, fn, args, kwargs, skey=None, by_shape=None) -> None:
@@ -299,8 +296,8 @@ def track_program(name: str):
 
 def log_programs(logger, peak=True, **extra) -> list[dict]:
     """Emit one JSONL record holding the program registry snapshot (plus
-    the resolved peak-FLOPs table when ``peak``, so an offline report can
-    compute MFU); returns the snapshot. The report CLI reads the LAST
+    the device's published peak when ``peak`` and the device has one, so
+    an offline report can compute MFU); returns the snapshot. The report CLI reads the LAST
     such record as the run's programs table."""
     snap = programs_snapshot()
     if logger is None:
@@ -317,19 +314,20 @@ def log_programs(logger, peak=True, **extra) -> list[dict]:
     if plrows:
         rec["plans"] = plrows
     if peak:
-        try:
-            import jax
+        import jax
 
-            from ._peak import resolve_peak
+        from ._peak import PEAKS, peak_for
 
-            pk = resolve_peak()
+        # a device without a published peak (any CPU run) records none:
+        # the report then skips its MFU columns ("not measured")
+        kind = jax.devices()[0].device_kind
+        if kind in PEAKS:
+            pk = peak_for(kind)
             rec.update(
                 peak_flop_per_s_per_chip=pk["flops"],
                 peak_source=pk["source"],
-                device_kind=pk["device_kind"],
+                device_kind=kind,
                 n_chips=len(jax.local_devices()),
             )
-        except Exception:
-            pass  # no peak: the report skips MFU columns
     logger.log(**rec, **extra)
     return snap

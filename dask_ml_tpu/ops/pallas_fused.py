@@ -198,16 +198,26 @@ def _tile_mask(x, nv_ref, i, tile):
     return (row_ids < nv_ref[0, 0]).astype(jnp.float32)  # (tile, 1)
 
 
+def _row_dot(x, b):
+    """(tile, d) x (1, d) -> (tile, 1) matvec, f32 accumulation, with
+    ``b`` rounded to x's dtype first (solvers._smooth_loss's contract:
+    a bf16 design sees a bf16 beta, so the Pallas and XLA etas agree).
+
+    Written as a VPU multiply + lane reduction, not ``dot_general``:
+    jax 0.9's Mosaic lowering special-cases exactly this shape (one rhs
+    row) into the same multiply-reduce, and for a non-f32 operand emits
+    an ill-typed ``vector.broadcast`` (bf16 source, f32 result) that
+    fails verification. v5e has no bf16 VPU, so the f32 upcast is what
+    the hardware does either way."""
+    bx = b.astype(x.dtype).astype(jnp.float32)
+    return jnp.sum(x.astype(jnp.float32) * bx, axis=1, keepdims=True)
+
+
 def _glm_eta_terms(x, yv, b, family):
-    """eta (matvec at x's dtype so bf16 rides the MXU at bf16 rate, f32
-    accum — solvers._smooth_loss's contract) plus the family's pointwise
-    NLL / residual. Family formulas come from
-    models/solvers/families.py — pure jnp ops that lower inside the
-    kernel, so the Pallas and XLA losses cannot diverge."""
-    eta = jax.lax.dot_general(
-        x, b.astype(x.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                   # (tile, 1)
+    """eta plus the family's pointwise NLL / residual. Family formulas
+    come from models/solvers/families.py — pure jnp ops that lower
+    inside the kernel, so the Pallas and XLA losses cannot diverge."""
+    eta = _row_dot(x, b)                # (tile, 1)
     from ..models.solvers.families import get_family
 
     fam = get_family(family)
@@ -624,13 +634,12 @@ def _sgd_grad_kernel(x_ref, y_ref, nv_ref, w_ref, b0_ref, loss_ref,
     x = x_ref[:]                        # (tile, d) f32
     yv = y_ref[:]                       # (tile, 1) f32
     w = w_ref[:]                        # (1, d) f32 coef row
-    b0 = b0_ref[:]                      # (1, 1) intercept*iflag
     m = _tile_mask(x, nv_ref, i, tile)
     xd = _mxu_cast(x, mxu)
-    eta = jax.lax.dot_general(
-        xd, w.astype(xd.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) + b0                              # (tile, 1)
+    # the (1, 1) intercept*iflag operand is read as a SCALAR: a (1, 1)
+    # vector added to a (tile, 1) one is a lane broadcast Mosaic does
+    # not implement
+    eta = _row_dot(xd, w) + b0_ref[0, 0]    # (tile, 1)
     per, resid = sgd_objective_terms(eta, yv, loss)
     rm = resid * m
 
@@ -701,13 +710,9 @@ def _glm_stream_kernel(x_ref, y_ref, nv_ref, b_ref, b0_ref, *outs,
     x = x_ref[:]                        # (tile, d)
     yv = y_ref[:]                       # (tile, 1)
     b = b_ref[:]                        # (1, d)
-    b0 = b0_ref[:]                      # (1, 1)
     m = _tile_mask(x, nv_ref, i, tile)
     xd = _mxu_cast(x, mxu)
-    eta = jax.lax.dot_general(
-        xd, b.astype(xd.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) + b0
+    eta = _row_dot(xd, b) + b0_ref[0, 0]    # scalar read: see _sgd_grad_kernel
     from ..models.solvers.families import get_family
 
     fam = get_family(family)
@@ -921,14 +926,18 @@ def _sgd_many_grad_kernel(x_ref, y_ref, nv_ref, w_ref, b0_ref, loss_ref,
     x = x_ref[:]                        # (tile, d)
     yv = y_ref[:]                       # (tile, 1) targets or codes
     W = w_ref[:]                        # (N, d) coef rows
-    b0 = b0_ref[:]                      # (1, N) intercept*iflag per row
     N = W.shape[0]
     m = _tile_mask(x, nv_ref, i, tile)
     xd = _mxu_cast(x, mxu)
-    eta = jax.lax.dot_general(
-        xd, W.astype(xd.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) + b0                              # (tile, N)
+    if N == 1:
+        # a cohort rung of ONE slot is the single-row matvec shape:
+        # same two Mosaic corners as _sgd_grad_kernel (see _row_dot)
+        eta = _row_dot(xd, W) + b0_ref[0, 0]
+    else:
+        eta = jax.lax.dot_general(
+            xd, W.astype(xd.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + b0_ref[:]                   # (tile, N) + (1, N) intercepts
     if codes:
         iota = jax.lax.broadcasted_iota(
             jnp.int32, (x.shape[0], N), 1

@@ -66,34 +66,30 @@ def device_mesh(shape=None, axis_names=(DATA_AXIS,), devices=None,
     if topology_order is None:
         topology_order = not explicit
     if topology_order and devices.flat[0].platform == "tpu":
-        arranged = _topology_mesh(shape, list(devices.flat))
-        if arranged is not None:
-            return Mesh(arranged, axis_names)
+        return Mesh(_topology_mesh(shape, list(devices.flat)), axis_names)
     return Mesh(devices.reshape(shape), axis_names)
 
 
 def _topology_mesh(shape, devices):
-    """TPU device array in torus-aware order, or None when the topology
-    helpers decline (odd shapes, unsupported slice forms) — the caller
-    then falls back to enumeration order."""
-    try:
-        from jax.experimental import mesh_utils
+    """TPU device array in torus-aware order. A shape the topology
+    helpers refuse raises — a mesh silently built in enumeration order
+    would put ICI non-neighbours next to each other and only show up as
+    slow collectives."""
+    from jax.experimental import mesh_utils
 
-        n_procs = len({d.process_index for d in devices})
-        if n_procs > 1 and len(devices) % n_procs == 0:
-            if shape[0] % n_procs == 0:
-                # DCN outer on the (leading) data axis, ICI inner
-                ici = (shape[0] // n_procs,) + tuple(shape[1:])
-                dcn = (n_procs,) + (1,) * (len(shape) - 1)
-                # granule = process (we factor by process count), not the
-                # default slice granule — a multi-host single slice would
-                # otherwise mismatch dcn and raise
-                return mesh_utils.create_hybrid_device_mesh(
-                    ici, dcn, devices=devices, process_is_granule=True
-                )
-        return mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        return None
+    n_procs = len({d.process_index for d in devices})
+    if n_procs > 1 and len(devices) % n_procs == 0:
+        if shape[0] % n_procs == 0:
+            # DCN outer on the (leading) data axis, ICI inner
+            ici = (shape[0] // n_procs,) + tuple(shape[1:])
+            dcn = (n_procs,) + (1,) * (len(shape) - 1)
+            # granule = process (we factor by process count), not the
+            # default slice granule — a multi-host single slice would
+            # otherwise mismatch dcn and raise
+            return mesh_utils.create_hybrid_device_mesh(
+                ici, dcn, devices=devices, process_is_granule=True
+            )
+    return mesh_utils.create_device_mesh(shape, devices=devices)
 
 
 def default_mesh() -> Mesh:

@@ -453,6 +453,14 @@ class BlockStream:
             4 * int(np.prod(a.shape[1:], dtype=np.int64) or 1)
             for a in self.arrays
         )
+        # ... and of the X position alone: the unit BOTH auto budgets
+        # count in. ``stream_plan`` sizes a block as _AUTO_BLOCK_BYTES of
+        # X, so a super-block budget that also counted y could never
+        # hold two of them (512 MiB // (256 MiB + 4 B/row) == 1): at the
+        # auto block size super-blocks — and with them the fused
+        # kernels — silently never engaged (first seen on the v5e, PR 21)
+        self._x_row_bytes = 4 * int(np.prod(
+            self.arrays[0].shape[1:], dtype=np.int64) or 1)
         if block_rows is None:
             block_rows = min(auto_block_rows(n, self._row_bytes), n)
         if prefetch is None:
@@ -553,7 +561,7 @@ class BlockStream:
                 self.sparse_reason = plan.reason
                 if plan.engaged:
                     self.sparse_plan = plan
-        from ..config import ensure_compile_cache, get_config
+        from ..config import get_config
         from ..observability.live import ensure_telemetry
 
         # reliability plane (ISSUE 11), captured once like _zero_copy:
@@ -637,62 +645,71 @@ class BlockStream:
             int(np.ceil(self.n_rows / budget_rows)), 1
         )
 
-        # streamed fits are the repeated-warmup-compile hot spot the
-        # persistent compile cache exists for; apply the knob (no-op
-        # when config.compile_cache_dir is unset)
-        ensure_compile_cache()
-        # ... and the long-running workload the live exporter exists
-        # for: arm /metrics//status (no-op when obs_http_port is 0)
+        # streamed fits are the long-running workload the live exporter
+        # exists for: arm /metrics//status (no-op when obs_http_port
+        # is 0)
         ensure_telemetry()
 
-    def _verify_native(self):
-        """Which arrays the C++ readahead reader can serve, verified by
-        comparing its block 0 against the numpy slice — catches sliced /
-        re-offset memmap views whose .offset no longer describes them."""
-        from ..io.native import NativeBlockReader, load_block_reader
+    def _native_plan(self):
+        """(per-array ok flags, reason) — which arrays the C++ readahead
+        reader can serve, and when none can, why (recorded in the pass
+        stats as ``native_reader_reason``). An eligible memmap is
+        verified by comparing the reader's block 0 against the numpy
+        slice, which catches sliced / re-offset memmap views whose
+        ``.offset`` no longer describes them; a reader that fails to
+        build, open or read raises."""
+        from ..io.native import NativeBlockReader, native_available
 
+        eligible = [
+            type(a) is np.memmap and a.flags["C_CONTIGUOUS"]
+            and getattr(a, "filename", None) is not None
+            for a in self.arrays
+        ]
+        if not any(eligible):
+            return [False] * len(self.arrays), "not-a-memmap"
+        if not native_available():
+            return [False] * len(self.arrays), "no-c++-compiler"
         oks = []
-        for a in self.arrays:
+        for a, el in zip(self.arrays, eligible):
             ok = False
-            if (type(a) is np.memmap and a.flags["C_CONTIGUOUS"]
-                    and getattr(a, "filename", None) is not None
-                    and load_block_reader() is not None):
+            if el:
+                # the offset/contiguity property is independent of
+                # block size: verify with a SMALL block instead of
+                # double-reading a full (possibly 256 MB) one.
+                # equal_nan: datasets with missing values must not
+                # lose the readahead path
+                vb = min(self.block_rows, len(a), 4096)
+                r = NativeBlockReader(a, vb)
                 try:
-                    # the offset/contiguity property is independent of
-                    # block size: verify with a SMALL block instead of
-                    # double-reading a full (possibly 256 MB) one.
-                    # equal_nan: datasets with missing values must not
-                    # silently lose the readahead path
-                    vb = min(self.block_rows, len(a), 4096)
-                    r = NativeBlockReader(a, vb)
                     blk = r.next()
                     ok = blk is not None and np.array_equal(
                         blk, np.asarray(a[: len(blk)]),
                         equal_nan=np.issubdtype(a.dtype, np.floating),
                     )
+                finally:
                     r.close()
-                except Exception:
-                    ok = False
             oks.append(ok)
-        return oks
+        return oks, None if any(oks) else "memmap-view-offset"
 
-    def _native_readers(self):
-        """Per-array readahead readers for a SEQUENTIAL pass (None where
-        inapplicable); the reader thread pread()s blocks ahead of the
-        consumer, overlapping disk latency with device transfer/compute
+    def _native_readers(self, sequential=True):
+        """(per-array readahead readers, reason): readers for a
+        SEQUENTIAL pass (None entries where inapplicable), or
+        ``(None, why)`` when no array takes the native path. The reader
+        thread pread()s blocks ahead of the consumer, overlapping disk
+        latency with device transfer/compute
         (native/block_reader.cpp)."""
-        if self.shuffle:
-            return None
+        if not sequential:
+            return None, "non-sequential-order"
         if getattr(self, "_native_ok", None) is None:
-            self._native_ok = self._verify_native()
+            self._native_ok, self._native_reason = self._native_plan()
         if not any(self._native_ok):
-            return None
+            return None, self._native_reason
         from ..io.native import NativeBlockReader
 
         return [
             NativeBlockReader(a, self.block_rows) if ok else None
             for ok, a in zip(self._native_ok, self.arrays)
-        ]
+        ], None
 
     def _profile_fold(self, blk, strided=False) -> None:
         """Fold one host X slab (valid rows only, pre-padding) into the
@@ -779,18 +796,21 @@ class BlockStream:
                 and a.ctypes.data % _ZC_ALIGN == 0
                 and (self.block_rows * a.strides[0]) % _ZC_ALIGN == 0)
 
-    def _gate_readers_for_zero_copy(self, readers):
+    def _gate_readers_for_zero_copy(self, readers, reason):
         """Null out (and close) readahead readers for arrays whose full
         blocks are GUARANTEED to stage as zero-copy aliases — the view
         path then pays neither the reader's copy-out nor a
-        device_put. Arrays without the guarantee keep their reader."""
+        device_put. Arrays without the guarantee keep their reader.
+        Returns (readers, reason) like :meth:`_native_readers`."""
         if readers is None or not self._zero_copy:
-            return readers
+            return readers, reason
         for i, (r, a) in enumerate(zip(readers, self.arrays)):
             if r is not None and self._zc_block_guarantee(a):
                 r.close()
                 readers[i] = None
-        return readers if any(r is not None for r in readers) else None
+        if any(r is not None for r in readers):
+            return readers, None
+        return None, "zero-copy-views"
 
     @staticmethod
     def _disable_reader(readers, i):
@@ -958,13 +978,9 @@ class BlockStream:
         order = np.arange(self.n_blocks)
         if self.shuffle:
             self.rng.shuffle(order)
-        readers = None
-        if not self.shuffle:
-            try:
-                readers = self._native_readers()
-            except Exception:
-                readers = None
-        readers = self._gate_readers_for_zero_copy(readers)
+        readers, why = self._gate_readers_for_zero_copy(
+            *self._native_readers(sequential=not self.shuffle)
+        )
         # per-pass overlap accounting (SURVEY §7 B0: the double buffer is
         # the heart of the system — measure it, don't assume it):
         #   host_s   — disk/densify/pad time building host blocks
@@ -974,7 +990,9 @@ class BlockStream:
         #   consume_s— time the consumer held each block (its compute)
         stats = {"host_s": 0.0, "put_s": 0.0, "wait_s": 0.0,
                  "consume_s": 0.0, "n_blocks": int(self.n_blocks),
-                 "block_rows": int(self.block_rows)}
+                 "block_rows": int(self.block_rows),
+                 "native_reader": readers is not None,
+                 "native_reader_reason": why}
         t_pass = _time.perf_counter()
         # k-deep prefetch: device_put is async, so issuing the next k
         # transfers before consuming the current block overlaps DMA with
@@ -1148,7 +1166,7 @@ class BlockStream:
         k = self._superblock_k_override or int(cfg.superblock_k)
         if k <= 0:
             k = _AUTO_SUPERBLOCK_K
-        block_bytes = max(self.block_rows * self._row_bytes, 1)
+        block_bytes = max(self.block_rows * self._x_row_bytes, 1)
         budget_k = max(_SUPERBLOCK_BYTES // block_bytes, 1)
         return int(max(min(k, self.n_blocks, budget_k), 1))
 
@@ -1349,12 +1367,9 @@ class BlockStream:
             len(order) == self.n_blocks
             and np.array_equal(order, np.arange(self.n_blocks))
         )
-        readers = None
-        if sequential:
-            try:
-                readers = self._native_readers()
-            except Exception:
-                readers = None
+        readers, why = self._gate_readers_for_zero_copy(
+            *self._native_readers(sequential=sequential)
+        )
         ring = self._sb_ring(k)
         unroll = superblock_unrolled()
         D = self.sb_data_shards()
@@ -1369,15 +1384,19 @@ class BlockStream:
                  # pass-span mesh tag: the 2-D shape the report CLI /
                  # /status render as "DxM"
                  "mesh": mesh_str(self.mesh),
-                 "dispatches_per_pass": int(n_sb)}
+                 "dispatches_per_pass": int(n_sb),
+                 # which layout the consumers' scans ran (stacked
+                 # lax.scan vs the CPU K-tuple chain) and whether the
+                 # C++ readahead reader fed the pass, else why not
+                 "layout": "unrolled" if unroll else "stacked",
+                 "native_reader": readers is not None,
+                 "native_reader_reason": why}
         t_pass = _time.perf_counter()
         from collections import deque
 
         pending = deque()
 
         view_ok = self._view_ok
-
-        readers = self._gate_readers_for_zero_copy(readers)
 
         def fill(slot, blocks):
             """Assemble ``blocks`` (block indices) into host parts:
@@ -1687,7 +1706,7 @@ class BlockStream:
         if not self._pass_data_bound(st):
             return
         k = int(st["superblock_k"])
-        block_bytes = max(self.block_rows * self._row_bytes, 1)
+        block_bytes = max(self.block_rows * self._x_row_bytes, 1)
         cap = int(max(min(self.n_blocks,
                           _SUPERBLOCK_BYTES // block_bytes), 1))
         new_k = min(k * 2, cap)

@@ -15,8 +15,7 @@ construct their compiled specializations through here:
 - :mod:`~dask_ml_tpu.plans.plan` — :class:`ProgramPlan`, the
   declarative spec whose :meth:`~ProgramPlan.build` is the one path to
   a tracked jitted entry point (cache keying, ``track_program``
-  registration, donation wiring, ``config.compile_cache_dir`` arming),
-  plus :func:`tracked` for pre-jitted scan builders;
+  registration, donation wiring), plus :func:`tracked` for pre-jitted scan builders;
 - :mod:`~dask_ml_tpu.plans.warmup` — the process-wide
   :data:`warmups` registry: idempotent, attributable
   (``plan_warmups``/``plan_cache_hits`` counters, the ``plans`` table

@@ -1,22 +1,19 @@
 """ProgramPlan: the one build path for every compiled specialization.
 
-Every jitted hot path in this repo used to hand-assemble the same four
+Every jitted hot path in this repo used to hand-assemble the same
 things at its own call site: ``jax.jit`` flags (donation, statics),
-``track_program`` registration, ``config.compile_cache_dir`` arming, and
-some ad-hoc warmup bookkeeping. A :class:`ProgramPlan` is the
-declarative spec — callable body, donation slots, static axes, a cache
+``track_program`` registration, and some ad-hoc warmup bookkeeping. A
+:class:`ProgramPlan` is the declarative spec — callable body, donation slots, static axes, a cache
 key carrying everything the traced program's identity depends on (mesh,
 dtype/mxu, parameter shapes, ladder rung), a program name and a ladder
 reference — and :meth:`ProgramPlan.build` is the ONE path that turns it
 into a tracked jitted entry point:
 
-1. ``config.compile_cache_dir`` is armed (idempotent, no-op when
-   unset) so every plan-built program lands in jax's persistent cache;
-2. the process-wide build cache is consulted (``config.plan_cache``):
+1. the process-wide build cache is consulted (``config.plan_cache``):
    two builds of an identical spec return the SAME tracked callable,
    so the second client's warmup hits warm jit caches instead of
    re-tracing — counted as ``plan_cache_hits``;
-3. on a miss the body is jitted with exactly the declared donation /
+2. on a miss the body is jitted with exactly the declared donation /
    static flags and wrapped in ``track_program`` — the jaxpr is
    byte-identical to a hand-assembled
    ``track_program(name)(jax.jit(body, ...))`` because it IS that
@@ -191,9 +188,8 @@ class ProgramPlan:
     def build(self):
         """The tracked jitted entry point for this plan — see the
         module docstring for the one-path contract."""
-        from ..config import ensure_compile_cache, get_config
+        from ..config import get_config
 
-        ensure_compile_cache()
         ck = self.cache_key()
         use_cache = bool(get_config().plan_cache) and ck is not None
         if use_cache:

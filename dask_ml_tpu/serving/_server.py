@@ -531,14 +531,11 @@ class ModelServer:
         and the plans table on ``/status`` / in the report CLI shows
         which ladder rung minted each specialization.
 
-        With ``config.compile_cache_dir`` set, these compiles also land
-        in jax's persistent compilation cache: warmup still walks the
-        full (method, bucket) grid, but a later process serving the same
+        These compiles also land in jax's persistent compilation cache
+        (``config.ensure_compile_cache``): warmup still walks the full
+        (method, bucket) grid, but a later process serving the same
         model shapes replays each program from disk instead of paying
         XLA again — cold-start warmup cost becomes mostly cache reads."""
-        from ..config import ensure_compile_cache
-
-        ensure_compile_cache()
         # every pre-built flavor warms (config.serving_warm_flavors):
         # a later f32 <-> int8 flavor swap then hits only warm caches
         for fns in self._flavor_fns.values():
@@ -613,10 +610,8 @@ class ModelServer:
         this, sparse traffic whose batches stay on the grid mints zero
         new XLA compiles; over-top-nnz batches spill to the
         (dense-warmed) densify path."""
-        from ..config import ensure_compile_cache
         from ..plans import warmups
 
-        ensure_compile_cache()
         for fn in self._sparse_fns.values():
             top = fn.nnz_ladder.max_rows if max_nnz is None \
                 else fn.nnz_bucket(min(max_nnz, fn.nnz_ladder.max_rows))

@@ -11,7 +11,7 @@ signal and moves replica count under hysteresis bands:
   ``serving_slo_ms``) for ``patience`` consecutive ticks ADDS a
   replica: built via the fleet's own ``_make_replica`` (identical
   config, device round-robin), warmed OFF the serving path — with the
-  plans plane armed (``plan_cache`` + ``compile_cache_dir``, PR 15) the
+  plan build cache and the persistent compile cache warm (PR 15) the
   warmup replays cached executables and spin-up is near-instant, zero
   fresh XLA compiles — then installed into the routing tuple under the
   fleet lock;

@@ -460,8 +460,8 @@ def _tracked_jit(est, method, core, donate, flavor=None, sig=None):
     """Build a serving core's tracked jitted entry point through the
     plan layer (``plans.ProgramPlan`` — ISSUE 15): cache keying,
     ``track_program`` registration as
-    ``serving.<Estimator>.<method>[.<flavor>]``, donation wiring and
-    ``compile_cache_dir`` arming all happen there. ``sig`` (the swap
+    ``serving.<Estimator>.<method>[.<flavor>]`` and donation wiring
+    all happen there. ``sig`` (the swap
     contract's structural signature) is the plan cache key: two builds
     over same-shaped fitted params return the SAME entry point, so a
     second server's warmup hits warm jit caches instead of re-tracing

@@ -4,7 +4,7 @@ scrape the plans table.
 The ``dask_ml_tpu/plans`` subsystem is the ONE layer every compiled
 specialization goes through — shape ladders (serving rows / sparse nnz
 / cohort slots), ``ProgramPlan.build()`` (cache keying, track_program
-registration, donation wiring, compile_cache_dir arming) and the
+registration, donation wiring) and the
 process-wide ``WarmupRegistry``. This example walks the whole loop on
 the newest plan client, GaussianNB:
 

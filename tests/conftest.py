@@ -7,12 +7,57 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# hermetic compiles: tests assert on compile counts, which must not
+# depend on what an earlier run left in <checkout>/.jax_cache (the
+# package places jax's persistent cache there at import). Set in the
+# environment so spawned worker processes inherit it.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+
 from dask_ml_tpu._platform import force_cpu_platform  # noqa: E402
 
 force_cpu_platform(n_devices=8)
 
+import gc  # noqa: E402
+
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+try:
+    with open("/proc/sys/vm/max_map_count") as _f:
+        _MAX_MAPPINGS = int(_f.read())
+except (OSError, ValueError):
+    _MAX_MAPPINGS = 65530  # the Linux default
+
+
+def _mappings():
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:  # not Linux: nothing to count, nothing to do
+        return 0
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_executables():
+    """XLA:CPU mmaps the code of every executable it compiles (~6
+    mappings each) and jax keeps them all alive in its jit caches. Run
+    as ONE process, the suite compiles ~10,000 programs and walks up to
+    the kernel's vm.max_map_count (65,530 here) about nine tenths of the
+    way through; the next mmap fails inside LLVM and the compile dies
+    with a segmentation fault, taking every later test with it. After
+    any module that leaves the process past half the limit, drop jax's
+    caches (the executables unmap; later modules recompile what they
+    need) — and the plan layer's build cache and warmup registry with
+    them, which would otherwise vouch for programs that are gone."""
+    yield
+    if _mappings() > _MAX_MAPPINGS // 2:
+        from dask_ml_tpu.plans import plans_reset
+
+        jax.clear_caches()
+        plans_reset()
+        gc.collect()
 
 
 @pytest.fixture(scope="session")

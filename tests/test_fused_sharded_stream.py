@@ -95,14 +95,23 @@ class TestFusedShardedGLM:
                             interpret=True, mesh=mesh)
         assert multi.program_name == "pallas.glm_vg_multi.psum"
 
-    def test_fused_fit_records_engagement_and_matches(self):
+    @pytest.mark.parametrize("sm", MESHES)
+    def test_fused_fit_records_engagement_and_matches(self, sm):
+        """A streamed fit with the kernels OFF equals the same fit with
+        them ON at every stream mesh width — the D-times-gradient
+        regression, stated once and directly. The XLA flavour autodiffs
+        the replicated beta inside shard_map and then psums; were the
+        body traced with check_vma=True, jax 0.9 would already have
+        psummed that gradient and the XLA fit's gradient would be sm
+        times the fused one's (right value, wrong line search, a coef_
+        that drifts apart with sm)."""
         n, d = 2300, 6
         X, y = _mk_xy(n, d)
         from dask_ml_tpu.linear_model import LogisticRegression
 
         fits = {}
         for interp in (False, True):
-            with config.set(stream_block_rows=1024,
+            with config.set(stream_block_rows=1024, stream_mesh=sm,
                             pallas_stream_interpret=interp):
                 fits[interp] = LogisticRegression(
                     solver="lbfgs", max_iter=15
@@ -110,7 +119,7 @@ class TestFusedShardedGLM:
         info = fits[True].solver_info_
         assert info["fused_stream"] is True
         assert info["fused_stream_reason"] is None
-        assert info["stream_shards"] == 8
+        assert info["stream_shards"] == sm
         assert fits[False].solver_info_["fused_stream"] is False
         assert fits[False].solver_info_["fused_stream_reason"] == "off-TPU"
         # per-PASS parity is 1e-6 (the objective test above); a full

@@ -84,17 +84,49 @@ def test_native_block_reader_matches_numpy(tmp_path):
 
     # BlockStream parity: native path (sequential) == numpy slicing
     stream = BlockStream((mm,), block_rows=96)
-    assert any(stream._verify_native())
+    assert stream._native_plan() == ([True], None)
     blocks = [np.asarray(b.arrays[0])[: b.n_rows] for b in stream]
     np.testing.assert_allclose(np.concatenate(blocks), X, rtol=1e-6)
+    assert stream.stats["native_reader"] is True
+    assert stream.stats["native_reader_reason"] is None
 
     # sliced memmap views (offset no longer authoritative) are detected
     # by the block-0 verification and fall back to numpy slicing
     view = mm[100:]
     s2 = BlockStream((view,), block_rows=96)
-    assert not any(s2._verify_native())
+    assert s2._native_plan() == ([False], "memmap-view-offset")
     blocks2 = [np.asarray(b.arrays[0])[: b.n_rows] for b in s2]
     np.testing.assert_allclose(np.concatenate(blocks2), X[100:], rtol=1e-6)
+    assert s2.stats["native_reader"] is False
+    assert s2.stats["native_reader_reason"] == "memmap-view-offset"
+
+
+def test_native_build_is_content_keyed_and_errors_surface(tmp_path,
+                                                          monkeypatch):
+    """A library loads only from the path keyed by its source's hash; a
+    source that does not compile raises every time it is asked for
+    (no latch onto a Python path), and nothing is left half-built."""
+    import os
+    import shutil
+
+    from dask_ml_tpu.io import native
+
+    if not native.native_available():
+        pytest.skip("native toolchain unavailable")
+    real = native._NATIVE_DIR
+    shutil.copy(os.path.join(real, "fast_loader.cpp"), tmp_path)
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_libs", {})
+    assert native.load_library() is not None
+    built = os.listdir(tmp_path / "_build")
+    assert len(built) == 1 and built[0].startswith("fast_loader-")
+
+    (tmp_path / "fast_loader.cpp").write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_libs", {})
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="building .*fast_loader"):
+            native.load_library()
+    assert os.listdir(tmp_path / "_build") == built
 
 
 @pytest.mark.slow

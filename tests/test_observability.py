@@ -521,21 +521,6 @@ def test_fleet_plane_adds_nothing_when_disabled():
         rtrace.traces_reset()
 
 
-def test_jit_callbacks_probe_resettable(monkeypatch):
-    from dask_ml_tpu.observability import _metrics
-
-    obs.reset_jit_callbacks_probe()
-    assert _metrics._callbacks_supported is None
-    first = obs.jit_callbacks_supported()
-    assert isinstance(first, bool)
-    assert _metrics._callbacks_supported == first
-    # a poisoned cache must be clearable (backend swaps in tests)
-    monkeypatch.setattr(_metrics, "_callbacks_supported", not first)
-    assert obs.jit_callbacks_supported() is (not first)
-    obs.reset_jit_callbacks_probe()
-    assert obs.jit_callbacks_supported() == first
-
-
 # -- back-compat shim -------------------------------------------------------
 
 def test_utils_observability_reexports_same_objects():

@@ -347,18 +347,19 @@ class TestStackedLayout:
         np.testing.assert_allclose(g_stacked, g_unrolled, atol=1e-6)
 
 
-def test_compile_cache_knob(tmp_path):
-    """config.compile_cache_dir routes jax's persistent compilation
-    cache; entries land on disk after a streamed fit warms up."""
-    import os
+def test_auto_sized_blocks_still_superblock():
+    """At the AUTO block size (256 MiB of X, what ``stream_plan`` gives a
+    memmap) the 512 MiB super-block budget must hold two blocks with y
+    riding along — both budgets count X bytes. Counting y in one of them
+    made K == 1 at exactly the sizes nobody sets ``stream_block_rows``
+    for: the deployment path ran per block, unfused (PR 21, on the v5e).
+    The arrays are stride-0 views: 1 GiB is never allocated."""
+    from dask_ml_tpu.parallel.streaming import auto_block_rows
 
-    from dask_ml_tpu.config import ensure_compile_cache
-    from dask_ml_tpu.models.sgd import SGDRegressor
-
-    d = str(tmp_path / "xla-cache")
-    X, y = _mk_xy(600)
-    with config.set(compile_cache_dir=d, stream_block_rows=96):
-        assert ensure_compile_cache() is True
-        SGDRegressor(max_iter=1, random_state=0).fit(X, y[: len(X)])
-    assert os.path.isdir(d)
-    assert os.listdir(d), "persistent cache wrote no entries"
+    n, d = 1 << 20, 256
+    X = np.broadcast_to(np.zeros((1, d), np.float32), (n, d))
+    y = np.broadcast_to(np.zeros((), np.float32), (n,))
+    stream = BlockStream((X, y), block_rows=auto_block_rows(n, 4 * d))
+    assert stream.block_rows == 262_144 and stream.n_blocks == 4
+    assert stream.resolve_superblock_k() == 2
+    assert stream.use_superblocks()
