@@ -1,0 +1,8 @@
+"""Inside the ``fit`` calls alone: 1 - union of device-op intervals / their
+summed length, worst chip, %."""
+from benchmark.metrics._lib import call_kind
+
+
+def read(ctx):
+    kind = call_kind(ctx, "bench.fit")
+    return None if kind is None else kind["idle_pct"]
