@@ -246,7 +246,10 @@ class Config:
     # measured MFU. Opt-in: the analysis pass re-lowers each program once
     # per fresh compile (an extra, in-memory-cached XLA compile that also
     # shows up in the recompiles counter), so steady-state zero-recompile
-    # contracts keep it off by default
+    # contracts keep it off by default. Also the flight recorder's switch
+    # for spans: on, every span records into the in-memory ring
+    # (observability.recent_spans()) and holds a `dmt.<name>`
+    # jax.profiler.TraceAnnotation open, with no sink configured
     obs_programs: bool = False
     # live telemetry exporter (observability/live.py): port for the
     # background HTTP daemon serving Prometheus /metrics, /healthz and

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import BaseEstimator, to_host
-from ..observability import span
+from ..observability import current_span, span
 from ..parallel.mesh import resolve_mesh
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_X_y, check_array, check_is_fitted
@@ -472,53 +472,69 @@ class _GLMBase(BaseEstimator):
         block_rows = stream_plan(X)
         if block_rows is not None:
             return self._fit_streamed(X, y, block_rows)
-        mesh = resolve_mesh(getattr(X, "mesh", None))
-        X, y = check_X_y(X, y, mesh=mesh, dtype=np.float32)
-        if self.penalty not in regularizers.KNOWN:
-            raise ValueError(f"Unknown penalty {self.penalty!r}")
-        # bf16 design matrix: the _smooth_loss matvec rides the MXU at
-        # bf16 rate with f32 accumulation; solver state / y / mask stay
-        # f32. Newton/ADMM are excluded — their Hessian matmuls would
-        # silently upcast (no speedup) and bf16 Hessians risk conditioning
-        from ..config import mxu_dtype
+        # the root span covers the whole resident call; its children
+        # (fit.validate, fit.prepare, fit.solve, fit.finish) are the
+        # phases, each ending where its host code ends
+        with span("fit", component=type(self).__name__,
+                  solver=self.solver) as root:
+            return self._fit_resident(X, y, root)
 
-        use_bf16 = mxu_dtype(self.fit_dtype) is not None and self.solver in (
-            "lbfgs", "gradient_descent", "proximal_grad"
-        )
-        # resolved precision on record: the auto policy's f32 fallback
-        # (off-TPU, or a solver whose Hessian math excludes bf16) must
-        # be visible, not silent
-        self.fit_dtype_ = "bfloat16" if use_bf16 else "float32"
-        mask = X.row_mask(dtype=jnp.float32)
-        data, y_data, packed = _prepare_fit(
-            X.data, y.data, mask, fit_intercept=self.fit_intercept,
-            to_bf16=use_bf16, encode=self.family == "logistic",
-        )
-        if self.family == "poisson":
-            _check_poisson_targets(
-                float(jnp.min(jnp.where(mask > 0, y_data, jnp.inf)))
+    def _fit_resident(self, X, y, root):
+        with span("fit.validate"):
+            mesh = resolve_mesh(getattr(X, "mesh", None))
+            X, y = check_X_y(X, y, mesh=mesh, dtype=np.float32)
+            if self.penalty not in regularizers.KNOWN:
+                raise ValueError(f"Unknown penalty {self.penalty!r}")
+            # bf16 design matrix: the _smooth_loss matvec rides the MXU
+            # at bf16 rate with f32 accumulation; solver state / y / mask
+            # stay f32. Newton/ADMM are excluded — their Hessian matmuls
+            # would silently upcast (no speedup) and bf16 Hessians risk
+            # conditioning
+            from ..config import mxu_dtype
+
+            use_bf16 = mxu_dtype(self.fit_dtype) is not None \
+                and self.solver in ("lbfgs", "gradient_descent",
+                                    "proximal_grad")
+            # resolved precision on record: the auto policy's f32
+            # fallback (off-TPU, or a solver whose Hessian math excludes
+            # bf16) must be visible, not silent
+            self.fit_dtype_ = "bfloat16" if use_bf16 else "float32"
+            mask = X.row_mask(dtype=jnp.float32)
+        root.add(n_rows=X.n_rows)
+        with span("fit.prepare") as sp:
+            data, y_data, packed = _prepare_fit(
+                X.data, y.data, mask, fit_intercept=self.fit_intercept,
+                to_bf16=use_bf16, encode=self.family == "logistic",
             )
-        classes = None
-        if self.family == "logistic":
-            pk = np.asarray(packed)  # one small fetch: (mn, mx, binary)
-            if not bool(pk[2]) or pk[0] == pk[1]:
-                # >2 (or 1) classes: the one-vs-rest path (vmapped
-                # multi-target solve; beyond the reference's binary-only
-                # dask-glm logistic family)
-                return self._fit_multiclass(X, y, data, mask)
-            classes = np.asarray(pk[:2])
-            self.classes_ = classes
-        d = data.shape[1]
-        pmask, lam = self._penalty_setup(d, X.n_rows)
-        beta0 = jnp.asarray(self._warm_beta0(d, np))
-        kwargs = dict(self.solver_kwargs or {})
-        l1_ratio = kwargs.pop("l1_ratio", 0.5)
+            if self.family == "poisson":
+                _check_poisson_targets(
+                    float(jnp.min(jnp.where(mask > 0, y_data, jnp.inf)))
+                )
+            classes = None
+            multiclass = False
+            if self.family == "logistic":
+                # one small fetch: (mn, mx, binary) — where the host
+                # waits for the prep pass
+                pk = np.asarray(sp.sync(packed))
+                multiclass = not bool(pk[2]) or pk[0] == pk[1]
+                if not multiclass:
+                    classes = np.asarray(pk[:2])
+                    self.classes_ = classes
+        if multiclass:
+            # >2 (or 1) classes: the one-vs-rest path (vmapped
+            # multi-target solve; beyond the reference's binary-only
+            # dask-glm logistic family)
+            return self._fit_multiclass(X, y, data, mask, root)
         from ..observability import active_logger, fit_logger
 
-        with span("fit", component=type(self).__name__, solver=self.solver,
-                  n_rows=X.n_rows) as sp, \
+        with span("fit.solve") as sp, \
                 fit_logger(type(self).__name__, solver=self.solver,
                            n_rows=X.n_rows) as logger, active_logger(logger):
+            d = data.shape[1]
+            pmask, lam = self._penalty_setup(d, X.n_rows)
+            beta0 = jnp.asarray(self._warm_beta0(d, np))
+            kwargs = dict(self.solver_kwargs or {})
+            l1_ratio = kwargs.pop("l1_ratio", 0.5)
             log_steps = logger is not None
             beta, info = solve(
                 self.solver,
@@ -529,12 +545,15 @@ class _GLMBase(BaseEstimator):
                 max_iter=self.max_iter, tol=self.tol, mesh=mesh,
                 log=log_steps, **kwargs,
             )
-            sp.add(n_iter=info.get("n_iter"))
+            sp.add(**{k: info[k] for k in ("n_iter", "n_evals", "fused")
+                      if k in info})
             if logger is not None and not log_steps:
                 logger.log(step=info.get("n_iter"), summary=True,
                            **{k: v for k, v in info.items()
                               if isinstance(v, (int, float))})
-        return self._finish_fit(to_host(beta), classes, info, X.shape[1])
+        root.add(n_iter=info.get("n_iter"))
+        with span("fit.finish"):
+            return self._finish_fit(to_host(beta), classes, info, X.shape[1])
 
     def _coef_flat(self):
         return np.ravel(self.coef_)
@@ -561,7 +580,9 @@ class _GLMBase(BaseEstimator):
                 X, block_rows, lambda blk: blk.arrays[0] @ coef + b0
             )
         X, eta = self._decision(X)
-        return to_host(eta)[: X.n_rows]
+        # the fetch is where the host waits for the matvec: charged to
+        # the open span (predict.decision) as sync_s
+        return to_host(current_span().sync(eta))[: X.n_rows]
 
     def _decision(self, X):
         X = check_array(X, dtype=np.float32)
@@ -614,7 +635,7 @@ class LogisticRegression(_GLMBase):
 
     family = "logistic"
 
-    def _fit_multiclass(self, X, y, data, mask):
+    def _fit_multiclass(self, X, y, data, mask, root):
         self._check_multi_class()
         classes = np.unique(y.to_numpy())
         if len(classes) < 2:
@@ -633,8 +654,8 @@ class LogisticRegression(_GLMBase):
         B0 = jnp.asarray(self._warm_B0(C, d))
         kwargs = dict(self.solver_kwargs or {})
         l1_ratio = kwargs.pop("l1_ratio", 0.5)
-        with span("fit", component=type(self).__name__, solver=self.solver,
-                  n_rows=X.n_rows, n_classes=C) as sp, \
+        root.add(n_classes=C)
+        with span("fit.solve") as sp, \
                 fit_logger(type(self).__name__, solver=self.solver,
                            n_rows=X.n_rows, n_classes=C) as logger:
             beta, info = solve_multi(
@@ -645,6 +666,7 @@ class LogisticRegression(_GLMBase):
                 mesh=X.mesh, **kwargs,
             )
             sp.add(n_iter=info.get("n_iter"))
+            root.add(n_iter=info.get("n_iter"))
             if logger is not None:
                 logger.log(step=info.get("n_iter"), summary=True,
                            **{k: v for k, v in info.items()
@@ -777,13 +799,18 @@ class LogisticRegression(_GLMBase):
         from scipy.special import expit
 
         check_is_fitted(self, "coef_")
-        if self._is_multiclass():
-            # OvR probabilities: per-class sigmoids normalized to sum 1
-            # (sklearn's OvR contract)
-            p = expit(self._eta_multi_host(X))
-            return p / np.maximum(p.sum(axis=1, keepdims=True), 1e-12)
-        p1 = expit(self._eta_host(X))
-        return np.stack([1.0 - p1, p1], axis=1)
+        with span("predict", component=type(self).__name__) as root:
+            if self._is_multiclass():
+                # OvR probabilities: per-class sigmoids normalized to sum
+                # 1 (sklearn's OvR contract)
+                p = expit(self._eta_multi_host(X))
+                return p / np.maximum(p.sum(axis=1, keepdims=True), 1e-12)
+            with span("predict.decision"):
+                eta = self._eta_host(X)
+            root.add(n_rows=len(eta))
+            with span("predict.host"):
+                p1 = expit(eta)
+                return np.stack([1.0 - p1, p1], axis=1)
 
     def predict_log_proba(self, X):
         """Log of predict_proba (sklearn API; the reference's glm lacks

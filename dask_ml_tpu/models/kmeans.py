@@ -1028,6 +1028,10 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         self.labels_ = labels
         self.inertia_ = inertia
         self.n_iter_ = int(n_iter)
+        # no "fused" here: the streamed loop picks its kernel per stream
+        # and does not report it (a resident fit's record must not stay)
+        self.solver_info_ = {"n_iter": self.n_iter_, "streamed": True,
+                             "fit_dtype": self.fit_dtype_}
         self.n_features_in_ = d
         # per-feature training profile for train-vs-serve drift scoring
         self.training_profile_ = stream.profile_snapshot()
@@ -1039,48 +1043,61 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         block_rows = stream_plan(X)
         if block_rows is not None:
             return self._fit_streamed(X, block_rows)
-        X = check_array(X, dtype=np.float32)
-        if self.n_clusters > X.n_rows:
-            raise ValueError(
-                f"n_clusters={self.n_clusters} > n_samples={X.n_rows}"
-            )
-        mask = X.row_mask(X.dtype)
-        centers0 = self._init_centers(X)
-        # sklearn-style tol scaling: tol * mean per-feature variance
-        _, var = masked_mean_var(X.data, mask, X.n_rows)
-        tol2 = jnp.asarray(self.tol, X.dtype) * jnp.mean(var)
+        # the root span covers the whole resident call; its children
+        # (fit.validate, fit.init, fit.tol_scale, fit.solve, fit.finish)
+        # are the phases, each ending where its host code ends
+        with span("fit", component="KMeans",
+                  n_clusters=self.n_clusters) as root:
+            return self._fit_resident(X, root)
+
+    def _fit_resident(self, X, root):
         from ..config import fit_dtype_info, mxu_dtype as _mxu_dtype
 
-        dt_info = fit_dtype_info(self.fit_dtype)
-        auto_pol = dt_info["fit_dtype_source"].startswith("auto")
-        mxu = _mxu_dtype(self.fit_dtype)
-        use_pallas = self.use_pallas
-        if use_pallas is None:
-            # auto: fused kernel on real TPU only — an EXPLICIT bf16
-            # request routes to the XLA distance path instead (the
-            # resident Pallas kernel's VMEM tiling is f32); under the
-            # default "auto" policy the f32 Pallas kernel keeps
-            # priority — one X pass per Lloyd iteration beats a bf16
-            # cross-term at this arithmetic intensity
-            use_pallas = jax.default_backend() == "tpu" \
-                and (mxu is None or auto_pol)
-        elif use_pallas and mxu is not None and not auto_pol:
-            import warnings
+        with span("fit.validate"):
+            X = check_array(X, dtype=np.float32)
+            if self.n_clusters > X.n_rows:
+                raise ValueError(
+                    f"n_clusters={self.n_clusters} > n_samples={X.n_rows}"
+                )
+            mask = X.row_mask(X.dtype)
+            dt_info = fit_dtype_info(self.fit_dtype)
+            auto_pol = dt_info["fit_dtype_source"].startswith("auto")
+            mxu = _mxu_dtype(self.fit_dtype)
+            use_pallas = self.use_pallas
+            if use_pallas is None:
+                # auto: fused kernel on real TPU only — an EXPLICIT bf16
+                # request routes to the XLA distance path instead (the
+                # resident Pallas kernel's VMEM tiling is f32); under the
+                # default "auto" policy the f32 Pallas kernel keeps
+                # priority — one X pass per Lloyd iteration beats a bf16
+                # cross-term at this arithmetic intensity
+                use_pallas = jax.default_backend() == "tpu" \
+                    and (mxu is None or auto_pol)
+            elif use_pallas and mxu is not None and not auto_pol:
+                import warnings
 
-            warnings.warn(
-                "KMeans(use_pallas=True) runs the f32 Pallas kernel; "
-                "config.dtype='bfloat16' is ignored on this path",
-                RuntimeWarning,
-            )
-        if use_pallas and mxu is not None:
-            mxu = None
-            dt_info = {"fit_dtype": "float32",
-                       "fit_dtype_source": "pallas-resident"}
-        self.fit_dtype_ = dt_info["fit_dtype"]
+                warnings.warn(
+                    "KMeans(use_pallas=True) runs the f32 Pallas kernel; "
+                    "config.dtype='bfloat16' is ignored on this path",
+                    RuntimeWarning,
+                )
+            if use_pallas and mxu is not None:
+                mxu = None
+                dt_info = {"fit_dtype": "float32",
+                           "fit_dtype_source": "pallas-resident"}
+            self.fit_dtype_ = dt_info["fit_dtype"]
+        root.add(n_rows=X.n_rows)
+        with span("fit.init"):
+            centers0 = self._init_centers(X)
+        with span("fit.tol_scale"):
+            # sklearn-style tol scaling: tol * mean per-feature variance
+            # (eager ops: dispatch only, the device works on into
+            # fit.solve)
+            _, var = masked_mean_var(X.data, mask, X.n_rows)
+            tol2 = jnp.asarray(self.tol, X.dtype) * jnp.mean(var)
         from ..observability import active_logger, fit_logger
 
-        with span("fit", component="KMeans", n_rows=X.n_rows,
-                  n_clusters=self.n_clusters) as sp, \
+        with span("fit.solve", fused=bool(use_pallas)) as sp, \
                 fit_logger("KMeans", n_rows=X.n_rows,
                            n_clusters=self.n_clusters) as logger, \
                 active_logger(logger):
@@ -1122,28 +1139,39 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
                     if int(it_c) < chunk:
                         break  # converged inside the chunk
                 ckpt.clear()
-            sp.add(n_iter=int(n_iter))
+            # the one scalar fetch: where the host waits for the loop
+            n_iter = int(sp.sync(n_iter))
+            sp.add(n_iter=n_iter)
             if logger is not None and not log_steps:
-                logger.log(step=int(n_iter), center_shift2=float(shift2),
+                logger.log(step=n_iter, center_shift2=float(shift2),
                            summary=True)
             # active_logger's exit runs jax.effects_barrier(), draining
             # the per-iteration callbacks before the sink unbinds
-        labels, inertia = _labels_inertia(X.data, mask, centers)
-        # NaN sanitizer (SURVEY.md §5): a NaN makes the tol while_loop
-        # exit as "converged" (NaN comparisons are False) — check the
-        # final inertia/centers instead of trusting convergence
-        if not bool(jnp.isfinite(inertia)) or \
-                not bool(jnp.isfinite(centers).all()):
-            raise FloatingPointError(
-                "KMeans produced non-finite centers/inertia: the input "
-                "contains NaN/Inf"
-            )
-        self.cluster_centers_ = to_host(centers)
-        self.labels_ = ShardedArray(labels, X.n_rows, X.mesh)
-        self.inertia_ = float(inertia)
-        self.n_iter_ = int(n_iter)
-        self.n_features_in_ = X.shape[1]
-        return self
+        root.add(n_iter=n_iter)
+        with span("fit.finish") as sp:
+            labels, inertia = _labels_inertia(X.data, mask, centers)
+            # NaN sanitizer (SURVEY.md §5): a NaN makes the tol while_loop
+            # exit as "converged" (NaN comparisons are False) — check the
+            # final inertia/centers instead of trusting convergence.
+            # The first check is where the host waits for the labels
+            # pass: sync on what it reads, never earlier (a sync on the
+            # pass itself would hold back the check's own dispatch)
+            if not bool(sp.sync(jnp.isfinite(inertia))) or \
+                    not bool(jnp.isfinite(centers).all()):
+                raise FloatingPointError(
+                    "KMeans produced non-finite centers/inertia: the input "
+                    "contains NaN/Inf"
+                )
+            self.cluster_centers_ = to_host(centers)
+            self.labels_ = ShardedArray(labels, X.n_rows, X.mesh)
+            self.inertia_ = float(inertia)
+            self.n_iter_ = n_iter
+            # what carried the fit: the resident twin of the GLMs'
+            # solver_info_ ("fused": the Pallas Lloyd kernel ran)
+            self.solver_info_ = {"n_iter": n_iter, "fused": bool(use_pallas),
+                                 "fit_dtype": self.fit_dtype_}
+            self.n_features_in_ = X.shape[1]
+            return self
 
     def predict(self, X):
         check_is_fitted(self, "cluster_centers_")
@@ -1156,10 +1184,13 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
                 X, block_rows,
                 lambda blk: _labels_inertia(blk.arrays[0], blk.mask, c)[0],
             )
-        X = check_array(X, dtype=np.float32)
-        centers = jnp.asarray(self.cluster_centers_, X.dtype)
-        labels, _ = _labels_inertia(X.data, X.row_mask(X.dtype), centers)
-        return ShardedArray(labels, X.n_rows, X.mesh)
+        # dispatch only: the labels stay on the device, nothing here waits
+        with span("predict", component="KMeans") as root:
+            X = check_array(X, dtype=np.float32)
+            root.add(n_rows=X.n_rows)
+            centers = jnp.asarray(self.cluster_centers_, X.dtype)
+            labels, _ = _labels_inertia(X.data, X.row_mask(X.dtype), centers)
+            return ShardedArray(labels, X.n_rows, X.mesh)
 
     def fit_predict(self, X, y=None):
         return self.fit(X).labels_

@@ -31,7 +31,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import DATA_AXIS
-from ...observability import emit_jit_step, track_program
+from ...observability import current_span, emit_jit_step, track_program
 from ...plans import ProgramPlan
 from ..solvers import regularizers
 from ..solvers.families import get_family
@@ -156,13 +156,19 @@ def _resolve_pallas(use_pallas, mesh, family, X=None):
     )
 
 
+@jax.jit
+def _pack_scalars(*vals):
+    return jnp.stack([jnp.asarray(v, jnp.float32) for v in vals])
+
+
 def _host_scalars(*vals):
-    """Fetch a handful of device result scalars in ONE device→host
-    transfer — separate int()/float() pulls each pay a host round
-    trip and a sync."""
-    return np.asarray(jnp.stack([
-        jnp.asarray(v, jnp.float32) for v in vals
-    ]))
+    """Fetch a handful of device result scalars in ONE launch and ONE
+    device→host transfer — separate int()/float() pulls each pay a host
+    round trip and a sync, and an eager cast per value is a launch of its
+    own behind the solve (~1 ms each on a mesh of four). This is where
+    the host waits for the whole solve: the wait is charged to the open
+    span (``fit.solve``) as ``sync_s``."""
+    return np.asarray(current_span().sync(_pack_scalars(*vals)))
 
 
 def check_finite_result(beta, info, solver):
@@ -213,6 +219,10 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
     """The optax L-BFGS while_loop, shared by every loss flavor (XLA,
     Pallas single-target, Pallas multi-target).
 
+    The single-target carry is ``(beta, state, gnorm, it, n_evals)``:
+    ``n_evals`` is an int32 sum of objective evaluations (one scalar add
+    an iteration, always on — a static switch would make two programs).
+
     ``n_blocks`` switches on the stacked multi-solve semantics: the flat
     vector is ``n_blocks`` independent row blocks (classes, lam
     candidates, or both) sharing ONE iteration budget — every iteration
@@ -247,6 +257,7 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
 
     def body(carry):
         beta, state, _, it = carry[:4]
+        stored = state[-1].value     # what the last line search left
         value, grad = value_and_grad(beta, state=state)
         if track:
             conv, frozen, cmask = carry[4:]
@@ -270,13 +281,21 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
             emit_jit_step(it, loss=value, grad_norm=gnorm)
         if track:
             return beta, state, gnorm, it + 1, conv, frozen, cmask
-        return beta, state, gnorm, it + 1
+        # objective evaluations so far: value_and_grad_from_state ran the
+        # loss only where no finite value was stored (the first
+        # iteration), the zoom line search once per step — and the state
+        # keeps only the last search's count
+        n_evals = (carry[4] + (~jnp.isfinite(stored)).astype(jnp.int32)
+                   + state[-1].info.num_linesearch_steps.astype(jnp.int32))
+        return beta, state, gnorm, it + 1, n_evals
 
     if track and len(carry) == 4:
         b0 = carry[0]
         carry = (*carry, jnp.zeros(n_blocks, jnp.int32),
                  b0.reshape(n_blocks, -1),
                  jnp.zeros(n_blocks, jnp.bool_))
+    elif not track and len(carry) == 4:   # a caller that starts from zero
+        carry = (*carry, jnp.zeros((), jnp.int32))
     out = jax.lax.while_loop(cond, body, carry)
     if track:
         beta, state, gnorm, it, conv, frozen, cmask = out
@@ -339,7 +358,11 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
     _check_smooth(reg, "lbfgs")
     use_pallas = _resolve_pallas(use_pallas, mesh, family, X)
     opt = optax.lbfgs(memory_size=memory)
-    carry = (beta0, opt.init(beta0), jnp.asarray(jnp.inf, beta0.dtype), 0)
+    # the evaluation counter starts as a HOST scalar: a device zero would
+    # be one more eager launch on the chain the chip idles through
+    # before the solver's program starts (~1 ms each on a mesh of four)
+    carry = (beta0, opt.init(beta0), jnp.asarray(jnp.inf, beta0.dtype), 0,
+             np.zeros((), np.int32))
     run = partial(
         _lbfgs_chunk, X, y, mask, n_rows, lam=lam, pmask=pmask,
         l1_ratio=l1_ratio, tol=jnp.asarray(tol, beta0.dtype),
@@ -349,9 +372,9 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
     )
     resumed_from = 0
     if not (checkpoint_path and checkpoint_every):
-        beta, state, gnorm, it = run(carry=carry,
-                                     stop_it=jnp.asarray(max_iter))
-        it, gnorm = _host_scalars(it, gnorm)
+        beta, state, gnorm, it, n_evals = run(
+            carry=carry, stop_it=jnp.asarray(max_iter))
+        it, gnorm, n_evals = _host_scalars(it, gnorm, n_evals)
     else:
         import os
 
@@ -380,11 +403,12 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
         import shutil
 
         shutil.rmtree(os.path.abspath(checkpoint_path), ignore_errors=True)
-        beta, state, gnorm, it = carry
+        beta, state, gnorm, it, n_evals = carry
     # "fused": whether the Pallas kernel carried the data term — the
-    # resident twin of the streamed fits' "fused_stream"
+    # resident twin of the streamed fits' "fused_stream"; "n_evals": how
+    # often the objective (one pass over X) ran, line search included
     info = {"n_iter": int(it), "grad_norm": float(gnorm),
-            "fused": bool(use_pallas)}
+            "n_evals": int(n_evals), "fused": bool(use_pallas)}
     if checkpoint_path and checkpoint_every:
         info["resumed_from"] = resumed_from
     return beta, info

@@ -11,7 +11,9 @@ equivalent is this package (grown from the flat per-step logger in
   bridge, the host-callback capability probe, profiler wrappers;
 - ``_spans``    — ``span(name, **attrs)``: nested span records (fit →
   pass → solve) with wall time, device-sync time, parent ids, and
-  counter deltas;
+  counter deltas; kept in an in-process ring (``recent_spans()``) and
+  mirrored as ``dmt.<name>`` annotations on the ``jax.profiler``
+  timeline;
 - ``_counters`` — flat counter/gauge registry: recompiles (via
   ``jax.monitoring``, with a jit-cache fallback), host↔device transfer
   bytes, donated-buffer reuse, per-device memory gauges;
@@ -136,9 +138,12 @@ from .sketch import CategoricalSketch, FeatureSketch, merge_profiles
 from ._spans import (
     NOOP_SPAN,
     add_span_observer,
+    current_span,
     current_span_id,
     open_spans_snapshot,
+    recent_spans,
     remove_span_observer,
+    reset_recent_spans,
     span,
 )
 from ._requests import (
@@ -217,6 +222,7 @@ __all__ = [
     "counters_enabled",
     "counters_reset",
     "counters_snapshot",
+    "current_span",
     "current_span_id",
     "device_memory_gauges",
     "emit_jit_step",
@@ -230,6 +236,7 @@ __all__ = [
     "note_event",
     "parse_rules",
     "replay",
+    "reset_recent_spans",
     "traces_data",
     "traces_reset",
     "tracing_enabled",
@@ -238,6 +245,7 @@ __all__ = [
     "programs_enabled",
     "programs_reset",
     "programs_snapshot",
+    "recent_spans",
     "record_donation",
     "record_fault_injected",
     "record_gspmd_reduce",

@@ -19,15 +19,31 @@ Sink resolution, per span open (cheap: one list peek + one config read):
 4. none of those set → the span is the singleton no-op: no record, no
    id allocation, no counter snapshot. The disabled path is a dict
    lookup and a None check — nothing is ever traced into jitted code.
+
+A span that has a sink — or that is opened under ``config.obs_programs``
+(the flight-recorder switch: no file, the same records) — also records
+into memory and onto the profiler's timeline:
+
+- the closed record is appended to a bounded in-process ring
+  (:func:`recent_spans`, newest last; :func:`reset_recent_spans`), with
+  ``root_id`` (the outermost open span of the thread: every span of one
+  ``fit`` or one ``predict`` shares it) and ``t_start_ns`` /
+  ``t_end_ns`` from ``time.time_ns()``;
+- while it is open it holds a ``jax.profiler.TraceAnnotation`` named
+  ``dmt.<span name>``, so a ``jax.profiler`` trace shows the same
+  interval on a host thread's line beside the device's ``XLA Ops``.
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
 import os
 import threading
 import time
+
+import jax
 
 from ._counters import counters_enabled, counters_snapshot
 from ._metrics import thread_bound_logger
@@ -89,6 +105,26 @@ def remove_span_observer(fn) -> None:
     _track_arm(-1)
 
 
+# closed span records of this process, newest last: what a benchmark
+# reader or a notebook reaches without a file. Bounded, so a service that
+# leaves obs_programs on keeps the last few hundred calls, not all.
+RING_SIZE = 4096
+_ring: collections.deque = collections.deque(maxlen=RING_SIZE)
+ANNOTATION_PREFIX = "dmt."
+
+
+def recent_spans():
+    """The last ``RING_SIZE`` closed span records, oldest first (copies of
+    the list, not of the records: treat them as read-only)."""
+    with _open_lock:
+        return list(_ring)
+
+
+def reset_recent_spans() -> None:
+    with _open_lock:
+        _ring.clear()
+
+
 def open_spans_snapshot():
     """[{span_id, span, thread, t_open_unix, parent_id, ...}] for every
     span currently open anywhere in the process, oldest first."""
@@ -104,6 +140,7 @@ _trace_lock = threading.Lock()
 
 
 def _stack():
+    """This thread's open spans, outermost first."""
     st = getattr(_tls, "stack", None)
     if st is None:
         st = _tls.stack = []
@@ -113,7 +150,16 @@ def _stack():
 def current_span_id():
     """Id of the innermost open span on this thread (None outside any)."""
     st = getattr(_tls, "stack", None)
-    return st[-1] if st else None
+    return st[-1].span_id if st else None
+
+
+def current_span():
+    """The innermost open span on this thread, ``NOOP_SPAN`` outside any:
+    how code below a span's ``with`` block (a solver's scalar fetch, an
+    estimator's ``to_host``) charges a sync the program needs anyway to
+    the phase it ends — ``current_span().sync(x)``."""
+    st = getattr(_tls, "stack", None)
+    return st[-1] if st else NOOP_SPAN
 
 
 class _FileSink:
@@ -135,10 +181,14 @@ class _FileSink:
             fh.write(line)
 
 
-def _trace_sink():
+def _resolve():
+    """(sink, armed) for a span opened now on this thread: where its JSONL
+    record goes (None: nowhere) and whether it records at all — into the
+    ring and onto the profiler's timeline. Any sink arms it, and so does
+    ``config.obs_programs`` alone."""
     lg = thread_bound_logger()
     if lg is not None:
-        return lg
+        return lg, True
     from ..config import get_config
 
     cfg = get_config()
@@ -146,11 +196,16 @@ def _trace_sink():
         try:
             os.makedirs(cfg.trace_dir, exist_ok=True)
         except OSError:
-            return None  # unusable sink disables the span, never the fit
-        return _FileSink(os.path.join(cfg.trace_dir, "trace.jsonl"))
+            # unusable sink disables the record, never the fit
+            return None, bool(cfg.obs_programs)
+        return _FileSink(os.path.join(cfg.trace_dir, "trace.jsonl")), True
     if cfg.metrics_path:
-        return _FileSink(cfg.metrics_path)
-    return None
+        return _FileSink(cfg.metrics_path), True
+    return None, bool(cfg.obs_programs)
+
+
+def _trace_sink():
+    return _resolve()[0]
 
 
 class _NoopSpan:
@@ -180,8 +235,9 @@ class span:
     sink configured the context yields the shared no-op span.
     """
 
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "sync_s",
-                 "_sink", "_t0", "_ctr0", "_tracked")
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "root_id",
+                 "sync_s", "_sink", "_t0", "_t0_ns", "_ctr0", "_tracked",
+                 "_annotation")
 
     def __init__(self, name, **attrs):
         self.name = name
@@ -189,13 +245,17 @@ class span:
         self.sync_s = 0.0
         self._sink = None
         self._tracked = False
+        self._annotation = None
 
     @property
     def recording(self):
-        """True when this span will emit a record at close — False for
-        spans tracked only for the watchdog (armed timeout, no sink).
-        The public signal call sites gate record-dependent work on
-        (e.g. the stream's wait_s readiness syncs)."""
+        """True when this span will write a record to a sink at close —
+        False for spans tracked only for the watchdog (armed timeout, no
+        sink) and for spans that record into the in-memory ring alone
+        (``obs_programs``, no sink). The public signal call sites gate
+        record-dependent work on (e.g. the stream's wait_s readiness
+        syncs, which change what a timed run does and so stay off until
+        someone asks for a file)."""
         return self._sink is not None
 
     def add(self, **attrs):
@@ -214,18 +274,19 @@ class span:
         return out
 
     def __enter__(self):
-        sink = _trace_sink()
-        if sink is None and not _armed_trackers:
+        sink, armed = _resolve()
+        if not armed and not _armed_trackers:
             return NOOP_SPAN
-        # sink None but a watchdog/observer armed: track the span
-        # (open-span registry + id stack); close emits to observers
-        # only, no JSONL record
+        # not armed but a watchdog/observer is: track the span
+        # (open-span registry + span stack); close emits to observers
+        # only, no record
         self._sink = sink
         self._tracked = True
         st = _stack()
-        self.parent_id = st[-1] if st else None
         self.span_id = next(_ids)
-        st.append(self.span_id)
+        self.parent_id = st[-1].span_id if st else None
+        self.root_id = st[0].root_id if st else self.span_id
+        st.append(self)
         with _open_lock:
             _open_spans[self.span_id] = {
                 "span_id": self.span_id,
@@ -239,7 +300,14 @@ class span:
                 "t_open_unix": time.time(),
             }
         self._ctr0 = (counters_snapshot()
-                      if sink is not None and counters_enabled() else None)
+                      if armed and counters_enabled() else None)
+        if armed:
+            # the same interval on the profiler's clock: opened last and
+            # closed first, so it lies inside [t_start_ns, t_end_ns]
+            self._t0_ns = time.time_ns()
+            self._annotation = jax.profiler.TraceAnnotation(
+                ANNOTATION_PREFIX + self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -247,27 +315,36 @@ class span:
         if not self._tracked:
             return False
         wall = time.perf_counter() - self._t0
+        armed = self._annotation is not None
+        if armed:
+            self._annotation.__exit__(exc_type, exc, tb)
+            t_end_ns = time.time_ns()
         st = _stack()
         # pop down to (and including) OUR frame: frames above ours are
         # spans abandoned mid-block (a generator dropped between yields)
         # — leaving them would corrupt every later span's parent id
         abandoned = []
-        if self.span_id in st:
-            while st and st[-1] != self.span_id:
+        if self in st:
+            while st and st[-1] is not self:
                 abandoned.append(st.pop())
             if st:
                 st.pop()
         with _open_lock:
             _open_spans.pop(self.span_id, None)
-            for sid in abandoned:  # their __exit__ will never run
-                _open_spans.pop(sid, None)
+            for sp in abandoned:  # their __exit__ will never run
+                _open_spans.pop(sp.span_id, None)
             observers = list(_span_observers)
-        if self._sink is None and not observers:
+        for sp in abandoned:
+            if sp._annotation is not None:
+                sp._annotation.__exit__(None, None, None)
+                sp._annotation = None   # a late __exit__ records nothing
+        if not armed and not observers:
             return False  # watchdog-only tracking: no record to emit
         rec = {
             "span": self.name,
             "span_id": self.span_id,
             "parent_id": self.parent_id,
+            "root_id": self.root_id,
             "depth": len(st),
             # absolute close time: the relative "time" field's origin
             # differs by sink (fit logger's t0 vs process start), so
@@ -289,6 +366,11 @@ class span:
                 if d:
                     rec[f"ctr_{k}"] = round(d, 6) if isinstance(
                         d, float) else d
+        if armed:
+            rec["t_start_ns"] = self._t0_ns
+            rec["t_end_ns"] = t_end_ns
+            with _open_lock:
+                _ring.append(rec)
         for fn in observers:
             # the live plane sees every closed span, recorded or not —
             # a failing observer must never surface into the fit

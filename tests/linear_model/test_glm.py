@@ -139,3 +139,57 @@ def test_class_weight_raises_not_silently_ignored():
                            class_weight="balanced").fit(X, y)
     # None stays allowed
     LogisticRegression(solver="lbfgs", max_iter=5).fit(X, y)
+
+
+@pytest.mark.parametrize("flavor", ["xla", "fused"])
+def test_lbfgs_counts_objective_evaluations(flavor):
+    """``solver_info_["n_evals"]`` is the number of times the objective ran
+    (the first ``value_and_grad`` plus every zoom line-search step): the
+    loop's own counter equals a Python counter around the loss when the
+    same loop runs un-jitted, and the jitted solver reports the same."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from dask_ml_tpu.models.solvers import solvers as S
+
+    rng = np.random.RandomState(0)
+    n, d = 96, 4
+    X = jnp.asarray(rng.randn(n, d), jnp.float32)
+    y = jnp.asarray(rng.rand(n) < 1 / (1 + np.exp(-2 * np.asarray(X[:, 0]))),
+                    jnp.float32)
+    mask, pmask = jnp.ones(n, jnp.float32), jnp.ones(d, jnp.float32)
+    lam, tol, max_iter = jnp.float32(1e-2), 1e-4, 30
+    beta0 = jnp.zeros(d, jnp.float32)
+    kwargs = {}
+    if flavor == "fused":
+        from dask_ml_tpu.parallel.mesh import device_mesh
+
+        kwargs = dict(use_pallas=True, pallas_interpret=True,
+                      mesh=device_mesh(devices=jax.devices()[:1]))
+    beta, info = S.lbfgs(X, y, mask, n, beta0, "logistic", "l2", lam, pmask,
+                         max_iter=max_iter, tol=tol, **kwargs)
+    assert info["fused"] is (flavor == "fused")
+    assert info["n_iter"] < max_iter
+    assert info["n_evals"] >= info["n_iter"] + 1
+
+    loss = partial(S._smooth_loss, X=X, y=y, mask=mask, n_rows=n, lam=lam,
+                   pmask=pmask, l1_ratio=0.5, family="logistic", reg="l2")
+    ran = []
+
+    def counted(b):
+        ran.append(1)
+        return loss(b)
+
+    opt = optax.lbfgs(memory_size=10)
+    carry = (beta0, opt.init(beta0), jnp.asarray(jnp.inf, jnp.float32), 0,
+             jnp.zeros((), jnp.int32))
+    with jax.disable_jit():
+        out = S._lbfgs_loop(counted, carry, jnp.asarray(max_iter),
+                            jnp.asarray(tol, jnp.float32), 10, False)
+    assert int(out[4]) == len(ran)
+    assert (info["n_iter"], info["n_evals"]) == (int(out[3]), len(ran))
+    np.testing.assert_allclose(np.asarray(beta), np.asarray(out[0]),
+                               atol=1e-4)

@@ -155,3 +155,15 @@ def test_kmeans_score_is_negative_inertia(blobs):
     # sklearn contract: score = -inertia of the assignment
     assert ours.score(X) == pytest.approx(-ours.inertia_, rel=1e-5)
     assert ours.inertia_ == pytest.approx(ref.inertia_, rel=1e-3)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_kmeans_records_what_carried_the_fit(blobs, use_pallas):
+    """``solver_info_`` names the kernel flavour that ran (``fused``: the
+    Pallas Lloyd kernel), the iterations and the precision."""
+    X, _ = blobs
+    km = KMeans(n_clusters=4, init=X.to_numpy()[:4].copy(), max_iter=7,
+                tol=0.0, use_pallas=use_pallas).fit(X)
+    assert km.solver_info_ == {"n_iter": 7, "fused": use_pallas,
+                               "fit_dtype": "float32"}
+    assert km.n_iter_ == 7 and km.fit_dtype_ == "float32"

@@ -84,6 +84,183 @@ def test_span_prefers_active_logger_sink(tmp_path):
     assert recs[0]["run"] == "r1"  # went through the bound logger
 
 
+# -- the in-memory ring and the profiler's timeline --------------------------
+
+def test_ring_keeps_closed_spans_with_root_and_clock(tmp_path):
+    """Every span that has a sink also lands in the ring, newest last: the
+    JSONL record plus ``root_id`` (shared by every span of the outermost
+    one) and ``t_start_ns`` / ``t_end_ns``; children lie inside their root,
+    starts and ends are ordered."""
+    obs.reset_recent_spans()
+    with config.set(trace_dir=str(tmp_path / "t")):
+        for _ in range(2):
+            with obs.span("call", component="X") as root:
+                assert obs.current_span() is root
+                with obs.span("call.a"):
+                    with obs.span("call.a.deep") as deep:
+                        assert obs.current_span() is deep
+                with obs.span("call.b"):
+                    pass
+        assert obs.current_span() is obs.NOOP_SPAN
+    ring = obs.recent_spans()
+    assert [r["span"] for r in ring] == [
+        "call.a.deep", "call.a", "call.b", "call"] * 2      # close order
+    # what the sink got, and nothing else (the file sink adds its "time")
+    assert ring == [{k: v for k, v in r.items() if k != "time"}
+                    for r in _read_jsonl(tmp_path / "t" / "trace.jsonl")]
+    first, second = ring[:4], ring[4:]
+    for call in (first, second):
+        root = call[-1]
+        assert root["parent_id"] is None
+        assert root["root_id"] == root["span_id"]
+        assert {r["root_id"] for r in call} == {root["span_id"]}
+        for r in call:
+            assert root["t_start_ns"] <= r["t_start_ns"] <= r["t_end_ns"] \
+                <= root["t_end_ns"]
+            assert abs((r["t_end_ns"] - r["t_start_ns"]) * 1e-9
+                       - r["wall_s"]) < 1e-3
+        a, b = call[1], call[2]
+        assert a["t_end_ns"] <= b["t_start_ns"]               # siblings
+        assert call[0]["parent_id"] == a["span_id"]
+    assert first[-1]["root_id"] != second[-1]["root_id"]
+    assert first[-1]["t_end_ns"] <= second[-1]["t_start_ns"]
+    obs.reset_recent_spans()
+    assert obs.recent_spans() == []
+
+
+def test_ring_is_bounded(monkeypatch):
+    import collections
+
+    from dask_ml_tpu.observability import _spans
+
+    assert _spans._ring.maxlen == _spans.RING_SIZE == 4096
+    monkeypatch.setattr(_spans, "_ring", collections.deque(maxlen=8))
+    with config.set(obs_programs=True):
+        for i in range(20):
+            with obs.span("s", i=i):
+                pass
+    assert [r["i"] for r in obs.recent_spans()] == list(range(12, 20))
+
+
+def test_obs_programs_alone_arms_ring_and_annotations(tmp_path):
+    """No sink, ``obs_programs`` on: spans record into the ring and onto the
+    profiler's timeline (``dmt.<name>``), write no file, and do not count as
+    ``recording`` (the stream's readiness syncs stay off)."""
+    import jax
+
+    from benchmark import trace_reduce
+
+    obs.reset_recent_spans()
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with config.set(obs_programs=True, trace_dir="", metrics_path=""):
+            with obs.span("armed", component="X") as sp:
+                assert sp is not obs.NOOP_SPAN and not sp.recording
+                assert sp.sync(5) == 5
+                with obs.span("armed.child"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [r["span"] for r in obs.recent_spans()] == ["armed.child", "armed"]
+    assert obs.recent_spans()[1]["component"] == "X"
+    assert [p.name for p in tmp_path.iterdir()] == ["prof"]   # no JSONL
+    table = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path / "prof")),
+                              host_prefix="dmt.")
+    events = {n: (s, s + d) for p in table["planes"] for line in p["lines"]
+              for n, s, d in line["events"]}
+    assert set(events) == {"dmt.armed", "dmt.armed.child"}
+    outer, inner = events["dmt.armed"], events["dmt.armed.child"]
+    assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+    obs.reset_recent_spans()
+
+
+def test_new_call_sites_are_noop_when_nothing_listens(monkeypatch):
+    """No sink, ``obs_programs`` off (the benchmark's untraced runs): every
+    span the resident fit/predict paths open resolves to ``NOOP_SPAN``, the
+    ring stays empty, and the natural syncs pass straight through."""
+    from dask_ml_tpu.cluster import KMeans
+    from dask_ml_tpu.linear_model import LogisticRegression
+    from dask_ml_tpu.observability import _spans
+    from dask_ml_tpu.parallel import as_sharded
+
+    opened = []
+    enter = _spans.span.__enter__
+
+    def spy(self):
+        got = enter(self)
+        opened.append((self.name, got))
+        return got
+
+    monkeypatch.setattr(_spans.span, "__enter__", spy)
+    obs.reset_recent_spans()
+    rng = np.random.RandomState(0)
+    X = rng.randn(300, 5).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    Xs = as_sharded(X)
+    with config.set(obs_programs=False, trace_dir="", metrics_path=""):
+        clf = LogisticRegression(solver="lbfgs", max_iter=10).fit(
+            Xs, as_sharded(y))
+        clf.predict_proba(Xs)
+        km = KMeans(n_clusters=2, init="random", random_state=0,
+                    max_iter=3).fit(Xs)
+        km.predict(Xs)
+    assert {n for n, _ in opened} == {
+        "fit", "fit.validate", "fit.prepare", "fit.init", "fit.tol_scale",
+        "fit.solve", "fit.finish", "predict", "predict.decision",
+        "predict.host"}
+    assert all(got is obs.NOOP_SPAN for _, got in opened)
+    assert obs.recent_spans() == []
+    assert clf.solver_info_["n_evals"] >= clf.n_iter_ + 1   # always counted
+
+
+def test_resident_fit_and_predict_phase_spans():
+    """``obs_programs`` on: one root per call, the phases as its children,
+    the solver's counts on ``fit.solve``, and the phases' walls sum to the
+    root's (what lies between them is span bookkeeping)."""
+    from dask_ml_tpu.cluster import KMeans
+    from dask_ml_tpu.linear_model import LogisticRegression
+    from dask_ml_tpu.parallel import as_sharded
+
+    rng = np.random.RandomState(0)
+    X = rng.randn(300, 5).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    Xs = as_sharded(X)
+    obs.reset_recent_spans()
+    with config.set(obs_programs=True):
+        clf = LogisticRegression(solver="lbfgs", max_iter=10).fit(
+            Xs, as_sharded(y))
+        clf.predict_proba(Xs)
+        km = KMeans(n_clusters=2, init="random", random_state=0,
+                    max_iter=3).fit(Xs)
+        km.predict(Xs)
+    ring = obs.recent_spans()
+    obs.reset_recent_spans()
+    roots = [r for r in ring if r["parent_id"] is None]
+    assert [(r["span"], r["component"]) for r in roots] == [
+        ("fit", "LogisticRegression"), ("predict", "LogisticRegression"),
+        ("fit", "KMeans"), ("predict", "KMeans")]
+    kids = [[r["span"] for r in ring if r["parent_id"] == root["span_id"]]
+            for root in roots]
+    assert kids == [
+        ["fit.validate", "fit.prepare", "fit.solve", "fit.finish"],
+        ["predict.decision", "predict.host"],
+        ["fit.validate", "fit.init", "fit.tol_scale", "fit.solve",
+         "fit.finish"], []]
+    assert all(r["root_id"] in {x["span_id"] for x in roots} for r in ring)
+    assert all(r["n_rows"] == 300 for r in roots)
+    glm_solve = next(r for r in ring if r["span"] == "fit.solve")
+    assert glm_solve["n_iter"] == clf.n_iter_ == roots[0]["n_iter"]
+    assert glm_solve["n_evals"] == clf.solver_info_["n_evals"]
+    assert glm_solve["fused"] is False
+    km_solve = [r for r in ring if r["span"] == "fit.solve"][1]
+    assert km_solve["n_iter"] == km.n_iter_ and km_solve["fused"] is False
+    for root in (roots[0], roots[2]):
+        walls = sum(r["wall_s"] for r in ring
+                    if r["parent_id"] == root["span_id"])
+        assert walls <= root["wall_s"]
+        assert root["wall_s"] - walls < 2e-3 + 0.02 * root["wall_s"]
+
+
 # -- counters ---------------------------------------------------------------
 
 def test_counter_snapshot_and_reset():
