@@ -22,7 +22,24 @@ real v5e chip):
   index_map) with @pl.when(first) init — TPU grids are sequential, so
   accumulation is race-free;
 - rows are padded to a 128-multiple tile (Mosaic minor-tiling), with the
-  mask zeroing padded rows out of every statistic.
+  mask zeroing padded rows out of every statistic;
+- per-row quantities come in two layouts. COLUMN form, ``(tile, 1)``: what
+  a lane reduction with ``keepdims=True`` leaves. Cheap to write, but a
+  ``(tile, 1)`` f32 value holds one useful number per 128-lane line, and an
+  ``(n, 1)`` f32 array in HBM is tiled T(8,128), so it takes 512 B a row —
+  128x its data, built and re-read on every call that takes ``y[:, None]``.
+  ROW form, ``(r, tile)``: rows of the data ride along LANES, ``y`` arrives
+  as a ``(1, n)`` view of the vector (no padding in HBM), eta is the MXU
+  contraction ``b(r, d) . x(tile, d)^T`` and the gradient the ordinary
+  product ``resid(r, tile) @ x(tile, d)`` (the q.k^T / p.v pair of an
+  attention kernel). ``fused_glm_value_grad`` — the resident single-target
+  kernel every lbfgs / gradient_descent / proximal_grad fit runs — is in
+  row form (``_eta_rows`` / ``_lane_mask`` / ``_glm_row_terms``). Its
+  siblings (``fused_glm_value_grad_hess``, ``fused_glm_multi_value_grad``'s
+  class codes, the ``*_stream`` and SGD kernels) still carry the column
+  contract (``_row_dot`` / ``_tile_mask`` / ``_glm_eta_terms``): no
+  benchmark cell runs them, so a conversion could not be measured; the row
+  helpers are written for them to move onto (ROADMAP S4).
 """
 
 from __future__ import annotations
@@ -192,7 +209,8 @@ def fused_lloyd_stats(x, n_valid, centers, interpret=False):
 
 def _tile_mask(x, nv_ref, i, tile):
     """Per-tile prefix-validity mask from the global row index vs the
-    scalar valid-row count — shared by every GLM kernel."""
+    scalar valid-row count — shared by every COLUMN-form GLM kernel
+    (``_lane_mask`` is its row-form twin)."""
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0) \
         + i * tile
     return (row_ids < nv_ref[0, 0]).astype(jnp.float32)  # (tile, 1)
@@ -208,15 +226,21 @@ def _row_dot(x, b):
     row) into the same multiply-reduce, and for a non-f32 operand emits
     an ill-typed ``vector.broadcast`` (bf16 source, f32 result) that
     fails verification. v5e has no bf16 VPU, so the f32 upcast is what
-    the hardware does either way."""
+    the hardware does either way.
+
+    The result is a COLUMN: one useful value per 128-lane line, and the
+    reason its callers take ``y`` as ``(tile, 1)`` blocks of a 128x-padded
+    ``(n, 1)`` array. ``fused_glm_value_grad`` no longer calls this (see
+    ``_eta_rows``); the Newton, streamed and SGD kernels still do."""
     bx = b.astype(x.dtype).astype(jnp.float32)
     return jnp.sum(x.astype(jnp.float32) * bx, axis=1, keepdims=True)
 
 
 def _glm_eta_terms(x, yv, b, family):
-    """eta plus the family's pointwise NLL / residual. Family formulas
-    come from models/solvers/families.py — pure jnp ops that lower
-    inside the kernel, so the Pallas and XLA losses cannot diverge."""
+    """eta plus the family's pointwise NLL / residual, COLUMN form
+    (``_glm_row_terms`` is the row form). Family formulas come from
+    models/solvers/families.py — pure jnp ops that lower inside the
+    kernel, so the Pallas and XLA losses cannot diverge."""
     eta = _row_dot(x, b)                # (tile, 1)
     from ..models.solvers.families import get_family
 
@@ -226,33 +250,75 @@ def _glm_eta_terms(x, yv, b, family):
     return fam, eta, per, resid
 
 
+# rows of the beta / residual operand of the row-form contractions: one
+# f32 sublane tile, the rows copies of each other, row 0 read out. Not
+# ONE row: that is the matmul shape jax 0.9's Mosaic special-cases and
+# mistypes for bf16 (see ``_row_dot``); 1, 8 and 16 rows timed the same
+# on the v5e, the contractions hide behind the X tile's DMA.
+_ROW_SUBLANES = 8
+
+
+def _lane_mask(shape, nv_ref, i, tile):
+    """Row-form prefix-validity mask: the global row index runs along
+    LANES (dim 1 of ``shape``), against the scalar valid-row count."""
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + i * tile
+    return (row_ids < nv_ref[0, 0]).astype(jnp.float32)
+
+
+def _eta_rows(x, b):
+    """(r, d) . (tile, d)^T -> (r, tile) eta on the MXU, f32
+    accumulation, ``b`` rounded to x's dtype first (``_row_dot``'s
+    contract). Products of two bf16 values are exact in f32, so a bf16
+    design differs from ``_row_dot`` in summation order only; an f32
+    design asks for ``HIGHEST``, or Mosaic's default would multiply in
+    bf16 (measured on the v5e: loss off by 3e-5 relative, as a bf16
+    design's)."""
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(
+        b.astype(x.dtype), x, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision,
+    )
+
+
+def _glm_row_terms(x, yv, b, nv_ref, i, tile, family):
+    """Row-form ``_glm_eta_terms``: (family, eta, MASKED pointwise NLL,
+    MASKED residual), each ``(r, tile)`` with rows along lanes; ``yv``
+    is the ``(1, tile)`` label row, broadcast over the r equal rows."""
+    from ..models.solvers.families import get_family
+
+    fam = get_family(family)
+    eta = _eta_rows(x, b)
+    m = _lane_mask(eta.shape, nv_ref, i, tile)
+    return fam, eta, fam.pointwise(eta, yv) * m, (fam.mean(eta) - yv) * m
+
+
 def _glm_value_grad_kernel(x_ref, y_ref, nv_ref, b_ref, loss_ref, grad_ref,
                            *, tile, family):
     """One X pass computing Σ pointwise-NLL AND Σ ∂NLL/∂β.
 
     The XLA path reads X twice per value_and_grad (forward matvec +
     gradient matmul) — at GLM arithmetic intensity the fit is HBM-bound,
-    so this halves the data traffic of every solver iteration. Same
-    layout rules as the Lloyd kernels: rank-2 everywhere, validity from
-    the global row index vs one scalar, accumulators revisited with a
-    constant index_map (sequential TPU grid: race-free)."""
+    so this halves the data traffic of every solver iteration. ROW form
+    (module header): every per-row quantity lives along lanes, validity
+    from the global row index vs one scalar, accumulators revisited with
+    a constant index_map (sequential TPU grid: race-free)."""
     i = pl.program_id(0)
     x = x_ref[:]                       # (tile, d) — f32 or bf16
-    yv = y_ref[:]                      # (tile, 1) f32
-    b = b_ref[:]                       # (1, d) f32
-    m = _tile_mask(x, nv_ref, i, tile)
-    _, _, per, resid = _glm_eta_terms(x, yv, b, family)
+    yv = y_ref[:]                      # (1, tile) f32
+    b = b_ref[:]                       # (r, d) f32, r equal rows
+    _, _, per, resid = _glm_row_terms(x, yv, b, nv_ref, i, tile, family)
 
     @pl.when(i == 0)
     def _init():
         loss_ref[:] = jnp.zeros_like(loss_ref)
         grad_ref[:] = jnp.zeros_like(grad_ref)
 
-    loss_ref[:] += jnp.sum(per * m, axis=0, keepdims=True)
+    loss_ref[:] += jnp.sum(per[0:1, :], axis=1, keepdims=True)
     grad_ref[:] += jax.lax.dot_general(
-        (resid * m).astype(x.dtype), x, (((0,), (0,)), ((), ())),
+        resid.astype(x.dtype), x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )                                   # (1, d) f32 accumulation
+    )[0:1, :]                           # (1, d) f32 accumulation
 
 
 @functools.partial(jax.jit, static_argnames=("family", "interpret"))
@@ -261,7 +327,14 @@ def fused_glm_value_grad(x, n_valid, y, beta, family, interpret=False):
     data pass. ``beta`` is f32 (d,); ``y`` f32 (n,); row validity is the
     scalar prefix count ``n_valid`` (GLM padding is trailing per shard).
     Callers psum both outputs across shards and add the penalty/mean
-    scaling in XLA."""
+    scaling in XLA.
+
+    LANE-DENSE: ``y`` reaches the kernel as a ``(1, n)`` view of the
+    vector in ``(1, tile)`` blocks, never as ``y[:, None]`` — this
+    wrapper is traced inside the solvers' ``while_loop``s, where an
+    ``(n, 1)`` operand was a 128x-padded buffer written and re-read on
+    every objective evaluation. The Newton / multi-target / streamed
+    kernels below still take the column (module header)."""
     n, d = x.shape
     y = y.astype(jnp.float32)
     beta = beta.astype(jnp.float32)
@@ -277,15 +350,16 @@ def fused_glm_value_grad(x, n_valid, y, beta, family, interpret=False):
         y = jnp.pad(y, (0, n_pad - n))
     grid = (n_pad // tile,)
     nv = jnp.asarray(n_valid, jnp.int32).reshape(1, 1)
+    r = _ROW_SUBLANES
     loss, grad = pl.pallas_call(
         functools.partial(_glm_value_grad_kernel, tile=tile,
                           family=family),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, tile), lambda i: (0, i)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((r, d), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
@@ -296,7 +370,7 @@ def fused_glm_value_grad(x, n_valid, y, beta, family, interpret=False):
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         interpret=interpret,
-    )(x, y[:, None], nv, beta[None, :])
+    )(x, y[None, :], nv, jnp.broadcast_to(beta[None, :], (r, d)))
     return loss[0, 0], grad[0]
 
 

@@ -47,34 +47,181 @@ def test_fused_glm_gradient_descent_and_bf16():
     assert b16.score(X, y) > 0.8
 
 
-def test_fused_glm_kernel_direct():
-    """Kernel-level check against the autodiff reference, including the
-    padded-tail masking."""
+def _direct_inputs(family, dtype, n, d=13, seed=2):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    X = jnp.asarray(rng.randn(n, d).astype(np.float32), dtype)
+    if family == "logistic":
+        y = (rng.uniform(size=n) < 0.5).astype(np.float32)
+    elif family == "poisson":   # counts beyond 256 are not bf16-exact
+        y = rng.poisson(3.0, size=n).astype(np.float32) * 101.0
+    else:
+        y = rng.randn(n).astype(np.float32)
+    beta = rng.randn(d).astype(np.float32) * 0.1
+    return X, jnp.asarray(y), jnp.asarray(beta)
+
+
+# (rows, n_valid): ragged with a masked tail (the original case); a tile
+# multiple; one padded tile; several tiles with rows past n_valid
+_DIRECT_ROWS = [(391, 350), (2048, 2048), (1000, 1000), (3000, 2500)]
+
+
+@pytest.mark.parametrize("n,n_valid", _DIRECT_ROWS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", ["logistic", "normal", "poisson"])
+def test_fused_glm_kernel_direct(family, dtype, n, n_valid):
+    """Kernel-level check against the plain XLA sum, including the
+    padded-tail masking. The reference multiplies what the kernel's
+    contract says it multiplies (X and beta at X's dtype, f32 products
+    and sums; the residual at X's dtype into the gradient), so BOTH
+    dtypes are held to the f32 band: an f32 design whose eta went
+    through bf16 anywhere misses it by 100x, and so would labels
+    rounded to bf16."""
     import jax
     import jax.numpy as jnp
 
     from dask_ml_tpu.models.solvers.families import get_family
     from dask_ml_tpu.ops.pallas_fused import fused_glm_value_grad
 
-    rng = np.random.RandomState(2)
-    n, d = 391, 13   # ragged on purpose: tile padding + masked tail
-    X = rng.randn(n, d).astype(np.float32)
-    y = (rng.uniform(size=n) < 0.5).astype(np.float32)
-    beta = rng.randn(d).astype(np.float32) * 0.1
-    n_valid = 350    # rows past this are padding
+    X, y, beta = _direct_inputs(family, dtype, n)
+    fam = get_family(family)
+    hi = jax.lax.Precision.HIGHEST
+    xf = X.astype(jnp.float32)
+    m = (jnp.arange(n) < n_valid).astype(jnp.float32)
+    eta = jnp.dot(xf, beta.astype(X.dtype).astype(jnp.float32),
+                  precision=hi)
+    v_ref = jnp.sum(fam.pointwise(eta, y) * m)
+    resid = ((fam.mean(eta) - y) * m).astype(X.dtype).astype(jnp.float32)
+    g_ref = np.asarray(jnp.dot(resid, xf, precision=hi))
 
-    def ref(b):
-        eta = X @ b
-        m = (np.arange(n) < n_valid).astype(np.float32)
-        return jnp.sum(get_family("logistic").pointwise(
-            jnp.asarray(eta), jnp.asarray(y)) * m)
-
-    v_ref = float(ref(jnp.asarray(beta)))
-    g_ref = np.asarray(jax.grad(lambda b: ref(b))(jnp.asarray(beta)))
-    v, g = fused_glm_value_grad(X, n_valid, y, beta, family="logistic",
+    v, g = fused_glm_value_grad(X, n_valid, y, beta, family=family,
                                 interpret=True)
-    np.testing.assert_allclose(float(v), v_ref, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(g), g_ref, rtol=1e-4, atol=1e-5)
+    assert v.dtype == jnp.float32 and g.dtype == jnp.float32
+    np.testing.assert_allclose(float(v), float(v_ref), rtol=1e-5)
+    # atol: the file's 1e-5 at the original case's gradient scale (~10)
+    np.testing.assert_allclose(np.asarray(g), g_ref, rtol=1e-4,
+                               atol=1e-6 * np.abs(g_ref).max())
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of every jaxpr nested in its
+    parameters (loop bodies, shard_map, custom_vjp, the Pallas kernel)."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from _walk_eqns(sub)
+
+
+def _avals(eqn):
+    return [v.aval for v in (*eqn.invars, *eqn.outvars)
+            if hasattr(v.aval, "shape")]
+
+
+def test_lbfgs_program_has_no_column_labels():
+    """No (n_local, 1) / (tile, 1) f32 array exists anywhere in the fused
+    ``glm.lbfgs`` program — loop bodies, the shard_map body and the
+    kernel itself included. On a TPU such an array is tiled T(8,128):
+    512 B a row, built and re-read on every objective evaluation. The
+    chip shows that as time; this counts it."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from dask_ml_tpu.models.solvers import solvers as S
+    from dask_ml_tpu.ops.pallas_fused import glm_tile
+    from dask_ml_tpu.parallel.mesh import device_mesh
+
+    shards, n_local, d = 4, 2560, 9       # 2560 rows a shard: 5 tiles of 512
+    n = shards * n_local
+    mesh = device_mesh(devices=jax.devices()[:shards])
+    tile = glm_tile(n_local, d, 2)
+    assert n_local % tile == 0 and n_local // tile > 1
+    X = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
+    y = mask = jax.ShapeDtypeStruct((n,), jnp.float32)
+    beta0 = jnp.zeros((d,), jnp.float32)
+    carry = (beta0, optax.lbfgs(memory_size=10).init(beta0),
+             jnp.asarray(jnp.inf, jnp.float32), 0, np.zeros((), np.int32))
+
+    def program(X, y, mask, carry):
+        return S._lbfgs_chunk.__wrapped_jit__(
+            X, y, mask, float(n), carry, lam=jnp.float32(1.0),
+            pmask=jnp.ones((d,), jnp.float32), l1_ratio=0.5,
+            stop_it=jnp.asarray(5), tol=jnp.float32(1e-3),
+            family="logistic", reg="l2", memory=10, log=False,
+            use_pallas=True, mesh=mesh, interpret=True)
+
+    eqns = list(_walk_eqns(jax.make_jaxpr(program)(X, y, mask, carry).jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels, "the fused kernel is not in the program"
+    for e in kernels:     # whatever feeds the kernel is lane-dense
+        for a in _avals(e):
+            assert not (len(a.shape) == 2 and a.shape[1] == 1
+                        and a.shape[0] > 1), (e.primitive.name, a)
+    columns = {(n, 1), (n_local, 1), (tile, 1)}
+    found = [(e.primitive.name, a) for e in eqns for a in _avals(e)
+             if tuple(a.shape) in columns]
+    assert not found, found[:5]
+
+
+@pytest.mark.parametrize("dtype,expected", [
+    ("float32", "HIGHEST"), ("bfloat16", None)])
+def test_fused_glm_eta_precision(dtype, expected):
+    """An f32 design's eta contraction asks the MXU for f32 products
+    (Mosaic's default multiplies f32 operands in bf16: seen on the chip,
+    not in the interpreter); a bf16 design needs no such request."""
+    import jax
+    import jax.numpy as jnp
+
+    from dask_ml_tpu.ops.pallas_fused import fused_glm_value_grad
+
+    n, d = 1024, 9
+    jaxpr = jax.make_jaxpr(
+        lambda x, y, b: fused_glm_value_grad(
+            x, n, y, b, family="logistic", interpret=True)
+    )(jnp.ones((n, d), dtype), jnp.zeros((n,), jnp.float32),
+      jnp.zeros((d,), jnp.float32)).jaxpr
+    dots = [e for e in _walk_eqns(jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == 2                   # eta, then the gradient
+    eta, grad = dots
+    assert eta.outvars[0].aval.shape[1] == n    # rows along lanes
+    prec = eta.params["precision"]
+    if expected is None:
+        assert prec is None
+    else:
+        assert {str(p) for p in prec} == {expected}
+    assert eta.outvars[0].aval.dtype == jnp.float32
+    assert grad.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_fused_glm_matches_xla_on_mesh(n_devices):
+    """Fit-level parity of the lane-dense kernel with the XLA loss on a
+    mesh of one device (one shard holds every tile) and of four (ragged
+    shards: 3001 rows pad to 751 a shard; ``test_fused_glm_matches_xla``
+    runs the default mesh of 8)."""
+    import jax
+
+    from dask_ml_tpu.parallel.mesh import device_mesh, use_mesh
+
+    with use_mesh(device_mesh(devices=jax.devices()[:n_devices])):
+        X, y = make_classification(n_samples=3001, n_features=12,
+                                   random_state=3)
+        kw = dict(solver="lbfgs", max_iter=60, tol=1e-8)
+        base = LogisticRegression(**kw).fit(X, y)
+        pal = LogisticRegression(**kw, solver_kwargs=PALLAS).fit(X, y)
+        assert len(X.data.sharding.device_set) == n_devices
+    assert pal.solver_info_["fused"] and not base.solver_info_["fused"]
+    np.testing.assert_allclose(pal.coef_, base.coef_, atol=5e-4)
+    np.testing.assert_allclose(np.ravel(pal.intercept_),
+                               np.ravel(base.intercept_), atol=5e-4)
 
 
 def test_auto_selected_kernel_failure_fails_the_fit(monkeypatch):
