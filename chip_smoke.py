@@ -23,7 +23,14 @@ under the DEFAULT config (``dtype="auto"``, ``pallas_stream=True``,
                  a converged resident fit of the same rows;
 5. kernels       every Pallas kernel a TPU auto-gate can select, compiled
                  (``interpret=False``) at production shape, f32 and the
-                 bf16 ``mxu`` variant, against its XLA flavour.
+                 bf16 ``mxu`` variant, against its XLA flavour;
+6. pca           ``PCA(64, svd_solver="randomized")`` on 1,048,576 x 512
+                 seeded rows born sharded (a planted 64-dimensional
+                 subspace): ``fit`` through TSQR and ``transform``, against
+                 the exact plain-f32 reference
+                 (``models/solvers/reference_pca.py``: the covariance of all
+                 the rows; the projection of a 65,536-row sample) under the
+                 benchmark's bands (``benchmark/tolerances_pca.py``).
 
 It exits non-zero — and prints no result line — unless jax's default backend
 is a TPU and every step held; a ``RuntimeWarning`` from ``dask_ml_tpu`` is an
@@ -76,6 +83,9 @@ class Sizes:
     n_classes: int = 8
     lloyd_k: int = 64
     lloyd_d: int = 128
+    pca_rows: int = 1_048_576          # 2 GiB of f32 at the source's width
+    pca_d: int = 512
+    pca_k: int = 64
 
 
 class SmokeFailure(AssertionError):
@@ -719,12 +729,81 @@ def step_kernels(sizes, interpret=False, state=None, facts=None):
     return facts
 
 
+# -- step 6: the third family, PCA by randomized SVD through TSQR --------------
+
+def make_planted(sizes, seed=2):
+    """``pca_rows`` x ``pca_d`` seeded rows BORN row-sharded, from the
+    benchmark configuration ``pca_1b_x512``'s own distribution
+    (``benchmark/families/pca.py``): ``pca_k`` planted orthonormal
+    directions with covariance eigenvalues falling geometrically from 64 to
+    16 over unit isotropic noise, plus a mean of order one."""
+    from benchmark.families import pca as family
+    from dask_ml_tpu.parallel import default_mesh
+    from dask_ml_tpu.parallel.mesh import row_sharding
+
+    mesh = default_mesh()
+    n, d = sizes.pca_rows, sizes.pca_d
+    spec = {"components": sizes.pca_k, "eigen_top": 64.0,
+            "eigen_bottom": 16.0, "mean_scale": 1.0}
+    hp = family.planted_params(np.random.default_rng(seed), d, spec)
+    X = jax.jit(lambda key: family.planted_rows(key, n, d, hp, spec)[0],
+                out_shardings=row_sharding(mesh, 2))(jax.random.PRNGKey(seed))
+    return as_sharded(X, mesh=mesh)
+
+
+def step_pca(sizes, interpret=False, state=None, facts=None):
+    from benchmark import tolerances_pca as T
+    from benchmark.families._common import device_rows
+    from dask_ml_tpu.decomposition import PCA
+    from dask_ml_tpu.models.solvers import reference_pca
+
+    facts = {} if facts is None else facts
+    k = sizes.pca_k
+    X = make_planted(sizes)
+    n = X.n_rows
+    facts["shards"] = len(X.data.sharding.device_set)
+    check(facts["shards"] == len(jax.devices()),
+          f"X lives on {facts['shards']} of {len(jax.devices())} devices")
+    # no padding rows: the reference below reads the shards as they are
+    check(X.data.shape[0] == n, f"{n} rows padded to {X.data.shape[0]}")
+    est = PCA(n_components=k, svd_solver="randomized", random_state=0).fit(X)
+    scores = est.transform(X)
+    info = dict(est.solver_info_)
+    facts.update(solver_info=info, fit_dtype=est.fit_dtype_)
+    check(info["solver"] == "randomized" and est.fit_dtype_ == "float32",
+          f"the randomized float32 solver did not carry the fit: {info}, "
+          f"{est.fit_dtype_}")
+
+    exact = reference_pca.pca_exact(reference_pca.shard_blocks(X.data), k)
+    check(exact["n"] == n, f"the reference saw {exact['n']} of {n} rows")
+    bad = []
+    for name, (value, band) in T.readings(
+            exact, est.mean_, est.components_, est.explained_variance_,
+            est.explained_variance_ratio_, info["size"],
+            info["n_iter"]).items():
+        facts[name], facts[name + "_band"] = value, band
+        if not value <= band:
+            bad.append(f"{name} {value:.3e} > {band:.3e}")
+
+    m = min(sizes.sample_rows, n // facts["shards"])   # of the first shard
+    want = reference_pca.transform(device_rows(X, m), est.mean_,
+                                   est.components_)
+    got = device_rows(scores, m)
+    facts["transform"] = T.transform_reading(np.asarray(got), want)
+    if not facts["transform"] <= T.TOL_TRANSFORM:
+        bad.append(f"transform off by {facts['transform']:.3e} of the score "
+                   f"scale (band {T.TOL_TRANSFORM:.0e})")
+    check(not bad, "; ".join(bad))
+    return facts
+
+
 STEPS = (
     ("resident", step_resident),
     ("predict", step_predict),
     ("objective", step_objective),
     ("streamed", step_streamed),
     ("kernels", step_kernels),
+    ("pca", step_pca),
 )
 
 

@@ -9,7 +9,10 @@ all-gather, psum-reduced matmul passes, small replicated SVD.
 
 Centering: padded rows must stay exactly zero after ``X - mean_``, so the
 centered matrix is re-masked before the SVD (zero rows leave R/range
-unchanged).
+unchanged). The resident fit centres INSIDE its solver program
+(``pca.rsvd`` / ``pca.svd_tall``): an eager ``(X - mean) * mask`` holds two
+X-sized buffers beside X, which at one chip's share of the tall-skinny
+deployment (2,097,152 x 512 float32, 4 GiB) is most of the chip.
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import BaseEstimator, TransformerMixin, to_host
+from ..observability import span, track_program
 from ..ops import linalg
 from ..ops.reductions import masked_mean_var
 from ..parallel.sharded import ShardedArray
@@ -68,6 +72,56 @@ def _block_pca_moments(X, mask, shift, mxu_dtype=None):
     return jnp.tensordot(mask, xc, axes=(0, 0)), g
 
 
+@track_program("pca.center")
+@jax.jit
+def _mean_var(x, mask, n_rows):
+    """(mean, unbiased variance) per feature in two fused passes over x:
+    plain f32 sums on the vector units (a ``tensordot`` with the mask would
+    round x to bf16 on a TPU's MXU), no X-sized temporary."""
+    m = mask[:, None]
+    mean = jnp.sum(x * m, axis=0) / n_rows
+    xc = (x - mean) * m
+    return mean, jnp.sum(xc * xc, axis=0) / jnp.maximum(n_rows - 1, 1)
+
+
+_MEAN_VAR_SWEEPS = 2     # passes over x that _mean_var makes
+
+
+def _centered(x, mask, mean):
+    return (x - mean) * mask[:, None]
+
+
+@track_program("pca.rsvd")
+@partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _rsvd_fit(x, mask, mean, key, k, n_iter, mesh, want_u):
+    """Centre, Halko randomized SVD, V-based signs: one program. ``u`` only
+    where the caller wants scores (``fit`` alone never forms it)."""
+    u, s, vt = linalg.randomized_svd(_centered(x, mask, mean), k, key, mesh,
+                                     n_iter=n_iter)
+    u, vt = linalg.svd_flip(u, vt)
+    return (u if want_u else None), s, vt
+
+
+@track_program("pca.svd_tall")
+@partial(jax.jit, static_argnums=(3, 4))
+def _svd_tall_fit(x, mask, mean, mesh, want_u):
+    """Centre, exact SVD through TSQR, V-based signs: one program."""
+    u, s, vt = linalg.svd_tall(_centered(x, mask, mean), mesh)
+    u, vt = linalg.svd_flip(u, vt)
+    return (u if want_u else None), s, vt
+
+
+@track_program("pca.transform")
+@jax.jit
+def _project(x, mask, mean, comp, scale):
+    """Scores ``((x - mean) * mask) @ comp.T / scale`` in one fused pass
+    over x, multiplied in f32 (``HIGHEST``): a score is a 512-term sum per
+    row, where a single bf16 pass would leave ~3e-3 relative error."""
+    scores = jnp.matmul(_centered(x, mask, mean), comp.T,
+                        precision=jax.lax.Precision.HIGHEST)
+    return scores if scale is None else scores / scale
+
+
 class PCA(TransformerMixin, BaseEstimator):
     """Ref: dask_ml/decomposition/pca.py::PCA."""
 
@@ -104,7 +158,11 @@ class PCA(TransformerMixin, BaseEstimator):
         block_rows = stream_plan(X)
         if block_rows is not None:
             return self._fit_streamed(X, block_rows)
-        self._fit(X)
+        # the root span covers the whole resident call; its children
+        # (fit.validate, fit.center, fit.solve, fit.finish) are the
+        # phases, each ending where its host code ends
+        with span("fit", component="PCA") as root:
+            self._fit(X, root)
         return self
 
     def _fit_streamed(self, X, block_rows):
@@ -249,63 +307,90 @@ class PCA(TransformerMixin, BaseEstimator):
         self.training_profile_ = out["stream"].profile_snapshot()
         return self
 
-    def _fit(self, X):
-        X = check_array(X, dtype=np.float32)
-        n, d = X.shape
-        if n < d:
-            raise ValueError(
-                "PCA requires tall data (n_samples >= n_features); got "
-                f"{n} x {d}"
-            )
-        frac = None
-        if (isinstance(self.n_components, float)
-                and 0.0 < self.n_components < 1.0):
-            # sklearn's variance-fraction API: needs the full spectrum
-            if self._solver(min(n, d), n, d) != "full" and \
-                    self.svd_solver not in ("auto", "full", "tsqr"):
+    def _fit(self, X, root, want_u=False):
+        """The resident fit. Returns (X, u, s, mask); ``u`` (flipped, on
+        the device) is None unless ``want_u``."""
+        with span("fit.validate"):
+            X = check_array(X, dtype=np.float32)
+            n, d = X.shape
+            if n < d:
                 raise ValueError(
-                    "n_components as a variance fraction requires "
-                    "svd_solver in ('auto', 'full', 'tsqr')"
+                    "PCA requires tall data (n_samples >= n_features); got "
+                    f"{n} x {d}"
                 )
-            frac, k = self.n_components, min(n, d)
-        else:
-            k = _resolve_n_components(self.n_components, n, d)
-        mask = X.row_mask(X.dtype)
-        mean, var = masked_mean_var(X.data, mask, n, ddof=1)
-        xc = (X.data - mean) * mask[:, None]
-        solver = "full" if frac is not None else self._solver(k, n, d)
-        if solver == "full":
-            u, s, vt = linalg.svd_tall_jit(xc, X.mesh)
-        else:
-            key = jax.random.PRNGKey(
-                0 if self.random_state is None else int(self.random_state)
-            )
-            u, s, vt = linalg.randomized_svd_jit(
-                xc, k, key, X.mesh,
-                n_iter=max(int(self.iterated_power), 2),
-            )
-        u, vt = linalg.svd_flip(u, vt)
-
-        total_var = float(jnp.sum(var))
-        ev = to_host(s).astype(np.float64) ** 2 / (n - 1)
-        if frac is not None:
-            ratio = np.cumsum(ev / total_var)
-            k = int(np.searchsorted(ratio, frac) + 1)
-        self.n_components_ = k
-        self.components_ = to_host(vt)[:k].astype(np.float64)
-        self.explained_variance_ = ev[:k]
-        self.explained_variance_ratio_ = ev[:k] / total_var
-        self.singular_values_ = to_host(s)[:k].astype(np.float64)
-        self.mean_ = to_host(mean).astype(np.float64)
-        if k < min(n, d):
-            self.noise_variance_ = max(
-                (total_var - ev[:k].sum()) / (min(n, d) - k), 0.0
-            )
-        else:
-            self.noise_variance_ = 0.0
-        self.n_features_in_ = d
-        self.n_samples_ = n
-        return X, u, s, vt, mask
+            frac = None
+            if (isinstance(self.n_components, float)
+                    and 0.0 < self.n_components < 1.0):
+                # sklearn's variance-fraction API: needs the full spectrum
+                if self._solver(min(n, d), n, d) != "full" and \
+                        self.svd_solver not in ("auto", "full", "tsqr"):
+                    raise ValueError(
+                        "n_components as a variance fraction requires "
+                        "svd_solver in ('auto', 'full', 'tsqr')"
+                    )
+                frac, k = self.n_components, min(n, d)
+            else:
+                k = _resolve_n_components(self.n_components, n, d)
+            solver = "full" if frac is not None else self._solver(k, n, d)
+            mask = X.row_mask(X.dtype)
+            # the resident factorisation is float32 whatever the dtype
+            # policy says (the QR chain is precision-bound); on record
+            self.fit_dtype_ = "float32"
+        root.add(n_rows=n)
+        with span("fit.center", x_sweeps=_MEAN_VAR_SWEEPS):
+            # dispatch only: the solver program takes the mean where it
+            # lives and makes the centred copy itself
+            mean, var = _mean_var(X.data, mask, jnp.asarray(n, X.dtype))
+        with span("fit.solve", solver=solver) as sp:
+            if solver == "full":
+                size, n_iter, sweeps = min(n, d), 0, 1
+                u, s, vt = _svd_tall_fit(X.data, mask, mean, X.mesh, want_u)
+            else:
+                key = jax.random.PRNGKey(
+                    0 if self.random_state is None else int(self.random_state)
+                )
+                # randomized_svd's own sketch width and sweep count
+                size = min(k + 10, min(n, d))
+                n_iter = max(int(self.iterated_power), 2)
+                sweeps = linalg.randomized_svd_sweeps(n_iter)
+                u, s, vt = _rsvd_fit(X.data, mask, mean, key, k, n_iter,
+                                     X.mesh, want_u)
+            sp.add(size=size, n_iter=n_iter, x_sweeps=sweeps)
+            root.add(n_iter=n_iter)
+            # the fetch of s is where the host waits for the program
+            s_h = to_host(sp.sync(s)).astype(np.float64)
+            vt_h = to_host(vt).astype(np.float64)
+            mean_h = to_host(mean).astype(np.float64)
+            total_var = float(np.sum(to_host(var), dtype=np.float64))
+        with span("fit.finish"):
+            if not np.isfinite(s_h).all():
+                raise FloatingPointError(
+                    "PCA produced non-finite singular values: the input "
+                    "contains NaN/Inf"
+                )
+            ev = s_h ** 2 / (n - 1)
+            if frac is not None:
+                ratio = np.cumsum(ev / total_var)
+                k = int(np.searchsorted(ratio, frac) + 1)
+            self.n_components_ = k
+            self.components_ = vt_h[:k]
+            self.explained_variance_ = ev[:k]
+            self.explained_variance_ratio_ = ev[:k] / total_var
+            self.singular_values_ = s_h[:k]
+            self.mean_ = mean_h
+            if k < min(n, d):
+                self.noise_variance_ = max(
+                    (total_var - ev[:k].sum()) / (min(n, d) - k), 0.0
+                )
+            else:
+                self.noise_variance_ = 0.0
+            self.n_features_in_ = d
+            self.n_samples_ = n
+            # what carried the fit (the GLMs' and KMeans' solver_info_):
+            # x_sweeps counts the solver program's products with X or X.T
+            self.solver_info_ = {"solver": solver, "size": size,
+                                 "n_iter": n_iter, "x_sweeps": sweeps}
+        return X, u, s, mask
 
     def fit_transform(self, X, y=None):
         from ..parallel.streaming import stream_plan
@@ -315,7 +400,8 @@ class PCA(TransformerMixin, BaseEstimator):
             # out-of-core: fit via the streamed moments pass, then the
             # streamed (block-wise) transform — X never materializes
             return self._fit_streamed(X, block_rows).transform(X)
-        X, u, s, vt, mask = self._fit(X)
+        with span("fit", component="PCA") as root:
+            X, u, s, mask = self._fit(X, root, want_u=True)
         k = self.n_components_
         scores = u[:, :k] * s[None, :k]
         if self.whiten:
@@ -342,16 +428,18 @@ class PCA(TransformerMixin, BaseEstimator):
                 return sc / scale if scale is not None else sc
 
             return streamed_map(X, block_rows, block_scores)
-        X = check_array(X, dtype=np.float32)
-        mask = X.row_mask(X.dtype)
-        comp = jnp.asarray(self.components_, X.dtype)
-        xc = (X.data - jnp.asarray(self.mean_, X.dtype)) * mask[:, None]
-        scores = xc @ comp.T
-        if self.whiten:
-            scores = scores / jnp.sqrt(
-                jnp.asarray(self.explained_variance_, X.dtype)
+        # dispatch only: the scores stay on the device, nothing here waits
+        with span("transform", component="PCA") as root:
+            X = check_array(X, dtype=np.float32)
+            root.add(n_rows=X.n_rows)
+            scale = np.sqrt(np.asarray(self.explained_variance_, np.float32)) \
+                if self.whiten else None
+            scores = _project(
+                X.data, X.row_mask(X.dtype),
+                np.asarray(self.mean_, np.float32),
+                np.asarray(self.components_, np.float32), scale,
             )
-        return ShardedArray(scores, X.n_rows, X.mesh)
+            return ShardedArray(scores, X.n_rows, X.mesh)
 
     def inverse_transform(self, X):
         check_is_fitted(self, "components_")

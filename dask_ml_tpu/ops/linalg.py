@@ -15,17 +15,30 @@ PCA/TruncatedSVD/spectral embedding. The TPU design (SURVEY.md §7 B1):
 Inputs are *padded* row-sharded arrays whose padding rows are exactly zero
 (zero rows leave R and the spanned range unchanged), so no masks are needed
 here — callers zero padding, e.g. after mean-centering.
+
+Precision: every contraction here asks for ``Precision.HIGHEST``. A TPU
+multiplies "f32" operands in one bf16 pass by default, which rounds the
+small replicated factors (``q2_i``, ``u_b``, ``qz``) the same way for every
+row: Q then loses orthonormality at ~2e-4 and the singular values inherit
+it, whatever the row count. The chain is float32 as stated; what each
+contraction costs on the v5e is in PERF.md section 6 (PR 25). XLA's own QR
+expander already multiplies at ``highest``.
 """
 
 from __future__ import annotations
 
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.mesh import DATA_AXIS
+
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=_PRECISION)
 
 
 def tsqr(x: jax.Array, mesh: Mesh, axis_name: str = DATA_AXIS):
@@ -46,7 +59,7 @@ def tsqr(x: jax.Array, mesh: Mesh, axis_name: str = DATA_AXIS):
         q2, r_final = jnp.linalg.qr(rs.reshape(s * r, d))
         i = jax.lax.axis_index(axis_name)
         q2_i = jax.lax.dynamic_slice_in_dim(q2, i * r, r)
-        return q1 @ q2_i, r_final
+        return _mm(q1, q2_i), r_final
 
     # check_vma=False, as at every shard_map site in this package: the
     # bodies do their own cross-shard accounting (explicit psum /
@@ -71,7 +84,7 @@ def svd_tall(x: jax.Array, mesh: Mesh):
     """
     q, r = tsqr(x, mesh)
     u_r, s, vt = jnp.linalg.svd(r, full_matrices=False)
-    return q @ u_r, s, vt
+    return _mm(q, u_r), s, vt
 
 
 def randomized_range_finder(x, size, key, n_iter, mesh):
@@ -83,14 +96,21 @@ def randomized_range_finder(x, size, key, n_iter, mesh):
     """
     d = x.shape[1]
     omega = jax.random.normal(key, (d, size), dtype=x.dtype)
-    y = x @ omega  # psum-reduced matmul pass
+    y = _mm(x, omega)  # psum-reduced matmul pass
     q, _ = tsqr(y, mesh)
     for _ in range(n_iter):
-        z = x.T @ q  # (d, size); XLA inserts the ICI reduction
+        z = _mm(x.T, q)  # (d, size); XLA inserts the ICI reduction
         qz, _ = jnp.linalg.qr(z)  # replicated small QR
-        y = x @ qz
+        y = _mm(x, qz)
         q, _ = tsqr(y, mesh)
     return q
+
+
+def randomized_svd_sweeps(n_iter):
+    """Products with ``x`` or ``x.T`` that one :func:`randomized_svd` makes,
+    each a pass over all of x: the sketch ``x @ omega``, ``x.T @ q`` and
+    ``x @ qz`` per power iteration, and ``q.T @ x``."""
+    return 2 + 2 * int(n_iter)
 
 
 def randomized_svd(x, n_components, key, mesh, n_oversamples=10, n_iter=4):
@@ -100,9 +120,9 @@ def randomized_svd(x, n_components, key, mesh, n_oversamples=10, n_iter=4):
     """
     size = min(n_components + n_oversamples, min(x.shape))
     q = randomized_range_finder(x, size, key, n_iter, mesh)
-    b = q.T @ x  # (size, d), psum-reduced second data pass
+    b = _mm(q.T, x)  # (size, d), psum-reduced second data pass
     u_b, s, vt = jnp.linalg.svd(b, full_matrices=False)
-    u = q @ u_b
+    u = _mm(q, u_b)
     k = n_components
     return u[:, :k], s[:k], vt[:k]
 

@@ -19,7 +19,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = chip_smoke.Sizes(
     d=64, resident_rows=16_384, sample_rows=2_048, stream_rows=16_384,
     stream_block_rows=2_048, kernel_rows=1_024, n_classes=8, lloyd_k=8,
-    lloyd_d=32,
+    lloyd_d=32, pca_rows=4_096, pca_d=96, pca_k=12,
 )
 
 
@@ -50,6 +50,16 @@ def test_kernels_rehearsal():
     assert len(facts) == len(chip_smoke.kernel_cases(TINY)) >= 20
     assert all(f["rel_err_vs_xla_highest"] <= chip_smoke.TOL_KERNEL
                for f in facts.values())
+
+
+def test_pca_rehearsal():
+    """The third family's step on the 8-device mesh: every reading inside
+    the benchmark's band, X on every device."""
+    facts = chip_smoke.step_pca(TINY, interpret=True)
+    assert facts["shards"] == 8
+    assert facts["solver_info"] == {"solver": "randomized", "size": 22,
+                                    "n_iter": 2, "x_sweeps": 6}
+    assert facts["transform"] <= 1e-5 and facts["angle"] < facts["angle_band"]
 
 
 def test_main_refuses_to_run_off_chip():

@@ -17,13 +17,35 @@ def _sharded(n, d, seed=0, dtype=np.float32):
     return x, ShardedArray.from_array(x, default_mesh())
 
 
-def test_tsqr_reconstruction_and_orthonormality():
-    x, sx = _sharded(96, 6)
+# (rows, columns): the small panel, and panels as tall and as wide (the
+# benchmark's 74-column sketch; a width past one 128-lane tile) as a CPU
+# test affords — the local factor is XLA's own column loop, whose length is
+# the width, so there is no blocking of ours to cross
+@pytest.mark.parametrize("n,d", [(96, 6), (20001, 74), (8192, 130)])
+def test_tsqr_reconstruction_and_orthonormality(n, d):
+    x, sx = _sharded(n, d)
     q, r = linalg.tsqr(sx.data, sx.mesh)
-    q, r = np.asarray(q), np.asarray(r)
+    q, r = np.asarray(q, np.float64)[:n], np.asarray(r, np.float64)
     np.testing.assert_allclose(q @ r, x, atol=1e-4)
-    np.testing.assert_allclose(q.T @ q, np.eye(6), atol=1e-4)
+    # ||Q^T Q - I||: f32 Householder, ~1e-6 at these heights (2.5e-5 at
+    # 2,097,152 rows on the v5e, PERF.md)
+    assert np.max(np.abs(q.T @ q - np.eye(d))) <= 2e-5
     assert np.allclose(r, np.triu(r))
+
+
+def test_randomized_svd_sweeps_counts_the_products_with_x():
+    """Counted beside the algorithm: the sketch, two per power iteration,
+    the projection."""
+    assert [linalg.randomized_svd_sweeps(q) for q in (0, 2, 4)] == [2, 6, 10]
+    x, sx = _sharded(256, 24)
+    jaxpr = jax.make_jaxpr(
+        lambda a, k: linalg.randomized_svd(a, 4, k, sx.mesh, n_iter=2)
+    )(sx.data, jax.random.PRNGKey(0))
+    big = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"
+           and any(v.aval.shape in (sx.data.shape, sx.data.shape[::-1])
+                   for v in e.invars)]
+    assert len(big) == linalg.randomized_svd_sweeps(2)
+    assert all(e.params["precision"] is not None for e in big)
 
 
 def test_tsqr_with_zero_padding_rows():
