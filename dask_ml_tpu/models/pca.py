@@ -4,8 +4,10 @@ Reference: ``dask_ml/decomposition/{pca,truncated_svd,incremental_pca}.py``
 (SURVEY.md §2a rows PCA/TruncatedSVD/IncrementalPCA, §3.3 call stack).
 The reference lowers to ``da.linalg.svd`` (TSQR task graph) or
 ``svd_compressed`` (Halko); here those are the single-program TSQR /
-randomized SVD kernels in ``ops/linalg.py`` — per-shard QR + ICI
-all-gather, psum-reduced matmul passes, small replicated SVD.
+randomized SVD kernels in ``ops/linalg.py`` — a per-shard local factor
+(guarded CholeskyQR2, Householder where the guard fails;
+``solver_info_["qr_fallbacks"]`` counts those) + ICI all-gather,
+psum-reduced matmul passes, small replicated SVD.
 
 Centering: padded rows must stay exactly zero after ``X - mean_``, so the
 centered matrix is re-masked before the SVD (zero rows leave R/range
@@ -95,20 +97,22 @@ def _centered(x, mask, mean):
 @partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _rsvd_fit(x, mask, mean, key, k, n_iter, mesh, want_u):
     """Centre, Halko randomized SVD, V-based signs: one program. ``u`` only
-    where the caller wants scores (``fit`` alone never forms it)."""
-    u, s, vt = linalg.randomized_svd(_centered(x, mask, mean), k, key, mesh,
-                                     n_iter=n_iter)
+    where the caller wants scores (``fit`` alone never forms it). Last: how
+    many of the tall QRs had a shard fall back to Householder."""
+    u, s, vt, fallbacks = linalg.randomized_svd(
+        _centered(x, mask, mean), k, key, mesh, n_iter=n_iter)
     u, vt = linalg.svd_flip(u, vt)
-    return (u if want_u else None), s, vt
+    return (u if want_u else None), s, vt, fallbacks
 
 
 @track_program("pca.svd_tall")
 @partial(jax.jit, static_argnums=(3, 4))
 def _svd_tall_fit(x, mask, mean, mesh, want_u):
-    """Centre, exact SVD through TSQR, V-based signs: one program."""
-    u, s, vt = linalg.svd_tall(_centered(x, mask, mean), mesh)
+    """Centre, exact SVD through TSQR, V-based signs: one program; last,
+    whether a shard of its tall QR fell back to Householder."""
+    u, s, vt, fallbacks = linalg.svd_tall(_centered(x, mask, mean), mesh)
     u, vt = linalg.svd_flip(u, vt)
-    return (u if want_u else None), s, vt
+    return (u if want_u else None), s, vt, fallbacks
 
 
 @track_program("pca.transform")
@@ -344,7 +348,8 @@ class PCA(TransformerMixin, BaseEstimator):
         with span("fit.solve", solver=solver) as sp:
             if solver == "full":
                 size, n_iter, sweeps = min(n, d), 0, 1
-                u, s, vt = _svd_tall_fit(X.data, mask, mean, X.mesh, want_u)
+                u, s, vt, fallbacks = _svd_tall_fit(X.data, mask, mean,
+                                                    X.mesh, want_u)
             else:
                 key = jax.random.PRNGKey(
                     0 if self.random_state is None else int(self.random_state)
@@ -353,8 +358,8 @@ class PCA(TransformerMixin, BaseEstimator):
                 size = min(k + 10, min(n, d))
                 n_iter = max(int(self.iterated_power), 2)
                 sweeps = linalg.randomized_svd_sweeps(n_iter)
-                u, s, vt = _rsvd_fit(X.data, mask, mean, key, k, n_iter,
-                                     X.mesh, want_u)
+                u, s, vt, fallbacks = _rsvd_fit(X.data, mask, mean, key, k,
+                                                n_iter, X.mesh, want_u)
             sp.add(size=size, n_iter=n_iter, x_sweeps=sweeps)
             root.add(n_iter=n_iter)
             # the fetch of s is where the host waits for the program
@@ -362,6 +367,8 @@ class PCA(TransformerMixin, BaseEstimator):
             vt_h = to_host(vt).astype(np.float64)
             mean_h = to_host(mean).astype(np.float64)
             total_var = float(np.sum(to_host(var), dtype=np.float64))
+            qr_fallbacks = int(to_host(fallbacks))
+            sp.add(qr_fallbacks=qr_fallbacks)
         with span("fit.finish"):
             if not np.isfinite(s_h).all():
                 raise FloatingPointError(
@@ -387,9 +394,12 @@ class PCA(TransformerMixin, BaseEstimator):
             self.n_features_in_ = d
             self.n_samples_ = n
             # what carried the fit (the GLMs' and KMeans' solver_info_):
-            # x_sweeps counts the solver program's products with X or X.T
+            # x_sweeps counts the solver program's products with X or X.T;
+            # qr_fallbacks the tall QRs (of 1 + n_iter) in which a shard's
+            # panel failed CholeskyQR2's guard and took Householder
             self.solver_info_ = {"solver": solver, "size": size,
-                                 "n_iter": n_iter, "x_sweeps": sweeps}
+                                 "n_iter": n_iter, "x_sweeps": sweeps,
+                                 "qr_fallbacks": qr_fallbacks}
         return X, u, s, mask
 
     def fit_transform(self, X, y=None):

@@ -58,7 +58,8 @@ def test_pca_rehearsal():
     facts = chip_smoke.step_pca(TINY, interpret=True)
     assert facts["shards"] == 8
     assert facts["solver_info"] == {"solver": "randomized", "size": 22,
-                                    "n_iter": 2, "x_sweeps": 6}
+                                    "n_iter": 2, "x_sweeps": 6,
+                                    "qr_fallbacks": 0}
     assert facts["transform"] <= 1e-5 and facts["angle"] < facts["angle_band"]
 
 

@@ -74,7 +74,8 @@ def test_randomized_pca_within_every_band_of_the_exact_reference(
     est, Xs = fitted(X, k, devices)
     assert len(Xs.data.sharding.device_set) == devices
     assert est.solver_info_ == {"solver": "randomized", "size": k + 10,
-                                "n_iter": 2, "x_sweeps": 6}
+                                "n_iter": 2, "x_sweeps": 6,
+                                "qr_fallbacks": 0}
     assert est.fit_dtype_ == "float32"
     got = all_readings(est, Xs, X, k)
     assert set(got) == {"mean", "orthonormal", "eigenvalue", "angle",
@@ -199,7 +200,8 @@ def test_resident_pca_records_spans_counters_and_programs(entry):
     assert kids["fit.center"]["x_sweeps"] == 2
     solve = kids["fit.solve"]
     assert (solve["solver"], solve["size"], solve["n_iter"],
-            solve["x_sweeps"]) == ("randomized", 14, 2, 6)
+            solve["x_sweeps"], solve["qr_fallbacks"]) == \
+        ("randomized", 14, 2, 6, 0)
     assert est.solver_info_["x_sweeps"] == 6
     # transform only dispatches: nothing in it waits for the device
     assert roots[1]["sync_s"] == 0.0
@@ -216,7 +218,7 @@ def test_full_solver_records_its_program_and_one_sweep():
         after = program_calls()
     assert after["pca.svd_tall"] - before.get("pca.svd_tall", 0) == 1
     assert est.solver_info_ == {"solver": "full", "size": 16, "n_iter": 0,
-                                "x_sweeps": 1}
+                                "x_sweeps": 1, "qr_fallbacks": 0}
     exact = ref.pca_exact(ref.row_blocks(X), 3)
     np.testing.assert_allclose(est.explained_variance_,
                                exact["explained_variance"], rtol=1e-4)
