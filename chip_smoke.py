@@ -458,7 +458,7 @@ def step_streamed(sizes, interpret=False, state=None, facts=None):
     # the LAST pass's staging clocks ride along as smoke timings: they say
     # which side of the double buffer a pass waits on
     facts["stats"] = {k: st.get(k) for k in (
-        "layout", "superblock_k", "n_blocks", "block_rows",
+        "superblock_k", "n_blocks", "block_rows",
         "dispatches_per_pass", "sb_shards", "native_reader",
         "native_reader_reason", "host_s", "put_s", "wait_s", "consume_s",
         "pass_s")}
@@ -466,7 +466,6 @@ def step_streamed(sizes, interpret=False, state=None, facts=None):
     facts["fit_dtype"] = info.get("fit_dtype")
     k = int(st.get("superblock_k", 0))
     check(k > 1, f"super-blocks did not engage: {st}")
-    check(st.get("layout") == "stacked", f"layout is {st.get('layout')!r}")
     check(info.get("fused_stream") is True
           and info.get("fused_stream_reason") is None,
           f"fused_stream={info.get('fused_stream')}, "

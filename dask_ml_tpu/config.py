@@ -49,9 +49,6 @@ class Config:
     # changes the minibatch partition — only dispatch granularity — so
     # results are identical at any K.
     superblock_k: int = 0
-    # opt-out: False forces the per-block dispatch path everywhere even
-    # for consumers that support the fused scan
-    stream_superblock: bool = True
     # -- data-parallel superblock streaming (ISSUE 9) ---------------------
     # data-axis shards for the STREAMED superblock hot loop: every
     # super-block stages as a batch-sharded jax.Array (per-shard host
@@ -83,19 +80,9 @@ class Config:
     # makes BlockStream refuse (typed StreamBudgetExceeded) any fit
     # whose per-device staged super-block bytes (K x block_rows/D x
     # ceil(d/M) x itemsize) exceed it, pointing at mesh_shape — the
-    # CPU-verifiable stand-in for real per-chip HBM limits (bench.py
-    # drives the 1-D-refuses / 2-D-completes point through this).
+    # CPU-verifiable stand-in for real per-chip HBM limits.
     # 0 = off (no budget enforced)
     stream_device_byte_budget: int = 0
-    # zero-copy CPU staging: on a single-device XLA:CPU mesh, full
-    # dense 64-byte-aligned blocks import into the runtime as ALIASES
-    # of the host memory (dlpack) instead of device_put copies — the
-    # staging memcpy that competes with the consumer's compute on small
-    # hosts disappears (the streamed hot loop reads X straight from the
-    # source/page cache). Safe because streamed data blocks are only
-    # ever READ (never donated) and source arrays outlive the stream;
-    # disable if the input array is mutated while a fit is running
-    stream_zero_copy: bool = True
     # fused Pallas streamed kernels (ops/pallas_fused.py): on real TPU
     # the super-block hot loops (SGD step, GLM val/vg/vgh reducers,
     # KMeans assign-stats) run fused objective+gradient kernels — one
@@ -140,7 +127,7 @@ class Config:
     # the stream mesh (shard_map + psum twins), the bucketed-nnz sparse
     # format and the fused Pallas bodies. Off keeps the SAME block
     # partition but executes rounds through the device-resident cohort
-    # machinery — the A/B bench.py records
+    # machinery
     search_stream: bool = True
     # automatic densify fallback threshold for the sparse streamed
     # path: a source whose overall nnz density exceeds this fraction
@@ -192,8 +179,7 @@ class Config:
     # times (stream_retries_total counts attempts) before raising the
     # typed StreamIORetriesExhausted. 0 = fail on first error
     stream_io_retries: int = 3
-    # non-finite streamed-block policy: "off" (no check — today's
-    # behavior; staging never reads blocks it can zero-copy), "raise"
+    # non-finite streamed-block policy: "off" (no check), "raise"
     # (typed NonFiniteBlock at the staging boundary), "quarantine"
     # (zero the block's data AND its valid-row count so the existing
     # masked prefix-count folds it out — no shape change, no recompile;

@@ -67,13 +67,14 @@ def max_error(y_true, y_pred):
 def median_absolute_error(y_true, y_pred, sample_weight=None):
     """Median of |err|, matching sklearn's two conventions exactly: the
     unweighted path is ``np.median`` (middle-two average over valid
-    rows), the weighted path is ``_weighted_percentile``'s inverted-cdf
-    — the FIRST sorted error whose cumulative weight reaches half the
-    total (so an explicit zero-weight row can never contribute its
-    error value, and an even split takes the LOWER of the two straddling
-    errors, as sklearn does). One device sort + host f64 prefix sums: an
-    f32 cumsum of unit weights saturates at 2**24 rows (the same hazard
-    the curve metrics guard)."""
+    rows), the weighted path is ``_weighted_percentile``'s
+    averaged-inverted-cdf (scikit-learn >= 1.8) — the FIRST sorted error
+    whose cumulative weight reaches half the total, averaged with the
+    next error of positive weight when the cumulative weight lands ON
+    the half (so unit weights give ``np.median``, and an explicit
+    zero-weight row can never contribute its error value). One device
+    sort + host f64 prefix sums: an f32 cumsum of unit weights saturates
+    at 2**24 rows (the same hazard the curve metrics guard)."""
     t, p, w, n = _canon(y_true, y_pred, sample_weight)
     err = jnp.abs(t - p)
     order = jnp.argsort(err)
@@ -84,4 +85,9 @@ def median_absolute_error(y_true, y_pred, sample_weight=None):
         return float(np.median(es[ws > 0]))
     cw = np.cumsum(ws)
     half = 0.5 * cw[-1]
-    return float(es[int(np.argmax(cw >= half))])
+    i = int(np.argmax(cw >= half))
+    if cw[i] - half > np.finfo(np.float64).eps:
+        return float(es[i])
+    # the next error that carries weight (none: es[i] stands alone)
+    j = min(int(np.searchsorted(cw, cw[i], side="right")), len(es) - 1)
+    return float(0.5 * (es[i] + es[j]))

@@ -149,7 +149,7 @@ class _StreamCohortPlane:
       surviving N.
 
     ``config.search_stream=False`` restores the device-resident cohort
-    path on the SAME partition (the honest A/B bench.py records)."""
+    path on the SAME partition."""
 
     def __init__(self, X_train, y_train, X_test, y_test, n_slots):
         from ..parallel.streaming import BlockStream, fit_block_rows
@@ -167,7 +167,7 @@ class _StreamCohortPlane:
                       "fused_reason": None}
         # probe: the hot loop must actually superblock this source at
         # this partition (a sparse corpus that fell back to per-block
-        # densify, or stream_superblock off, keeps the device plane)
+        # densify, or superblock_k=1, keeps the device plane)
         probe = BlockStream((X_train,), block_rows=self.block_rows,
                             profile=False)
         self.engaged = bool(

@@ -207,12 +207,8 @@ def _sb_assign_stats(acc, Xs, counts, centers, mxu_dtype=None):
     """Super-block Lloyd pass (ISSUE 3): scan the (K, S, d) stack
     through the per-block assign+update kernel, accumulating
     (sums, counts, inertia) in a DONATED carry — one dispatch per K
-    blocks; all-padding slots (counts == 0) contribute zero. ``Xs`` may
-    be a K-tuple of blocks (the CPU layout, see
-    ``streaming.superblock_unrolled``): the chain unrolls at trace time
-    into the same single program."""
-    unrolled = isinstance(Xs, (tuple, list))
-    r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+    blocks; all-padding slots (counts == 0) contribute zero."""
+    r = jnp.arange(Xs.shape[1])
 
     def step(acc, X, c):
         mask = (r < c).astype(X.dtype)
@@ -220,11 +216,6 @@ def _sb_assign_stats(acc, Xs, counts, centers, mxu_dtype=None):
             X, mask, centers, mxu_dtype=mxu_dtype
         )
         return (acc[0] + s, acc[1] + cnt, acc[2] + i)
-
-    if unrolled:
-        for j in range(len(Xs)):
-            acc = step(acc, Xs[j], counts[j])
-        return acc
 
     def scan_step(acc, inp):
         return step(acc, *inp), jnp.float32(0.0)
@@ -263,8 +254,7 @@ def _sb_assign_stats_sharded(mesh, mxu_dtype=None, fused=False,
         from ..ops.pallas_fused import fused_kmeans_block_stats
 
     def body(acc, Xs, counts, centers):
-        unrolled = isinstance(Xs, (tuple, list))
-        r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+        r = jnp.arange(Xs.shape[1])
         cts = counts[0]
         local = jax.tree.map(jnp.zeros_like, acc)
 
@@ -280,25 +270,18 @@ def _sb_assign_stats_sharded(mesh, mxu_dtype=None, fused=False,
                 )
             return (lacc[0] + s, lacc[1] + cnt, lacc[2] + i)
 
-        if unrolled:
-            for j in range(len(Xs)):
-                local = step(local, Xs[j], cts[j])
-        else:
-            def scan_step(lacc, inp):
-                return step(lacc, *inp), jnp.float32(0.0)
+        def scan_step(lacc, inp):
+            return step(lacc, *inp), jnp.float32(0.0)
 
-            local, _ = jax.lax.scan(scan_step, local, (Xs, cts))
+        local, _ = jax.lax.scan(scan_step, local, (Xs, cts))
         local = jax.lax.psum(local, DATA_AXIS)
         return tuple(a + l for a, l in zip(acc, local))
 
     @partial(jax.jit, donate_argnums=(0,))
     def run(acc, Xs, counts, centers):
-        unrolled = isinstance(Xs, (tuple, list))
-        xs_spec = tuple(spec_of(a, 0) for a in Xs) if unrolled \
-            else spec_of(Xs, 1)
         f = jax.shard_map(
             body, mesh=mesh,
-            in_specs=(P(), xs_spec, P(DATA_AXIS, None), P()),
+            in_specs=(P(), spec_of(Xs, 1), P(DATA_AXIS, None), P()),
             out_specs=P(),
             check_vma=False,
         )
@@ -409,18 +392,11 @@ def _sb_assign_stats_pallas(acc, Xs, counts, centers, mxu_dtype=None,
     (tests/test_precision.py)."""
     from ..ops.pallas_fused import fused_kmeans_block_stats
 
-    unrolled = isinstance(Xs, (tuple, list))
-
     def step(acc, X, c):
         s, cnt, i = fused_kmeans_block_stats(
             X, c, centers, mxu=mxu_dtype, interpret=interpret
         )
         return (acc[0] + s, acc[1] + cnt, acc[2] + i)
-
-    if unrolled:
-        for j in range(len(Xs)):
-            acc = step(acc, Xs[j], counts[j])
-        return acc
 
     def scan_step(acc, inp):
         return step(acc, *inp), jnp.float32(0.0)

@@ -223,14 +223,8 @@ def _sgd_sb_scan(W, Xs, ys, counts, lrs, alpha, l2w, l1w, iflag, loss,
     carries the host-precomputed lr clock values (identical to the
     per-block loop's ``_step_args`` sequence). All-padding slots
     (``counts == 0``, the ragged final super-block) leave W untouched —
-    a masked-empty update would still apply the l2/prox terms.
-
-    ``Xs``/``ys`` may instead be K-tuples of per-block arrays (the CPU
-    layout, ``streaming.superblock_unrolled``): the chain unrolls at
-    trace time into the same single program, minus XLA:CPU's per-step
-    block-sized slice copy of a stacked operand."""
-    unrolled = isinstance(Xs, (tuple, list))
-    S = Xs[0].shape[0] if unrolled else Xs.shape[1]
+    a masked-empty update would still apply the l2/prox terms."""
+    S = Xs.shape[1]
     r = jnp.arange(S)
 
     def step(W, Xb, yb, c, lr):
@@ -250,13 +244,6 @@ def _sgd_sb_scan(W, Xs, ys, counts, lrs, alpha, l2w, l1w, iflag, loss,
             W2, loss_v = _sgd_update_one(W, yb, Xb, mask, nv, lr, alpha,
                                          l2w, l1w, iflag, loss, mxu=mxu)
         return jnp.where(c > 0, W2, W), loss_v
-
-    if unrolled:
-        losses = []
-        for j in range(len(Xs)):
-            W, loss_v = step(W, Xs[j], ys[j], counts[j], lrs[j])
-            losses.append(loss_v)
-        return W, jnp.stack(losses)
 
     def scan_step(W, inp):
         Xb, yb, c, lr = inp
@@ -285,8 +272,6 @@ def _sgd_sb_scan_pallas(W, Xs, ys, counts, lrs, alpha, l2w, l1w, iflag,
     from ..ops.pallas_fused import (fused_sgd_block_grad,
                                     fused_sgd_many_block_grad)
 
-    unrolled = isinstance(Xs, (tuple, list))
-
     def step(W, Xb, yb, c, lr):
         nv = jnp.maximum(c.astype(jnp.float32), 1.0)
         if n_out is not None:
@@ -312,13 +297,6 @@ def _sgd_sb_scan_pallas(W, Xs, ys, counts, lrs, alpha, l2w, l1w, iflag,
         )
         W2 = W2.at[:-1].set(coef)
         return jnp.where(c > 0, W2, W), loss_v
-
-    if unrolled:
-        losses = []
-        for j in range(len(Xs)):
-            W, loss_v = step(W, Xs[j], ys[j], counts[j], lrs[j])
-            losses.append(loss_v)
-        return W, jnp.stack(losses)
 
     def scan_step(W, inp):
         Xb, yb, c, lr = inp
@@ -551,8 +529,7 @@ def _sgd_sb_scan_sharded(mesh, loss, n_out, mxu=None, fused=False,
 
     def body(W, Xs, ys, shard_counts, counts, lrs, alpha, l2w, l1w,
              iflag):
-        unrolled = isinstance(Xs, (tuple, list))
-        S = Xs[0].shape[0] if unrolled else Xs.shape[1]
+        S = Xs.shape[1]
         r = jnp.arange(S)
         cts_local = shard_counts[0]
 
@@ -630,14 +607,6 @@ def _sgd_sb_scan_sharded(mesh, loss, n_out, mxu=None, fused=False,
                 W2, loss_v = one(W, yb)
             return jnp.where(c_glob > 0, W2, W), loss_v
 
-        if unrolled:
-            losses = []
-            for j in range(len(Xs)):
-                W, loss_v = step(W, Xs[j], ys[j], cts_local[j],
-                                 counts[j], lrs[j])
-                losses.append(loss_v)
-            return W, jnp.stack(losses)
-
         def scan_step(W, inp):
             Xb, yb, cl, cg, lr = inp
             return step(W, Xb, yb, cl, cg, lr)
@@ -648,13 +617,8 @@ def _sgd_sb_scan_sharded(mesh, loss, n_out, mxu=None, fused=False,
     @partial(jax.jit, donate_argnums=(0,))
     def run(W, Xs, ys, shard_counts, counts, lrs, alpha, l2w, l1w,
             iflag):
-        unrolled = isinstance(Xs, (tuple, list))
-        if unrolled:
-            xs_spec = tuple(spec_of(a, 0) for a in Xs)
-            ys_spec = tuple(spec_of(a, 0) for a in ys)
-        else:
-            xs_spec = spec_of(Xs, 1)
-            ys_spec = spec_of(ys, 1)
+        xs_spec = spec_of(Xs, 1)
+        ys_spec = spec_of(ys, 1)
         f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), xs_spec, ys_spec, P(DATA_AXIS, None), P(),
@@ -854,8 +818,7 @@ def _sgd_cohort_sb_scan(W, idx, Xs, ys, counts, LRS, ACT, alphas,
     identical updates and lr clocks to the device-resident
     ``_sgd_cohort_scan`` over the same minibatches — and an inactive
     (masked or padding) slot passes its weights through untouched."""
-    unrolled = isinstance(Xs, (tuple, list))
-    S = Xs[0].shape[0] if unrolled else Xs.shape[1]
+    S = Xs.shape[1]
     r = jnp.arange(S)
     Wc = _cohort_gather(W, idx)
 
@@ -872,13 +835,6 @@ def _sgd_cohort_sb_scan(W, idx, Xs, ys, counts, LRS, ACT, alphas,
         )
         keep = (act > 0) & (c > 0)
         return jnp.where(keep[:, None], W2, Wc), losses
-
-    if unrolled:
-        losses = []
-        for j in range(len(Xs)):
-            Wc, lv = step(Wc, Xs[j], ys[j], counts[j], LRS[j], ACT[j])
-            losses.append(lv)
-        return _cohort_scatter(W, idx, Wc), jnp.stack(losses)
 
     def scan_step(Wc, inp):
         Xb, yb, c, lrs, act = inp
@@ -901,7 +857,6 @@ def _sgd_cohort_sb_scan_pallas(W, idx, Xs, ys, counts, LRS, ACT,
     step/slot pass-through mask."""
     from ..ops.pallas_fused import fused_sgd_many_block_grad
 
-    unrolled = isinstance(Xs, (tuple, list))
     Wc = _cohort_gather(W, idx)
 
     def step(Wc, Xb, yb, c, lrs, act):
@@ -914,13 +869,6 @@ def _sgd_cohort_sb_scan_pallas(W, idx, Xs, ys, counts, LRS, ACT,
                                       alphas, l2ws, l1ws, iflags)
         keep = (act > 0) & (c > 0)
         return jnp.where(keep[:, None], W2, Wc), losses
-
-    if unrolled:
-        losses = []
-        for j in range(len(Xs)):
-            Wc, lv = step(Wc, Xs[j], ys[j], counts[j], LRS[j], ACT[j])
-            losses.append(lv)
-        return _cohort_scatter(W, idx, Wc), jnp.stack(losses)
 
     def scan_step(Wc, inp):
         Xb, yb, c, lrs, act = inp
@@ -953,8 +901,7 @@ def _sgd_cohort_sb_scan_sharded(mesh, loss, mxu=None, fused=False,
 
     def body(Wc, Xs, ys, shard_counts, counts, LRS, ACT, alphas, l2ws,
              l1ws, iflags):
-        unrolled = isinstance(Xs, (tuple, list))
-        S = Xs[0].shape[0] if unrolled else Xs.shape[1]
+        S = Xs.shape[1]
         r = jnp.arange(S)
         cts_local = shard_counts[0]
 
@@ -996,14 +943,6 @@ def _sgd_cohort_sb_scan_sharded(mesh, loss, mxu=None, fused=False,
             keep = (act > 0) & (c_glob > 0)
             return jnp.where(keep[:, None], W2, W), losses
 
-        if unrolled:
-            losses = []
-            for j in range(len(Xs)):
-                Wc, lv = step(Wc, Xs[j], ys[j], cts_local[j],
-                              counts[j], LRS[j], ACT[j])
-                losses.append(lv)
-            return Wc, jnp.stack(losses)
-
         def scan_step(Wc, inp):
             Xb, yb, cl, cg, lrs, act = inp
             return step(Wc, Xb, yb, cl, cg, lrs, act)
@@ -1014,13 +953,8 @@ def _sgd_cohort_sb_scan_sharded(mesh, loss, mxu=None, fused=False,
     @partial(jax.jit, donate_argnums=(0,))
     def run(W, idx, Xs, ys, shard_counts, counts, LRS, ACT, alphas,
             l2ws, l1ws, iflags):
-        unrolled = isinstance(Xs, (tuple, list))
-        if unrolled:
-            xs_spec = tuple(spec_of(a, 0) for a in Xs)
-            ys_spec = tuple(spec_of(a, 0) for a in ys)
-        else:
-            xs_spec = spec_of(Xs, 1)
-            ys_spec = spec_of(ys, 1)
+        xs_spec = spec_of(Xs, 1)
+        ys_spec = spec_of(ys, 1)
         f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), xs_spec, ys_spec, P(DATA_AXIS, None), P(),
@@ -1582,8 +1516,7 @@ class _SGDBase(BaseEstimator):
             return False, mxu, False, reason
         _, interp = stream_kernel_mode()
         Xs = sb.arrays[0]
-        S, d = Xs[0].shape if isinstance(Xs, (tuple, list)) \
-            else Xs.shape[1:]
+        S, d = Xs.shape[1:]
         D = sb.shard_counts.shape[0] if sb.shard_counts is not None \
             else 1
         S_local = int(S) // max(int(D), 1)
@@ -1888,8 +1821,7 @@ class _SGDBase(BaseEstimator):
             return False, mxu, False, reason
         _, interp = stream_kernel_mode()
         Xs = sb.arrays[0]
-        S, d = Xs[0].shape if isinstance(Xs, (tuple, list)) \
-            else Xs.shape[1:]
+        S, d = Xs.shape[1:]
         D = sb.shard_counts.shape[0] if sb.shard_counts is not None \
             else 1
         S_local = int(S) // max(int(D), 1)

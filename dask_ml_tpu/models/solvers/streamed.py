@@ -317,10 +317,9 @@ def _sb_reducer_sharded(kind, family, intercept, n_classes, mesh,
         fn, extra = _reducer_blocks(kind, n_classes)
 
     def body(acc, beta, Xs, ys, counts):
-        # LOCAL view: Xs (K, S/D, d) or a K-tuple of (S/D, d) blocks,
-        # counts (1, K) — this shard's own valid-row counts
-        unrolled = isinstance(Xs, (tuple, list))
-        r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+        # LOCAL view: Xs (K, S/D, d), counts (1, K) — this shard's own
+        # valid-row counts
+        r = jnp.arange(Xs.shape[1])
         cts = counts[0]
         local = jax.tree.map(jnp.zeros_like, acc)
 
@@ -333,14 +332,10 @@ def _sb_reducer_sharded(kind, family, intercept, n_classes, mesh,
                 out = out if isinstance(out, tuple) else (out,)
             return tuple(l + o for l, o in zip(lacc, out))
 
-        if unrolled:
-            for j in range(len(Xs)):
-                local = step(local, Xs[j], ys[j], cts[j])
-        else:
-            def scan_step(lacc, inp):
-                return step(lacc, *inp), jnp.float32(0.0)
+        def scan_step(lacc, inp):
+            return step(lacc, *inp), jnp.float32(0.0)
 
-            local, _ = jax.lax.scan(scan_step, local, (Xs, ys, cts))
+        local, _ = jax.lax.scan(scan_step, local, (Xs, ys, cts))
         # the super-block's ONE collective: local sums -> replicated
         # global sums, folded into the replicated running carry
         local = jax.lax.psum(local, DATA_AXIS)
@@ -348,13 +343,8 @@ def _sb_reducer_sharded(kind, family, intercept, n_classes, mesh,
 
     @partial(jax.jit, donate_argnums=(0,))
     def run(acc, beta, Xs, ys, counts):
-        unrolled = isinstance(Xs, (tuple, list))
-        if unrolled:
-            xs_spec = tuple(spec_of(a, 0) for a in Xs)
-            ys_spec = tuple(spec_of(a, 0) for a in ys)
-        else:
-            xs_spec = spec_of(Xs, 1)
-            ys_spec = spec_of(ys, 1)
+        xs_spec = spec_of(Xs, 1)
+        ys_spec = spec_of(ys, 1)
         f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), xs_spec, ys_spec, P(DATA_AXIS, None)),
@@ -560,8 +550,7 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
         return (val, g, H)
 
     def body(acc, beta, Xs, ys, counts):
-        unrolled = isinstance(Xs, (tuple, list))
-        r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+        r = jnp.arange(Xs.shape[1])
         cts = counts[0]
         p = acc[1].shape[-1] if len(acc) > 1 else 0
 
@@ -569,7 +558,7 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
             # local accumulators mirror block_sums' output layout
             # (per-feature slices stay sliced until after the data
             # psum), not the replicated carry's
-            dm = (Xs[0].shape[-1] if unrolled else Xs.shape[-1])
+            dm = Xs.shape[-1]
 
             def z(*s):
                 return jnp.zeros(s, jnp.float32)
@@ -594,14 +583,11 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
             return tuple(l + o for l, o in zip(lacc, out))
 
         local = zeros_local()
-        if unrolled:
-            for j in range(len(Xs)):
-                local = step(local, Xs[j], ys[j], cts[j])
-        else:
-            def scan_step(lacc, inp):
-                return step(lacc, *inp), jnp.float32(0.0)
 
-            local, _ = jax.lax.scan(scan_step, local, (Xs, ys, cts))
+        def scan_step(lacc, inp):
+            return step(lacc, *inp), jnp.float32(0.0)
+
+        local, _ = jax.lax.scan(scan_step, local, (Xs, ys, cts))
         # the super-block's ONE data collective, as in the 1-D flavor
         local = jax.lax.psum(local, DATA_AXIS)
         # ... then the per-super-block feature reassembly
@@ -610,13 +596,8 @@ def _sb_reducer_feature_sharded(kind, family, intercept, n_classes,
 
     @partial(jax.jit, donate_argnums=(0,))
     def run(acc, beta, Xs, ys, counts):
-        unrolled = isinstance(Xs, (tuple, list))
-        if unrolled:
-            xs_spec = tuple(_x_spec(a, 0) for a in Xs)
-            ys_spec = tuple(_y_spec(a, 0) for a in ys)
-        else:
-            xs_spec = _x_spec(Xs, 1)
-            ys_spec = _y_spec(ys, 1)
+        xs_spec = _x_spec(Xs, 1)
+        ys_spec = _y_spec(ys, 1)
         f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), xs_spec, ys_spec, P(DATA_AXIS, None)),
@@ -693,16 +674,9 @@ def _sb_reducer(kind, family, intercept, n_classes, mxu=None,
 
         @partial(jax.jit, donate_argnums=(0,))
         def run_fused(acc, beta, Xs, ys, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-
             def step(acc, Xb, yb, c):
                 out = block_sums(beta, Xb, yb, c)
                 return tuple(a + o for a, o in zip(acc, out))
-
-            if unrolled:
-                for j in range(len(Xs)):
-                    acc = step(acc, Xs[j], ys[j], counts[j])
-                return acc
 
             def scan_step(acc, inp):
                 return step(acc, *inp), jnp.float32(0.0)
@@ -716,19 +690,18 @@ def _sb_reducer(kind, family, intercept, n_classes, mxu=None,
 
     @partial(jax.jit, donate_argnums=(0,))
     def run(acc, beta, Xs, ys, counts):
-        unrolled = isinstance(Xs, (tuple, list))
-        r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+        # the shape every super-block reducer repeats (the flavors in
+        # this file, models/kmeans.py, models/streamed_svd.py): prefix
+        # masks from one arange over the block rows, a scan_step that
+        # drops lax.scan's per-step output, ONE scan over the
+        # (K, S, ...) stacks and their (K,) valid-row counts
+        r = jnp.arange(Xs.shape[1])
 
         def step(acc, Xb, yb, c):
             mask = (r < c).astype(Xb.dtype)
             out = fn(beta, Xb, yb, mask, family, intercept, *extra)
             out = out if isinstance(out, tuple) else (out,)
             return tuple(a + o for a, o in zip(acc, out))
-
-        if unrolled:  # CPU layout: same single program, no slice copies
-            for j in range(len(Xs)):
-                acc = step(acc, Xs[j], ys[j], counts[j])
-            return acc
 
         def scan_step(acc, inp):
             return step(acc, *inp), jnp.float32(0.0)
@@ -906,8 +879,7 @@ def _sb_admm_local(local_iter, family, intercept, n_classes,
 
     @partial(jax.jit, donate_argnums=(0,))
     def run(Bk, Uk, Xs, ys, counts, z, rho, n_rows):
-        unrolled = isinstance(Xs, (tuple, list))
-        r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+        r = jnp.arange(Xs.shape[1])
 
         def one(b, u, X, y, c):
             mask = (r < c).astype(X.dtype)
@@ -924,11 +896,6 @@ def _sb_admm_local(local_iter, family, intercept, n_classes,
                                       local_iter, family, intercept)
             return jnp.where(c > 0, nb, b)
 
-        if unrolled:  # CPU layout: same single program, no slice copies
-            return jnp.stack([
-                one(Bk[j], Uk[j], Xs[j], ys[j], counts[j])
-                for j in range(len(Xs))
-            ])
         return jax.vmap(one)(Bk, Uk, Xs, ys, counts)
 
     suffix = "_multi" if n_classes else ""

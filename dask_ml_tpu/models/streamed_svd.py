@@ -85,19 +85,13 @@ def _pca_reducer(kind, mesh=None, model_shards=1):
 
     if kind == "moments":
         def body(acc, shift, Xs, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-            r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+            r = jnp.arange(Xs.shape[1])
 
             def step(a, Xb, c):
                 mask = (r < c).astype(Xb.dtype)
                 cb = (Xb - shift) * mask[:, None]
                 return (a[0] + jnp.sum(cb, axis=0),
                         a[1] + jnp.sum(cb * cb, axis=0))
-
-            if unrolled:
-                for j in range(len(Xs)):
-                    acc = step(acc, Xs[j], counts[j])
-                return acc
 
             def scan_step(a, inp):
                 return step(a, *inp), jnp.float32(0.0)
@@ -106,8 +100,7 @@ def _pca_reducer(kind, mesh=None, model_shards=1):
             return acc
     else:
         def body(acc, mean, omega, Xs, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-            r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+            r = jnp.arange(Xs.shape[1])
 
             def step(a, Xb, c):
                 Z, R = a
@@ -116,11 +109,6 @@ def _pca_reducer(kind, mesh=None, model_shards=1):
                 Yb = cb @ omega
                 return (Z + cb.T @ Yb,
                         _qr_r(jnp.concatenate([R, Yb], axis=0)))
-
-            if unrolled:
-                for j in range(len(Xs)):
-                    acc = step(acc, Xs[j], counts[j])
-                return acc
 
             def scan_step(a, inp):
                 return step(a, *inp), jnp.float32(0.0)
@@ -191,10 +179,9 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
 
     if kind == "moments":
         def body(acc, shift, Xs, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-            r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+            r = jnp.arange(Xs.shape[1])
             cts = counts[0]
-            dm = (Xs[0].shape[-1] if unrolled else Xs.shape[-1])
+            dm = Xs.shape[-1]
             sh = _feat_slice(shift, dm) if M > 1 else shift
             local = (jnp.zeros((dm,), jnp.float32),
                      jnp.zeros((dm,), jnp.float32))
@@ -205,23 +192,17 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
                 return (a[0] + jnp.sum(cb, axis=0),
                         a[1] + jnp.sum(cb * cb, axis=0))
 
-            if unrolled:
-                for j in range(len(Xs)):
-                    local = step(local, Xs[j], cts[j])
-            else:
-                def scan_step(a, inp):
-                    return step(a, *inp), jnp.float32(0.0)
+            def scan_step(a, inp):
+                return step(a, *inp), jnp.float32(0.0)
 
-                local, _ = jax.lax.scan(scan_step, local, (Xs, cts))
+            local, _ = jax.lax.scan(scan_step, local, (Xs, cts))
             local = jax.lax.psum(local, DATA_AXIS)
             if M > 1:
                 local = tuple(_scatter_feat(t) for t in local)
             return tuple(a + l for a, l in zip(acc, local))
 
         def run_body(acc, shift, Xs, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-            xs_spec = (tuple(_x_spec(a, 0) for a in Xs) if unrolled
-                       else _x_spec(Xs, 1))
+            xs_spec = _x_spec(Xs, 1)
             f = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(), xs_spec, P(DATA_AXIS, None)),
@@ -231,10 +212,9 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
             return f(acc, shift, Xs, counts)
     else:
         def body(acc, mean, omega, Xs, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-            r = jnp.arange(Xs[0].shape[0] if unrolled else Xs.shape[1])
+            r = jnp.arange(Xs.shape[1])
             cts = counts[0]
-            dm = (Xs[0].shape[-1] if unrolled else Xs.shape[-1])
+            dm = Xs.shape[-1]
             kp = omega.shape[1]
             if M > 1:
                 mn, om = _feat_slice(mean, dm), _feat_slice(omega, dm)
@@ -254,14 +234,11 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
                         _qr_r(jnp.concatenate([Rl, Yb], axis=0)))
 
             local = (Z0, R0)
-            if unrolled:
-                for j in range(len(Xs)):
-                    local = step(local, Xs[j], cts[j])
-            else:
-                def scan_step(a, inp):
-                    return step(a, *inp), jnp.float32(0.0)
 
-                local, _ = jax.lax.scan(scan_step, local, (Xs, cts))
+            def scan_step(a, inp):
+                return step(a, *inp), jnp.float32(0.0)
+
+            local, _ = jax.lax.scan(scan_step, local, (Xs, cts))
             Zl, Rl = local
             Zd = jax.lax.psum(_scatter_feat(Zl) if M > 1 else Zl,
                               DATA_AXIS)
@@ -272,9 +249,7 @@ def _pca_reducer_sharded(kind, mesh, model_shards):
             return (acc[0] + Zd, Rn)
 
         def run_body(acc, mean, omega, Xs, counts):
-            unrolled = isinstance(Xs, (tuple, list))
-            xs_spec = (tuple(_x_spec(a, 0) for a in Xs) if unrolled
-                       else _x_spec(Xs, 1))
+            xs_spec = _x_spec(Xs, 1)
             f = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(), P(), xs_spec, P(DATA_AXIS, None)),

@@ -26,7 +26,7 @@ equivalent is this package (grown from the flat per-step logger in
   the trace sink without touching the fit;
 - ``_peak``     — the peak-FLOPs table (published peaks by exact
   ``device_kind``; an unknown device is an error) the report's measured
-  MFU and bench.py's analytic MFU both divide by;
+  MFU divides by;
 - ``export``    — span JSONL -> Chrome-trace/Perfetto JSON
   (``report ... --perfetto out.json``);
 - ``report``    — ``python -m dask_ml_tpu.observability.report
@@ -112,7 +112,6 @@ from ._counters import (
     record_superblock,
     record_superblock_donation,
     record_transfer,
-    record_zero_copy,
 )
 from ._metrics import (
     MetricsLogger,
@@ -267,7 +266,6 @@ __all__ = [
     "record_superblock",
     "record_superblock_donation",
     "record_transfer",
-    "record_zero_copy",
     "span",
     "start_profiler_server",
     "stop_engine",

@@ -94,16 +94,6 @@ def record_superblock(n_blocks: int) -> None:
         counter_add("superblock_blocks", int(n_blocks))
 
 
-def record_zero_copy(nbytes: int) -> None:
-    """One streamed block staged as a zero-copy ALIAS of host memory
-    (dlpack import on XLA:CPU) instead of a device_put copy —
-    zero_copy_bytes is host memcpy traffic the staging path did NOT
-    pay (the h2d_bytes counter only counts real copies)."""
-    if counters_enabled():
-        counter_add("zero_copy_bytes", int(nbytes))
-        counter_add("zero_copy_blocks", 1)
-
-
 def record_shard_staging(n_shards: int) -> None:
     """One batch-sharded staging assembly: ``n_shards`` per-shard host
     slabs were placed onto their own devices (ISSUE 9 data-parallel

@@ -16,11 +16,9 @@ deadline, dumps to the trace sink:
 Contract: the watchdog NEVER raises into or kills the observed fit
 (same never-raise posture as ``_spans._FileSink``) — it reports each
 stalled span once and keeps polling. An optional ``on_stall`` callback
-receives each record (bench prints it to stderr; a serving deployment
-could page on it).
+receives each record (a serving deployment could page on it).
 
-``bench.py``'s TPU child and ``ModelServer``'s worker both run under
-``watchdog()``; with ``watchdog_timeout_s == 0`` (the default) the
+``ModelServer``'s worker runs under ``watchdog()``; with ``watchdog_timeout_s == 0`` (the default) the
 context manager is a complete no-op — no thread, nothing armed, nothing
 in traced code.
 """

@@ -152,10 +152,9 @@ class SuperBlock:
     """K stacked streamed blocks: ONE dispatch's worth of data.
 
     ``arrays[i]`` is the stream's i-th array as a device
-    ``(K, block_rows, ...)`` stack — or, in the CPU layout, a K-tuple
-    of ``(block_rows, ...)`` device blocks (see ``superblock_unrolled``)
-    — and ``counts`` the device ``(K,)`` int32 valid-row counts (a
-    consumer derives each step's prefix mask from them). The FINAL
+    ``(K, block_rows, ...)`` stack and ``counts`` the device ``(K,)``
+    int32 valid-row counts (a consumer derives each step's prefix mask
+    from them). The FINAL
     super-block of a pass is padded to the same K — missing block slots
     carry ``counts == 0`` and all-zero data, so every dispatch compiles
     once — and ``n_blocks`` says how many slots are real. ``n_rows`` is
@@ -181,90 +180,32 @@ class SuperBlock:
         self.shard_counts = shard_counts
 
 
-# XLA:CPU's dlpack import aliases host memory (zero-copy) only at
-# >=64-byte alignment; below it the runtime silently copies — correct
-# but pointless, so misaligned blocks keep the plain device_put path
-_ZC_ALIGN = 64
-
-
-def _dlpack_alias(a):
-    """Import one host block into the runtime as a zero-copy ALIAS of
-    its memory (XLA:CPU dlpack), or None when the import cannot be
-    zero-copy (alignment / layout) or fails — callers then device_put a
-    copy as before.
-
-    Safety contract (why aliasing host memory is sound here): streamed
-    data blocks are only ever READ by the consumers (input buffers are
-    immutable to XLA unless donated, and no streamed kernel donates its
-    data operands — only accumulator/weight carries), the block is
-    either a view of a source array the stream holds alive for its own
-    lifetime or a freshly allocated buffer the returned array's dlpack
-    capsule keeps alive, and staging-ring slabs (which ARE refilled)
-    never take this path. ``config.stream_zero_copy`` opts out for
-    callers that mutate the source mid-fit."""
-    if (a.ctypes.data % _ZC_ALIGN) or not a.flags["C_CONTIGUOUS"] \
-            or a.nbytes == 0:
-        return None
-    try:
-        if not a.flags.writeable:
-            # numpy refuses dlpack export of readonly arrays (e.g.
-            # mode="r" memmaps). XLA only reads the buffer, so re-wrap
-            # the same memory writeable for the export alone. The
-            # ctypes buffer owns NOTHING (from_address) — pin the
-            # original view on it so the capsule chain
-            # (jax.Array -> wrapper -> ctypes buf -> view -> mmap)
-            # keeps the mapping alive for as long as the device array
-            # exists, even if the caller drops the source mid-pass.
-            import ctypes
-
-            buf = (ctypes.c_byte * a.nbytes).from_address(a.ctypes.data)
-            buf._keepalive = a
-            src = np.frombuffer(buf, dtype=a.dtype).reshape(a.shape)
-        else:
-            src = a
-        from jax import dlpack as _jdl
-
-        return _jdl.from_dlpack(src)
-    except Exception:
-        return None
-
-
 _PUT_ALIASES = None
 
 
 def _device_put_aliases() -> bool:
     """One-time semantic probe: does this backend's ``device_put``
-    alias (zero-copy) host numpy memory? Every backend in CI copies —
-    but if one ever aliases, a reused staging buffer would be mutated
-    under a still-queued consumer computation (block_until_ready only
-    covers the transfer, not later reads of an aliased buffer), so the
-    super-block ring switches to fresh per-super-block buffers there.
-    The probe is the direct hazard: mutate the source after the put and
-    see whether the device array changed."""
+    alias (zero-copy) host numpy memory? Where it does, a reused staging
+    buffer would be mutated under a still-queued consumer computation
+    (block_until_ready only covers the transfer, not later reads of an
+    aliased buffer), so the super-block ring switches to fresh
+    per-super-block buffers there. The probe is the direct hazard:
+    mutate the source after the put and see whether the device array
+    changed. The source is 64-byte aligned because that is the case a
+    runtime aliases (XLA:CPU does, and copies anything less aligned: a
+    probe left to malloc's 16 bytes reads "copies" by luck)."""
     global _PUT_ALIASES
     if _PUT_ALIASES is None:
         try:
-            probe = np.zeros(8, np.float32)
+            raw = np.zeros(8 + 16, np.float32)
+            lead = (-raw.ctypes.data % 64) // 4
+            probe = raw[lead:lead + 8]
             dev = jax.block_until_ready(jax.device_put(probe))
             probe[:] = 1.0
             _PUT_ALIASES = bool(float(np.asarray(dev)[0]) == 1.0)
         except Exception:
             _PUT_ALIASES = True  # cannot prove safety: assume aliasing
     return _PUT_ALIASES
-
-
-def superblock_unrolled() -> bool:
-    """Which super-block layout this backend wants. TPU/GPU: ONE
-    stacked [K, block_rows, d] buffer consumed by a lax.scan — one DMA
-    per super-block, and HBM scan slices are effectively free. XLA:CPU
-    lowers each scan step's dynamic-slice of the stacked operand as a
-    block-sized memcpy (measured ~2x the whole step's compute) and a
-    stacked device_put as one single-threaded copy — there the executor
-    keeps K separate block buffers (put as one pytree: transfers run
-    concurrently, and full blocks stage as VIEWS with no host copy) and
-    the kernels unroll the K-step chain inside the same single
-    dispatch. Same math, same dispatch count, per-backend layout."""
-    return jax.default_backend() == "cpu"
 
 
 # auto block budget: bytes of ONE block's X on device. Fixed bytes (not a
@@ -564,10 +505,8 @@ class BlockStream:
         from ..config import get_config
         from ..observability.live import ensure_telemetry
 
-        # reliability plane (ISSUE 11), captured once like _zero_copy:
-        # bounded-backoff IO retry budget, the non-finite block policy,
-        # and whether any fault plan is armed (the zero-overhead gate
-        # for the staging-read fault site on the zero-copy view path)
+        # reliability plane (ISSUE 11), captured once: bounded-backoff
+        # IO retry budget and the non-finite block policy
         cfg_rel = get_config()
         self._io_retries = max(int(cfg_rel.stream_io_retries), 0)
         nf = (cfg_rel.stream_nonfinite if nonfinite is None
@@ -582,25 +521,6 @@ class BlockStream:
         # staging runs on a worker thread whose thread-local config does
         # not carry the creator's config.set overrides
         self._fault_spec = cfg_rel.fault_plan
-        self._fault_armed = bool(self._fault_spec)
-
-        # zero-copy staging (config.stream_zero_copy): on a
-        # single-device XLA:CPU mesh, full-height aligned dense blocks
-        # import as dlpack ALIASES of host memory instead of paying a
-        # device_put memcpy — see _dlpack_alias for the safety
-        # contract. Multi-device meshes keep the sharded put (an
-        # aliased import is single-device), other backends have real
-        # device memory to copy into.
-        # ... and the one device must BE the process default device: a
-        # dlpack import always lands on jax.devices()[0], so a stream
-        # pinned to any other device (a virtual rank's submesh) would
-        # stage its aliases onto the wrong chip
-        self._zero_copy = bool(
-            get_config().stream_zero_copy
-            and jax.default_backend() == "cpu"
-            and self.mesh.devices.size == 1
-            and self.mesh.devices.flat[0] == jax.devices()[0]
-        )
 
         # per-feature training profile (observability/sketch.py): the
         # staging path folds a strided row sample of the FIRST pass's
@@ -773,45 +693,6 @@ class BlockStream:
         prof = self.profile
         return prof.to_dict() if prof is not None and prof.rows else None
 
-    def _view_ok(self, a):
-        # a full-height dense block whose dtype already matches can
-        # skip host staging as a VIEW of the source — zero host copy
-        # (np.memmap is an ndarray subclass, so sequential memmap
-        # passes stage straight from the page cache)
-        return (isinstance(a, np.ndarray)
-                and not isinstance(a, np.generic)
-                and a.dtype == self.dtype)
-
-    def _zc_block_guarantee(self, a):
-        """True when EVERY full-height block of ``a`` is guaranteed to
-        import zero-copy: dtype matches (view staging), the source is
-        C-contiguous, and both the base pointer and the per-block byte
-        stride are 64-byte aligned (a block's offset is
-        ``b * block_rows * strides[0]``). A dtype-match alone is NOT
-        enough to reroute staging — a misaligned or non-contiguous
-        source would lose the readahead/overlap machinery and then pay
-        full copies on the consumer thread anyway."""
-        return (self._view_ok(a)
-                and a.flags["C_CONTIGUOUS"]
-                and a.ctypes.data % _ZC_ALIGN == 0
-                and (self.block_rows * a.strides[0]) % _ZC_ALIGN == 0)
-
-    def _gate_readers_for_zero_copy(self, readers, reason):
-        """Null out (and close) readahead readers for arrays whose full
-        blocks are GUARANTEED to stage as zero-copy aliases — the view
-        path then pays neither the reader's copy-out nor a
-        device_put. Arrays without the guarantee keep their reader.
-        Returns (readers, reason) like :meth:`_native_readers`."""
-        if readers is None or not self._zero_copy:
-            return readers, reason
-        for i, (r, a) in enumerate(zip(readers, self.arrays)):
-            if r is not None and self._zc_block_guarantee(a):
-                r.close()
-                readers[i] = None
-        if any(r is not None for r in readers):
-            return readers, None
-        return None, "zero-copy-views"
-
     @staticmethod
     def _disable_reader(readers, i):
         """A reader whose read failed mid-stream has an untrustworthy
@@ -954,23 +835,12 @@ class BlockStream:
 
     def _put_impl(self, host_block):
         outs, m, mask = host_block
-        from ..observability import record_transfer, record_zero_copy
+        from ..observability import record_transfer
 
-        dev = []
-        copied = mask.nbytes
-        for a, s in zip(outs, self._shardings):
-            # full blocks reach here as source views (or fresh reader
-            # copies); both are safe to alias — see _dlpack_alias
-            zc = _dlpack_alias(a) if self._zero_copy else None
-            if zc is not None:
-                record_zero_copy(a.nbytes)
-                dev.append(zc)
-            else:
-                copied += a.nbytes
-                dev.append(jax.device_put(a, s))
-        record_transfer(copied)
-        return Block(tuple(dev), m,
-                     jax.device_put(mask, self._mask_sharding))
+        record_transfer(sum(a.nbytes for a in outs) + mask.nbytes)
+        dev = tuple(jax.device_put(a, s)
+                    for a, s in zip(outs, self._shardings))
+        return Block(dev, m, jax.device_put(mask, self._mask_sharding))
 
     def __iter__(self):
         import time as _time
@@ -978,9 +848,7 @@ class BlockStream:
         order = np.arange(self.n_blocks)
         if self.shuffle:
             self.rng.shuffle(order)
-        readers, why = self._gate_readers_for_zero_copy(
-            *self._native_readers(sequential=not self.shuffle)
-        )
+        readers, why = self._native_readers(sequential=not self.shuffle)
         # per-pass overlap accounting (SURVEY §7 B0: the double buffer is
         # the heart of the system — measure it, don't assume it):
         #   host_s   — disk/densify/pad time building host blocks
@@ -1137,14 +1005,12 @@ class BlockStream:
         """Blocks per super-block for this stream: the K autotuner's
         override, else ``config.superblock_k``, else the auto policy
         (8, capped by the pass length and the super-block byte budget).
-        1 — the per-block path — when super-blocking is opted out or the
-        source is sparse (ragged CSR densify slices stage per-block; the
-        fixed staging ring would re-densify whole slabs)."""
+        1 — the per-block path — when the source is sparse and densifies
+        (ragged CSR densify slices stage per-block; the fixed staging
+        ring would re-densify whole slabs)."""
         from ..config import get_config
 
         cfg = get_config()
-        if not cfg.stream_superblock:
-            return 1
         if any(_is_sparse_source(a) for a in self.arrays):
             if self.sparse_plan is None:
                 return 1
@@ -1290,7 +1156,7 @@ class BlockStream:
         self._ring_key = shape_key
         return ring
 
-    def _guard_sb_block(self, slot, parts, j, m, counts, unroll):
+    def _guard_sb_block(self, slot, j, m, counts):
         """Apply ``stream_nonfinite`` to one staged super-block slot:
         a non-finite block either raises typed or quarantines — data
         zeroed and ``counts[j]`` folded to 0, exactly the shape the
@@ -1299,11 +1165,8 @@ class BlockStream:
         it). No-op at the default policy."""
         if self._nonfinite == "off" or m == 0:
             return
-        n_arr = len(self.arrays)
-        pieces = ([parts[i][j] for i in range(n_arr)] if unroll
-                  else [slot["bufs"][i][j] for i in range(n_arr)])
-        if all(bool(np.isfinite(np.asarray(p)[:m]).all())
-               for p in pieces):
+        if all(bool(np.isfinite(buf[j, :m]).all())
+               for buf in slot["bufs"]):
             return
         from ..reliability.faults import NonFiniteBlock
 
@@ -1315,12 +1178,8 @@ class BlockStream:
         from ..observability import record_stream_quarantine
 
         counts[j] = 0
-        for i in range(n_arr):
-            slot["bufs"][i][j] = 0
-            if unroll:
-                # a view / zero-copy alias can't be zeroed in place —
-                # swap the slot's zeroed staging buffer in instead
-                parts[i][j] = slot["bufs"][i][j]
+        for buf in slot["bufs"]:
+            buf[j] = 0
         record_stream_quarantine()
 
     def _sb_slot(self, k):
@@ -1352,8 +1211,7 @@ class BlockStream:
             return
         import time as _time
 
-        from ..observability import (record_superblock,
-                                     record_transfer, record_zero_copy,
+        from ..observability import (record_superblock, record_transfer,
                                      span)
 
         k = self.resolve_superblock_k()
@@ -1367,11 +1225,12 @@ class BlockStream:
             len(order) == self.n_blocks
             and np.array_equal(order, np.arange(self.n_blocks))
         )
-        readers, why = self._gate_readers_for_zero_copy(
-            *self._native_readers(sequential=sequential)
-        )
-        ring = self._sb_ring(k)
-        unroll = superblock_unrolled()
+        readers, why = self._native_readers(sequential=sequential)
+        # aliasing backends (device_put zero-copies host memory, see
+        # _device_put_aliases) can never see a REUSED staging buffer — a
+        # queued consumer computation would read the refill: no ring
+        # there, a fresh slab set per super-block
+        ring = None if _device_put_aliases() else self._sb_ring(k)
         D = self.sb_data_shards()
         sharded = self.sb_sharded()
         self._check_device_budget(k)
@@ -1385,10 +1244,8 @@ class BlockStream:
                  # /status render as "DxM"
                  "mesh": mesh_str(self.mesh),
                  "dispatches_per_pass": int(n_sb),
-                 # which layout the consumers' scans ran (stacked
-                 # lax.scan vs the CPU K-tuple chain) and whether the
-                 # C++ readahead reader fed the pass, else why not
-                 "layout": "unrolled" if unroll else "stacked",
+                 # whether the C++ readahead reader fed the pass, else
+                 # why not
                  "native_reader": readers is not None,
                  "native_reader_reason": why}
         t_pass = _time.perf_counter()
@@ -1396,12 +1253,9 @@ class BlockStream:
 
         pending = deque()
 
-        view_ok = self._view_ok
-
         def fill(slot, blocks):
-            """Assemble ``blocks`` (block indices) into host parts:
-            the slot's stacked slabs (scan layout) or per-block host
-            buffers/views (unrolled layout). Returns (parts, counts)."""
+            """Assemble ``blocks`` (block indices) into the slot's
+            stacked host slabs and valid-row counts."""
             if slot["dev"] is not None:
                 # the slot's previous transfer must have committed
                 # before its host buffer is rewritten
@@ -1409,7 +1263,6 @@ class BlockStream:
                 slot["dev"] = None
             counts = slot["counts"]
             counts[:] = 0
-            parts = [[] for _ in self.arrays] if unroll else None
             for j, b in enumerate(blocks):
                 lo = int(b) * self.block_rows
                 hi = min(lo + self.block_rows, self.n_rows)
@@ -1417,72 +1270,32 @@ class BlockStream:
                 counts[j] = m
                 for i, a in enumerate(self.arrays):
                     buf = slot["bufs"][i]
-                    from_reader = (readers is not None
-                                   and readers[i] is not None)
-                    if (unroll and not from_reader
-                            and m == self.block_rows and view_ok(a)):
-                        if i == 0:
-                            self._profile_fold(a[lo:hi])
-                        # with a fault plan armed the view read runs
-                        # through the staging_read site (which may
-                        # return a poisoned COPY — never the source);
-                        # unarmed, the pristine zero-copy view path is
-                        # untouched
-                        blk = self._read_block_host(i, a, lo, hi, None) \
-                            if self._fault_armed else a[lo:hi]
-                        if self._zero_copy:
-                            # source view -> zero-copy alias now, ON
-                            # the staging thread; put() passes the
-                            # already-imported array through
-                            dev = _dlpack_alias(blk)
-                            if dev is not None:
-                                record_zero_copy(blk.nbytes)
-                                parts[i].append(dev)
-                                continue
-                        parts[i].append(blk)
-                        continue
                     self._read_block_host(i, a, lo, hi, readers,
                                           out=buf[j])
                     if i == 0:
                         self._profile_fold(buf[j, :m])
                     if m < self.block_rows:
                         buf[j, m:] = 0
-                    if unroll:
-                        parts[i].append(buf[j])
-                self._guard_sb_block(slot, parts, j, m, counts, unroll)
-            for i in range(len(self.arrays)):
-                for j in range(len(blocks), k):
-                    slot["bufs"][i][j] = 0
-                    if unroll:
-                        parts[i].append(slot["bufs"][i][j])
-            return (parts if unroll else slot["bufs"]), counts
+                self._guard_sb_block(slot, j, m, counts)
+            for buf in slot["bufs"]:
+                buf[len(blocks):] = 0
 
         shard_counts_of = self._shard_counts_of
 
-        def put(slot, parts, counts, n_real):
+        def put(slot, n_real):
+            parts, counts = slot["bufs"], slot["counts"]
+            record_transfer(sum(b.nbytes for b in parts) + counts.nbytes)
+            counts_d = jax.device_put(counts, self._counts_sharding)
             if sharded:
                 # data-parallel staging (ISSUE 9): each array becomes a
                 # batch-sharded jax.Array assembled from per-shard host
                 # slabs placed onto their own device — the consumer's
                 # shard_map scan then reads purely local rows and pays
                 # ONE psum per super-block for its reducers
-                if unroll:
-                    nbytes = sum(b.nbytes for p in parts for b in p)
-                    record_transfer(nbytes + counts.nbytes)
-                    dev = tuple(
-                        tuple(self._put_sharded(b, self._shardings[i])
-                              for b in p)
-                        for i, p in enumerate(parts)
-                    )
-                else:
-                    record_transfer(
-                        sum(b.nbytes for b in parts) + counts.nbytes
-                    )
-                    dev = tuple(
-                        self._put_sharded(b, s)
-                        for b, s in zip(parts, self._sb_shardings)
-                    )
-                counts_d = jax.device_put(counts, self._counts_sharding)
+                dev = tuple(
+                    self._put_sharded(b, s)
+                    for b, s in zip(parts, self._sb_shardings)
+                )
                 shard_d = self._put_sharded(
                     shard_counts_of(counts), self._shard_counts_sharding
                 )
@@ -1490,38 +1303,10 @@ class BlockStream:
                 return SuperBlock(dev, counts_d, n_real,
                                   int(counts[:n_real].sum()),
                                   shard_counts=shard_d)
-            if unroll:
-                nbytes = sum(b.nbytes for p in parts for b in p
-                             if not isinstance(b, jax.Array))
-                record_transfer(nbytes + counts.nbytes)
-                # ONE pytree device_put per array: the K block
-                # transfers are issued together (concurrent copies — a
-                # single stacked put is one serial memcpy on CPU).
-                # Blocks the staging thread already imported zero-copy
-                # (jax.Array entries) pass straight through; the
-                # leftovers (ragged tail, padding slots, unaligned
-                # arrays) are put individually — they are the small
-                # minority whenever aliasing is on at all
-                dev = tuple(
-                    tuple(jax.device_put(
-                        p, [self._shardings[i]] * len(p)
-                    )) if not any(isinstance(b, jax.Array) for b in p)
-                    else tuple(
-                        b if isinstance(b, jax.Array)
-                        else jax.device_put(b, self._shardings[i])
-                        for b in p
-                    )
-                    for i, p in enumerate(parts)
-                )
-            else:
-                record_transfer(
-                    sum(b.nbytes for b in parts) + counts.nbytes
-                )
-                dev = tuple(
-                    jax.device_put(b, s)
-                    for b, s in zip(parts, self._sb_shardings)
-                )
-            counts_d = jax.device_put(counts, self._counts_sharding)
+            dev = tuple(
+                jax.device_put(b, s)
+                for b, s in zip(parts, self._sb_shardings)
+            )
             slot["dev"] = dev + (counts_d,)
             return SuperBlock(dev, counts_d, n_real,
                               int(counts[:n_real].sum()))
@@ -1534,16 +1319,13 @@ class BlockStream:
             whose device_put is a synchronous host copy (CPU) the
             thread is what makes the overlap real."""
             blocks = order[i * k:(i + 1) * k]
-            # aliasing backends (device_put zero-copies host memory, see
-            # _device_put_aliases) can never see a REUSED staging buffer
-            # — a queued consumer computation would read the refill
-            slot = self._sb_slot(k) if _device_put_aliases() \
+            slot = self._sb_slot(k) if ring is None \
                 else ring[i % len(ring)]
             t0 = _time.perf_counter()
-            parts, counts = fill(slot, blocks)
+            fill(slot, blocks)
             t1 = _time.perf_counter()
             stats["host_s"] += t1 - t0
-            sb = put(slot, parts, counts, len(blocks))
+            sb = put(slot, len(blocks))
             stats["put_s"] += _time.perf_counter() - t1
             return sb
 
@@ -1570,43 +1352,9 @@ class BlockStream:
             yield sb
             stats["consume_s"] += _time.perf_counter() - t_y
 
-        # when every array's staging is guaranteed (near-)free — its
-        # full blocks alias zero-copy, or its per-block bytes are so
-        # small the copy is noise — the background staging worker has
-        # nothing real to overlap, and the per-pass executor spin-up,
-        # future hand-offs, and GIL ping-pong between the two threads
-        # cost more than they hide (~30% of a steady-state CPU pass at
-        # bench shapes). Stage inline there; keep the worker wherever a
-        # real memcpy/densify/device_put pipeline exists to overlap
-        # (non-contiguous or misaligned sources, dtype conversion).
-        def _cheap_to_stage(a):
-            if self._zc_block_guarantee(a):
-                return True
-            row_bytes = 4 * int(np.prod(a.shape[1:], dtype=np.int64)
-                                or 1)
-            return row_bytes * self.block_rows <= (1 << 20)
+        from concurrent.futures import ThreadPoolExecutor
 
-        inline = self._zero_copy and all(
-            _cheap_to_stage(a) for a in self.arrays
-        )
-
-        class _Done:
-            __slots__ = ("v",)
-
-            def __init__(self, v):
-                self.v = v
-
-            def result(self):
-                return self.v
-
-        if inline:
-            staging = None
-            submit = lambda fn, i: _Done(fn(i))  # noqa: E731
-        else:
-            from concurrent.futures import ThreadPoolExecutor
-
-            staging = ThreadPoolExecutor(max_workers=1)
-            submit = staging.submit
+        staging = ThreadPoolExecutor(max_workers=1)
         with span("streaming.superblock") as sp:
             # recording spans only: a span tracked solely for the
             # watchdog (sinkless, armed timeout) must not switch on the
@@ -1617,14 +1365,13 @@ class BlockStream:
             )
             try:
                 for i in range(n_sb):
-                    pending.append(submit(produce, i))
+                    pending.append(staging.submit(produce, i))
                     if len(pending) > self.prefetch:
                         yield from emit(pop())
                 while pending:
                     yield from emit(pop())
             finally:
-                if staging is not None:
-                    staging.shutdown(wait=True)
+                staging.shutdown(wait=True)
                 stats["pass_s"] = _time.perf_counter() - t_pass
                 self.stats = stats
                 self._passes = getattr(self, "_passes", 0) + 1

@@ -272,7 +272,7 @@ def fire_plan(spec: str, site: str, payload=None):
     sites running on worker threads (super-block staging) where the
     thread-local config does not carry the creator's ``config.set``
     overrides; the creator captures its spec once and threads it
-    through, the way ``BlockStream`` captures ``stream_zero_copy``."""
+    through."""
     if not spec:
         return payload
     plan = _plans.get(spec)
@@ -298,8 +298,8 @@ def fire_plan(spec: str, site: str, payload=None):
     if arm.kind == "hang":
         time.sleep(arm.hang_s)
         return payload
-    # "nan": poison a COPY — the payload may be a view of user data /
-    # a zero-copy staging alias, which must never be mutated in place
+    # "nan": poison a COPY — the payload may be a view of user data,
+    # which must never be mutated in place
     if payload is not None:
         try:
             poisoned = np.array(payload, copy=True)

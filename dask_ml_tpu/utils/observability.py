@@ -1,8 +1,8 @@
 """Back-compat shim: the observability subsystem grew into the
 ``dask_ml_tpu.observability`` package (span tracing, counters, report
 CLI). Every name that ever lived here re-exports from there — including
-the module-global ``_active_loggers`` sink registry, which external
-code (bench.py, tests) binds directly."""
+the module-global ``_active_loggers`` sink registry, which tests bind
+directly."""
 
 from ..observability import *  # noqa: F401,F403
 from ..observability import (  # noqa: F401

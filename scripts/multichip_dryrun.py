@@ -1,15 +1,15 @@
 """8-device CPU multichip dryrun with a recorded flight-recorder trace.
 
-Extends the MULTICHIP_r*.json dryrun (8 virtual XLA:CPU devices via
-``--xla_force_host_platform_device_count``) beyond "does the sharded
-path run": the run records a span trace + program registry under
+On 8 virtual XLA:CPU devices
+(``--xla_force_host_platform_device_count``) this goes beyond "does the
+sharded path run": the run records a span trace + program registry under
 ``config.trace_dir`` (spans) plus a separate counters/programs file and
 ASSERTS ``report --merge`` folds both into ONE timeline rendering spans
 AND a programs table for the sharded L-BFGS and ADMM fit paths — the
 observability the next wedged-TPU round will need, proven on the same
 virtual mesh the tier-1 suite uses.
 
-Prints one JSON line (MULTICHIP_r*.json shape, plus the trace fields):
+Prints one JSON line:
 
     {"n_devices": 8, "ok": true, "rc": 0, "trace_records": ...,
      "report_spans": [...], "report_programs": [...]}
@@ -87,8 +87,8 @@ def main():
                 sglm.solver_info_
             trace = os.path.join(trace_dir, "trace.jsonl")
             # counters/programs land in a SEPARATE file, the shape a
-            # multi-process run produces (bench child + serving worker
-            # each append their own sink) — report --merge below must
+            # multi-process run produces (each process appends its own
+            # sink) — report --merge below must
             # fold both into one timeline
             aux = os.path.join(trace_dir, "aux.jsonl")
             with obs.MetricsLogger(aux) as lg:

@@ -1,8 +1,8 @@
 """Perf smoke gate for the super-block streaming hot loop (ISSUE 3 +
 the ISSUE 9 data-parallel flavor).
 
-Runs a scaled-down version of bench.py's streamed-SGD section and fails
-(exit 1) when the dispatch-collapse contract regresses:
+Runs a small streamed-SGD fit and fails (exit 1) when the
+dispatch-collapse contract regresses:
 
 - ``dispatches_per_pass`` must not exceed ceil(n_blocks / superblock_k)
   + 1 — the whole point of super-block execution is one XLA dispatch
@@ -17,8 +17,8 @@ Runs a scaled-down version of bench.py's streamed-SGD section and fails
   dispatches per pass — one per super-block, NOT one per shard — and
   the same zero-compiles-after-pass-1 contract.
 
-Kept small (~64k rows) so verify.sh stays fast; bench.py carries the
-full-size throughput numbers.
+Kept small (~64k rows) so verify.sh stays fast. It asserts counts, which
+a CPU can say; it yields no time worth writing down.
 """
 
 import math
@@ -28,8 +28,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # 8 virtual devices BEFORE jax initializes so the sharded section has a
-# mesh to shard over; the single-device section pins stream_mesh=1,
-# which restores the exact pre-mesh staging (including zero-copy).
+# mesh to shard over; the single-device section pins stream_mesh=1.
 # force_cpu_platform APPENDS/RAISES the device-count flag inside an
 # already-set XLA_FLAGS instead of silently losing it (a setdefault
 # would fail the gate on any box that exports XLA_FLAGS for tuning)

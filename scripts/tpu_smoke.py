@@ -348,7 +348,7 @@ def main():
                         init="random").fit(Xh)
             assert np.isfinite(km.cluster_centers_).all()
         # parity vs the per-block XLA path on the same partition
-        with config.set(stream_block_rows=2048, stream_superblock=False,
+        with config.set(stream_block_rows=2048, superblock_k=1,
                         pallas_stream=False, dtype="float32"):
             ref = SGDClassifier(max_iter=2, random_state=0,
                                 shuffle=False).fit(Xh, yh)

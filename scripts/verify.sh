@@ -76,15 +76,6 @@ if [ "${1:-}" = "--lint" ]; then
     exit 0
 fi
 
-# -- bench sentinel: recorded-round regression gate (ISSUE 4) ----------------
-# the latest BENCH_r*.json family must hold its per-metric budget floors
-# (seeded from r05): >20% throughput loss / slowdown on a comparable
-# backend fails verify before any throughput number quietly rots.
-if ! python scripts/bench_sentinel.py; then
-    echo "VERIFY FAIL: bench sentinel (recorded-round regression)"
-    exit 1
-fi
-
 # -- perf smoke: super-block dispatch collapse (ISSUE 3) ---------------------
 # streamed-SGD at smoke scale: fails when dispatches_per_pass exceeds
 # ceil(n_blocks / superblock_k) + 1 or when passes after the first pay

@@ -227,6 +227,14 @@ def test_extended_regression_metrics_match_sklearn():
     np.testing.assert_allclose(
         median_absolute_error(t2, p2, sample_weight=w2),
         skm.median_absolute_error(t2, p2, sample_weight=w2), rtol=1e-9)
+    # ... and the cumulative weight lands ON the half: the two middle
+    # errors are averaged (2.5), on host and on sharded (padded) inputs
+    f32 = np.float32
+    for wt in (w2, as_sharded(f32(w2))):
+        assert median_absolute_error(
+            as_sharded(f32(t2)), as_sharded(f32(p2)), sample_weight=wt
+        ) == 2.5
+    assert median_absolute_error(t2, p2, sample_weight=w2) == 2.5
 
 
 def test_extended_scorer_strings_device_resident():

@@ -23,12 +23,9 @@ TINY = chip_smoke.Sizes(
 )
 
 
-def test_steps_rehearsal(tmp_path, monkeypatch):
+def test_steps_rehearsal(tmp_path):
     """resident -> predict -> objective -> streamed, sharing state as
-    ``main`` does, in the STACKED super-block layout (the one a TPU runs)."""
-    from dask_ml_tpu.parallel import streaming
-
-    monkeypatch.setattr(streaming, "superblock_unrolled", lambda: False)
+    ``main`` does."""
     state = {"memmap": chip_smoke.make_memmap(TINY, str(tmp_path))}
     facts = {}
     for name, step in chip_smoke.STEPS[:4]:
@@ -40,7 +37,7 @@ def test_steps_rehearsal(tmp_path, monkeypatch):
     for flav in ("fused", "xla"):
         assert facts["objective"][f"{flav}/full-vs-one"]["grad"] <= 1e-5
     st = facts["streamed"]["stats"]
-    assert st["layout"] == "stacked" and st["sb_shards"] == 8
+    assert st["sb_shards"] == 8
     assert st["native_reader"] is True and st["superblock_k"] > 1
 
 

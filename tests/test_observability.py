@@ -709,8 +709,8 @@ def test_utils_observability_reexports_same_objects():
     assert legacy.emit_jit_step is obs.emit_jit_step
     assert legacy.fit_logger is obs.fit_logger
     assert legacy.timed is obs.timed
-    # the mutable sink registry must be the SAME list object — bench.py
-    # and streaming.py bind through different import paths
+    # the mutable sink registry must be the SAME list object: the shim
+    # and streaming.py bind it through different import paths
     assert legacy._active_loggers is _metrics._active_loggers
 
 

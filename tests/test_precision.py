@@ -1,7 +1,7 @@
 """ISSUE 8 precision ladder: fused Pallas streamed kernels (interpret
 parity), the bf16 "auto" fit policy with its recorded f32 fallback and
-per-estimator opt-out, the int8 weight-quantized serving flavor, the
-zero-copy CPU staging path, and the dtype-alias config surface.
+per-estimator opt-out, the int8 weight-quantized serving flavor, and
+the dtype-alias config surface.
 
 Tolerance notes: bf16 input rounding is ~0.4% relative, so bf16-vs-f32
 fit parity is documented at ~1e-2 relative (matching
@@ -411,89 +411,3 @@ def test_registry_publish_quantize_reaches_server():
         snap = regy.status_snapshot()["m"]
         assert snap["quantize"] is None             # current is v2/f32
 
-
-# ---------------------------------------------------------------------------
-# zero-copy CPU staging
-# ---------------------------------------------------------------------------
-
-def _one_device_mesh():
-    from dask_ml_tpu.parallel.mesh import device_mesh
-
-    return device_mesh(devices=[jax.devices()[0]])
-
-
-def test_zero_copy_staging_parity_and_counters(tmp_path):
-    """On a single-device CPU mesh, aligned full dense blocks stage as
-    dlpack ALIASES (zero_copy_bytes counts them; h2d_bytes drops to the
-    leftovers) and the fit is bit-identical to the copying path."""
-    from dask_ml_tpu.models.sgd import SGDClassifier
-    from dask_ml_tpu.parallel.mesh import use_mesh
-
-    n, d = 4096, 16
-    path = str(tmp_path / "x.f32")
-    mm = np.memmap(path, dtype=np.float32, mode="w+", shape=(n, d))
-    mm[:] = rng.randn(n, d)
-    mm.flush()
-    Xr = np.memmap(path, dtype=np.float32, mode="r", shape=(n, d))
-    y = (np.asarray(Xr[:, 0]) > 0).astype(np.float32)
-
-    def run(zc):
-        with use_mesh(_one_device_mesh()), \
-                config.set(stream_block_rows=512, stream_zero_copy=zc):
-            obs.counters_reset()
-            clf = SGDClassifier(max_iter=2, random_state=0,
-                                shuffle=False).fit(Xr, y)
-            return clf, obs.counters_snapshot()
-
-    on, snap_on = run(True)
-    off, snap_off = run(False)
-    np.testing.assert_array_equal(on.coef_, off.coef_)
-    assert snap_on.get("zero_copy_bytes", 0) > 0
-    assert snap_off.get("zero_copy_bytes", 0) == 0
-    # the aliased bytes were real copies on the off path
-    assert snap_on.get("h2d_bytes", 0) < snap_off.get("h2d_bytes", 1)
-
-
-def test_zero_copy_alias_reads_source_memory():
-    """The imported block really is an alias of host memory (no copy):
-    64-byte-aligned writeable arrays round-trip a mutation."""
-    from dask_ml_tpu.parallel.streaming import _ZC_ALIGN, _dlpack_alias
-
-    raw = np.zeros(1024 + _ZC_ALIGN, np.float32)
-    off = (-raw.ctypes.data) % (_ZC_ALIGN * 4)
-    a = raw[off // 4: off // 4 + 256].reshape(16, 16)
-    if a.ctypes.data % _ZC_ALIGN:
-        pytest.skip("could not build an aligned view")
-    dev = _dlpack_alias(a)
-    if dev is None:
-        pytest.skip("backend refuses dlpack import")
-    jax.block_until_ready(dev)
-    a[0, 0] = 42.0
-    assert float(np.asarray(dev)[0, 0]) == 42.0
-    # readonly sources (mode="r" memmaps) import through the writeable
-    # re-wrap — same memory, still zero-copy. Reuse the SAME aligned
-    # buffer: a fresh numpy allocation has no alignment guarantee, and
-    # an unaligned copy would (correctly) refuse the zero-copy path
-    a.flags.writeable = False
-    try:
-        dev2 = _dlpack_alias(a)
-        assert dev2 is not None
-        np.testing.assert_array_equal(np.asarray(dev2), a)
-    finally:
-        a.flags.writeable = True
-
-
-def test_zero_copy_disabled_on_multi_device_mesh():
-    from dask_ml_tpu.parallel.streaming import BlockStream
-
-    X = rng.randn(1024, 8).astype(np.float32)
-    s = BlockStream((X,), block_rows=256)       # conftest: 8-dev mesh
-    assert s._zero_copy is False
-    from dask_ml_tpu.parallel.mesh import use_mesh
-
-    with use_mesh(_one_device_mesh()):
-        s1 = BlockStream((X,), block_rows=256)
-        assert s1._zero_copy is True
-        with config.set(stream_zero_copy=False):
-            s2 = BlockStream((X,), block_rows=256)
-            assert s2._zero_copy is False

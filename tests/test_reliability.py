@@ -168,7 +168,7 @@ class TestStagingHardening:
         X, y = _xy(600)
         from dask_ml_tpu.parallel.streaming import BlockStream
 
-        with config.set(stream_block_rows=128, stream_superblock=False,
+        with config.set(stream_block_rows=128, superblock_k=1,
                         stream_io_retries=2, fault_plan="stream_put:io@1"):
             blocks = list(BlockStream((X, y), block_rows=128))
         assert counters_snapshot().get("stream_retries", 0) >= 1

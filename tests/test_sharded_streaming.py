@@ -59,7 +59,6 @@ class TestShardedStaging:
             sbs = list(s.superblocks())
         for sb in sbs:
             blk = sb.arrays[0]
-            blk = blk[0] if isinstance(blk, tuple) else blk
             # every device owns its own contiguous row slab
             assert len(blk.sharding.device_set) == 8
             sc = np.asarray(sb.shard_counts)
@@ -93,7 +92,6 @@ class TestShardedStaging:
             sb = next(iter(s.superblocks()))
         assert sb.shard_counts is None
         blk = sb.arrays[0]
-        blk = blk[0] if isinstance(blk, tuple) else blk
         assert len(blk.sharding.device_set) == 1
 
     def test_stream_mesh_n_limits_the_shard_count(self):
@@ -306,8 +304,8 @@ class TestTrivialMeshJaxpr:
 
         def trace_xla():
             W = jnp.zeros(d + 1, jnp.float32)
-            Xs = tuple(jnp.zeros((S, d), jnp.float32) for _ in range(K))
-            ys = tuple(jnp.zeros((S,), jnp.float32) for _ in range(K))
+            Xs = jnp.zeros((K, S, d), jnp.float32)
+            ys = jnp.zeros((K, S), jnp.float32)
             counts = jnp.zeros((K,), jnp.int32)
             lrs = jnp.ones((K,), jnp.float32)
             z = jnp.float32(0.0)
@@ -327,8 +325,8 @@ class TestTrivialMeshJaxpr:
         mesh = stream_data_mesh()
         run = _sgd_sb_scan_sharded(mesh, "log_loss", None, None)
         W = jnp.zeros(d + 1, jnp.float32)
-        Xs = tuple(jnp.zeros((S, d), jnp.float32) for _ in range(K))
-        ys = tuple(jnp.zeros((S,), jnp.float32) for _ in range(K))
+        Xs = jnp.zeros((K, S, d), jnp.float32)
+        ys = jnp.zeros((K, S), jnp.float32)
         sc = jnp.zeros((8, K), jnp.int32)
         counts = jnp.zeros((K,), jnp.int32)
         lrs = jnp.ones((K,), jnp.float32)

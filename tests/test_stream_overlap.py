@@ -109,3 +109,94 @@ def test_stats_logged_to_ambient_logger(tmp_path):
             pass
     recs = [json.loads(l) for l in path.read_text().splitlines()]
     assert any("stream_pass" in r for r in recs)
+
+
+# -- one staging path: ring slabs, native readers, one worker thread --------
+# (stream_mesh=1 below: the single-device stream is where a source could
+# once be imported as an alias instead of staged)
+
+def _aligned_copy(a, lead_bytes=0):
+    """``a`` copied into memory whose base address is ``lead_bytes`` past
+    a 64-byte boundary (0: what XLA:CPU's device_put aliases)."""
+    raw = np.empty(a.size + 32, a.dtype)
+    lead = ((-raw.ctypes.data) % 64 + lead_bytes) // a.itemsize
+    out = raw[lead:lead + a.size].reshape(a.shape)
+    out[...] = a
+    assert out.ctypes.data % 64 == lead_bytes
+    return out
+
+
+def _sgd_fit(Xs, ys, block_rows=512):
+    from dask_ml_tpu import observability as obs
+    from dask_ml_tpu.models.sgd import SGDClassifier
+
+    with config.set(stream_block_rows=block_rows, stream_mesh=1):
+        obs.counters_reset()
+        clf = SGDClassifier(max_iter=2, random_state=0,
+                            shuffle=False).fit(Xs, ys)
+        return clf, obs.counters_snapshot()
+
+
+Y = (X[:, 0] > 0.5).astype(np.float32)
+
+
+def test_readonly_memmap_fit_equals_in_memory_and_reads_natively(tmp_path):
+    from dask_ml_tpu.io.native import native_available
+    from dask_ml_tpu.linear_model import LogisticRegression
+
+    if not native_available():
+        pytest.skip("native toolchain unavailable")
+    path = str(tmp_path / "x.f32")
+    mm = np.memmap(path, dtype=np.float32, mode="w+", shape=X.shape)
+    mm[:] = X
+    mm.flush()
+    del mm
+    Xr = np.memmap(path, dtype=np.float32, mode="r", shape=X.shape)
+    assert not Xr.flags.writeable
+
+    def fit(Xs):
+        with config.set(stream_block_rows=512, stream_mesh=1):
+            return LogisticRegression(solver="lbfgs", max_iter=5).fit(Xs, Y)
+
+    on_disk, in_mem = fit(Xr), fit(X.copy())
+    st = on_disk._last_stream_stats
+    assert st["native_reader"] is True and st["superblock_k"] > 1
+    assert in_mem._last_stream_stats["native_reader"] is False
+    np.testing.assert_array_equal(on_disk.coef_, in_mem.coef_)
+    np.testing.assert_array_equal(on_disk.intercept_, in_mem.intercept_)
+
+
+@pytest.mark.parametrize("source", ["aligned", "misaligned", "fortran"])
+def test_every_source_layout_stages_the_same_slabs(source):
+    """A source's address and memory order decide nothing: every pass
+    copies it into the ring's (K, rows, d) slabs, so the fit and the
+    bytes put on the device are the same."""
+    Xs = {"aligned": lambda: _aligned_copy(X),
+          "misaligned": lambda: _aligned_copy(X, lead_bytes=4),
+          "fortran": lambda: np.asfortranarray(X)}[source]()
+    ref, _ = _sgd_fit(X.copy(), Y)
+    clf, snap = _sgd_fit(Xs, Y)
+    np.testing.assert_array_equal(clf.coef_, ref.coef_)
+    st = clf._last_stream_stats
+    k, rows = st["superblock_k"], st["block_rows"]
+    slab_bytes = k * rows * X.shape[1] * 4 + k * rows * 4 + k * 4
+    passes = 2
+    assert snap["h2d_bytes"] == passes * st["dispatches_per_pass"] * slab_bytes
+
+
+def test_a_staged_superblock_is_a_copy_of_the_source():
+    """No alias of user memory outlives ``fill``: rows overwritten after a
+    super-block was staged do not reach the held super-block, and do
+    reach the next pass."""
+    Xs = _aligned_copy(X)
+    with config.set(stream_mesh=1):
+        stream = BlockStream((Xs,), block_rows=256)
+        k = stream.resolve_superblock_k()
+        assert k > 1
+        passes = stream.superblocks()
+        held = next(passes)
+        Xs[: k * 256] = np.nan
+        assert np.isfinite(np.asarray(held.arrays[0])).all()
+        passes.close()
+        again = next(iter(stream.superblocks()))
+        assert np.isnan(np.asarray(again.arrays[0])).all()
