@@ -231,11 +231,13 @@ def _system_value_and_grad(beta, data, y, mask, n_rows, lam, pmask,
                            use_pallas, mesh, interpret):
     """Loss and gradient through the SAME loss the resident solvers
     minimise (``solvers._select_loss``: the fused kernel under shard_map +
-    custom_vjp, or the XLA objective)."""
+    custom_vjp, or the XLA objective), the intercept as the last entry of
+    beta and ``data`` as wide as the features, as an lbfgs fit runs it."""
     from dask_ml_tpu.models.solvers import solvers as S
 
     loss = S._select_loss(use_pallas, data, y, mask, n_rows, lam, pmask,
-                          0.5, "logistic", "l2", mesh, interpret)
+                          0.5, "logistic", "l2", mesh, interpret,
+                          intercept=True)
     return jax.value_and_grad(loss)(beta)
 
 
@@ -259,6 +261,9 @@ def step_resident(sizes, interpret=False, state=None, facts=None):
     facts["fused"] = clf.solver_info_.get("fused")
     check(facts["fused"] is True,
           f"the fused GLM kernel was not selected: {clf.solver_info_}")
+    facts["intercept"] = clf.solver_info_.get("intercept")
+    check(facts["intercept"] == "scalar",
+          f"the intercept rode as a column of X: {clf.solver_info_}")
     check(clf.n_iter_ == 5, f"n_iter_ {clf.n_iter_} != 5")
 
     # nothing piled on one device: bytes in use, X still alive
@@ -293,9 +298,10 @@ def step_resident(sizes, interpret=False, state=None, facts=None):
     Xs, ys = as_sharded(Xh, mesh=X.mesh), as_sharded(yh, mesh=X.mesh)
     mask = Xs.row_mask(dtype=jnp.float32)
     data, y_enc, _ = _prepare_fit(
-        Xs.data, ys.data, mask, fit_intercept=True,
+        Xs.data, ys.data, mask, fit_intercept=False,
         to_bf16=clf.fit_dtype_ == "bfloat16", encode=True,
     )
+    check(data.shape[1] == sizes.d, f"prep widened X to {data.shape}")
     use_pallas = S._resolve_pallas(True if interpret else None, X.mesh,
                                    "logistic", data)
     check(use_pallas, "the fused GLM kernel's gate refuses the sample shape")
@@ -569,6 +575,12 @@ def kernel_inputs(sizes):
     return gen(jax.random.PRNGKey(11))
 
 
+def _vg_intercept(out):
+    """(value, (d,) gradient, intercept gradient) -> (value, (d + 1,))."""
+    v, g, gb = out
+    return v, jnp.concatenate([g, gb[None]])
+
+
 def kernel_cases(sizes):
     """[(name, kernel(inp, interpret) -> outputs, xla(inp) -> outputs)] over
     the dict from :func:`kernel_inputs`. Each pair runs on IDENTICAL
@@ -599,6 +611,16 @@ def kernel_cases(sizes):
                 interpret=i),
             lambda a, b=b: ST._block_val_grad(
                 r(a["beta"], b), r(a["X1"], b), a["y"], a["mask"], L, False))
+        # ... and as every lbfgs / gradient_descent / proximal_grad fit
+        # calls it: d columns, the intercept an f32 scalar operand that
+        # no policy rounds
+        add(f"fused_glm_value_grad[{n},intercept]",
+            lambda a, i, dt=dt: _vg_intercept(pf.fused_glm_value_grad(
+                a["X"].astype(dt), a["nv"], a["y"], a["beta"][:-1], L,
+                interpret=i, intercept=a["beta"][-1])),
+            lambda a, b=b: ST._block_val_grad(
+                jnp.concatenate([r(a["beta"][:-1], b), a["beta"][-1:]]),
+                r(a["X"], b), a["y"], a["mask"], L, True))
         add(f"fused_glm_multi_value_grad[{n}]",
             lambda a, i, dt=dt: pf.fused_glm_multi_value_grad(
                 a["X1"].astype(dt), a["nv"], a["codes"], a["B"], L,

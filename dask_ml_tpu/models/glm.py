@@ -5,7 +5,10 @@ Reference equivalent: ``dask_ml/linear_model/glm.py`` (SURVEY.md §2a GLMs
 row; §3.2 call stack) — sklearn-style wrappers dispatching to dask-glm
 solvers, with ``fit_intercept`` via an appended ones column and predict as
 blocked matvec. Same surface here; the solvers are the device-resident jax
-programs in ``solvers/solvers.py``.
+programs in ``solvers/solvers.py``. The intercept is the last entry of beta
+everywhere; whether it also costs X a column is the solver's affair
+(``_GLMBase._intercept_form``: lbfgs / gradient_descent / proximal_grad add
+it to eta as a scalar and leave X as wide as its features).
 
 Regularization scaling: the objective is ``mean-NLL + lam * r(coef)`` with
 ``lam = 1 / (C * n_samples)`` and the intercept unpenalized, matching
@@ -26,7 +29,7 @@ from ..parallel.mesh import resolve_mesh
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_X_y, check_array, check_is_fitted
 from .solvers import regularizers
-from .solvers.solvers import solve
+from .solvers.solvers import SCALAR_INTERCEPT_SOLVERS, solve
 
 
 def _check_poisson_targets(ymin):
@@ -80,14 +83,29 @@ def _onehot_targets(yd, mask, classes_d):
     return onehot_targets(yd, mask, classes_d)
 
 
+@jax.jit
+def _append_intercept(Xd, mask):
+    """The ones column (zero on padding rows) as the last column of X,
+    for the paths that take the intercept that way."""
+    return jnp.concatenate([Xd, mask[:, None].astype(Xd.dtype)], axis=1)
+
+
 @_partial(jax.jit, static_argnames=("fit_intercept", "to_bf16", "encode"))
 def _prepare_fit(Xd, yd, mask, fit_intercept, to_bf16, encode):
-    """ONE program for all fit prep: intercept column, bf16 cast, binary
-    label scan + encoding — one launch and one pass over X instead of
-    an eager chain (concat, cast, scan, eq, mul) that materializes an
-    X-sized temporary per op."""
+    """ONE program for all fit prep: bf16 cast, binary label scan +
+    encoding — one launch and one pass over X instead of an eager chain
+    (cast, scan, eq, mul) that materializes an X-sized temporary per op.
+
+    ``fit_intercept`` appends the ones COLUMN, and is passed true only
+    for a path whose mathematics index it (Newton, ADMM, the C grid; see
+    ``_GLMBase._intercept_form``). It is not free: on a TPU a 257-wide
+    bf16 design is laid out column-major, so the column costs a
+    transpose and a pad of X here and a transpose back in front of a
+    Pallas kernel. Without it prep is the cast alone and X keeps its
+    width. A caller whose X needs neither passes ``Xd=None`` and keeps
+    its own array: a jit hands an unchanged input back as a fresh copy."""
     if fit_intercept:
-        Xd = jnp.concatenate([Xd, mask[:, None].astype(Xd.dtype)], axis=1)
+        Xd = _append_intercept(Xd, mask)
     if to_bf16:
         Xd = Xd.astype(jnp.bfloat16)
     if encode:
@@ -199,6 +217,7 @@ class _GLMBase(BaseEstimator):
             # the joint budget stays readable as
             # max(solver_info_["n_iter_per_candidate"])
             info_i = dict(info)
+            info_i["intercept"] = self._intercept_form(stacked=True)
             if per_cand is not None:
                 info_i["n_iter"] = int(per_cand[i])
             # a sparse fold the fast path densified under the byte
@@ -262,6 +281,19 @@ class _GLMBase(BaseEstimator):
             pmask[-1] = 0.0
         lam = 1.0 / (self.C * n_rows) if self.penalty != "none" else 0.0
         return pmask, lam
+
+    def _intercept_form(self, stacked=False):
+        """Where a resident fit keeps the intercept, recorded as
+        ``solver_info_["intercept"]``: ``"scalar"`` — the last entry of
+        beta, added to eta by the loss, X as wide as the features (every
+        solver that touches X through ``_select_loss`` alone);
+        ``"column"`` — a ones column appended to X (Newton and ADMM,
+        whose Hessians index it; the ``stacked`` one-vs-rest and C-grid
+        programs); ``"none"``."""
+        if not self.fit_intercept:
+            return "none"
+        scalar = not stacked and self.solver in SCALAR_INTERCEPT_SOLVERS
+        return "scalar" if scalar else "column"
 
     def _warm_beta0(self, d, xp):
         """Shape-guarded warm start: a stale coef_ from a DIFFERENT
@@ -501,11 +533,16 @@ class _GLMBase(BaseEstimator):
             self.fit_dtype_ = "bfloat16" if use_bf16 else "float32"
             mask = X.row_mask(dtype=jnp.float32)
         root.add(n_rows=X.n_rows)
+        form = self._intercept_form()
         with span("fit.prepare") as sp:
+            touches_x = use_bf16 or form == "column"
             data, y_data, packed = _prepare_fit(
-                X.data, y.data, mask, fit_intercept=self.fit_intercept,
-                to_bf16=use_bf16, encode=self.family == "logistic",
+                X.data if touches_x else None, y.data, mask,
+                fit_intercept=form == "column", to_bf16=use_bf16,
+                encode=self.family == "logistic",
             )
+            if data is None:       # an f32 scalar-form fit: X as it is
+                data = X.data
             if self.family == "poisson":
                 _check_poisson_targets(
                     float(jnp.min(jnp.where(mask > 0, y_data, jnp.inf)))
@@ -523,14 +560,18 @@ class _GLMBase(BaseEstimator):
         if multiclass:
             # >2 (or 1) classes: the one-vs-rest path (vmapped
             # multi-target solve; beyond the reference's binary-only
-            # dask-glm logistic family)
+            # dask-glm logistic family). Its programs take the intercept
+            # as a column, and only the label scan above could tell:
+            # the column prep left out is appended here
+            if form == "scalar":
+                data = _append_intercept(data, mask)
             return self._fit_multiclass(X, y, data, mask, root)
         from ..observability import active_logger, fit_logger
 
         with span("fit.solve") as sp, \
                 fit_logger(type(self).__name__, solver=self.solver,
                            n_rows=X.n_rows) as logger, active_logger(logger):
-            d = data.shape[1]
+            d = data.shape[1] + (form == "scalar")     # beta's length
             pmask, lam = self._penalty_setup(d, X.n_rows)
             beta0 = jnp.asarray(self._warm_beta0(d, np))
             kwargs = dict(self.solver_kwargs or {})
@@ -543,10 +584,11 @@ class _GLMBase(BaseEstimator):
                 reg=self.penalty, lam=jnp.asarray(lam, jnp.float32),
                 pmask=jnp.asarray(pmask), l1_ratio=l1_ratio,
                 max_iter=self.max_iter, tol=self.tol, mesh=mesh,
-                log=log_steps, **kwargs,
+                log=log_steps, intercept=form == "scalar", **kwargs,
             )
-            sp.add(**{k: info[k] for k in ("n_iter", "n_evals", "fused")
-                      if k in info})
+            info["intercept"] = form
+            sp.add(**{k: info[k] for k in ("n_iter", "n_evals", "fused",
+                                           "intercept") if k in info})
             if logger is not None and not log_steps:
                 logger.log(step=info.get("n_iter"), summary=True,
                            **{k: v for k, v in info.items()
@@ -665,7 +707,8 @@ class LogisticRegression(_GLMBase):
                 l1_ratio=l1_ratio, max_iter=self.max_iter, tol=self.tol,
                 mesh=X.mesh, **kwargs,
             )
-            sp.add(n_iter=info.get("n_iter"))
+            info["intercept"] = self._intercept_form(stacked=True)
+            sp.add(n_iter=info.get("n_iter"), intercept=info["intercept"])
             root.add(n_iter=info.get("n_iter"))
             if logger is not None:
                 logger.log(step=info.get("n_iter"), summary=True,

@@ -37,22 +37,33 @@ from ..solvers import regularizers
 from ..solvers.families import get_family
 
 
-def _smooth_loss(beta, X, y, mask, n_rows, lam, pmask, l1_ratio, family, reg):
+def _smooth_loss(beta, X, y, mask, n_rows, lam, pmask, l1_ratio, family, reg,
+                 intercept=False):
     """Mask-weighted mean NLL + smooth penalty. One psum under jit.
 
     The matvec casts beta to X's dtype with f32 accumulation, so a bf16
     design matrix (config.dtype="bfloat16") runs the MXU at bf16 rate
-    while the loss/penalty stay f32."""
+    while the loss/penalty stay f32.
+
+    ``intercept`` (static): the intercept is the LAST ENTRY OF BETA,
+    added to eta in f32 — X is ``(n, d)`` for a ``(d + 1,)`` beta and
+    carries no ones column (a 257th column relays a 256-wide bf16 design
+    out of its row-major layout and pads it to 384 lanes on a TPU).
+    Autodiff gives its gradient, Σ resid * mask; padding rows see
+    ``eta = beta[-1]`` and are masked as before."""
+    coef = beta[:-1] if intercept else beta
     eta = jax.lax.dot_general(
-        X, beta.astype(X.dtype), (((1,), (0,)), ((), ())),
+        X, coef.astype(X.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    if intercept:
+        eta = eta + beta[-1]
     base = jnp.sum(get_family(family).pointwise(eta, y) * mask) / n_rows
     return base + regularizers.value(reg, beta, lam, pmask, l1_ratio)
 
 
 def _pallas_loss(X, y, mask, n_rows, lam, pmask, l1_ratio, family, reg,
-                 mesh, interpret):
+                 mesh, interpret, intercept=False):
     """Smooth loss whose DATA term's value and gradient both come from
     the fused Pallas kernel (``ops/pallas_fused.fused_glm_value_grad``):
     one X pass per value_and_grad instead of XLA's two (forward matvec +
@@ -60,17 +71,25 @@ def _pallas_loss(X, y, mask, n_rows, lam, pmask, l1_ratio, family, reg,
     traffic of every solver iteration. The kernel runs per shard inside
     shard_map with a psum merge; a custom_vjp hands autodiff the
     kernel's gradient, and the penalty/mean scaling stay ordinary XLA on
-    the (d,) vector."""
+    the (d,) vector. ``intercept``: ``_smooth_loss``'s contract — the
+    kernel takes ``beta[-1]`` as its scalar operand and returns that
+    entry's gradient beside the (d,) one."""
     from ...ops.pallas_fused import fused_glm_value_grad
 
-    def data_vg(beta):
-        return _shard_psum_call(
-            mesh,
-            lambda bs, xs, ys, ms, nv: fused_glm_value_grad(
-                xs, nv, ys, bs, family=family, interpret=interpret
-            ),
-            2, beta, X, y, mask,
+    def per_shard(bs, xs, ys, ms, nv):
+        if not intercept:
+            return fused_glm_value_grad(xs, nv, ys, bs, family=family,
+                                        interpret=interpret)
+        v, g, gb = fused_glm_value_grad(
+            xs, nv, ys, bs[:-1], family=family, interpret=interpret,
+            intercept=bs[-1],
         )
+        # one (d + 1,) gradient BEFORE the psum: as many all-reduces an
+        # evaluation as the column form had
+        return v, jnp.concatenate([g, gb[None]])
+
+    def data_vg(beta):
+        return _shard_psum_call(mesh, per_shard, 2, beta, X, y, mask)
 
     return _custom_vjp_loss(data_vg, n_rows, reg, lam, pmask, l1_ratio)
 
@@ -122,16 +141,17 @@ def _custom_vjp_loss(data_vg, n_rows, reg, lam, pmask, l1_ratio):
 
 
 def _select_loss(use_pallas, X, y, mask, n_rows, lam, pmask, l1_ratio,
-                 family, reg, mesh, interpret):
+                 family, reg, mesh, interpret, intercept=False):
     """The ONE place a jitted solver body picks its smooth loss: the
     fused Pallas value+grad (one X pass per evaluation) or the plain
-    XLA objective."""
+    XLA objective. Both read the static ``intercept`` the same way
+    (``_smooth_loss``): the last entry of beta, not a column of X."""
     if use_pallas:
         return _pallas_loss(X, y, mask, n_rows, lam, pmask, l1_ratio,
-                            family, reg, mesh, interpret)
+                            family, reg, mesh, interpret, intercept)
     return partial(_smooth_loss, X=X, y=y, mask=mask, n_rows=n_rows,
                    lam=lam, pmask=pmask, l1_ratio=l1_ratio,
-                   family=family, reg=reg)
+                   family=family, reg=reg, intercept=intercept)
 
 
 def _resolve_pallas(use_pallas, mesh, family, X=None):
@@ -201,17 +221,18 @@ def _check_smooth(reg, solver):
 
 @track_program("glm.lbfgs")
 @partial(jax.jit, static_argnames=("family", "reg", "memory", "log",
-                                   "use_pallas", "mesh", "interpret"))
+                                   "use_pallas", "mesh", "interpret",
+                                   "intercept"))
 def _lbfgs_chunk(X, y, mask, n_rows, carry, lam, pmask, l1_ratio, stop_it,
                  tol, family, reg, memory=10, log=False, use_pallas=False,
-                 mesh=None, interpret=False):
+                 mesh=None, interpret=False, intercept=False):
     """Run the L-BFGS while_loop from ``carry`` until ``stop_it`` (or
     convergence). A full solve is one chunk with stop_it = max_iter; the
     checkpointed path runs k-iteration chunks so (beta, optimizer state)
     hits stable storage between programs (SURVEY.md §5 checkpoint row —
     TPU slices fail whole, recovery is checkpoint-restart)."""
     loss = _select_loss(use_pallas, X, y, mask, n_rows, lam, pmask,
-                        l1_ratio, family, reg, mesh, interpret)
+                        l1_ratio, family, reg, mesh, interpret, intercept)
     return _lbfgs_loop(loss, carry, stop_it, tol, memory, log)
 
 
@@ -350,7 +371,7 @@ def _lbfgs_multi_pallas_chunk(X, codes, mask, n_rows, carry, lam, pmask_t,
 def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
           max_iter=100, tol=1e-6, memory=10, log=False, checkpoint_path=None,
           checkpoint_every=0, mesh=None, use_pallas=None,
-          pallas_interpret=False, **_):
+          pallas_interpret=False, intercept=False, **_):
     """When ``checkpoint_path`` + ``checkpoint_every`` are set (via
     ``solver_kwargs``), the solve runs in k-iteration chunks with
     (beta, optimizer state, it) persisted after each — a killed 3-hour
@@ -368,7 +389,7 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
         l1_ratio=l1_ratio, tol=jnp.asarray(tol, beta0.dtype),
         family=family, reg=reg, memory=memory, log=log,
         use_pallas=use_pallas, mesh=mesh if use_pallas else None,
-        interpret=pallas_interpret,
+        interpret=pallas_interpret, intercept=intercept,
     )
     resumed_from = 0
     if not (checkpoint_path and checkpoint_every):
@@ -420,12 +441,13 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
 
 @track_program("glm.gradient_descent")
 @partial(jax.jit, static_argnames=("family", "reg", "log", "use_pallas",
-                                   "mesh", "interpret"))
+                                   "mesh", "interpret", "intercept"))
 def _gd_run(X, y, mask, n_rows, beta0, lam, pmask, l1_ratio, max_iter, tol,
             init_step, family, reg, armijo=1e-4, backtrack=0.5, grow=2.0,
-            log=False, use_pallas=False, mesh=None, interpret=False):
+            log=False, use_pallas=False, mesh=None, interpret=False,
+            intercept=False):
     loss = _select_loss(use_pallas, X, y, mask, n_rows, lam, pmask,
-                        l1_ratio, family, reg, mesh, interpret)
+                        l1_ratio, family, reg, mesh, interpret, intercept)
 
     def outer_cond(carry):
         beta, step, gnorm, it = carry
@@ -456,7 +478,7 @@ def _gd_run(X, y, mask, n_rows, beta0, lam, pmask, l1_ratio, max_iter, tol,
 def gradient_descent(X, y, mask, n_rows, beta0, family, reg, lam, pmask,
                      l1_ratio=0.5, max_iter=100, tol=1e-6, init_step=1.0,
                      log=False, mesh=None, use_pallas=None,
-                     pallas_interpret=False, **_):
+                     pallas_interpret=False, intercept=False, **_):
     _check_smooth(reg, "gradient_descent")
     use_pallas = _resolve_pallas(use_pallas, mesh, family, X)
     beta, it, gnorm = _gd_run(
@@ -464,6 +486,7 @@ def gradient_descent(X, y, mask, n_rows, beta0, family, reg, lam, pmask,
         jnp.asarray(max_iter), jnp.asarray(tol, beta0.dtype),
         init_step, family, reg, log=log, use_pallas=use_pallas,
         mesh=mesh if use_pallas else None, interpret=pallas_interpret,
+        intercept=intercept,
     )
     it, gnorm = _host_scalars(it, gnorm)
     return beta, {"n_iter": int(it), "grad_norm": float(gnorm),
@@ -477,13 +500,14 @@ def gradient_descent(X, y, mask, n_rows, beta0, family, reg, lam, pmask,
 
 @track_program("glm.proximal_grad")
 @partial(jax.jit, static_argnames=("family", "reg", "log", "use_pallas",
-                                   "mesh", "interpret"))
+                                   "mesh", "interpret", "intercept"))
 def _pg_run(X, y, mask, n_rows, beta0, lam, pmask, l1_ratio, max_iter, tol,
             init_step, family, reg, backtrack=0.5, grow=1.2, log=False,
-            use_pallas=False, mesh=None, interpret=False):
+            use_pallas=False, mesh=None, interpret=False, intercept=False):
     # penalty handled by the prox: the selected loss is smooth-only
     smooth = _select_loss(use_pallas, X, y, mask, n_rows, lam * 0.0,
-                          pmask, l1_ratio, family, "none", mesh, interpret)
+                          pmask, l1_ratio, family, "none", mesh, interpret,
+                          intercept)
 
     def outer_cond(carry):
         beta, step, delta, it = carry
@@ -520,13 +544,14 @@ def _pg_run(X, y, mask, n_rows, beta0, lam, pmask, l1_ratio, max_iter, tol,
 def proximal_grad(X, y, mask, n_rows, beta0, family, reg, lam, pmask,
                   l1_ratio=0.5, max_iter=100, tol=1e-7, init_step=1.0,
                   log=False, mesh=None, use_pallas=None,
-                  pallas_interpret=False, **_):
+                  pallas_interpret=False, intercept=False, **_):
     use_pallas = _resolve_pallas(use_pallas, mesh, family, X)
     beta, it, delta = _pg_run(
         X, y, mask, n_rows, beta0, lam, pmask, l1_ratio,
         jnp.asarray(max_iter), jnp.asarray(tol, beta0.dtype),
         init_step, family, reg, log=log, use_pallas=use_pallas,
         mesh=mesh if use_pallas else None, interpret=pallas_interpret,
+        intercept=intercept,
     )
     it, delta = _host_scalars(it, delta)
     return beta, {"n_iter": int(it), "opt_residual": float(delta),
@@ -726,10 +751,21 @@ SOLVERS = {
     "proximal_grad": proximal_grad,
 }
 
+# solvers whose only touch of X is the loss from ``_select_loss``: they
+# take ``intercept=True`` (the last entry of beta, X without a ones
+# column). Newton's Hessian (``X.T W X``, the fused vgh kernel) and
+# ADMM's per-shard Newton index the intercept as a COLUMN of X.
+SCALAR_INTERCEPT_SOLVERS = ("lbfgs", "gradient_descent", "proximal_grad")
+
 
 def solve(solver: str, **kwargs):
     if solver not in SOLVERS:
         raise ValueError(f"Unknown solver {solver!r}; options: {sorted(SOLVERS)}")
+    if kwargs.get("intercept") and solver not in SCALAR_INTERCEPT_SOLVERS:
+        raise ValueError(
+            f"solver {solver!r} takes the intercept as a ones column of "
+            f"X, not as intercept=True"
+        )
     beta, info = SOLVERS[solver](**kwargs)
     return check_finite_result(beta, info, solver)
 

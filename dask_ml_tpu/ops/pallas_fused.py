@@ -39,7 +39,18 @@ real v5e chip):
   class codes, the ``*_stream`` and SGD kernels) still carry the column
   contract (``_row_dot`` / ``_tile_mask`` / ``_glm_eta_terms``): no
   benchmark cell runs them, so a conversion could not be measured; the row
-  helpers are written for them to move onto (ROADMAP S4).
+  helpers are written for them to move onto (ROADMAP S4);
+- the intercept is a SCALAR OPERAND, never a column of X: a ``(1, 1)`` f32
+  block read as a scalar and added to eta, its gradient a ``(1, 1)``
+  accumulator of its own. A 257th column costs a 256-wide bf16 design its
+  layout (the TPU compiler stores ``bf16[n, 257]`` column-major, so the
+  column is a transpose and a pad where it is appended and a transpose back
+  in front of the kernel) and half again its bytes (257 columns pad to 384
+  lanes in the kernel's blocks). The streamed and SGD kernels always took
+  it so (``b0_ref``); since PR 28 ``fused_glm_value_grad`` does too
+  (``intercept=``). ``fused_glm_value_grad_hess`` and
+  ``fused_glm_multi_value_grad`` still read it from a column their callers
+  append (Newton's bordered Hessian and the one-vs-rest stack index it).
 """
 
 from __future__ import annotations
@@ -231,7 +242,11 @@ def _row_dot(x, b):
     The result is a COLUMN: one useful value per 128-lane line, and the
     reason its callers take ``y`` as ``(tile, 1)`` blocks of a 128x-padded
     ``(n, 1)`` array. ``fused_glm_value_grad`` no longer calls this (see
-    ``_eta_rows``); the Newton, streamed and SGD kernels still do."""
+    ``_eta_rows``); the Newton, streamed and SGD kernels still do. ``b``
+    is the coefficients alone wherever the intercept is a scalar operand
+    (streamed, SGD); the resident Newton and multi-target kernels pass a
+    ``b`` whose last entry meets a ones column of ``x``, and that
+    intercept IS rounded to x's dtype with the rest."""
     bx = b.astype(x.dtype).astype(jnp.float32)
     return jnp.sum(x.astype(jnp.float32) * bx, axis=1, keepdims=True)
 
@@ -281,20 +296,25 @@ def _eta_rows(x, b):
     )
 
 
-def _glm_row_terms(x, yv, b, nv_ref, i, tile, family):
+def _glm_row_terms(x, yv, b, nv_ref, i, tile, family, b0=None):
     """Row-form ``_glm_eta_terms``: (family, eta, MASKED pointwise NLL,
     MASKED residual), each ``(r, tile)`` with rows along lanes; ``yv``
-    is the ``(1, tile)`` label row, broadcast over the r equal rows."""
+    is the ``(1, tile)`` label row, broadcast over the r equal rows.
+    ``b0`` is the intercept as an f32 SCALAR added to eta after the
+    contraction (never rounded to x's dtype); padding rows then see
+    ``eta = b0``, and the mask zeroes their terms as before."""
     from ..models.solvers.families import get_family
 
     fam = get_family(family)
     eta = _eta_rows(x, b)
+    if b0 is not None:
+        eta = eta + b0
     m = _lane_mask(eta.shape, nv_ref, i, tile)
     return fam, eta, fam.pointwise(eta, yv) * m, (fam.mean(eta) - yv) * m
 
 
-def _glm_value_grad_kernel(x_ref, y_ref, nv_ref, b_ref, loss_ref, grad_ref,
-                           *, tile, family):
+def _glm_value_grad_kernel(x_ref, y_ref, nv_ref, b_ref, *refs, tile,
+                           family, intercept):
     """One X pass computing Σ pointwise-NLL AND Σ ∂NLL/∂β.
 
     The XLA path reads X twice per value_and_grad (forward matvec +
@@ -302,39 +322,59 @@ def _glm_value_grad_kernel(x_ref, y_ref, nv_ref, b_ref, loss_ref, grad_ref,
     so this halves the data traffic of every solver iteration. ROW form
     (module header): every per-row quantity lives along lanes, validity
     from the global row index vs one scalar, accumulators revisited with
-    a constant index_map (sequential TPU grid: race-free)."""
+    a constant index_map (sequential TPU grid: race-free).
+
+    With ``intercept`` the refs carry one more operand and one more
+    output, as the streamed kernels do (``_glm_stream_kernel``): the
+    intercept as a ``(1, 1)`` block read as a scalar, and its gradient
+    Σ resid as a ``(1, 1)`` accumulator — X carries no ones column."""
+    if intercept:
+        b0_ref, *outs = refs
+        b0 = b0_ref[0, 0]
+    else:
+        outs, b0 = refs, None
+    loss_ref, grad_ref = outs[:2]
     i = pl.program_id(0)
     x = x_ref[:]                       # (tile, d) — f32 or bf16
     yv = y_ref[:]                      # (1, tile) f32
     b = b_ref[:]                       # (r, d) f32, r equal rows
-    _, _, per, resid = _glm_row_terms(x, yv, b, nv_ref, i, tile, family)
+    _, _, per, resid = _glm_row_terms(x, yv, b, nv_ref, i, tile, family,
+                                      b0)
 
     @pl.when(i == 0)
     def _init():
-        loss_ref[:] = jnp.zeros_like(loss_ref)
-        grad_ref[:] = jnp.zeros_like(grad_ref)
+        for o in outs:
+            o[:] = jnp.zeros_like(o)
 
     loss_ref[:] += jnp.sum(per[0:1, :], axis=1, keepdims=True)
     grad_ref[:] += jax.lax.dot_general(
         resid.astype(x.dtype), x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )[0:1, :]                           # (1, d) f32 accumulation
+    if intercept:
+        outs[2][:] += jnp.sum(resid[0:1, :], axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("family", "interpret"))
-def fused_glm_value_grad(x, n_valid, y, beta, family, interpret=False):
+def fused_glm_value_grad(x, n_valid, y, beta, family, interpret=False,
+                         intercept=None):
     """(Σ pointwise-NLL, Σ ∂/∂β (d,)) of one (per-device) block in ONE
     data pass. ``beta`` is f32 (d,); ``y`` f32 (n,); row validity is the
     scalar prefix count ``n_valid`` (GLM padding is trailing per shard).
-    Callers psum both outputs across shards and add the penalty/mean
+    Callers psum the outputs across shards and add the penalty/mean
     scaling in XLA.
+
+    ``intercept``: an f32 scalar added to eta (``eta = x @ beta +
+    intercept``); the result is then (Σ NLL, Σ ∂/∂β (d,), Σ ∂/∂intercept
+    scalar). ``None`` is a model without one — or a caller whose ``x``
+    carries it as a ones column.
 
     LANE-DENSE: ``y`` reaches the kernel as a ``(1, n)`` view of the
     vector in ``(1, tile)`` blocks, never as ``y[:, None]`` — this
     wrapper is traced inside the solvers' ``while_loop``s, where an
     ``(n, 1)`` operand was a 128x-padded buffer written and re-read on
     every objective evaluation. The Newton / multi-target / streamed
-    kernels below still take the column (module header)."""
+    kernels below still take the ``y`` column (module header)."""
     n, d = x.shape
     y = y.astype(jnp.float32)
     beta = beta.astype(jnp.float32)
@@ -351,26 +391,33 @@ def fused_glm_value_grad(x, n_valid, y, beta, family, interpret=False):
     grid = (n_pad // tile,)
     nv = jnp.asarray(n_valid, jnp.int32).reshape(1, 1)
     r = _ROW_SUBLANES
-    loss, grad = pl.pallas_call(
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    operands = [x, y[None, :], nv, jnp.broadcast_to(beta[None, :], (r, d))]
+    in_specs = [
+        pl.BlockSpec((tile, d), lambda i: (i, 0)),
+        pl.BlockSpec((1, tile), lambda i: (0, i)),
+        scalar,
+        pl.BlockSpec((r, d), lambda i: (0, 0)),
+    ]
+    out_specs = [scalar, pl.BlockSpec((1, d), lambda i: (0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((1, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((1, d), jnp.float32)]
+    if intercept is not None:
+        operands.append(jnp.asarray(intercept, jnp.float32).reshape(1, 1))
+        in_specs.append(scalar)
+        out_specs.append(scalar)
+        out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.float32))
+    loss, grad, *gb = pl.pallas_call(
         functools.partial(_glm_value_grad_kernel, tile=tile,
-                          family=family),
+                          family=family, intercept=intercept is not None),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((r, d), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, d), jnp.float32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(x, y[None, :], nv, jnp.broadcast_to(beta[None, :], (r, d)))
+    )(*operands)
+    if gb:
+        return loss[0, 0], grad[0], gb[0][0, 0]
     return loss[0, 0], grad[0]
 
 

@@ -193,3 +193,249 @@ def test_lbfgs_counts_objective_evaluations(flavor):
     assert (info["n_iter"], info["n_evals"]) == (int(out[3]), len(ran))
     np.testing.assert_allclose(np.asarray(beta), np.asarray(out[0]),
                                atol=1e-4)
+
+
+# -- where the intercept lives (PR 28) ----------------------------------------
+# lbfgs / gradient_descent / proximal_grad carry it as the last entry of
+# beta over an X as wide as the features ("scalar"); Newton, ADMM, the
+# one-vs-rest and C-grid programs as a ones column of X ("column").
+
+_FAMILIES = {
+    "logistic": (LogisticRegression, "make_classification"),
+    "normal": (LinearRegression, "make_regression"),
+    "poisson": (PoissonRegression, "make_counts"),
+}
+
+
+def _family_data(family, n=1200, d=8, seed=3):
+    from dask_ml_tpu import datasets
+
+    Est, maker = _FAMILIES[family]
+    X, y = getattr(datasets, maker)(n_samples=n, n_features=d,
+                                    random_state=seed)
+    return Est, X, y
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+@pytest.mark.parametrize("solver", ["lbfgs", "gradient_descent",
+                                    "proximal_grad"])
+def test_scalar_intercept_matches_column(solver, family):
+    """One problem, the scalar form against Newton's column form:
+    coef_ and intercept_ agree, and each fit says which form it ran."""
+    Est, X, y = _family_data(family)
+    col = Est(solver="newton", max_iter=100, tol=1e-9).fit(X, y)
+    sca = Est(solver=solver, max_iter=3000, tol=1e-8).fit(X, y)
+    assert col.solver_info_["intercept"] == "column"
+    assert sca.solver_info_["intercept"] == "scalar"
+    assert abs(float(np.ravel(col.intercept_)[0])) > 1e-3  # a real offset
+    atol = 2e-3 if solver == "lbfgs" else 2e-2
+    np.testing.assert_allclose(np.ravel(sca.coef_), np.ravel(col.coef_),
+                               atol=atol)
+    np.testing.assert_allclose(np.ravel(sca.intercept_),
+                               np.ravel(col.intercept_), atol=atol)
+
+
+@pytest.mark.parametrize("solver,form", [
+    ("lbfgs", "scalar"), ("gradient_descent", "scalar"),
+    ("proximal_grad", "scalar"), ("newton", "column"), ("admm", "column")])
+@pytest.mark.parametrize("fit_intercept", [True, False])
+def test_solver_info_names_the_intercept_form(solver, form, fit_intercept):
+    Est, X, y = _family_data("logistic", n=400, d=5)
+    clf = Est(solver=solver, max_iter=30, fit_intercept=fit_intercept)
+    clf.fit(X, y)
+    assert clf.solver_info_["intercept"] == (form if fit_intercept
+                                             else "none")
+    assert clf.coef_.shape == (1, 5)
+    if not fit_intercept:
+        assert float(np.ravel(clf.intercept_)[0]) == 0.0
+
+
+def test_fit_solve_span_carries_the_intercept_form():
+    from dask_ml_tpu import config
+    from dask_ml_tpu.observability import recent_spans
+
+    Est, X, y = _family_data("logistic", n=400, d=5)
+    with config.set(obs_programs=True):
+        Est(solver="lbfgs", max_iter=20).fit(X, y)
+        solves = [s for s in recent_spans() if s["span"] == "fit.solve"]
+    assert solves and solves[-1]["intercept"] == "scalar"
+
+
+def test_column_solvers_refuse_the_scalar_form():
+    from dask_ml_tpu.models.solvers import solvers as S
+
+    with pytest.raises(ValueError, match="ones column"):
+        S.solve("newton", intercept=True)
+    assert set(S.SCALAR_INTERCEPT_SOLVERS) < set(S.SOLVERS)
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_scalar_intercept_warm_start(family):
+    """The second fit of a warm-started estimator starts from
+    (coef_, intercept_) of the first: at the optimum it stops at once
+    and keeps both."""
+    Est, X, y = _family_data(family)
+    # targets in the hundreds: f32 gradient noise sits above 1e-6 there
+    tol = 1e-3 if family == "normal" else 1e-6
+    est = Est(solver="lbfgs", max_iter=300, tol=tol, warm_start=True)
+    est.fit(X, y)
+    c1, b1 = np.ravel(est.coef_).copy(), float(np.ravel(est.intercept_)[0])
+    n1 = est.n_iter_
+    est.fit(X, y)
+    assert est.n_iter_ <= 2 < n1
+    np.testing.assert_allclose(np.ravel(est.coef_), c1, atol=1e-4)
+    assert float(np.ravel(est.intercept_)[0]) == pytest.approx(b1, abs=1e-4)
+
+
+def test_scalar_intercept_checkpointed_lbfgs_resumes(tmp_path, monkeypatch):
+    """The chunked lbfgs carries the (d + 1,) beta through its
+    checkpoints: killed after the second chunk, the fit resumes at
+    iteration 8 and ends where the uninterrupted fit ends, intercept
+    included; a finished solve leaves no checkpoint."""
+    import os
+
+    from dask_ml_tpu.utils import checkpoint as ckpt
+
+    Est, X, y = _family_data("logistic", n=600, d=6)
+    path = str(tmp_path / "ck")
+    kw = dict(solver="lbfgs", max_iter=16, tol=0.0)
+    ckw = dict(kw, solver_kwargs={"checkpoint_path": path,
+                                  "checkpoint_every": 4})
+    ref = Est(**kw).fit(X, y)
+
+    real_save, saves = ckpt.save_pytree, {"n": 0}
+
+    def dying_save(p, tree, force=True):
+        real_save(p, tree, force=force)
+        saves["n"] += 1
+        if saves["n"] == 2:
+            raise KeyboardInterrupt("injected kill")
+
+    monkeypatch.setattr(ckpt, "save_pytree", dying_save)
+    with pytest.raises(KeyboardInterrupt):
+        Est(**ckw).fit(X, y)
+    monkeypatch.setattr(ckpt, "save_pytree", real_save)
+    assert os.path.exists(path)
+
+    clf = Est(**ckw).fit(X, y)
+    assert clf.solver_info_["resumed_from"] == 8
+    assert clf.solver_info_["n_iter"] == 16
+    assert clf.solver_info_["intercept"] == "scalar"
+    np.testing.assert_allclose(clf.coef_, ref.coef_, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(clf.intercept_, ref.intercept_, rtol=1e-4,
+                               atol=1e-6)
+    assert not os.path.exists(path)
+
+
+def test_multiclass_fit_appends_the_column_itself():
+    """A 3-class lbfgs fit learns only from prep's label scan that it is
+    one: the column prep left out is appended for the one-vs-rest
+    program, and every class row equals that class's own binary fit."""
+    from dask_ml_tpu import datasets
+
+    X, y = datasets.make_classification(
+        n_samples=1500, n_features=8, n_classes=3, n_informative=5,
+        random_state=2)
+    ovr = LogisticRegression(solver="lbfgs", max_iter=300, tol=1e-7)
+    ovr.fit(X, y)
+    assert ovr.solver_info_["intercept"] == "column"
+    assert ovr.coef_.shape == (3, 8) and ovr.intercept_.shape == (3,)
+    yh = y.to_numpy()
+    for c in range(3):
+        one = LogisticRegression(solver="newton", max_iter=100, tol=1e-9)
+        one.fit(X, (yh == c).astype(np.float32))
+        np.testing.assert_allclose(ovr.coef_[c], one.coef_[0], atol=3e-3)
+        np.testing.assert_allclose(ovr.intercept_[c], one.intercept_[0],
+                                   atol=3e-3)
+
+
+@pytest.mark.parametrize("fit_intercept", [True, False])
+def test_C_grid_column_form_equals_scalar_fits(fit_intercept):
+    """The stacked C-grid program keeps the column; each of its clones
+    equals the estimator's own (scalar-form) fit at that C."""
+    Est, X, y = _family_data("logistic", n=900, d=6)
+    Cs = [0.1, 1.0, 10.0]
+    base = Est(solver="lbfgs", max_iter=300, tol=1e-7,
+               fit_intercept=fit_intercept)
+    fitted = base._fit_C_grid(X, y, Cs)
+    assert fitted is not None and len(fitted) == 3
+    for C, est in zip(Cs, fitted):
+        assert est.solver_info_["intercept"] == (
+            "column" if fit_intercept else "none")
+        solo = Est(solver="lbfgs", max_iter=300, tol=1e-7, C=C,
+                   fit_intercept=fit_intercept).fit(X, y)
+        np.testing.assert_allclose(est.coef_, solo.coef_, atol=3e-3)
+        np.testing.assert_allclose(est.intercept_, solo.intercept_,
+                                   atol=3e-3)
+
+
+def _row_sized_ops(jaxpr, n):
+    """Names of the equations (nested jaxprs included; the ``jit``
+    wrappers themselves left out) that read or write a rank-2 array of
+    ``n`` rows: what touches something X-sized."""
+    from tests.test_pallas_glm import _avals, _walk_eqns
+
+    return [e.primitive.name for e in _walk_eqns(jaxpr)
+            if e.primitive.name not in ("jit", "pjit")
+            and any(len(a.shape) == 2 and a.shape[0] == n
+                    for a in _avals(e))]
+
+
+@pytest.mark.parametrize("to_bf16", [True, False])
+def test_prepare_fit_scalar_path_builds_no_column(to_bf16):
+    """Pins PR 28's finding: on the scalar path prep is the cast (or
+    nothing) — nothing X-sized in its jaxpr but that one
+    ``convert_element_type``, no ``concatenate`` / ``pad``, X out as wide
+    as X in; the column path still appends one."""
+    import jax
+    import jax.numpy as jnp
+
+    from dask_ml_tpu.models.glm import _prepare_fit
+
+    n, d = 4096, 256
+    X = jax.ShapeDtypeStruct((n, d), jnp.float32)
+    v = jax.ShapeDtypeStruct((n,), jnp.float32)
+
+    def trace(fit_intercept):
+        return jax.make_jaxpr(
+            lambda X, y, m: _prepare_fit(
+                X, y, m, fit_intercept=fit_intercept, to_bf16=to_bf16,
+                encode=True))(X, v, v)
+
+    scalar = trace(False)
+    names = sorted(_row_sized_ops(scalar.jaxpr, n))
+    assert names == (["convert_element_type"] if to_bf16 else []), names
+    assert scalar.out_avals[0].shape == (n, d)
+    assert scalar.out_avals[0].dtype == (jnp.bfloat16 if to_bf16
+                                         else jnp.float32)
+    column = trace(True)
+    assert "concatenate" in set(_row_sized_ops(column.jaxpr, n))
+    assert column.out_avals[0].shape == (n, d + 1)
+
+
+@pytest.mark.parametrize("solver,width", [("lbfgs", 0), ("newton", 1)])
+def test_solver_sees_X_as_wide_as_the_features(solver, width, monkeypatch):
+    """What reaches the solver's program in a fit: an lbfgs fit hands
+    ``_lbfgs_chunk`` an (n, d) X beside a (d + 1,) beta and
+    ``intercept=True``; a Newton fit an (n, d + 1) X."""
+    from dask_ml_tpu.models.solvers import solvers as S
+
+    Est, X, y = _family_data("logistic", n=400, d=5)
+    name = {"lbfgs": "_lbfgs_chunk", "newton": "_newton_run"}[solver]
+    real, seen = getattr(S, name), {}
+
+    def spy(*a, **kw):
+        beta = kw["carry"][0] if solver == "lbfgs" else a[4]
+        seen.update(X=a[0].shape, beta=beta.shape, data=a[0],
+                    intercept=kw.get("intercept", False))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(S, name, spy)
+    est = Est(solver=solver, max_iter=5).fit(X, y)
+    assert seen["X"][1] == 5 + width
+    # an f32 fit in the scalar form leaves X alone: prep returns no X,
+    # the solver reads the caller's own array
+    assert est.fit_dtype_ == "float32"
+    assert (seen["data"] is X.data) is (solver == "lbfgs")
+    assert seen["beta"] == (6,)
+    assert seen["intercept"] is (solver == "lbfgs")

@@ -3,6 +3,8 @@ per value_and_grad. Interpret-mode parity vs the XLA loss across
 families, solvers, and dtypes (the kernel auto-engages compiled on real
 TPU; scripts/tpu_smoke.py asserts the same parity there)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -104,6 +106,138 @@ def test_fused_glm_kernel_direct(family, dtype, n, n_valid):
                                atol=1e-6 * np.abs(g_ref).max())
 
 
+def _intercept_reference(family, X, y, beta, n_valid):
+    """Raw sums (value, (d + 1,) gradient) by autodiff of
+    ``solvers._smooth_loss`` in its scalar-intercept form, at the
+    kernel's contract: X and coef at X's dtype, f32 products and sums,
+    the intercept an f32 scalar nobody rounds."""
+    import jax
+    import jax.numpy as jnp
+
+    from dask_ml_tpu.models.solvers import solvers as S
+
+    n = X.shape[0]
+    m = (jnp.arange(n) < n_valid).astype(jnp.float32)
+    b = jnp.concatenate([beta[:-1].astype(X.dtype).astype(jnp.float32),
+                         beta[-1:]])
+    loss = partial(S._smooth_loss, X=X.astype(jnp.float32), y=y, mask=m,
+                   n_rows=1.0, lam=jnp.float32(0.0),
+                   pmask=jnp.ones_like(b), l1_ratio=0.5, family=family,
+                   reg="none", intercept=True)
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(loss)(b)
+
+
+@pytest.mark.parametrize("n,n_valid", _DIRECT_ROWS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", ["logistic", "normal", "poisson"])
+def test_fused_glm_kernel_intercept(family, dtype, n, n_valid):
+    """The scalar-intercept form against ``_smooth_loss``'s autodiff:
+    value, (d,) gradient and the intercept's gradient. Rows past
+    ``n_valid`` hold ordinary data (not zeros) and see ``eta = b0``;
+    they must add nothing to any of the three sums. ``n`` runs over
+    ragged, tile-multiple and padded-tile row counts."""
+    import jax.numpy as jnp
+
+    from dask_ml_tpu.ops.pallas_fused import fused_glm_value_grad
+
+    X, y, coef = _direct_inputs(family, dtype, n)
+    beta = jnp.concatenate([coef, jnp.asarray([0.37], jnp.float32)])
+    v_ref, g_ref = _intercept_reference(family, X, y, beta, n_valid)
+    g_ref = np.asarray(g_ref)
+
+    v, g, gb = fused_glm_value_grad(X, n_valid, y, beta[:-1], family=family,
+                                    interpret=True, intercept=beta[-1])
+    assert v.dtype == g.dtype == gb.dtype == jnp.float32
+    assert g.shape == (X.shape[1],) and gb.shape == ()
+    np.testing.assert_allclose(float(v), float(v_ref), rtol=1e-5)
+    # a bf16 design rounds the residual to bf16 into the MXU (as the
+    # column form always did); autodiff's reference does not
+    band = 1e-6 if dtype == "float32" else 4e-3
+    np.testing.assert_allclose(np.asarray(g), g_ref[:-1], rtol=1e-4,
+                               atol=band * np.abs(g_ref).max())
+    # the intercept's gradient sums the f32 residual itself
+    np.testing.assert_allclose(float(gb), g_ref[-1], rtol=1e-4,
+                               atol=1e-6 * np.abs(g_ref).max())
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", ["logistic", "normal", "poisson"])
+def test_fused_glm_kernel_without_intercept_is_unchanged(family, dtype):
+    """``intercept=None`` is the kernel as it was: four operands, two
+    outputs, and bit for bit the value and gradient of a zero intercept
+    (``eta + 0.0`` is ``eta``). An f32 ones column agrees with the
+    scalar to rounding, and its gradient entry is the scalar's."""
+    import jax
+    import jax.numpy as jnp
+
+    from dask_ml_tpu.ops.pallas_fused import fused_glm_value_grad
+
+    n, n_valid = 3000, 2500
+    X, y, coef = _direct_inputs(family, dtype, n)
+    call = partial(fused_glm_value_grad, family=family, interpret=True)
+    v0, g0 = call(X, n_valid, y, coef)
+    vz, gz, _ = call(X, n_valid, y, coef, intercept=0.0)
+    assert np.asarray(v0) == np.asarray(vz)
+    np.testing.assert_array_equal(np.asarray(g0), np.asarray(gz))
+
+    kernels = [e for e in _walk_eqns(jax.make_jaxpr(
+        lambda x, y, b: call(x, n_valid, y, b))(X, y, coef).jaxpr)
+        if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    assert (len(kernels[0].invars), len(kernels[0].outvars)) == (4, 2)
+
+    if dtype == "float32":       # a bf16 column would round 0.37
+        ones = (jnp.arange(n) < n_valid).astype(X.dtype)[:, None]
+        vc, gc = call(jnp.concatenate([X, ones], axis=1), n_valid, y,
+                      jnp.concatenate([coef, jnp.asarray([0.37])]))
+        vs, gs, gbs = call(X, n_valid, y, coef, intercept=0.37)
+        np.testing.assert_allclose(float(vs), float(vc), rtol=1e-5)
+        np.testing.assert_allclose(np.r_[np.asarray(gs), float(gbs)],
+                                   np.asarray(gc), rtol=1e-4,
+                                   atol=1e-6 * np.abs(gc).max())
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_scalar_intercept_losses_agree_on_mesh(n_devices, dtype):
+    """The two flavours of ``_select_loss`` in the scalar-intercept form
+    — the kernel per shard under ``_shard_psum_call``, and the XLA
+    objective — give one value and one (d + 1,) gradient on a mesh of
+    one device and of four with ragged shards (3001 rows pad to 751 a
+    shard: the padding rows of EVERY shard see ``eta = b0``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dask_ml_tpu.models.solvers import solvers as S
+    from dask_ml_tpu.parallel.mesh import device_mesh
+    from dask_ml_tpu.parallel.sharded import as_sharded
+
+    n, d = 3001, 12
+    rng = np.random.RandomState(5)
+    mesh = device_mesh(devices=jax.devices()[:n_devices])
+    Xs = as_sharded(rng.randn(n, d).astype(np.float32), mesh=mesh)
+    ys = as_sharded((rng.rand(n) < 0.4).astype(np.float32), mesh=mesh)
+    mask = Xs.row_mask(dtype=jnp.float32)
+    X = Xs.data.astype(dtype)
+    assert X.shape[0] > n or n_devices == 1
+    beta = jnp.asarray(np.r_[rng.randn(d) * 0.2, -0.8], jnp.float32)
+    pmask = jnp.asarray(np.r_[np.ones(d), 0.0], jnp.float32)
+
+    def vg(use_pallas):
+        loss = S._select_loss(use_pallas, X, ys.data, mask, float(n),
+                              jnp.float32(1e-3), pmask, 0.5, "logistic",
+                              "l2", mesh, True, intercept=True)
+        return jax.jit(jax.value_and_grad(loss))(beta)
+
+    (v_k, g_k), (v_x, g_x) = vg(True), vg(False)
+    assert g_k.shape == g_x.shape == (d + 1,)
+    np.testing.assert_allclose(float(v_k), float(v_x), rtol=2e-5)
+    band = 1e-5 if dtype == "float32" else 4e-3
+    np.testing.assert_allclose(np.asarray(g_k), np.asarray(g_x),
+                               atol=band * np.abs(np.asarray(g_x)).max())
+
+
 def _walk_eqns(jaxpr):
     """Every equation of ``jaxpr`` and of every jaxpr nested in its
     parameters (loop bodies, shard_map, custom_vjp, the Pallas kernel)."""
@@ -124,12 +258,15 @@ def _avals(eqn):
             if hasattr(v.aval, "shape")]
 
 
-def test_lbfgs_program_has_no_column_labels():
+@pytest.mark.parametrize("intercept", [False, True])
+def test_lbfgs_program_has_no_column_labels(intercept):
     """No (n_local, 1) / (tile, 1) f32 array exists anywhere in the fused
     ``glm.lbfgs`` program — loop bodies, the shard_map body and the
     kernel itself included. On a TPU such an array is tiled T(8,128):
     512 B a row, built and re-read on every objective evaluation. The
-    chip shows that as time; this counts it."""
+    chip shows that as time; this counts it. With ``intercept`` beta is
+    one longer than X is wide, and no ``concatenate`` / ``pad`` in the
+    program touches anything X-sized."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -145,17 +282,19 @@ def test_lbfgs_program_has_no_column_labels():
     assert n_local % tile == 0 and n_local // tile > 1
     X = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
     y = mask = jax.ShapeDtypeStruct((n,), jnp.float32)
-    beta0 = jnp.zeros((d,), jnp.float32)
+    w = d + int(intercept)
+    beta0 = jnp.zeros((w,), jnp.float32)
     carry = (beta0, optax.lbfgs(memory_size=10).init(beta0),
              jnp.asarray(jnp.inf, jnp.float32), 0, np.zeros((), np.int32))
 
     def program(X, y, mask, carry):
         return S._lbfgs_chunk.__wrapped_jit__(
             X, y, mask, float(n), carry, lam=jnp.float32(1.0),
-            pmask=jnp.ones((d,), jnp.float32), l1_ratio=0.5,
+            pmask=jnp.ones((w,), jnp.float32), l1_ratio=0.5,
             stop_it=jnp.asarray(5), tol=jnp.float32(1e-3),
             family="logistic", reg="l2", memory=10, log=False,
-            use_pallas=True, mesh=mesh, interpret=True)
+            use_pallas=True, mesh=mesh, interpret=True,
+            intercept=intercept)
 
     eqns = list(_walk_eqns(jax.make_jaxpr(program)(X, y, mask, carry).jaxpr))
     kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
@@ -168,6 +307,12 @@ def test_lbfgs_program_has_no_column_labels():
     found = [(e.primitive.name, a) for e in eqns for a in _avals(e)
              if tuple(a.shape) in columns]
     assert not found, found[:5]
+    for e in kernels:     # the kernel's X block is as wide as X
+        assert e.invars[0].aval.shape == (n_local, d)
+    wide = [(e.primitive.name, a) for e in eqns
+            if e.primitive.name in ("concatenate", "pad")
+            for a in _avals(e) if a.shape and a.shape[0] in (n, n_local)]
+    assert not wide, wide[:5]
 
 
 @pytest.mark.parametrize("dtype,expected", [
