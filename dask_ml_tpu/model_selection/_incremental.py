@@ -621,11 +621,11 @@ def _fit(model_factory, params_list, train_blocks, X_test, y_test, scorer,
                 train_blocks[j][0].data.nbytes for j in uniq
                 if isinstance(train_blocks[j][0], ShardedArray)
             )
-            from ..wrappers import _device_headroom_bytes
+            from ..wrappers import _device_headroom
 
-            fused = _device_headroom_bytes(
+            fused = _device_headroom(
                 stack_bytes, train_blocks[uniq[0]][0]
-            )
+            )["fits"]
         if fused:
             cls._batched_fused_calls(
                 cohort, [train_blocks[j] for j in uniq],

@@ -32,13 +32,38 @@ import numpy as np
 
 from ..base import BaseEstimator, ClassifierMixin, RegressorMixin, to_host
 from ..metrics import accuracy_score, r2_score
-from ..observability import track_program
+from ..observability import span, track_program
 from ..plans import tracked as plan_tracked, warmups as plan_warmups
 from ..parallel.sharded import ShardedArray, as_sharded
 from ..utils.validation import check_is_fitted
 
 _LOSSES = ("log_loss", "hinge", "squared_error")
 _PENALTIES = ("l2", "l1", "elasticnet", None, "none")
+
+
+@jax.custom_vjp
+def _design_matvec(Xd, coef):
+    """``Xd @ coef`` with ``coef`` (f32) rounded to the design matrix's
+    dtype for this product only, f32 accumulation — and an f32 GRADIENT:
+    left to autodiff, the transpose of the cast rounds ``Xd^T r`` to the
+    design's dtype too (a bf16 gradient under ``dtype="auto"`` on a TPU,
+    2^-9 of every entry at every step), which nothing states and the f32
+    update does not want. For an f32 ``Xd`` this is ``Xd @ coef``."""
+    return jnp.matmul(Xd, coef.astype(Xd.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _design_matvec_fwd(Xd, coef):
+    return _design_matvec(Xd, coef), Xd
+
+
+def _design_matvec_bwd(Xd, ct):
+    # the same product autodiff makes, kept in f32; the design matrix is
+    # data (no cotangent)
+    return None, jnp.matmul(ct, Xd, preferred_element_type=jnp.float32)
+
+
+_design_matvec.defvjp(_design_matvec_fwd, _design_matvec_bwd)
 
 
 def _sgd_data_loss(w, y, X, mask, n_valid, iflag, loss, mxu=None):
@@ -55,9 +80,7 @@ def _sgd_data_loss(w, y, X, mask, n_valid, iflag, loss, mxu=None):
     # block (config.dtype="bfloat16" epoch grids) rides the MXU at
     # bf16 rate; for f32 X this is exactly `X @ w[:-1]`
     Xd = X if mxu is None else X.astype(mxu)
-    eta = jnp.matmul(Xd, w[:-1].astype(Xd.dtype),
-                     preferred_element_type=jnp.float32) \
-        + w[-1] * iflag
+    eta = _design_matvec(Xd, w[:-1]) + w[-1] * iflag
     if loss == "log_loss":
         per = jax.nn.softplus(eta) - y * eta
     elif loss == "hinge":
@@ -654,6 +677,7 @@ def _sgd_epoch(Xr, yr, order, W, t0, eta0, power_t, alpha, l2w, l1w,
             return eta0 / t ** power_t
         return 1.0 / (alpha * (1e3 + t))  # "optimal"
 
+    @jax.named_scope("sgd_step")
     def step(carry, b):
         W, t = carry
         Xb = jnp.take(Xr, b, axis=0)          # (S, d), axis 0 sharded
@@ -1120,10 +1144,9 @@ def fused_blocks(X) -> tuple[int, int]:
 
     Layout note: a STRIDED partition ({r ≡ b mod B}, grid (S, B, d)
     axis-0-sharded) would make the grid build collective-free, but each
-    scan step then reads d-length runs strided B·d apart — measured ~4x
-    slower per epoch than contiguous reads; the contiguous grid pays one
-    all-to-all at build and streams contiguously ever after, which wins
-    on CPU and maps better to TPU HBM burst reads."""
+    scan step would then read d-length runs strided B·d apart; the
+    contiguous grid pays one all-to-all at build (on more than one
+    device) and every step reads one contiguous (S, d) slab."""
     from ..parallel.mesh import data_shards
     from ..parallel.streaming import grid_partition
 
@@ -1132,30 +1155,29 @@ def fused_blocks(X) -> tuple[int, int]:
 
 @_functools.lru_cache(maxsize=32)
 def _grid_builders(mesh, B, S, dtype=None):
-    """Cached jitted block-grid programs per (mesh, grid shape): pad the
-    (n_pad, d) row-sharded array to B*S rows and reshape to (B, S, d)
-    with axis 1 sharded (every scan step uses the whole mesh). One
-    contiguous pad+reshape+reshard — the gather this replaced was ~6x
-    slower on the same data and dominated the whole fused fit. Cached
-    because a fresh ``jax.jit(lambda)`` per fit would retrace every
-    epoch."""
+    """Cached block-grid programs per (mesh, grid shape), tracked as
+    ``sgd.grid_x`` / ``sgd.grid_y``: pad the (n_pad, d) row-sharded array
+    to B*S rows and reshape to (B, S, d) with axis 1 sharded (every scan
+    step uses the whole mesh) — one contiguous pad + reshape + reshard,
+    with the cast to the fit dtype fused in. Cached because a fresh jit
+    per fit would retrace every epoch."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS
 
     sh3 = NamedSharding(mesh, P(None, DATA_AXIS, None))
     sh2 = NamedSharding(mesh, P(None, DATA_AXIS))
-    fX = jax.jit(
-        lambda a: jnp.pad(
+
+    def grid_x(a):
+        return jnp.pad(
             a, ((0, B * S - a.shape[0]), (0, 0))
-        ).reshape(B, S, a.shape[1]).astype(dtype or a.dtype),
-        out_shardings=sh3,
-    )
-    fy = jax.jit(
-        lambda a: jnp.pad(a, (0, B * S - a.shape[0])).reshape(B, S),
-        out_shardings=sh2,
-    )
-    return fX, fy
+        ).reshape(B, S, a.shape[1]).astype(dtype or a.dtype)
+
+    def grid_y(a):
+        return jnp.pad(a, (0, B * S - a.shape[0])).reshape(B, S)
+
+    return (plan_tracked("sgd.grid_x", jax.jit(grid_x, out_shardings=sh3)),
+            plan_tracked("sgd.grid_y", jax.jit(grid_y, out_shardings=sh2)))
 
 
 @jax.jit
@@ -1275,12 +1297,17 @@ class _SGDBase(BaseEstimator):
         return X, y
 
     def partial_fit(self, X, y, classes=None, **kwargs):
-        if classes is not None:
-            self._set_classes(np.asarray(classes))
-        X, y = self._block(X, y)
-        self._ensure_state(X.shape[1])
-        self._one_step(X.data, y.data, X.row_mask(jnp.float32), X.n_rows)
-        self._publish(X.shape[1])
+        # a root span when called on its own; under Incremental's block
+        # loop it nests in the wrapper's pass.solve
+        with span("partial_fit", component=type(self).__name__) as sp:
+            if classes is not None:
+                self._set_classes(np.asarray(classes))
+            X, y = self._block(X, y)
+            self._ensure_state(X.shape[1])
+            self._one_step(X.data, y.data, X.row_mask(jnp.float32),
+                           X.n_rows)
+            self._publish(X.shape[1])
+            sp.add(n_rows=X.n_rows, t_end=int(self._t))
         return self
 
     def _fused_epoch(self, X, y, order, n_blocks=None, classes=None):
@@ -1294,57 +1321,79 @@ class _SGDBase(BaseEstimator):
         same lr clock, same masking), minus one dispatch round trip per
         block. NOTE the grid is a second device copy of the dataset for
         the epoch's duration — the wrapper falls back to the block loop
-        when HBM headroom is insufficient."""
-        if classes is not None:
-            self._set_classes(np.asarray(classes))
-        if isinstance(self, ClassifierMixin) and \
-                getattr(self, "classes_", None) is None:
-            raise ValueError(
-                "classes must be passed on the first call to partial_fit."
-            )
-        X = as_sharded(X, dtype=np.float32)
-        y_enc = as_sharded(self._encode_y(y), mesh=X.mesh,
-                           dtype=np.float32)
-        mesh = X.mesh
-        d = X.data.shape[1]
-        B, S = fused_blocks(X)
-        if n_blocks is not None and n_blocks != B:
-            # ``order`` indexes the caller's block partition; a
-            # mismatched one would silently train wrong minibatches
-            raise ValueError(
-                f"_fused_epoch grid has {B} blocks of {S} rows; caller "
-                f"partitioned into {n_blocks}"
-            )
-        order = np.asarray(order, np.int32)
-        if order.size and (order.min() < 0 or order.max() >= B):
-            raise ValueError(
-                f"order indexes blocks 0..{B - 1}; got "
-                f"[{order.min()}, {order.max()}]"
-            )
-        self._ensure_state(d)
-        self._lr()  # validate the schedule name eagerly, like the loop
+        when HBM headroom is insufficient.
+
+        Three spans, children of the caller's root (``Incremental.fit`` /
+        ``partial_fit``): ``pass.validate`` (classes, ``as_sharded``, the
+        label encoding with its one-scalar fetch), ``pass.grid`` (the
+        DISPATCH of ``sgd.grid_x`` / ``sgd.grid_y``: it does not wait for
+        them, so the grid's device time lands in ``pass.solve``, which
+        waits for everything) and ``pass.solve`` (``sgd.fused_epoch``
+        through the weights on the host). ``solver_info_`` records what
+        ran."""
+        with span("pass.validate"):
+            if classes is not None:
+                self._set_classes(np.asarray(classes))
+            if isinstance(self, ClassifierMixin) and \
+                    getattr(self, "classes_", None) is None:
+                raise ValueError(
+                    "classes must be passed on the first call to "
+                    "partial_fit."
+                )
+            X = as_sharded(X, dtype=np.float32)
+            y_enc = as_sharded(self._encode_y(y), mesh=X.mesh,
+                               dtype=np.float32)
+            mesh = X.mesh
+            d = X.data.shape[1]
+            B, S = fused_blocks(X)
+            if n_blocks is not None and n_blocks != B:
+                # ``order`` indexes the caller's block partition; a
+                # mismatched one would silently train wrong minibatches
+                raise ValueError(
+                    f"_fused_epoch grid has {B} blocks of {S} rows; "
+                    f"caller partitioned into {n_blocks}"
+                )
+            order = np.asarray(order, np.int32)
+            if order.size and (order.min() < 0 or order.max() >= B):
+                raise ValueError(
+                    f"order indexes blocks 0..{B - 1}; got "
+                    f"[{order.min()}, {order.max()}]"
+                )
+            self._ensure_state(d)
+            self._lr()  # validate the schedule name eagerly, like the loop
         from ..config import mxu_dtype
 
-        # bf16 epoch grid: halves the grid's HBM (it's a second copy of
-        # X) and the scan's matvecs ride the MXU at bf16 rate with f32
-        # accumulation; weights/targets/updates stay f32. Weight parity
-        # vs f32 ~1e-2 relative (input rounding on the design matrix)
-        fX, fy = _grid_builders(mesh, B, S, mxu_dtype(self.fit_dtype))
-        Xr = fX(X.data)
-        yr = fy(y_enc.data)
-        l2w, l1w = self._penalty_weights()
-        W, _t = _sgd_epoch(
-            Xr, yr, jnp.asarray(order), self._w,
-            np.float32(self._t), np.float32(self.eta0),
-            np.float32(self.power_t), np.float32(self.alpha),
-            np.float32(l2w), np.float32(l1w),
-            np.float32(1.0 if self.fit_intercept else 0.0),
-            np.int32(X.n_rows), loss=self._loss(),
-            schedule=self.learning_rate, n_out=self._n_out(),
-        )
-        self._w = W
-        self._t += int(len(order))
-        self._publish(d)
+        with span("pass.grid") as sp:
+            # bf16 epoch grid: halves the grid's HBM (it's a second copy
+            # of X) and the scan's matvecs ride the MXU at bf16 rate with
+            # f32 accumulation; weights/targets/updates stay f32. Weight
+            # parity vs f32 ~1e-2 relative (input rounding on the design
+            # matrix)
+            fX, fy = _grid_builders(mesh, B, S, mxu_dtype(self.fit_dtype))
+            Xr = fX(X.data)
+            yr = fy(y_enc.data)
+            grid_bytes = int(Xr.nbytes) + int(yr.nbytes)
+            sp.add(grid_bytes=grid_bytes)
+        with span("pass.solve") as sp:
+            l2w, l1w = self._penalty_weights()
+            W, _t = _sgd_epoch(
+                Xr, yr, jnp.asarray(order), self._w,
+                np.float32(self._t), np.float32(self.eta0),
+                np.float32(self.power_t), np.float32(self.alpha),
+                np.float32(l2w), np.float32(l1w),
+                np.float32(1.0 if self.fit_intercept else 0.0),
+                np.int32(X.n_rows), loss=self._loss(),
+                schedule=self.learning_rate, n_out=self._n_out(),
+            )
+            self._w = sp.sync(W)
+            self._t += int(len(order))
+            self._publish(d)
+            sp.add(steps=int(len(order)), t_end=int(self._t))
+        self.solver_info_ = {
+            "path": "fused_epoch", "program": "sgd.fused_epoch",
+            "blocks": int(B), "block_rows": int(S),
+            "steps": int(len(order)), "grid_bytes": grid_bytes,
+        }
         return self
 
     # -- batched-trial protocol (consumed by model_selection._incremental) --
@@ -2254,6 +2303,12 @@ class _SGDBase(BaseEstimator):
         return self
 
     def fit(self, X, y, **kwargs):
+        with span("fit", component=type(self).__name__) as sp:
+            self._fit(X, y, **kwargs)
+            sp.add(n_iter=int(self.n_iter_), t_end=int(self._t))
+        return self
+
+    def _fit(self, X, y, **kwargs):
         if not self.warm_start:
             self._w = None
             if getattr(self, "classes_", None) is not None:
@@ -2562,10 +2617,18 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
         return to_host(eta)[: X.n_rows]
 
     def predict(self, X):
-        scores = self.decision_function(X)
-        if self._n_out() is not None:
-            return self.classes_[np.argmax(scores, axis=1)]
-        return self.classes_[(scores > 0).astype(int)]
+        # a root span when called on its own; under ParallelPostFit /
+        # Incremental it nests in the wrapper's ``predict``. The host half
+        # (threshold or argmax, the class lookup over every row) is
+        # ``predict.host``, as in the GLMs
+        with span("predict", component=type(self).__name__) as root:
+            with span("predict.decision"):
+                scores = self.decision_function(X)
+            root.add(n_rows=len(scores))
+            with span("predict.host"):
+                if self._n_out() is not None:
+                    return self.classes_[np.argmax(scores, axis=1)]
+                return self.classes_[(scores > 0).astype(int)]
 
     def predict_proba(self, X):
         if self._loss() != "log_loss":

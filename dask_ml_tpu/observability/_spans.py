@@ -301,24 +301,28 @@ class span:
             }
         self._ctr0 = (counters_snapshot()
                       if armed and counters_enabled() else None)
+        # the two clocks are read side by side at each end, so the
+        # record's wall_s and its [t_start_ns, t_end_ns] are the same
+        # interval (readers subtract t_start_ns values and expect what
+        # wall_s says)
+        self._t0_ns = time.time_ns()
+        self._t0 = time.perf_counter()
         if armed:
             # the same interval on the profiler's clock: opened last and
-            # closed first, so it lies inside [t_start_ns, t_end_ns]
-            self._t0_ns = time.time_ns()
+            # closed first, so it lies inside both
             self._annotation = jax.profiler.TraceAnnotation(
                 ANNOTATION_PREFIX + self.name)
             self._annotation.__enter__()
-        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if not self._tracked:
             return False
-        wall = time.perf_counter() - self._t0
         armed = self._annotation is not None
         if armed:
             self._annotation.__exit__(exc_type, exc, tb)
-            t_end_ns = time.time_ns()
+        wall = time.perf_counter() - self._t0
+        t_end_ns = time.time_ns()
         st = _stack()
         # pop down to (and including) OUR frame: frames above ours are
         # spans abandoned mid-block (a generator dropped between yields)

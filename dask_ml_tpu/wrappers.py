@@ -38,34 +38,33 @@ def _data_shards(mesh):
     return data_shards(mesh)
 
 
-def _device_headroom_bytes(nbytes, sample, fraction=0.5):
-    """True when an extra device allocation of ``nbytes`` (sharded like
-    ``sample``) plausibly fits: per-device free bytes (when the runtime
-    reports memory_stats — TPU does, CPU returns None and passes) must
-    cover the per-device share with ``fraction`` slack."""
+def _device_headroom(nbytes, sample, fraction=0.5):
+    """What the headroom gate reads for an extra device allocation of
+    ``nbytes`` sharded like ``sample``: ``{"needed": bytes a device,
+    "free": the fullest device's bytes_limit - bytes_in_use, "fits":
+    needed <= fraction x free}``. ``free`` is None (and the gate passes)
+    where the runtime reports no memory_stats — the CPU — or the sample
+    lives on the host."""
+    out = {"needed": None, "free": None, "fits": True}
     try:
         data = getattr(sample, "data", None)
         if data is None:
-            return True  # host sample: no device copy involved
+            return out  # host sample: no device copy involved
         devs = list(data.devices())
-        per_dev = nbytes / max(len(devs), 1)
+        out["needed"] = int(nbytes // max(len(devs), 1))
         for dev in devs:
             stats = dev.memory_stats()
             if not stats:
                 continue
-            free = stats.get("bytes_limit", 0) - stats.get(
-                "bytes_in_use", 0
-            )
-            if per_dev > fraction * free:
-                return False
-        return True
+            free = int(stats.get("bytes_limit", 0)
+                       - stats.get("bytes_in_use", 0))
+            if out["free"] is None or free < out["free"]:
+                out["free"] = free
+        if out["free"] is not None:
+            out["fits"] = out["needed"] <= fraction * out["free"]
     except Exception:
-        return True  # no reliable stats: assume fine (host-backed CPU)
-
-
-def _device_headroom_for_copy(X, fraction=0.5):
-    """True when a full second device copy of ``X`` plausibly fits."""
-    return _device_headroom_bytes(X.data.nbytes, X, fraction)
+        out["fits"] = True  # no reliable stats: assume fine (host CPU)
+    return out
 
 
 def _is_device_estimator(est):
@@ -163,6 +162,15 @@ class ParallelPostFit(BaseEstimator):
         return out
 
     def _apply(self, X, method):
+        """One post-fit call: a root span named after ``method``
+        (``predict``, ``transform``, ...) around it."""
+        from .observability import span
+
+        with span(method, component=type(self).__name__,
+                  estimator=type(self._est).__name__):
+            return self._apply_blocks(X, method)
+
+    def _apply_blocks(self, X, method):
         est = self._est
         from .parallel.frames import PartitionedFrame
 
@@ -1048,7 +1056,61 @@ class Incremental(ParallelPostFit):
         self.predict_proba_meta = predict_proba_meta
         self.transform_meta = transform_meta
 
-    def _partial_fit_pass(self, est, X, y, block_size, rng, **fit_kwargs):
+    def _pass(self, root, est, X, y, **fit_kwargs):
+        """One pass of ``fit`` / ``partial_fit`` under the call's ``root``
+        span, and the record of it: ``pass_info_`` —
+
+        ``path``
+            which of the four ran: ``"fused_epoch"`` (device estimator,
+            device data, one scan program over a block grid),
+            ``"block_loop"`` (the same blocks, one ``partial_fit`` each),
+            ``"stream_pass"`` (device estimator, host data, super-block
+            scans) or ``"host_loop"`` (one ``partial_fit`` a host block);
+        ``blocks``, ``steps``
+            blocks of the pass; updates it made (the clock's advance
+            where the estimator keeps one);
+        ``dispatches``
+            tracked-program calls in the pass (the registry's delta;
+            None unless ``config.obs_programs`` is on);
+        ``grid_bytes``
+            device bytes of the epoch grid (0 off the fused path);
+        ``headroom``
+            what the fused path's gate read (``needed`` / ``free`` bytes a
+            device, ``fits``; ``free`` None where the backend reports no
+            memory stats; None where the gate was not asked);
+        ``fit_dtype``, ``t_end``
+            the resolved fit dtype and the clock after the pass (None for
+            an estimator that has neither).
+
+        One dict rebuilt per pass; its keys are also the root span's
+        attributes."""
+        from .observability import programs_enabled, programs_snapshot
+
+        def program_calls():
+            return sum(int(r["calls"]) for r in programs_snapshot())
+
+        before = program_calls() if programs_enabled() else None
+        t0 = getattr(est, "_t", None)
+        rng = np.random.RandomState(self.random_state)
+        info = {"path": None, "blocks": 0, "grid_bytes": 0,
+                "headroom": None}
+        est = self._partial_fit_pass(est, X, y, self._block_size(X), rng,
+                                     info, **fit_kwargs)
+        t1 = getattr(est, "_t", None)
+        info["steps"] = info["blocks"] if t0 is None or t1 is None \
+            else int(t1) - int(t0)
+        info["dispatches"] = None if before is None \
+            else program_calls() - before
+        info["fit_dtype"] = getattr(est, "fit_dtype_", None)
+        info["t_end"] = None if t1 is None else int(t1)
+        self.pass_info_ = info
+        root.add(**info)
+        return est
+
+    def _partial_fit_pass(self, est, X, y, block_size, rng, info,
+                          **fit_kwargs):
+        from .observability import span
+
         if _is_device_estimator(est) and isinstance(X, ShardedArray):
             # device estimator + device data: blocks are the fused-epoch
             # grid's contiguous S-row ranges (fused_blocks), so the
@@ -1068,64 +1130,77 @@ class Incremental(ParallelPostFit):
             order = list(range(B))
             if self.shuffle_blocks:
                 rng.shuffle(order)
+            info["blocks"] = B
             if (hasattr(est, "_fused_epoch") and ys is not None
                     and B > 1
-                    and set(fit_kwargs) <= {"classes"}
-                    and _device_headroom_for_copy(X)):
+                    and set(fit_kwargs) <= {"classes"}):
                 # fused-epoch fast path: the whole pass compiles into ONE
                 # scan program (same updates/order/lr clock as the block
                 # loop) — per-block dispatch round trips vanish. The
                 # grid is a second device copy of X for the epoch, hence
                 # the headroom gate (the loop gathers one block at a
                 # time and stays the fallback near HBM capacity).
-                est._fused_epoch(
-                    X, ys, order, n_blocks=B,
-                    classes=fit_kwargs.get("classes"),
-                )
-                return est
+                info["headroom"] = _device_headroom(X.data.nbytes, X)
+                if info["headroom"]["fits"]:
+                    est._fused_epoch(
+                        X, ys, order, n_blocks=B,
+                        classes=fit_kwargs.get("classes"),
+                    )
+                    info["path"] = est.solver_info_["path"]
+                    info["grid_bytes"] = est.solver_info_["grid_bytes"]
+                    return est
             from .observability.live import publish_progress
 
-            for done, b in enumerate(order):
-                idx = np.arange(b * S, min((b + 1) * S, X.n_rows))
-                Xb = take_rows(X, idx)
-                if ys is None:
-                    est.partial_fit(Xb, **fit_kwargs)
-                else:
-                    yb = take_rows(ys, idx) if isinstance(ys, ShardedArray) \
-                        else ys[idx]
-                    est.partial_fit(Xb, yb, **fit_kwargs)
-                # live pass progress (host ints; no-op without the
-                # telemetry server)
-                publish_progress(block=done + 1, blocks_total=B)
+            info["path"] = "block_loop"
+            with span("pass.solve") as sp:
+                for done, b in enumerate(order):
+                    idx = np.arange(b * S, min((b + 1) * S, X.n_rows))
+                    Xb = take_rows(X, idx)
+                    if ys is None:
+                        est.partial_fit(Xb, **fit_kwargs)
+                    else:
+                        yb = take_rows(ys, idx) \
+                            if isinstance(ys, ShardedArray) else ys[idx]
+                        est.partial_fit(Xb, yb, **fit_kwargs)
+                    # live pass progress (host ints; no-op without the
+                    # telemetry server)
+                    publish_progress(block=done + 1, blocks_total=B)
+                sp.add(steps=B)
             return est
         # sparse X blocks stay CSR host-side: a device estimator's
         # partial_fit densifies ONE block at placement (as_sharded), a
         # host estimator consumes the CSR block natively — either way
         # peak memory is O(block), never the dense corpus
-        Xh = _host_matrix(X)
-        yh = y.to_numpy() if isinstance(y, ShardedArray) else np.asarray(y)
-        starts = list(range(0, Xh.shape[0], block_size))
-        order = np.arange(len(starts))
-        if self.shuffle_blocks:
-            rng.shuffle(order)
-        if (_is_device_estimator(est) and hasattr(est, "_stream_pass")
-                and set(fit_kwargs) <= {"classes"}):
-            # super-block fast path for device estimators on host data:
-            # the pass's per-block partial_fit dispatches collapse into
-            # donated-carry scans over K-stacked blocks — identical
-            # minibatches, order, and lr clock. Returns False (sparse
-            # source, K == 1 opt-out, partition mismatch) -> the
-            # per-block loop below.
-            if est._stream_pass(Xh, yh, block_size, order=order,
-                                classes=fit_kwargs.get("classes")):
-                return est
-        from .observability.live import publish_progress
+        with span("pass.validate"):
+            Xh = _host_matrix(X)
+            yh = y.to_numpy() if isinstance(y, ShardedArray) \
+                else np.asarray(y)
+            starts = list(range(0, Xh.shape[0], block_size))
+            order = np.arange(len(starts))
+            if self.shuffle_blocks:
+                rng.shuffle(order)
+        info["blocks"] = len(starts)
+        with span("pass.solve"):
+            if (_is_device_estimator(est) and hasattr(est, "_stream_pass")
+                    and set(fit_kwargs) <= {"classes"}):
+                # super-block fast path for device estimators on host
+                # data: the pass's per-block partial_fit dispatches
+                # collapse into donated-carry scans over K-stacked blocks
+                # — identical minibatches, order, and lr clock. Returns
+                # False (sparse source, K == 1 opt-out, partition
+                # mismatch) -> the per-block loop below.
+                if est._stream_pass(Xh, yh, block_size, order=order,
+                                    classes=fit_kwargs.get("classes")):
+                    info["path"] = "stream_pass"
+                    return est
+            from .observability.live import publish_progress
 
-        for done, oi in enumerate(order):
-            s = starts[int(oi)]
-            est.partial_fit(Xh[s:s + block_size], yh[s:s + block_size],
-                            **fit_kwargs)
-            publish_progress(block=done + 1, blocks_total=len(starts))
+            info["path"] = "host_loop"
+            for done, oi in enumerate(order):
+                s = starts[int(oi)]
+                est.partial_fit(Xh[s:s + block_size], yh[s:s + block_size],
+                                **fit_kwargs)
+                publish_progress(block=done + 1, blocks_total=len(starts))
         return est
 
     # -- pass-granular checkpoint/auto-resume (ISSUE 11) -------------------
@@ -1211,6 +1286,14 @@ class Incremental(ParallelPostFit):
         return self.completed_passes_
 
     def fit(self, X, y=None, **fit_kwargs):
+        from .observability import span
+
+        with span("fit", component=type(self).__name__,
+                  estimator=type(self.estimator).__name__) as root:
+            self._fit(root, X, y, **fit_kwargs)
+        return self
+
+    def _fit(self, root, X, y, **fit_kwargs):
         est = clone(self.estimator)
         if not hasattr(est, "partial_fit"):
             raise ValueError(
@@ -1239,13 +1322,17 @@ class Incremental(ParallelPostFit):
                 ckpt.clear()
         except Exception:
             pass
-        rng = np.random.RandomState(self.random_state)
-        self.estimator_ = self._partial_fit_pass(
-            est, X, y, self._block_size(X), rng, **fit_kwargs
-        )
-        return self
+        self.estimator_ = self._pass(root, est, X, y, **fit_kwargs)
 
     def partial_fit(self, X, y=None, **fit_kwargs):
+        from .observability import span
+
+        with span("partial_fit", component=type(self).__name__,
+                  estimator=type(self.estimator).__name__) as root:
+            self._partial_fit(root, X, y, **fit_kwargs)
+        return self
+
+    def _partial_fit(self, root, X, y, **fit_kwargs):
         if getattr(self, "estimator_", None) is None:
             # fresh wrapper: a matching checkpoint restores the killed
             # driver's inner carry before this pass runs
@@ -1254,10 +1341,7 @@ class Incremental(ParallelPostFit):
         if est is None:
             est = clone(self.estimator)
         ckpt = self._pass_checkpoint(est, X, y, fit_kwargs)
-        rng = np.random.RandomState(self.random_state)
-        self.estimator_ = self._partial_fit_pass(
-            est, X, y, self._block_size(X), rng, **fit_kwargs
-        )
+        self.estimator_ = self._pass(root, est, X, y, **fit_kwargs)
         if ckpt is not None:
             self.completed_passes_ = \
                 getattr(self, "completed_passes_", 0) + 1
@@ -1271,7 +1355,6 @@ class Incremental(ParallelPostFit):
                     classes=None if classes is None
                     else np.asarray(classes),
                 )
-        return self
 
     @staticmethod
     def _block_size(X):
