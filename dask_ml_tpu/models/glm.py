@@ -24,8 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import BaseEstimator, to_host
-from ..observability import current_span, span
-from ..parallel.mesh import resolve_mesh
+from ..observability import current_span, span, track_program
+from ..parallel.mesh import data_shards, resolve_mesh
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_X_y, check_array, check_is_fitted
 from .solvers import regularizers
@@ -62,8 +62,165 @@ from functools import partial as _partial
 
 @jax.jit
 def _matvec_eta(data, coef, intercept):
-    """Decision values as ONE program (one launch, not one per op)."""
+    """Decision values, the matvec every link below starts from (a jit of
+    its own only so that it can be lowered alone; ``_decision_link``
+    inlines it)."""
     return data @ coef.astype(data.dtype) + intercept.astype(data.dtype)
+
+
+# The pointwise tail of each kind of linear prediction: decision values in,
+# what the method returns a row out. Kinds by the serving plane's names —
+# ``wrappers._linear_core`` compiles these same functions at a serving
+# batch, ``_decision_link`` over a whole resident X: one formula each.
+LINK_TAILS = {
+    "margin": lambda eta: eta,
+    "proba": jax.nn.sigmoid,             # P(y == classes_[1])
+    "classify": lambda eta: eta > 0,     # the binary class choice
+    "poisson": jnp.exp,
+}
+
+_LANES = 128
+
+
+def _lane_rows(v, quantum):
+    """``(n,)`` -> ``(n' / 128, 128)``: rows go 128 to a sublane row, n
+    rounded up to ``quantum`` (128 a row shard, so that no shard's rows
+    split; the tail is padding). Every link leaves the device in this
+    lane-dense 2-D form, never as ``(n, 1)`` or ``(n, 2)``: on a TPU those
+    are tiled ``T(8, 128)``, 128 / 64 times their numbers' bytes."""
+    return jnp.pad(v, (0, -v.shape[0] % quantum)).reshape(-1, _LANES)
+
+
+def _twice(v):
+    """``(r, 128)`` -> ``(r, 256)`` with lane i at lanes 2i and 2i + 1 —
+    row-major, two adjacent entries a row of X — by ONE product with a
+    constant 0 / 1 matrix: the MXU is the lane shuffle XLA does not have.
+    Exact for f32 at ``HIGHEST`` (the three bf16 pieces of an entry times
+    1, summed in f32), and for 0 / 1 in bf16."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (_LANES, 2 * _LANES), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (_LANES, 2 * _LANES), 1)
+    return jnp.dot(v, (col // 2 == row).astype(v.dtype),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _odd_lanes():
+    return jax.lax.broadcasted_iota(jnp.int32, (2 * _LANES,), 0) % 2 == 1
+
+
+def _proba_pairs(p1, quantum):
+    """``(n,)`` p1 -> ``(n' / 128, 256)``, the row-major ``(n', 2)`` matrix
+    ``[1 - p1, p1]``: each entry f32 ``1 - p1`` or ``p1`` to the bit. A NaN
+    row would reach its 127 neighbours through the zeros of ``_twice``'s
+    product: it travels as 2.0, which no sigmoid returns, and is put back
+    after."""
+    both = _twice(_lane_rows(jnp.where(jnp.isnan(p1), 2.0, p1), quantum))
+    pairs = jnp.where(_odd_lanes(), both, 1.0 - both)
+    return jnp.where(both == 2.0, jnp.nan, pairs)
+
+
+def _class_words(pick, words, quantum):
+    """``(n,)`` bool -> ``(n' / 128, 128 k)``: the chosen class's ``k``
+    machine words a row, row-major; ``words`` is ``(2, k)`` unsigned
+    (``_label_carrier``), k = 2 for an 8-byte ``classes_.dtype``."""
+    pick = _lane_rows(pick, quantum)
+    if words.shape[1] == 1:
+        return jnp.where(pick, words[1, 0], words[0, 0])
+    pick = _twice(pick.astype(jnp.bfloat16)) > 0.5
+    low, high = (jnp.where(_odd_lanes(), w[1], w[0]) for w in words)
+    return jnp.where(pick, high, low)
+
+
+@track_program("glm.decision")
+@_partial(jax.jit, static_argnames=("link", "quantum"))
+def _decision_link(data, beta, words, link, quantum):
+    """Decision values AND the link in one program over a resident X: what
+    a predict fetches is what it returns, lane-dense (``_lane_rows``).
+    ``beta`` is ``(d + 1,)`` with the intercept last, ``words`` the two
+    class values as machine words (``_label_carrier``; None for the other
+    links): operands, so a new fit compiles nothing. Links (static):
+    ``identity`` eta; ``exp`` the Poisson mean; ``proba2`` ``[1 - p1, p1]``
+    a row (``_proba_pairs``); ``label`` / ``label_proba`` the class chosen
+    by ``eta > 0`` (SGDClassifier) / ``sigmoid(eta) > 0.5``
+    (LogisticRegression) — they differ for |eta| under ~1e-7 and each
+    estimator keeps its own."""
+    eta = _matvec_eta(data, beta[:-1], beta[-1])
+    if link == "identity":
+        return _lane_rows(LINK_TAILS["margin"](eta), quantum)
+    if link == "exp":
+        return _lane_rows(LINK_TAILS["poisson"](eta), quantum)
+    if link == "proba2":
+        return _proba_pairs(LINK_TAILS["proba"](eta), quantum)
+    if link == "label":
+        pick = LINK_TAILS["classify"](eta)
+    elif link == "label_proba":
+        pick = LINK_TAILS["proba"](eta) > 0.5
+    else:
+        raise ValueError(f"unknown link {link!r}")
+    return _class_words(pick, words, quantum)
+
+
+_LABEL_LINKS = ("label", "label_proba")
+
+
+def _label_carrier(classes):
+    """``(words, mapped)`` for the two ``classes_``. Numeric classes of 1,
+    2, 4 or 8 bytes cross as their own bytes — ``(2, k)`` unsigned words in
+    memory order, k = 2 for 8 bytes — so the host VIEWS what it fetched as
+    ``classes_.dtype``: exact for every value, no pass. Anything else
+    (strings, objects) crosses as the index 0 / 1 in one byte, which the
+    host maps through ``classes_``."""
+    classes = np.asarray(classes)
+    if classes.dtype.kind in "biuf" and classes.dtype.itemsize <= 8:
+        word = np.dtype(f"u{min(classes.dtype.itemsize, 4)}")
+        return np.ascontiguousarray(classes).view(word).reshape(2, -1), False
+    return np.arange(2, dtype=np.uint8).reshape(2, 1), True
+
+
+def link_fetch(X, beta, link, classes=None):
+    """The device half of one prediction over a resident ``X``: ONE
+    dispatch of ``glm.decision`` and ONE fetch of its (row-padded, 2-D)
+    result. The wait lands on the open span (``predict.decision``) as
+    ``sync_s``, with the engagement record ``link="device"`` and
+    ``fetch_bytes``. ``classes`` (the estimator's ``classes_``) matters to
+    a label link alone."""
+    sp = current_span()
+    words = _label_carrier(classes)[0] if link in _LABEL_LINKS else None
+    out = _decision_link(X.data, beta, words, link=link,
+                         quantum=_LANES * data_shards(X.mesh))
+    host = to_host(sp.sync(out))
+    sp.add(link="device", fetch_bytes=host.nbytes)
+    return host
+
+
+def link_finish(host, n_rows, link, classes=None):
+    """The host half: views of what ``link_fetch`` brought (row-major, so
+    flat again, as ``classes_.dtype`` or two to a row, cut to ``n_rows``)
+    and a pass over the rows only where an index had to cross: the lookup
+    in ``classes_``."""
+    if link == "proba2":
+        return host.reshape(-1, 2)[:n_rows]
+    host = host.reshape(-1)
+    if link not in _LABEL_LINKS:
+        return host[:n_rows]
+    classes = np.asarray(classes)
+    if _label_carrier(classes)[1]:
+        return classes[host[:n_rows]]
+    return host.view(classes.dtype)[:n_rows]
+
+
+def predict_resident(est, X, link):
+    """One predict over a device-resident X, in the spans every linear
+    estimator shares: root ``predict`` > ``predict.decision`` (the
+    estimator's ``_decision``: placement checks, dispatch, fetch) and
+    ``predict.host`` (``link_finish``, the remainder)."""
+    with span("predict", component=type(est).__name__) as root:
+        with span("predict.decision"):
+            X, host = est._decision(X, link)
+        root.add(n_rows=X.n_rows)
+        with span("predict.host"):
+            return link_finish(host, X.n_rows, link,
+                               getattr(est, "classes_", None))
 
 
 @jax.jit
@@ -609,28 +766,38 @@ class _GLMBase(BaseEstimator):
     def _set_coef(self, coef, classes):
         self.coef_ = coef
 
+    def _beta(self) -> np.ndarray:
+        """``(d + 1,)`` float32, the intercept last: the operand of
+        ``glm.decision``."""
+        return np.append(np.asarray(self._coef_flat(), np.float32),
+                         self._intercept_scalar())
+
     def _eta_host(self, X):
-        """Decision values as a host (n,) array; streams block-wise for
-        out-of-core inputs instead of materializing X on device."""
+        """Decision values as a host (n,) array. An out-of-core X streams
+        block-wise through the matvec and is assembled on the host; a
+        resident X runs ``glm.decision`` with the ``identity`` link and is
+        fetched once (the wait is the open span's ``sync_s``)."""
         from ..parallel.streaming import stream_plan, streamed_map
 
         block_rows = stream_plan(X)
         if block_rows is not None:
             coef = jnp.asarray(self._coef_flat(), jnp.float32)
             b0 = jnp.asarray(self._intercept_scalar())
-            return streamed_map(
+            eta = streamed_map(
                 X, block_rows, lambda blk: blk.arrays[0] @ coef + b0
             )
-        X, eta = self._decision(X)
-        # the fetch is where the host waits for the matvec: charged to
-        # the open span (predict.decision) as sync_s
-        return to_host(current_span().sync(eta))[: X.n_rows]
+            current_span().add(link="host", fetch_bytes=eta.nbytes)
+            return eta
+        X, host = self._decision(X)
+        return link_finish(host, X.n_rows, "identity")
 
-    def _decision(self, X):
+    def _decision(self, X, link="identity"):
+        """The device half for a resident X: ``(X as placed, the fetched
+        host array of glm.decision under link)``; ``link_finish`` is the
+        host half."""
         X = check_array(X, dtype=np.float32)
-        eta = _matvec_eta(X.data, np.asarray(self._coef_flat(), np.float32),
-                          self._intercept_scalar())
-        return X, eta
+        return X, link_fetch(X, self._beta(), link,
+                             getattr(self, "classes_", None))
 
 
 class LinearRegression(_GLMBase):
@@ -660,8 +827,12 @@ class PoissonRegression(_GLMBase):
         return y, None
 
     def predict(self, X):
+        from ..parallel.streaming import stream_plan
+
         check_is_fitted(self, "coef_")
-        return np.exp(self._eta_host(X))
+        if stream_plan(X) is not None:     # assembled on the host anyway
+            return np.exp(self._eta_host(X))
+        return predict_resident(self, X, "exp")
 
     def score(self, X, y):
         from ..metrics import r2_score
@@ -838,16 +1009,33 @@ class LogisticRegression(_GLMBase):
             return self._eta_multi_host(X)
         return self._eta_host(X)
 
+    def _device_link_applies(self, X):
+        """Where the link runs, by what the input and the fit show: on the
+        device for a resident X and a binary fit. A streamed X is
+        assembled block by block on the host anyway, and an ``(n, C)``
+        one-vs-rest result has the lane problem of ``(n, 2)`` with no
+        dense form yet: both keep the host tail and say ``link="host"``."""
+        from ..parallel.streaming import stream_plan
+
+        return not self._is_multiclass() and stream_plan(X) is None
+
     def predict_proba(self, X):
+        check_is_fitted(self, "coef_")
+        if self._device_link_applies(X):
+            return predict_resident(self, X, "proba2")
         from scipy.special import expit
 
-        check_is_fitted(self, "coef_")
         with span("predict", component=type(self).__name__) as root:
             if self._is_multiclass():
                 # OvR probabilities: per-class sigmoids normalized to sum
                 # 1 (sklearn's OvR contract)
-                p = expit(self._eta_multi_host(X))
-                return p / np.maximum(p.sum(axis=1, keepdims=True), 1e-12)
+                with span("predict.decision", link="host"):
+                    eta = self._eta_multi_host(X)
+                root.add(n_rows=len(eta))
+                with span("predict.host"):
+                    p = expit(eta)
+                    return p / np.maximum(p.sum(axis=1, keepdims=True),
+                                          1e-12)
             with span("predict.decision"):
                 eta = self._eta_host(X)
             root.add(n_rows=len(eta))
@@ -863,6 +1051,9 @@ class LogisticRegression(_GLMBase):
         return log_proba(self.predict_proba(X))
 
     def predict(self, X):
+        check_is_fitted(self, "coef_")
+        if self._device_link_applies(X):
+            return predict_resident(self, X, "label_proba")
         if self._is_multiclass():
             eta = self._eta_multi_host(X)
             return self.classes_[np.argmax(eta, axis=1)]

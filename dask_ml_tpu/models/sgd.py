@@ -36,6 +36,7 @@ from ..observability import span, track_program
 from ..plans import tracked as plan_tracked, warmups as plan_warmups
 from ..parallel.sharded import ShardedArray, as_sharded
 from ..utils.validation import check_is_fitted
+from .glm import link_fetch, link_finish, predict_resident
 
 _LOSSES = ("log_loss", "hinge", "squared_error")
 _PENALTIES = ("l2", "l1", "elasticnet", None, "none")
@@ -2427,10 +2428,16 @@ class _SGDBase(BaseEstimator):
         self.n_iter_ = self.max_iter
         return self
 
-    def _decision(self, X):
+    def _decision(self, X, link="identity"):
+        """The device half for a resident X, as the GLMs': ``(X as placed,
+        the fetched host array of glm.decision under link)`` — the same
+        f32 matvec over ``_w`` (the intercept its last entry) and the link
+        in one program, one fetch; ``link_finish`` is the host half. The
+        weights ride as host numpy, as in ``_eta_stream``: X may be placed
+        on another mesh than the one the fit committed ``_w`` to."""
         X = as_sharded(X, dtype=np.float32)
-        w = self._w
-        return X, X.data @ w[:-1] + w[-1]
+        return X, link_fetch(X, np.asarray(self._w, np.float32), link,
+                             getattr(self, "classes_", None))
 
     def _eta_stream(self, X, block_rows):
         """Decision values for out-of-core / sparse X: blocks stream
@@ -2602,6 +2609,14 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
         )
         return np.asarray(acc, np.float64)[:N]
 
+    def _device_link_applies(self, X):
+        """Where the link runs (the GLMs' rule): on the device for a
+        resident X and a binary fit; a streamed X and the ``(n, C)``
+        one-vs-rest scores keep the host tail and say ``link="host"``."""
+        from ..parallel.streaming import stream_plan
+
+        return self._n_out() is None and stream_plan(X) is None
+
     def decision_function(self, X):
         check_is_fitted(self, "coef_")
         from ..parallel.streaming import stream_plan
@@ -2613,16 +2628,25 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
             Xs = as_sharded(X, dtype=np.float32)
             eta = _batched_eta(Xs.data, self._w)   # (n, C)
             return to_host(eta)[: Xs.n_rows]
-        X, eta = self._decision(X)
-        return to_host(eta)[: X.n_rows]
+        X, host = self._decision(X)
+        return link_finish(host, X.n_rows, "identity")
 
     def predict(self, X):
-        # a root span when called on its own; under ParallelPostFit /
-        # Incremental it nests in the wrapper's ``predict``. The host half
-        # (threshold or argmax, the class lookup over every row) is
-        # ``predict.host``, as in the GLMs
+        """A root span when called on its own; under ParallelPostFit /
+        Incremental it nests in the wrapper's ``predict``. Binary and
+        resident: the matvec, ``eta > 0`` and the choice between the two
+        class values run in ONE device program (``glm.decision``,
+        ``predict.decision`` with its fetch); ``predict.host`` is what the
+        labels' dtype leaves — one ``astype`` to ``classes_.dtype``, or
+        the lookup of a one-byte index for classes the device cannot
+        carry. Streamed or multiclass: the scores come to the host and the
+        threshold or argmax and the class lookup over every row are
+        ``predict.host``."""
+        check_is_fitted(self, "coef_")
+        if self._device_link_applies(X):
+            return predict_resident(self, X, "label")
         with span("predict", component=type(self).__name__) as root:
-            with span("predict.decision"):
+            with span("predict.decision", link="host"):
                 scores = self.decision_function(X)
             root.add(n_rows=len(scores))
             with span("predict.host"):
@@ -2634,6 +2658,8 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
         if self._loss() != "log_loss":
             raise AttributeError("predict_proba requires loss='log_loss'")
         check_is_fitted(self, "coef_")
+        if self._device_link_applies(X):
+            return predict_resident(self, X, "proba2")
         from scipy.special import expit
 
         if self._n_out() is not None:
@@ -2704,8 +2730,8 @@ class SGDRegressor(RegressorMixin, _SGDBase):
         block_rows = stream_plan(X)
         if block_rows is not None:
             return self._eta_stream(X, block_rows)
-        X, eta = self._decision(X)
-        return to_host(eta)[: X.n_rows]
+        X, host = self._decision(X)
+        return link_finish(host, X.n_rows, "identity")
 
     def score(self, X, y):
         return r2_score(

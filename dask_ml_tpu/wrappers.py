@@ -595,8 +595,14 @@ def _linear_extract_int8(est, method):
 
 
 def _linear_core(kind, multi, eta=None):
-    import jax
+    """``(params, X)`` -> one method's rows at a serving batch: eta, then
+    the kind's pointwise tail — ``models.glm.LINK_TAILS``, the functions
+    the estimators' own ``glm.decision`` program applies over a whole
+    resident X (which assembles its result lane-dense instead: the
+    ``jnp.stack`` below is right at a few hundred rows only)."""
     import jax.numpy as jnp
+
+    from .models.glm import LINK_TAILS as tails
 
     if eta is None:
         def eta(p, X):
@@ -608,21 +614,22 @@ def _linear_core(kind, multi, eta=None):
     if kind == "proba":
         if multi:
             def core(p, X):
-                pr = jax.nn.sigmoid(eta(p, X))  # OvR sigmoids, normed
+                pr = tails["proba"](eta(p, X))  # OvR sigmoids, normed
                 return pr / jnp.maximum(
                     jnp.sum(pr, axis=1, keepdims=True), 1e-12
                 )
         else:
             def core(p, X):
-                p1 = jax.nn.sigmoid(eta(p, X)[:, 0])
+                p1 = tails["proba"](eta(p, X)[:, 0])
                 return jnp.stack([1.0 - p1, p1], axis=1)
         return core
     if kind == "classify":
         if multi:
             return lambda p, X: jnp.argmax(eta(p, X), axis=1)
-        return lambda p, X: (eta(p, X)[:, 0] > 0).astype(jnp.int32)
+        return lambda p, X: tails["classify"](
+            eta(p, X)[:, 0]).astype(jnp.int32)
     if kind == "poisson":
-        return lambda p, X: jnp.exp(eta(p, X)[:, 0])
+        return lambda p, X: tails["poisson"](eta(p, X)[:, 0])
     return lambda p, X: eta(p, X)[:, 0]            # regression
 
 
