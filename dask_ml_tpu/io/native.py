@@ -202,3 +202,36 @@ class NativeBlockReader:
             self.close()
         except Exception:
             pass
+
+
+# -- numpy's legacy shuffle, sooner (native/legacy_shuffle.cpp) --------------
+
+def _configure_legacy_shuffle(lib):
+    lib.legacy_shuffle_i32.restype = None
+    lib.legacy_shuffle_i32.argtypes = [
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_int32),
+    ]
+
+
+def legacy_shuffle(rng, x):
+    """``rng.shuffle(x)`` for a ``np.random.RandomState`` and a
+    one-dimensional array, in place: the same permutation and the same
+    generator state afterwards. A contiguous int32 ``x`` takes the native
+    loop (the draws a batch ahead, their targets prefetched); anything
+    else, or a host without a compiler, is numpy's own call."""
+    lib = _build_and_load("legacy_shuffle", _configure_legacy_shuffle) \
+        if (isinstance(x, np.ndarray) and x.ndim == 1
+            and x.dtype == np.int32 and x.flags.c_contiguous
+            and x.flags.writeable) else None
+    state = rng.get_state() if lib is not None else None
+    if state is None or state[0] != "MT19937":
+        rng.shuffle(x)
+        return
+    key = np.ascontiguousarray(state[1], np.uint32).copy()
+    pos = ctypes.c_int32(int(state[2]))
+    lib.legacy_shuffle_i32(
+        x.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)), int(x.shape[0]),
+        key.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+        ctypes.byref(pos))
+    rng.set_state((state[0], key, int(pos.value)) + tuple(state[3:]))

@@ -28,7 +28,7 @@ from sklearn.model_selection import ParameterSampler
 from ..base import clone
 from ._incremental import (
     BaseIncrementalSearchCV, disable_process_distribution,
-    host_view_estimator,
+    host_view_estimator, top_scores,
 )
 from ._successive_halving import SuccessiveHalvingSearchCV
 
@@ -170,16 +170,18 @@ class HyperbandSearchCV(BaseIncrementalSearchCV):
                 out.update(pending)
                 continue
             n_keep = max(1, math.floor(len(scores) / eta))
-            keep = sorted(scores, key=scores.get, reverse=True)[:n_keep]
+            keep = top_scores(scores, n_keep)
             self._rungs[s] += 1
             next_target = min(r * (eta ** self._rungs[s]), self.max_iter)
             promote = {mid: next_target - calls[mid] for mid in keep}
             out.update({mid: c for mid, c in promote.items() if c > 0})
         return out
 
-    def _fit_interleaved(self, X, y, **fit_params):
-        super().fit(X, y, **fit_params)
-        # bracket annotations on the merged controller outputs
+    def _annotate_results(self):
+        # bracket annotations on the merged controller outputs (inside
+        # the root span's ``fit.finish``)
+        if not getattr(self, "_bounds", None):
+            return
         for rec in self.history_:
             rec["bracket"] = self._bracket_of(rec["model_id"])
         res = self.cv_results_
@@ -196,7 +198,6 @@ class HyperbandSearchCV(BaseIncrementalSearchCV):
                 ),
             })
         self.metadata_["brackets"] = meta_brackets
-        return self
 
     def fit(self, X, y=None, **fit_params):
         rng_seed = self.random_state
@@ -212,7 +213,7 @@ class HyperbandSearchCV(BaseIncrementalSearchCV):
 
         n_proc = _dist.process_count()
         if n_proc == 1:
-            return self._fit_interleaved(X, y, **fit_params)
+            return super().fit(X, y, **fit_params)
         from ..parallel.sharded import ShardedArray
 
         if isinstance(X, ShardedArray) or isinstance(y, ShardedArray):

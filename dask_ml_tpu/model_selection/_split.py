@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..io.native import legacy_shuffle
 from ..parallel.mesh import data_shards
 from ..parallel.sharded import ShardedArray, take_rows
 
@@ -58,18 +59,53 @@ def _shard_row_ranges(x: ShardedArray):
 
 
 def _blockwise_split_indices(x, test_size, train_size, rng, shuffle):
+    """Indices are int32 where the rows allow it, and a one-shard split
+    hands out views: the shuffle visits the same positions whatever the
+    dtype (the same rows are held out), at half the bytes — at 4,194,304
+    rows a resident search's fits took 0.43-0.49 s or 0.52-0.63 s, two of
+    every six the slow way, with the int64 buffers of one split (32 + 28 +
+    4 MiB, freed after every fit and faulted in again) and 0.43-0.47 s
+    with these (my chip runs, PR 32)."""
     train_parts, test_parts = [], []
     for lo, hi in _shard_row_ranges(x):
         m = hi - lo
         if m == 0:
             continue
         n_train, n_test = _validate_sizes(m, test_size, train_size)
-        idx = np.arange(lo, hi)
+        idx = np.arange(lo, hi,
+                        dtype=np.int32 if hi < 2 ** 31 else np.int64)
         if shuffle:
-            rng.shuffle(idx)
+            # rng.shuffle(idx), bit for bit, by a loop that prefetches
+            legacy_shuffle(rng, idx)
         test_parts.append(idx[:n_test])
         train_parts.append(idx[n_test:n_test + n_train])
-    return np.concatenate(train_parts), np.concatenate(test_parts)
+
+    def joined(parts):
+        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+
+    return joined(train_parts), joined(test_parts)
+
+
+def split_indices(first, n, test_size=None, train_size=None, rng=None,
+                  shuffle=True, blockwise=True):
+    """``(train_idx, test_idx)`` of :func:`train_test_split` for a first
+    array ``first`` of ``n`` rows — the split as host row indices, for a
+    caller that gathers the rows itself (the adaptive search over a
+    resident table: split and blocking in one gather)."""
+    rng = np.random.RandomState(None) if rng is None else rng
+    if not shuffle:
+        blockwise = False
+    if blockwise and isinstance(first, ShardedArray):
+        return _blockwise_split_indices(first, test_size, train_size, rng,
+                                        shuffle)
+    n_train, n_test = _validate_sizes(n, test_size, train_size)
+    if shuffle:
+        idx = rng.permutation(n)
+        return idx[n_test:n_test + n_train], idx[:n_test]
+    # sklearn contract: unshuffled split is train = LEADING rows,
+    # test = trailing (the chronological-holdout idiom)
+    idx = np.arange(n)
+    return idx[:n_train], idx[n_train:n_train + n_test]
 
 
 def train_test_split(*arrays, test_size=None, train_size=None,
@@ -100,22 +136,8 @@ def train_test_split(*arrays, test_size=None, train_size=None,
         if _rows(a) != n:
             raise ValueError("arrays have inconsistent lengths")
 
-    if blockwise and isinstance(first, ShardedArray):
-        train_idx, test_idx = _blockwise_split_indices(
-            first, test_size, train_size, rng, shuffle
-        )
-    else:
-        n_train, n_test = _validate_sizes(n, test_size, train_size)
-        if shuffle:
-            idx = rng.permutation(n)
-            test_idx, train_idx = idx[:n_test], idx[n_test:n_test + n_train]
-        else:
-            # sklearn contract: unshuffled split is train = LEADING rows,
-            # test = trailing (the chronological-holdout idiom)
-            idx = np.arange(n)
-            train_idx = idx[:n_train]
-            test_idx = idx[n_train:n_train + n_test]
-
+    train_idx, test_idx = split_indices(first, n, test_size, train_size,
+                                        rng, shuffle, blockwise)
     out = []
     for a in arrays:
         if isinstance(a, ShardedArray):

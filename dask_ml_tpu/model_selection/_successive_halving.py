@@ -13,7 +13,7 @@ import math
 
 import numpy as np
 
-from ._incremental import BaseIncrementalSearchCV
+from ._incremental import BaseIncrementalSearchCV, top_scores
 
 
 class SuccessiveHalvingSearchCV(BaseIncrementalSearchCV):
@@ -59,7 +59,7 @@ class SuccessiveHalvingSearchCV(BaseIncrementalSearchCV):
             return {mid: max(c, 0) for mid, c in pending.items()}
         # rung complete: cut to top 1/eta
         n_keep = max(1, math.floor(len(scores) / eta))
-        keep = sorted(scores, key=scores.get, reverse=True)[:n_keep]
+        keep = top_scores(scores, n_keep)
         self._rung += 1
         next_target = self.n_initial_iter * (eta ** self._rung)
         if self.max_iter is not None:

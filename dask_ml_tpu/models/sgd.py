@@ -24,6 +24,7 @@ cohort with the data block read from HBM once.
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 
 import jax
@@ -60,8 +61,18 @@ def _design_matvec_fwd(Xd, coef):
 
 def _design_matvec_bwd(Xd, ct):
     # the same product autodiff makes, kept in f32; the design matrix is
-    # data (no cotangent)
-    return None, jnp.matmul(ct, Xd, preferred_element_type=jnp.float32)
+    # data (no cotangent). Over a design NARROWER than f32 the product is
+    # asked for at ``highest``: one weight vector's is a multiply-and-
+    # reduce on the VPU, exact as it is, but vmapped over a cohort it is
+    # an (N, S) x (S, d) matmul on the MXU, which at the default precision
+    # rounds the f32 residuals to bf16 first — the rounding the cast's
+    # transpose made. An f32 design keeps the default, as ``Xd @ coef``
+    # does (what "f32" means on the MXU is one question for both products,
+    # and the Mosaic kernels answer it the same way: PERF.md section 7)
+    return None, jnp.matmul(
+        ct, Xd, preferred_element_type=jnp.float32,
+        precision=(None if Xd.dtype == jnp.float32
+                   else jax.lax.Precision.HIGHEST))
 
 
 _design_matvec.defvjp(_design_matvec_fwd, _design_matvec_bwd)
@@ -1181,6 +1192,72 @@ def _grid_builders(mesh, B, S, dtype=None):
             plan_tracked("sgd.grid_y", jax.jit(grid_y, out_shardings=sh2)))
 
 
+# what a cohort scan reads: the DISTINCT blocks ``Xr (B, S, d)`` in the
+# design dtype, their encoded targets ``yr (B, S)`` (f32) and each block's
+# count of valid leading rows ``NV (B,)``
+CohortGrid = collections.namedtuple("CohortGrid", "Xr yr NV")
+
+
+@_functools.lru_cache(maxsize=32)
+def _split_builders(mesh, B, S, T, dtype=None):
+    """Cached programs of an adaptive search over a resident table, per
+    (mesh, shapes), tracked as ``search.split_x`` / ``search.split_y``:
+    the train/held-out split and the blocking as ONE gather — rows
+    ``tr (B*S,)`` of the (n_pad, d) row-sharded array as the ``(B, S, d)``
+    block grid (axis 1 sharded, as ``_grid_builders`` lays it out) and
+    rows ``te (T,)`` as the held-out block, the cast to the fit dtype
+    fused in, so no float32 copy of either split is ever made."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..parallel.mesh import DATA_AXIS
+
+    def sh(*spec):
+        return NamedSharding(mesh, P(*spec))
+
+    def split_x(a, tr, te):
+        dt = dtype or a.dtype
+        return (jnp.take(a, tr, axis=0).astype(dt).reshape(B, S, a.shape[1]),
+                jnp.take(a, te, axis=0).astype(dt))
+
+    def split_y(a, tr, te):
+        return jnp.take(a, tr).reshape(B, S), jnp.take(a, te)
+
+    return (plan_tracked("search.split_x", jax.jit(
+                split_x, out_shardings=(sh(None, DATA_AXIS, None),
+                                        sh(DATA_AXIS, None)))),
+            plan_tracked("search.split_y", jax.jit(
+                split_y, out_shardings=(sh(None, DATA_AXIS),
+                                        sh(DATA_AXIS)))))
+
+
+@plan_tracked("sgd.cohort_score")
+@partial(jax.jit, static_argnames=("kind",))
+def _sgd_cohort_score(Xt, yt, n_valid, W, kind):
+    """The default score (``kind``: ``"accuracy"`` / ``"r2"``) of EVERY row
+    of the stacked weights ``W (n_slots, d+1)`` on the held-out block
+    ``Xt (T, d)`` (design dtype; the first ``n_valid`` rows count): one
+    program of one shape a search, whoever survives. The eta product keeps
+    ``_design_matvec``'s contract — coef rounded to the block's dtype for
+    this product only, f32 accumulation."""
+    mask = (jnp.arange(Xt.shape[0]) < n_valid).astype(jnp.float32)
+    n = jnp.maximum(n_valid.astype(jnp.float32), 1.0)
+    eta = jnp.matmul(
+        Xt, W[:, :-1].T.astype(Xt.dtype),
+        preferred_element_type=jnp.float32,
+        # a float32 block's product is float32 on the MXU too; a narrower
+        # block's products are exact as they are
+        precision=(jax.lax.Precision.HIGHEST if Xt.dtype == jnp.float32
+                   else None),
+    ) + W[:, -1][None, :]
+    if kind == "accuracy":
+        hit = ((eta > 0).astype(jnp.float32) == yt[:, None])
+        return jnp.sum(hit * mask[:, None], axis=0) / n
+    y_mean = jnp.sum(yt * mask) / n
+    ss_tot = jnp.sum(((yt - y_mean) * mask) ** 2)
+    ss_res = jnp.sum(((eta - yt[:, None]) * mask[:, None]) ** 2, axis=0)
+    return 1.0 - ss_res / jnp.maximum(ss_tot, 1e-12)
+
+
 @jax.jit
 def _batched_eta(X, W):
     """(n, N) decision values for N stacked models on one shared X."""
@@ -1474,20 +1551,13 @@ class _SGDBase(BaseEstimator):
         return np.asarray(out, np.float32)
 
     @classmethod
-    def _batched_fused_calls(cls, models, blocks, order=None):
-        """Advance the cohort through a sequence of block steps in ONE
-        scan program (``_sgd_cohort_scan``) — equivalent to that many
-        ``_batched_partial_fit`` calls (same updates, same per-model lr
-        clocks) minus the per-call dispatch round trips. ``blocks`` are
-        the DISTINCT blocks and ``order`` (default: each once, in
-        sequence) indexes the steps into them — a multi-epoch rung
-        revisits blocks without duplicating them on device. Blocks may
-        be ragged (the last data block is shorter): they stack padded
-        to the widest with per-block valid-row counts."""
-        if order is None:
-            order = list(range(len(blocks)))
-        S = len(order)
-        enc = models[0]
+    def _cohort_grid_of_blocks(cls, enc, blocks):
+        """A :class:`CohortGrid` stacked from a LIST of (X, y) blocks
+        (host arrays or ShardedArrays; targets encoded by ``enc``), padded
+        to the tallest with per-block valid-row counts — what a search
+        over host input hands ``_batched_fused_calls`` a round. A search
+        over a resident table builds its grid once a fit instead
+        (``search.split_x``)."""
         Xs_list, ys_list, nvs = [], [], []
         for Xb, yb in blocks:
             Xs = as_sharded(Xb, dtype=np.float32)
@@ -1496,9 +1566,6 @@ class _SGDBase(BaseEstimator):
             Xs_list.append(Xs)
             ys_list.append(ys)
             nvs.append(Xs.n_rows)
-        d = Xs_list[0].shape[1]
-        for m in models:
-            m._ensure_state(d)
         bs_max = max(x.data.shape[0] for x in Xs_list)
 
         def padded(a):
@@ -1507,43 +1574,115 @@ class _SGDBase(BaseEstimator):
                 a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
             return a
 
-        Xr = jnp.stack([padded(x.data) for x in Xs_list])
-        yr = jnp.stack([padded(y.data) for y in ys_list])
-        NV = jnp.asarray(nvs, jnp.int32)
-        LRS = jnp.asarray(np.stack(
-            [m._lr_schedule(S) for m in models], axis=1
-        ))                                   # (S, N)
+        return CohortGrid(jnp.stack([padded(x.data) for x in Xs_list]),
+                          jnp.stack([padded(y.data) for y in ys_list]),
+                          jnp.asarray(nvs, jnp.int32))
+
+    @classmethod
+    def _batched_fused_calls(cls, models, blocks, order=None, W=None):
+        """Advance the cohort through a sequence of block steps in ONE
+        scan program (``_sgd_cohort_scan``) — equivalent to that many
+        ``_batched_partial_fit`` calls (same updates, same per-model lr
+        clocks) minus the per-call dispatch round trips. ``blocks`` is a
+        :class:`CohortGrid` of the DISTINCT blocks (or a list of (X, y)
+        blocks, stacked here) and ``order`` (default: each once, in
+        sequence) indexes the steps into them — a multi-epoch rung
+        revisits blocks without duplicating them on device.
+
+        ``W (N, d+1)``: the cohort's stacked weights where the caller
+        keeps them stacked on the device (a search over a resident table);
+        ``(the advanced stack, the program's name)`` is returned and no
+        model's ``_w`` is touched. Without it the stack is built from and
+        written back to the models, which are returned."""
+        grid = blocks if isinstance(blocks, CohortGrid) \
+            else cls._cohort_grid_of_blocks(models[0], blocks)
+        if order is None:
+            order = list(range(grid.Xr.shape[0]))
+        stacked = W is not None
+        if not stacked:
+            for m in models:
+                m._ensure_state(int(grid.Xr.shape[2]))
+            W = jnp.stack([m._w for m in models])
+        W, losses, name = cls._cohort_scan(
+            models, grid, order, W, cls._cohort_operands(models, len(order)))
+        if stacked:
+            return W, name
+        for i, m in enumerate(models):
+            m._w = W[i]
+            m._last_loss = losses[i]
+        return models
+
+    @classmethod
+    def _cohort_operands(cls, models, n_steps):
+        """The per-model operands of a cohort scan of ``n_steps`` steps, on
+        the device: ``(LRS (S, N), alphas, l2 weights, l1 weights,
+        intercept flags)`` — each model's next lr clock values and its
+        penalties. Host work; nothing of the scan is dispatched."""
+        LRS = np.stack([m._lr_schedule(n_steps) for m in models], axis=1)
         args = np.asarray(
             [(m.alpha,) + m._penalty_weights()
              + (1.0 if m.fit_intercept else 0.0,) for m in models],
             np.float32,
         )
-        W = jnp.stack([m._w for m in models])
+        return (jnp.asarray(LRS),) + tuple(
+            jnp.asarray(args[:, j]) for j in range(4))
+
+    @classmethod
+    def _cohort_scan(cls, models, grid, order, W, operands):
+        """Dispatch ONE cohort scan of ``len(order)`` steps over ``grid``
+        for the stacked ``W`` and advance the models' clocks:
+        ``(W, last losses, the program's name)``, nothing waited for."""
         from ..config import mxu_dtype
         from ..ops.pallas_fused import (sgd_many_stream_tile,
                                         stream_kernel_mode)
 
         # fused cohort flavor (ISSUE 12): one VMEM pass per block step
-        # serves every model in the cohort (the last XLA-only SGD hot
-        # path) when the stacked block height fits the kernel grid —
-        # cohort weights are flat by construction (_batch_key refuses
-        # multiclass), so the kernel's (N, d+1) stack always applies
+        # serves every model in the cohort when the stacked block height
+        # fits the kernel grid — cohort weights are flat by construction
+        # (_batch_key refuses multiclass), so the kernel's (N, d+1) stack
+        # always applies. Not over a design narrower than float32: the
+        # kernel multiplies the residuals at the design's dtype (a bf16
+        # gradient product), and ``_design_matvec`` states an f32 one
+        enc = models[0]
+        bs, d = (int(v) for v in grid.Xr.shape[1:])
+        mxu = mxu_dtype(enc.fit_dtype)
         use_k, interp = stream_kernel_mode()
-        fused = bool(use_k and sgd_many_stream_tile(
-            int(bs_max), int(d), len(models)) is not None)
+        fused = bool(use_k and grid.Xr.dtype == jnp.float32
+                     and mxu is None and sgd_many_stream_tile(
+                         bs, d, len(models)) is not None)
         runner = (partial(_sgd_cohort_scan_pallas, interpret=interp)
                   if fused else _sgd_cohort_scan)
         W, losses = runner(
-            Xr, yr, NV, jnp.asarray(np.asarray(order, np.int32)), W,
-            LRS, jnp.asarray(args[:, 0]), jnp.asarray(args[:, 1]),
-            jnp.asarray(args[:, 2]), jnp.asarray(args[:, 3]),
-            enc._loss(), mxu=mxu_dtype(enc.fit_dtype),
+            grid.Xr, grid.yr, grid.NV,
+            jnp.asarray(np.asarray(order, np.int32)), W, *operands,
+            enc._loss(), mxu=mxu,
         )
-        for i, m in enumerate(models):
-            m._w = W[i]
-            m._last_loss = losses[i]
-            m._t += S
-        return models
+        for m in models:
+            m._t += len(order)
+        return W, losses, "pallas.sgd_cohort" if fused else "sgd.cohort_scan"
+
+    # -- resident-cohort protocol (consumed by model_selection.
+    # _incremental's _ResidentCohortPlane: a search over a resident table) --
+    _cohort_score_kind = "r2"
+
+    @staticmethod
+    def _cohort_split_programs(mesh, B, S, T, dtype):
+        return _split_builders(mesh, B, S, T, dtype)
+
+    @staticmethod
+    def _cohort_grid_dtype(estimator, searched=False):
+        """The grid's dtype: the fit dtype the estimator resolves to (a
+        bfloat16 design under ``dtype="auto"`` on a TPU), None for the
+        table's own. A search OVER ``fit_dtype`` keeps the table's and
+        every cohort casts for itself."""
+        from ..config import mxu_dtype
+
+        return None if searched else mxu_dtype(estimator.fit_dtype)
+
+    @classmethod
+    def _cohort_score(cls, W, Xt, yt, n_valid):
+        return _sgd_cohort_score(Xt, yt, n_valid, W,
+                                 kind=cls._cohort_score_kind)
 
     # -- streamed-cohort protocol (ISSUE 14 tentpole; consumed by
     # model_selection._incremental's _StreamCohortPlane) ----------------
@@ -2473,6 +2612,7 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
     Incremental / adaptive-search streaming paths."""
 
     loss_default = "log_loss"
+    _cohort_score_kind = "accuracy"
 
     def _batch_key(self):
         if getattr(self, "classes_", None) is None:

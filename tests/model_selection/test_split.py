@@ -99,3 +99,31 @@ def test_unshuffled_split_is_train_leading():
     Xtr, Xte = train_test_split(X, test_size=0.25, shuffle=False)
     assert Xtr[0, 0] == 0 and Xtr[-1, 0] == 74
     assert Xte[0, 0] == 75 and Xte[-1, 0] == 99
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 1000, 65537])
+def test_native_legacy_shuffle_is_numpys(n):
+    """``io.native.legacy_shuffle`` is ``RandomState.shuffle`` bit for bit:
+    the same permutation, and the same generator state afterwards (also
+    from the middle of the stream); what is not a contiguous int32 vector
+    is numpy's own call."""
+    from dask_ml_tpu.io.native import legacy_shuffle
+
+    for seed in (0, 3, 2 ** 31 - 5):
+        a, b = np.random.RandomState(seed), np.random.RandomState(seed)
+        a.rand(seed % 700)
+        b.rand(seed % 700)
+        x = np.arange(n, dtype=np.int32)
+        y = x.copy()
+        a.shuffle(x)
+        legacy_shuffle(b, y)
+        np.testing.assert_array_equal(x, y)
+        a.shuffle(x)
+        legacy_shuffle(b, y)
+        np.testing.assert_array_equal(x, y)
+        assert a.randint(0, 2 ** 31) == b.randint(0, 2 ** 31)
+        x64 = np.arange(n)
+        y64 = x64.copy()
+        a.shuffle(x64)
+        legacy_shuffle(b, y64)                 # int64: numpy's own
+        np.testing.assert_array_equal(x64, y64)

@@ -360,8 +360,13 @@ def test_adaptive_search_metrics(tmp_path):
             n_initial_parameters=3, max_iter=5, random_state=0,
         )
         search.fit(X, y, classes=[0.0, 1.0])
-    recs = [r for r in _read_steps(path)
-            if r.get("component") == "adaptive_search"]
+    logged = [r for r in _read_steps(path)
+              if r.get("component") == "adaptive_search"]
+    # one line a scored trial, and one ``search.round`` event a round
+    recs = [r for r in logged if "model_id" in r]
+    rounds = [r for r in logged if r.get("event") == "search.round"]
+    assert len(recs) + len(rounds) == len(logged)
+    assert len(rounds) == search.search_info_["n_rounds"]
     assert len(recs) == len(search.history_)
     for r in recs:
         assert "model_id" in r and "score" in r and "batch_size" in r

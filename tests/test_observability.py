@@ -851,14 +851,34 @@ def test_search_round_spans_and_trial_tags(tmp_path):
     y = (X[:, 0] > 0).astype(np.float32)
     p = str(tmp_path / "hb.jsonl")
     with config.set(metrics_path=p):
-        HyperbandSearchCV(
+        search = HyperbandSearchCV(
             SGDClassifier(random_state=0),
             {"alpha": [1e-4, 1e-3, 1e-2]},
             max_iter=4, random_state=0,
         ).fit(X, y, classes=[0.0, 1.0])
     recs = _read_jsonl(p)
-    rounds = [r for r in recs if r.get("span") == "search.round"]
-    assert rounds and all("n_trials" in r for r in rounds)
+    # a round is a RECORD, not a span: one ``search.round`` event line in
+    # the fit's logger with the fields the span had and its wall and sync
+    # time, and the same entry in ``search_info_["rounds"]``
+    assert not [r for r in recs if r.get("span") == "search.round"]
+    rounds = [r for r in recs if r.get("event") == "search.round"]
+    info = search.search_info_
+    assert len(rounds) == info["n_rounds"] == len(info["rounds"]) >= 2
+    for line, rec in zip(rounds, info["rounds"]):
+        assert line["component"] == "adaptive_search"
+        for k in ("round", "n_trials", "n_calls", "wall_s", "sync_s"):
+            assert line[k] == pytest.approx(rec[k])
+        assert rec["groups"] and sum(
+            g["model_steps"] for g in rec["groups"]) == rec["n_calls"]
+    # the search's spans: one root, four flat children, no other
+    spans = [r for r in recs if "span_id" in r and r.get("span", "")
+             .startswith("fit")]
+    root = [r for r in spans if r["span"] == "fit"]
+    assert len(root) == 1 and root[0]["component"] == "HyperbandSearchCV"
+    assert root[0]["n_iter"] == info["n_rounds"]
+    assert sorted(r["span"] for r in spans if r["parent_id"]
+                  == root[0]["span_id"]) == [
+        "fit.finish", "fit.prepare", "fit.solve", "fit.validate"]
     trials = [r for r in recs
               if r.get("component") == "adaptive_search"
               and "model_id" in r]
