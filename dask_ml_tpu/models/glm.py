@@ -730,7 +730,10 @@ class _GLMBase(BaseEstimator):
                            n_rows=X.n_rows) as logger, active_logger(logger):
             d = data.shape[1] + (form == "scalar")     # beta's length
             pmask, lam = self._penalty_setup(d, X.n_rows)
-            beta0 = jnp.asarray(self._warm_beta0(d, np))
+            # beta0, lam and pmask go to the solver as HOST values: they
+            # ride in with its program's dispatch (``jnp.asarray`` of each
+            # is an eager launch with the chip idle)
+            beta0 = self._warm_beta0(d, np)
             kwargs = dict(self.solver_kwargs or {})
             l1_ratio = kwargs.pop("l1_ratio", 0.5)
             log_steps = logger is not None
@@ -738,8 +741,8 @@ class _GLMBase(BaseEstimator):
                 self.solver,
                 X=data, y=y_data, mask=mask,
                 n_rows=X.n_rows, beta0=beta0, family=self.family,
-                reg=self.penalty, lam=jnp.asarray(lam, jnp.float32),
-                pmask=jnp.asarray(pmask), l1_ratio=l1_ratio,
+                reg=self.penalty, lam=np.float32(lam), pmask=pmask,
+                l1_ratio=l1_ratio,
                 max_iter=self.max_iter, tol=self.tol, mesh=mesh,
                 log=log_steps, intercept=form == "scalar", **kwargs,
             )
@@ -864,7 +867,7 @@ class LogisticRegression(_GLMBase):
         d = data.shape[1]
         pmask, lam = self._penalty_setup(d, X.n_rows)
         C = len(classes)
-        B0 = jnp.asarray(self._warm_B0(C, d))
+        B0 = self._warm_B0(C, d)
         kwargs = dict(self.solver_kwargs or {})
         l1_ratio = kwargs.pop("l1_ratio", 0.5)
         root.add(n_classes=C)
@@ -874,7 +877,7 @@ class LogisticRegression(_GLMBase):
             beta, info = solve_multi(
                 self.solver, X=data, Y=Y, mask=mask, n_rows=X.n_rows,
                 B0=B0, family=self.family, reg=self.penalty,
-                lam=jnp.asarray(lam, jnp.float32), pmask=jnp.asarray(pmask),
+                lam=np.float32(lam), pmask=pmask,
                 l1_ratio=l1_ratio, max_iter=self.max_iter, tol=self.tol,
                 mesh=X.mesh, **kwargs,
             )

@@ -188,7 +188,30 @@ def _host_scalars(*vals):
     own behind the solve (~1 ms each on a mesh of four). This is where
     the host waits for the whole solve: the wait is charged to the open
     span (``fit.solve``) as ``sync_s``."""
-    return np.asarray(current_span().sync(_pack_scalars(*vals)))
+    return _fetch(_pack_scalars(*vals))[0]
+
+
+def _fetch(*vals):
+    """A program's results to the host in ONE ``jax.device_get``, with no
+    launch in front of it (what the host reads is among the program's
+    own outputs). The copies are queued behind the program before the
+    host waits, and the wait is the open span's ``sync_s``."""
+    sp = current_span()
+    sp.count("fetches")
+    for v in vals:
+        v.copy_to_host_async()
+    return jax.device_get(sp.sync(vals))
+
+
+def _operand(v, dtype=np.float32):
+    """A small operand as the solver's jit takes it. A host value stays a
+    host value of ``dtype`` and rides in with the program's dispatch:
+    ``jnp.asarray`` of it is an eager launch and a transfer of its own,
+    on every device of the mesh, with the chip idle (same aval either
+    way, so the same program). A device array is handed on."""
+    if isinstance(v, jax.Array):
+        return v if v.dtype == dtype else v.astype(dtype)
+    return np.asarray(v, dtype)
 
 
 def check_finite_result(beta, info, solver):
@@ -196,6 +219,8 @@ def check_finite_result(beta, info, solver):
     ``gnorm > tol`` while_loop as "converged", silently. Every solver
     funnels its result through here; non-finite parameters raise instead
     of becoming a model."""
+    if isinstance(beta, jax.Array):
+        current_span().count("fetches")
     beta_h = np.asarray(beta)  # the one beta fetch — callers reuse it
     scalars = [v for v in info.values() if isinstance(v, (int, float))]
     if not np.isfinite(beta_h).all() or not np.all(np.isfinite(scalars)):
@@ -230,10 +255,31 @@ def _lbfgs_chunk(X, y, mask, n_rows, carry, lam, pmask, l1_ratio, stop_it,
     convergence). A full solve is one chunk with stop_it = max_iter; the
     checkpointed path runs k-iteration chunks so (beta, optimizer state)
     hits stable storage between programs (SURVEY.md §5 checkpoint row —
-    TPU slices fail whole, recovery is checkpoint-restart)."""
+    TPU slices fail whole, recovery is checkpoint-restart).
+
+    ``carry`` is ``(beta0,)`` for a fresh start — the program builds its
+    own first state (``_lbfgs_loop``) — or a whole carry to continue
+    from; the pytree's structure is part of the jit's cache key, so these
+    are two programs over one loop. Returns ``(carry, result)``:
+    ``result`` is what the host reads of a solve, ``[*beta, it, gnorm,
+    n_evals]`` as one vector, so that it leaves in one fetch (the
+    counters are exact in float32 up to 2**24)."""
     loss = _select_loss(use_pallas, X, y, mask, n_rows, lam, pmask,
                         l1_ratio, family, reg, mesh, interpret, intercept)
-    return _lbfgs_loop(loss, carry, stop_it, tol, memory, log)
+    carry = _lbfgs_loop(loss, carry, stop_it, tol, memory, log)
+    beta, _, gnorm, it, n_evals = carry
+    scalars = jnp.stack([jnp.asarray(v, beta.dtype)
+                         for v in (it, gnorm, n_evals)])
+    return carry, jnp.concatenate([beta, scalars])
+
+
+def _fresh_carry(opt, beta0):
+    """The carry an L-BFGS solve starts from: ``(beta0, the optimizer's
+    empty state, gnorm = inf, it = 0)``. Built INSIDE the solver's
+    program from a one-element carry (``_lbfgs_loop``): built on the
+    host it is a dozen eager launches (every leaf of the optax state a
+    ``zeros`` or a cast of its own) with the chip idle."""
+    return (beta0, opt.init(beta0), jnp.asarray(jnp.inf, beta0.dtype), 0)
 
 
 def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
@@ -243,6 +289,9 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
     The single-target carry is ``(beta, state, gnorm, it, n_evals)``:
     ``n_evals`` is an int32 sum of objective evaluations (one scalar add
     an iteration, always on — a static switch would make two programs).
+    A one-element carry ``(beta0,)`` is a fresh start (``_fresh_carry``),
+    a four-element one a state without its counters: both are completed
+    here, in the traced function.
 
     ``n_blocks`` switches on the stacked multi-solve semantics: the flat
     vector is ``n_blocks`` independent row blocks (classes, lam
@@ -310,6 +359,8 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
                    + state[-1].info.num_linesearch_steps.astype(jnp.int32))
         return beta, state, gnorm, it + 1, n_evals
 
+    if len(carry) == 1:
+        carry = _fresh_carry(opt, carry[0])
     if track and len(carry) == 4:
         b0 = carry[0]
         carry = (*carry, jnp.zeros(n_blocks, jnp.int32),
@@ -336,10 +387,19 @@ def _per_block_iters(conv, it_total):
     return np.minimum(c, int(it_total))
 
 
+def _stacked_solve(chunk, *args, **kwargs):
+    """One stacked L-BFGS program (``_lbfgs_loop`` with ``n_blocks``)
+    from a fresh start, and what the host reads of it in one fetch:
+    ``(beta, n_iter, grad_norm, conv)`` as host values."""
+    beta, _state, gnorm, it, conv = chunk(*args, **kwargs)
+    beta, gnorm, it, conv = _fetch(beta, gnorm, it, conv)
+    return beta, int(it), float(gnorm), conv
+
+
 @track_program("glm.lbfgs_multi_pallas")
 @partial(jax.jit, static_argnames=("family", "reg", "memory", "log",
                                    "mesh", "interpret", "n_classes"))
-def _lbfgs_multi_pallas_chunk(X, codes, mask, n_rows, carry, lam, pmask_t,
+def _lbfgs_multi_pallas_chunk(X, Y, mask, n_rows, carry, lam, pmask,
                               l1_ratio, stop_it, tol, family, reg, mesh,
                               n_classes, memory=10, log=False,
                               interpret=False):
@@ -348,10 +408,15 @@ def _lbfgs_multi_pallas_chunk(X, codes, mask, n_rows, carry, lam, pmask_t,
     reads X ONCE for all C classes (the stacked XLA path reads it twice
     — one batched forward matmul + one gradient matmul). The objective is
     separable across classes, so the joint optimum equals the per-class
-    optima; ``pmask_t`` arrives tiled to (C*d,)."""
+    optima; the (d,) ``pmask`` is tiled to (C*d,) here. ``Y`` is the
+    (C, n) one-hot target stack: the kernel takes class CODES, read off
+    it here once a solve (padding rows are all-zero -> code 0, masked
+    in-kernel)."""
     from ...ops.pallas_fused import fused_glm_multi_value_grad
 
-    d = pmask_t.shape[0] // n_classes
+    d = pmask.shape[0]
+    pmask_t = jnp.tile(pmask, n_classes)
+    codes = jnp.argmax(Y, axis=0).astype(jnp.float32)
 
     def data_vg(bflat):
         v, g = _shard_psum_call(
@@ -378,45 +443,52 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
     fit resumes mid-solve instead of from zero (VERDICT r2 #5)."""
     _check_smooth(reg, "lbfgs")
     use_pallas = _resolve_pallas(use_pallas, mesh, family, X)
-    opt = optax.lbfgs(memory_size=memory)
-    # the evaluation counter starts as a HOST scalar: a device zero would
-    # be one more eager launch on the chain the chip idles through
-    # before the solver's program starts (~1 ms each on a mesh of four)
-    carry = (beta0, opt.init(beta0), jnp.asarray(jnp.inf, beta0.dtype), 0,
-             np.zeros((), np.int32))
+    beta0 = _operand(beta0)
     run = partial(
-        _lbfgs_chunk, X, y, mask, n_rows, lam=lam, pmask=pmask,
-        l1_ratio=l1_ratio, tol=jnp.asarray(tol, beta0.dtype),
-        family=family, reg=reg, memory=memory, log=log,
-        use_pallas=use_pallas, mesh=mesh if use_pallas else None,
-        interpret=pallas_interpret, intercept=intercept,
+        _lbfgs_chunk, X, y, mask, n_rows, lam=_operand(lam),
+        pmask=_operand(pmask), l1_ratio=l1_ratio,
+        tol=_operand(tol, beta0.dtype), family=family, reg=reg,
+        memory=memory, log=log, use_pallas=use_pallas,
+        mesh=mesh if use_pallas else None, interpret=pallas_interpret,
+        intercept=intercept,
     )
+    carry = (beta0,)          # a fresh start: the program builds the rest
     resumed_from = 0
     if not (checkpoint_path and checkpoint_every):
-        beta, state, gnorm, it, n_evals = run(
-            carry=carry, stop_it=jnp.asarray(max_iter))
-        it, gnorm, n_evals = _host_scalars(it, gnorm, n_evals)
+        _, result = run(carry=carry, stop_it=np.int32(max_iter))
+        (result,) = _fetch(result)
+        beta, (it, gnorm, n_evals) = result[:-3], result[-3:]
     else:
         import os
 
         from ...utils import checkpoint as ckpt
 
+        it, gnorm = 0, np.inf
         if os.path.exists(os.path.abspath(checkpoint_path)):
-            restored = ckpt.restore_pytree(checkpoint_path, like=carry)
+            # the template of a saved carry, from its shapes alone (no
+            # state is built to be thrown away), placed on one device
+            opt = optax.lbfgs(memory_size=memory)
+            one = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
+            like = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one),
+                jax.eval_shape(
+                    lambda b: (*_fresh_carry(opt, b),
+                               jnp.zeros((), jnp.int32)), beta0))
+            restored = ckpt.restore_pytree(checkpoint_path, like=like)
             # host views: restored leaves come back committed to one
             # device; jit must be free to re-place them with X's sharding
             carry = tuple(jax.tree.map(
                 lambda a: np.asarray(a), tuple(restored)
             ))
-            resumed_from = int(carry[3])
-        while True:
-            it = int(carry[3])
-            gnorm = float(carry[2])
-            if it >= max_iter or (it > 0 and gnorm <= tol):
-                break
+            it, gnorm = int(carry[3]), float(carry[2])
+            resumed_from = it
+        while it < max_iter and not (it > 0 and gnorm <= tol):
             stop = min(it + int(checkpoint_every), max_iter)
-            carry = run(carry=carry, stop_it=jnp.asarray(stop))
+            carry, result = run(carry=carry, stop_it=np.int32(stop))
             ckpt.save_pytree(checkpoint_path, tuple(carry))
+            (result,) = _fetch(result)
+            it, gnorm = int(result[-3]), float(result[-2])
         # completed: CLEAR the checkpoint — a finished solve's state left
         # on disk would be silently "resumed" (returning the stale beta)
         # by the next fit sharing the path. The path identifies ONE fit;
@@ -424,7 +496,7 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
         import shutil
 
         shutil.rmtree(os.path.abspath(checkpoint_path), ignore_errors=True)
-        beta, state, gnorm, it, n_evals = carry
+        beta, n_evals = carry[0], carry[-1]
     # "fused": whether the Pallas kernel carried the data term — the
     # resident twin of the streamed fits' "fused_stream"; "n_evals": how
     # often the objective (one pass over X) ran, line search included
@@ -817,28 +889,18 @@ def solve_multi(solver, X, Y, mask, n_rows, B0, family, reg, lam, pmask,
         if fits_vmem:
             _check_smooth(reg, solver)
             memory = int(kwargs.get("memory", 10))
-            # class CODES from the one-hot target stack (padding rows
-            # are all-zero -> code 0, masked in-kernel)
-            codes = jnp.argmax(Y, axis=0).astype(jnp.float32)
-            pmask_t = jnp.tile(jnp.asarray(pmask), C)
-            b0 = B0.reshape(-1)
-            opt = optax.lbfgs(memory_size=memory)
-            carry = (b0, opt.init(b0),
-                     jnp.asarray(jnp.inf, b0.dtype), 0)
-            beta, _state, gnorm, it, conv = _lbfgs_multi_pallas_chunk(
-                X, codes, mask, n_rows, carry, lam, pmask_t,
-                l1_ratio, jnp.asarray(max_iter),
-                jnp.asarray(tol, b0.dtype), family, reg, mesh,
-                C, memory=memory, interpret=pallas_interpret,
+            beta, it, gnorm, conv = _stacked_solve(
+                _lbfgs_multi_pallas_chunk, X, Y, mask, n_rows,
+                (_operand(B0).reshape(-1),), _operand(lam),
+                _operand(pmask), l1_ratio, np.int32(max_iter),
+                _operand(tol), family, reg, mesh, C, memory=memory,
+                interpret=pallas_interpret,
             )
-            it, gnorm = _host_scalars(it, gnorm)
-            info = {"n_iter": int(it), "grad_norm": float(gnorm),
+            info = {"n_iter": it, "grad_norm": gnorm,
                     "n_iter_per_class":
                         _per_block_iters(conv, it).tolist(),
                     "fused_multi": True}
-            return check_finite_result(
-                np.asarray(beta).reshape(C, d), info, solver
-            )
+            return check_finite_result(beta.reshape(C, d), info, solver)
         elif use_pallas is not None:
             raise ValueError(
                 f"design too wide for the fused multi-target GLM kernel "
@@ -857,21 +919,15 @@ def solve_multi(solver, X, Y, mask, n_rows, B0, family, reg, lam, pmask,
         _check_smooth(reg, solver)
         memory = int(kwargs.pop("memory", 10))
         C, d = B0.shape
-        opt = optax.lbfgs(memory_size=memory)
-        b0 = jnp.asarray(B0, jnp.float32).reshape(-1)
-        carry = (b0, opt.init(b0), jnp.asarray(jnp.inf, b0.dtype), 0)
-        beta, _state, gnorm, it, conv = _multi_stacked_chunk(
-            X, Y, mask, n_rows, carry, lam, jnp.asarray(pmask),
-            l1_ratio, jnp.asarray(max_iter),
-            jnp.asarray(tol, jnp.float32), family, reg, C,
+        beta, it, gnorm, conv = _stacked_solve(
+            _multi_stacked_chunk, X, Y, mask, n_rows,
+            (_operand(B0).reshape(-1),), _operand(lam), _operand(pmask),
+            l1_ratio, np.int32(max_iter), _operand(tol), family, reg, C,
             memory=memory,
         )
-        it_h, gnorm_h = _host_scalars(it, gnorm)
-        info = {"n_iter": int(it_h), "grad_norm": float(gnorm_h),
-                "n_iter_per_class": _per_block_iters(conv, it_h).tolist()}
-        return check_finite_result(
-            np.asarray(beta).reshape(C, d), info, solver
-        )
+        info = {"n_iter": it, "grad_norm": gnorm,
+                "n_iter_per_class": _per_block_iters(conv, it).tolist()}
+        return check_finite_result(beta.reshape(C, d), info, solver)
     # per-class loop: forward the pallas knobs — the single-target
     # solvers honor them (an explicit use_pallas request must not be
     # silently dropped here)
@@ -1008,30 +1064,25 @@ def solve_lam_grid_multi(X, Y, mask, n_rows, lams, pmask, family, reg,
     ((k, C, d) betas, info) for k lam values over the shared (C, n)
     one-vs-rest targets."""
     _check_smooth(reg, "lbfgs")
-    lams = jnp.asarray(lams, jnp.float32)
+    lams = _operand(lams)
     k = int(lams.shape[0])
     C = int(Y.shape[0])
     d = X.shape[1]
-    opt = optax.lbfgs(memory_size=memory)
-    b0 = jnp.zeros((k * C * d,), jnp.float32)
-    carry = (b0, opt.init(b0), jnp.asarray(jnp.inf, b0.dtype), 0)
-    beta, _state, gnorm, it, conv = _lam_grid_multi_chunk(
-        X, Y, mask, n_rows, carry, lams, jnp.asarray(pmask),
-        jnp.asarray(max_iter), jnp.asarray(tol, jnp.float32),
-        family, reg, k, C, memory=memory,
+    beta, it, gnorm, conv = _stacked_solve(
+        _lam_grid_multi_chunk, X, Y, mask, n_rows,
+        (np.zeros((k * C * d,), np.float32),), lams, _operand(pmask),
+        np.int32(max_iter), _operand(tol), family, reg, k, C,
+        memory=memory,
     )
-    it_h, gnorm_h = _host_scalars(it, gnorm)
     # block j = i*C + c: a candidate's own n_iter is its slowest class
     # (the iteration count a standalone OvR fit of that candidate would
     # have reported)
-    conv_kc = _per_block_iters(conv, it_h).reshape(k, C)
-    info = {"n_iter": int(it_h), "grad_norm": float(gnorm_h),
+    conv_kc = _per_block_iters(conv, it).reshape(k, C)
+    info = {"n_iter": it, "grad_norm": gnorm,
             "lam_grid": k, "n_classes": C,
             "n_iter_per_candidate": conv_kc.max(axis=1).tolist(),
             "n_iter_per_block": conv_kc.tolist()}
-    return check_finite_result(
-        np.asarray(beta).reshape(k, C, d), info, "lbfgs"
-    )
+    return check_finite_result(beta.reshape(k, C, d), info, "lbfgs")
 
 
 def solve_lam_grid(X, y, mask, n_rows, lams, pmask, family, reg,
@@ -1051,22 +1102,14 @@ def solve_lam_grid(X, y, mask, n_rows, lams, pmask, family, reg,
     point within the joint trajectory — the last iteration its
     per-block gradient norm still exceeded tol."""
     _check_smooth(reg, "lbfgs")
-    lams = jnp.asarray(lams, jnp.float32)
+    lams = _operand(lams)
     k = int(lams.shape[0])
     d = X.shape[1]
-    opt = optax.lbfgs(memory_size=memory)
-    b0 = jnp.zeros((k * d,), jnp.float32)
-    carry = (b0, opt.init(b0), jnp.asarray(jnp.inf, b0.dtype), 0)
-    beta, _state, gnorm, it, conv = _lam_grid_chunk(
-        X, y, mask, n_rows, carry, lams, jnp.asarray(pmask),
-        jnp.asarray(max_iter), jnp.asarray(tol, jnp.float32),
-        family, reg, k, memory=memory,
+    beta, it, gnorm, conv = _stacked_solve(
+        _lam_grid_chunk, X, y, mask, n_rows,
+        (np.zeros((k * d,), np.float32),), lams, _operand(pmask),
+        np.int32(max_iter), _operand(tol), family, reg, k, memory=memory,
     )
-    it_h, gnorm_h = _host_scalars(it, gnorm)
-    info = {"n_iter": int(it_h), "grad_norm": float(gnorm_h),
-            "lam_grid": k,
-            "n_iter_per_candidate":
-                _per_block_iters(conv, it_h).tolist()}
-    return check_finite_result(
-        np.asarray(beta).reshape(k, d), info, "lbfgs"
-    )
+    info = {"n_iter": it, "grad_norm": gnorm, "lam_grid": k,
+            "n_iter_per_candidate": _per_block_iters(conv, it).tolist()}
+    return check_finite_result(beta.reshape(k, d), info, "lbfgs")

@@ -218,6 +218,9 @@ class _NoopSpan:
     def add(self, **attrs):
         return self
 
+    def count(self, name, n=1):
+        return self
+
     def sync(self, value):
         return value
 
@@ -260,6 +263,12 @@ class span:
 
     def add(self, **attrs):
         self.attrs.update(attrs)
+        return self
+
+    def count(self, name, n=1):
+        """Add ``n`` to the integer attribute ``name`` (0 where unset):
+        what code under the span did so often, e.g. ``fetches``."""
+        self.attrs[name] = self.attrs.get(name, 0) + n
         return self
 
     def sync(self, value):
