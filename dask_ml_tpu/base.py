@@ -21,6 +21,8 @@ from sklearn.base import (  # re-exported contract, verified sklearn 1.9
     clone,
 )
 
+from .observability._spans import current_span
+
 __all__ = [
     "BaseEstimator",
     "ClassifierMixin",
@@ -41,8 +43,21 @@ def log_proba(p):
         return np.log(p)
 
 
+def _read_device(x):
+    if not x.is_fully_addressable:
+        from jax.experimental import multihost_utils
+
+        return np.asarray(multihost_utils.process_allgather(x, tiled=True))
+    return np.asarray(x)
+
+
 def to_host(x):
-    """Move a fitted attribute to host numpy (fitted attrs are small).
+    """A device value as host numpy: the one door a blocking read of a
+    device value goes through (fitted attributes, a predict's result, a
+    scalar the host decides on), so that it is one FETCH of the open
+    span's handoff ledger (``observability/_spans.py``: ``fetches``,
+    ``fetch_bytes``, ``fetch_s``). A value already on the host counts
+    nothing, and with tracing off this is ``np.asarray``.
 
     Under a multi-process runtime an array on the global mesh spans
     devices this process cannot address; it is gathered to every host
@@ -50,8 +65,6 @@ def to_host(x):
     the same contract as any other collective op on the global mesh)."""
     import jax
 
-    if isinstance(x, jax.Array) and not x.is_fully_addressable:
-        from jax.experimental import multihost_utils
-
-        return np.asarray(multihost_utils.process_allgather(x, tiled=True))
+    if isinstance(x, jax.Array):
+        return current_span().fetch(_read_device, x)
     return np.asarray(x)

@@ -25,7 +25,7 @@ import time
 import numpy as np
 from sklearn.model_selection import ParameterSampler
 
-from ..base import BaseEstimator, clone
+from ..base import BaseEstimator, clone, to_host
 from ..metrics.scorer import check_scoring
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import data_fingerprint as _data_fingerprint
@@ -96,8 +96,6 @@ def host_view_estimator(est):
     pickles across the process-gather channel (and stays usable — every
     consumer re-coerces with jnp.asarray)."""
     import jax
-
-    from ..base import to_host
 
     if est is None:
         return est
@@ -482,8 +480,6 @@ class _ResidentCohortPlane:
                       else self.in_stack.intersection(mids))
         if not held:
             return
-        from ..base import to_host
-
         Wh = np.asarray(to_host(self.W), np.float32)
         d = Wh.shape[1] - 1
         for m in held:
@@ -1025,7 +1021,7 @@ def _fit(model_factory, params_list, train_blocks, X_test, y_test, scorer,
             wait(dev)
             score_s = time.perf_counter() - t0
             t0 = time.perf_counter()
-            scores = np.asarray(dev, np.float64)[trained]
+            scores = np.asarray(to_host(dev), np.float64)[trained]
             publish_s = time.perf_counter() - t0
         else:
             plane.adopt(models, trained)
@@ -1305,7 +1301,9 @@ class BaseIncrementalSearchCV(BaseEstimator):
                         fit_params, X, y, stats)
                     sums = self._search_sums(stats,
                                              time.perf_counter() - t0)
-                    sp.add(**sums)
+                    # ``dispatches`` on the span is its ledger's own count
+                    sp.add(**{k: v for k, v in sums.items()
+                              if k != "dispatches"})
                 with span("fit.finish"):
                     self._fit_finish(plane, params_list, scorer_raw, solved,
                                      stats, sums)

@@ -181,16 +181,15 @@ def link_fetch(X, beta, link, classes=None):
     """The device half of one prediction over a resident ``X``: ONE
     dispatch of ``glm.decision`` and ONE fetch of its (row-padded, 2-D)
     result. The wait lands on the open span (``predict.decision``) as
-    ``sync_s``, with the engagement record ``link="device"`` and
-    ``fetch_bytes``. ``classes`` (the estimator's ``classes_``) matters to
+    ``sync_s`` and the read in its ledger (``fetch_bytes``, ``fetch_s``),
+    with the engagement record ``link="device"``. ``classes`` (the estimator's ``classes_``) matters to
     a label link alone."""
     sp = current_span()
     words = _label_carrier(classes)[0] if link in _LABEL_LINKS else None
     out = _decision_link(X.data, beta, words, link=link,
                          quantum=_LANES * data_shards(X.mesh))
-    host = to_host(sp.sync(out))
-    sp.add(link="device", fetch_bytes=host.nbytes)
-    return host
+    sp.add(link="device")
+    return to_host(sp.sync(out))
 
 
 def link_finish(host, n_rows, link, classes=None):
@@ -247,6 +246,7 @@ def _append_intercept(Xd, mask):
     return jnp.concatenate([Xd, mask[:, None].astype(Xd.dtype)], axis=1)
 
 
+@track_program("glm.prepare")
 @_partial(jax.jit, static_argnames=("fit_intercept", "to_bf16", "encode"))
 def _prepare_fit(Xd, yd, mask, fit_intercept, to_bf16, encode):
     """ONE program for all fit prep: bf16 cast, binary label scan +
@@ -629,7 +629,7 @@ class _GLMBase(BaseEstimator):
             )
         classes = None
         if self.family == "logistic":
-            pk = np.asarray(packed)
+            pk = to_host(packed)
             if not bool(pk[2]) or pk[0] == pk[1]:
                 # >2 classes: the grid stacks k*C one-vs-rest blocks in
                 # one program (degenerate single-class keeps None — the
@@ -709,7 +709,7 @@ class _GLMBase(BaseEstimator):
             if self.family == "logistic":
                 # one small fetch: (mn, mx, binary) — where the host
                 # waits for the prep pass
-                pk = np.asarray(sp.sync(packed))
+                pk = to_host(sp.sync(packed))
                 multiclass = not bool(pk[2]) or pk[0] == pk[1]
                 if not multiclass:
                     classes = np.asarray(pk[:2])
@@ -789,7 +789,7 @@ class _GLMBase(BaseEstimator):
             eta = streamed_map(
                 X, block_rows, lambda blk: blk.arrays[0] @ coef + b0
             )
-            current_span().add(link="host", fetch_bytes=eta.nbytes)
+            current_span().add(link="host")
             return eta
         X, host = self._decision(X)
         return link_finish(host, X.n_rows, "identity")

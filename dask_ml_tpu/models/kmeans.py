@@ -1116,7 +1116,7 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
                         break  # converged inside the chunk
                 ckpt.clear()
             # the one scalar fetch: where the host waits for the loop
-            n_iter = int(sp.sync(n_iter))
+            n_iter = int(to_host(sp.sync(n_iter)))
             sp.add(n_iter=n_iter)
             if logger is not None and not log_steps:
                 logger.log(step=n_iter, center_shift2=float(shift2),
@@ -1132,15 +1132,15 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             # The first check is where the host waits for the labels
             # pass: sync on what it reads, never earlier (a sync on the
             # pass itself would hold back the check's own dispatch)
-            if not bool(sp.sync(jnp.isfinite(inertia))) or \
-                    not bool(jnp.isfinite(centers).all()):
+            if not bool(to_host(sp.sync(jnp.isfinite(inertia)))) or \
+                    not bool(to_host(jnp.isfinite(centers).all())):
                 raise FloatingPointError(
                     "KMeans produced non-finite centers/inertia: the input "
                     "contains NaN/Inf"
                 )
             self.cluster_centers_ = to_host(centers)
             self.labels_ = ShardedArray(labels, X.n_rows, X.mesh)
-            self.inertia_ = float(inertia)
+            self.inertia_ = float(to_host(inertia))
             self.n_iter_ = n_iter
             # what carried the fit: the resident twin of the GLMs'
             # solver_info_ ("fused": the Pallas Lloyd kernel ran)

@@ -2665,7 +2665,7 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
                 idx_c = jnp.clip(idx, 0, len(self.classes_) - 1)
                 ok = jnp.take(classes_d, idx_c) == y.data
                 bad = jnp.any(y.row_mask(jnp.bool_) & ~ok)
-                if bool(bad):
+                if bool(to_host(bad)):
                     raise ValueError(
                         "y contains classes not passed via `classes` on "
                         "the first partial_fit call"
@@ -2686,7 +2686,7 @@ class SGDClassifier(ClassifierMixin, _SGDBase):
         if isinstance(y, ShardedArray):
             is_pos = y.data == jnp.asarray(pos)
             known = is_pos | (y.data == jnp.asarray(neg))
-            if bool(jnp.any(y.row_mask(jnp.bool_) & ~known)):
+            if bool(to_host(jnp.any(y.row_mask(jnp.bool_) & ~known))):
                 raise ValueError(
                     "y contains classes not passed via `classes` on the "
                     "first partial_fit call"

@@ -30,6 +30,7 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
+from ...base import to_host
 from ...parallel.mesh import DATA_AXIS
 from ...observability import current_span, emit_jit_step, track_program
 from ...plans import ProgramPlan
@@ -195,12 +196,12 @@ def _fetch(*vals):
     """A program's results to the host in ONE ``jax.device_get``, with no
     launch in front of it (what the host reads is among the program's
     own outputs). The copies are queued behind the program before the
-    host waits, and the wait is the open span's ``sync_s``."""
+    host waits; the wait is the open span's ``sync_s`` and the read one
+    fetch of its ledger."""
     sp = current_span()
-    sp.count("fetches")
     for v in vals:
         v.copy_to_host_async()
-    return jax.device_get(sp.sync(vals))
+    return sp.fetch(jax.device_get, sp.sync(vals))
 
 
 def _operand(v, dtype=np.float32):
@@ -219,9 +220,7 @@ def check_finite_result(beta, info, solver):
     ``gnorm > tol`` while_loop as "converged", silently. Every solver
     funnels its result through here; non-finite parameters raise instead
     of becoming a model."""
-    if isinstance(beta, jax.Array):
-        current_span().count("fetches")
-    beta_h = np.asarray(beta)  # the one beta fetch — callers reuse it
+    beta_h = to_host(beta)  # the one beta fetch — callers reuse it
     scalars = [v for v in info.values() if isinstance(v, (int, float))]
     if not np.isfinite(beta_h).all() or not np.all(np.isfinite(scalars)):
         raise FloatingPointError(

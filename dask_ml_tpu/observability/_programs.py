@@ -33,6 +33,15 @@ is counted ONCE because XLA cannot know the trip count — those
 programs' flops_per_call, and any span MFU built on them, are honest
 LOWER bounds (one iteration's worth per call).
 
+Each tracked call is also one DISPATCH of the handoff ledger
+(``_spans.py``): it runs under a bare ``dmt.dispatch.<program>``
+annotation and charges the innermost open span with ``dispatches``,
+``dispatch_s`` (the same host wall that feeds ``exec_s``),
+``host_operands`` and ``host_operand_bytes`` — the call's array leaves
+that are host values (numpy arrays and scalars, and Python numbers the
+jit does not take as static): each is placed on the device with the
+dispatch, on every device of the mesh, while the chip waits.
+
 Gating: ``config.obs_programs`` (default OFF). Disabled, a tracked call
 is one config read and a plain passthrough — nothing enters traced
 code, the registry stays empty, and no extra compile ever runs. Enabled,
@@ -45,10 +54,15 @@ counters, which is why zero-recompile perf gates keep the knob off.
 from __future__ import annotations
 
 import functools
+import inspect
 import threading
 import time
 
+import jax
+import numpy as np
+
 from ._counters import counter_add, counters_enabled
+from ._spans import ANNOTATION_PREFIX, current_span
 
 _lock = threading.Lock()
 _programs: dict[str, dict] = {}
@@ -167,6 +181,42 @@ def _shape_key(args, kwargs):
         return None
 
 
+def _static_params(fn):
+    """(every parameter name in order, the names ``fn``'s jit takes as
+    static), from the jit's own record; nothing static where ``fn`` keeps
+    none (not a ``jax.jit``)."""
+    info = getattr(fn, "_jit_info", None)
+    if info is None:
+        return (), frozenset()
+    try:
+        params = tuple(inspect.signature(fn).parameters)
+    except (TypeError, ValueError):
+        params = ()
+    static = set(info.static_argnames or ())
+    static.update(params[i] for i in info.static_argnums or ()
+                  if -len(params) <= i < len(params))
+    return params, frozenset(static)
+
+
+_HOST_NUMBERS = (bool, int, float, complex, np.generic, np.ndarray)
+
+
+def _host_operands(args, kwargs, params, static):
+    """(count, bytes) of one call's operands that cross from the host with
+    the dispatch: the array leaves of its non-static arguments that are not
+    ``jax.Array`` — numpy arrays and scalars, Python numbers."""
+    dynamic = [a for i, a in enumerate(args)
+               if i >= len(params) or params[i] not in static]
+    dynamic += [v for k, v in kwargs.items() if k not in static]
+    count = nbytes = 0
+    for x in jax.tree_util.tree_leaves(dynamic):
+        if isinstance(x, _HOST_NUMBERS):    # a jax.Array is none of them
+            count += 1
+            nbytes += x.nbytes if hasattr(x, "nbytes") else \
+                jax.dtypes.canonicalize_dtype(type(x)).itemsize
+    return count, nbytes
+
+
 def _cost_dict(compiled):
     return compiled.cost_analysis() or {}
 
@@ -234,6 +284,8 @@ def track_program(name: str):
         # per-name map would let one variant's analysis overwrite
         # another's at the same shapes and credit the wrong kernel
         by_shape: dict = {}
+        params, static = _static_params(fn)
+        annotation = ANNOTATION_PREFIX + "dispatch." + name
 
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
@@ -245,9 +297,15 @@ def track_program(name: str):
                     before = cache_size()
                 except Exception:
                     before = None
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            dt = time.perf_counter() - t0
+            with jax.profiler.TraceAnnotation(annotation):
+                t0 = time.perf_counter()
+                out = fn(*args, **kwargs)
+                t1 = time.perf_counter()
+            dt = t1 - t0
+            sp = current_span()
+            if sp.ledger:
+                sp.dispatched(
+                    dt, t1, *_host_operands(args, kwargs, params, static))
             skey = _shape_key(args, kwargs)
             grew = False
             if before is not None:

@@ -32,6 +32,32 @@ into memory and onto the profiler's timeline:
 - while it is open it holds a ``jax.profiler.TraceAnnotation`` named
   ``dmt.<span name>``, so a ``jax.profiler`` trace shows the same
   interval on a host thread's line beside the device's ``XLA Ops``.
+
+Under ``config.obs_programs`` a span also keeps the HANDOFF LEDGER: what
+the host handed the device and took back while the span was open, each
+recorded where it happens and charged to the innermost open span —
+
+- by ``track_program``'s wrapper (``Span.dispatched``): ``dispatches``,
+  ``dispatch_s`` (the host's wall inside the jitted calls),
+  ``host_operands`` / ``host_operand_bytes`` (the calls' array leaves that
+  were host values: each is placed on the device with the dispatch);
+- by ``base.to_host`` and the solvers' ``_fetch`` (``Span.fetch``):
+  ``fetches``, ``fetch_bytes``, ``fetch_s`` (the wall of the whole
+  device-to-host read, wait and copy; where the caller synced first the
+  wait is ``sync_s`` and ``fetch_s`` the copy);
+- ``host_gap_s``, the host's own count of a starved chip. Per thread,
+  beside the span stack, "in flight" is set at the END of a tracked
+  dispatch and cleared at the RETURN of a wait (``Span.sync``,
+  ``Span.fetch``); a span's ``host_gap_s`` is the time inside it with the
+  flag clear: from a root's open, or a wait's return, to the end of the
+  next tracked dispatch, or to the span's close. It assumes that a wait
+  drains the queue and it sees no untracked launch, so it is an upper
+  bound of the chip's idle time.
+
+The seven totals are inclusive, as ``wall_s`` is: at close a span adds its
+own to its parent's, so the root record of a ``fit`` or a ``predict``
+carries the call's. Each dispatch and each fetch is also a bare
+``dmt.dispatch.<program>`` / ``dmt.fetch`` annotation (no ring record).
 """
 
 from __future__ import annotations
@@ -162,6 +188,39 @@ def current_span():
     return st[-1] if st else NOOP_SPAN
 
 
+# -- the handoff ledger -----------------------------------------------------
+# the seven inclusive totals of a span, in the order ``span._ledger`` keeps
+# them; ``host_gap_s`` is the eighth attribute, a difference of _gap_until
+LEDGER_KEYS = ("dispatches", "dispatch_s", "host_operands",
+               "host_operand_bytes", "fetches", "fetch_bytes", "fetch_s")
+
+
+def _gap_until(t):
+    """Seconds up to ``t`` (``perf_counter``) in which this thread had
+    nothing in flight. ``_tls.idle_t0`` is when the flag was last cleared,
+    None while a dispatch is in flight."""
+    idle_t0 = getattr(_tls, "idle_t0", None)
+    return getattr(_tls, "gap_s", 0.0) + (
+        0.0 if idle_t0 is None else t - idle_t0)
+
+
+def _in_flight(t):
+    """The end of a tracked dispatch: the device has work from ``t`` on."""
+    _tls.gap_s = _gap_until(t)
+    _tls.idle_t0 = None
+
+
+def _drained(t):
+    """The return of a wait: nothing is in flight from ``t`` on."""
+    if getattr(_tls, "idle_t0", None) is None:
+        _tls.idle_t0 = t
+
+
+def _nbytes(out):
+    return sum(getattr(a, "nbytes", 0)
+               for a in jax.tree_util.tree_leaves(out))
+
+
 class _FileSink:
     """Open-per-record append sink: no file descriptor outlives the
     write (a long-lived process tracing many distinct paths must not
@@ -214,6 +273,7 @@ class _NoopSpan:
     __slots__ = ()
 
     recording = False
+    ledger = False
 
     def add(self, **attrs):
         return self
@@ -223,6 +283,9 @@ class _NoopSpan:
 
     def sync(self, value):
         return value
+
+    def fetch(self, read, value):
+        return read(value)
 
 
 NOOP_SPAN = _NoopSpan()
@@ -240,7 +303,7 @@ class span:
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "root_id",
                  "sync_s", "_sink", "_t0", "_t0_ns", "_ctr0", "_tracked",
-                 "_annotation")
+                 "_annotation", "_ledger", "_gap0")
 
     def __init__(self, name, **attrs):
         self.name = name
@@ -249,6 +312,13 @@ class span:
         self._sink = None
         self._tracked = False
         self._annotation = None
+        self._ledger = None
+
+    @property
+    def ledger(self):
+        """True when this span keeps the handoff ledger (it records, and
+        ``config.obs_programs`` was on when it opened)."""
+        return self._ledger is not None
 
     @property
     def recording(self):
@@ -267,7 +337,7 @@ class span:
 
     def count(self, name, n=1):
         """Add ``n`` to the integer attribute ``name`` (0 where unset):
-        what code under the span did so often, e.g. ``fetches``."""
+        what code under the span did so often."""
         self.attrs[name] = self.attrs.get(name, 0) + n
         return self
 
@@ -279,7 +349,39 @@ class span:
 
         t0 = time.perf_counter()
         out = jax.block_until_ready(value)
-        self.sync_s += time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self.sync_s += t1 - t0
+        _drained(t1)
+        return out
+
+    def dispatched(self, dt, t_end, operands, operand_bytes):
+        """One tracked program call that ended at ``t_end`` after ``dt``
+        seconds on the host, with so many host operands: the ledger's
+        dispatch side (``track_program``'s wrapper calls it, on a span
+        that keeps a ledger)."""
+        _in_flight(t_end)
+        led = self._ledger
+        led[0] += 1
+        led[1] += dt
+        led[2] += operands
+        led[3] += operand_bytes
+
+    def fetch(self, read, value):
+        """``read(value)``, a blocking device-to-host read the program
+        makes anyway, as one fetch of the ledger: its wall is ``fetch_s``,
+        what came back ``fetch_bytes``, under a bare ``dmt.fetch``
+        annotation. Without a ledger it is ``read(value)``."""
+        led = self._ledger
+        if led is None:
+            return read(value)
+        with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + "fetch"):
+            t0 = time.perf_counter()
+            out = read(value)
+            t1 = time.perf_counter()
+        _drained(t1)
+        led[4] += 1
+        led[5] += _nbytes(out)
+        led[6] += t1 - t0
         return out
 
     def __enter__(self):
@@ -317,6 +419,15 @@ class span:
         self._t0_ns = time.time_ns()
         self._t0 = time.perf_counter()
         if armed:
+            from ..config import get_config
+
+            if get_config().obs_programs:
+                self._ledger = [0, 0.0, 0, 0, 0, 0, 0.0]
+                if self.parent_id is None:
+                    # a root's open: whatever an earlier call left in
+                    # flight, its caller has waited for
+                    _tls.idle_t0 = self._t0
+                self._gap0 = _gap_until(self._t0)
             # the same interval on the profiler's clock: opened last and
             # closed first, so it lies inside both
             self._annotation = jax.profiler.TraceAnnotation(
@@ -330,7 +441,8 @@ class span:
         armed = self._annotation is not None
         if armed:
             self._annotation.__exit__(exc_type, exc, tb)
-        wall = time.perf_counter() - self._t0
+        t_end = time.perf_counter()
+        wall = t_end - self._t0
         t_end_ns = time.time_ns()
         st = _stack()
         # pop down to (and including) OUR frame: frames above ours are
@@ -372,6 +484,16 @@ class span:
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         rec.update(self.attrs)
+        led = self._ledger
+        if led is not None:
+            # after the caller's attributes: the ledger's names are its own
+            parent = st[-1]._ledger if st else None
+            if parent is not None:
+                for i, v in enumerate(led):
+                    parent[i] += v
+            rec.update(zip(LEDGER_KEYS, (
+                round(v, 6) if isinstance(v, float) else v for v in led)))
+            rec["host_gap_s"] = round(_gap_until(t_end) - self._gap0, 6)
         if self._ctr0 is not None:
             now = counters_snapshot()
             for k, v in now.items():

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..base import to_host
+from ..observability import track_program
 from .mesh import (
     DATA_AXIS, MODEL_AXIS, data_shards, logical_axis_spec, resolve_mesh,
 )
@@ -187,7 +189,7 @@ class ShardedArray:
             # cross-process array would raise
             rep = _replicator(self.mesh)(self.data)
             return np.asarray(rep)[: self.n_rows]
-        return np.asarray(self.data)[: self.n_rows]
+        return to_host(self.data)[: self.n_rows]
 
     def astype(self, dtype) -> "ShardedArray":
         return ShardedArray(self.data.astype(dtype), self.n_rows, self.mesh)
@@ -228,6 +230,7 @@ class ShardedArray:
 
 
 
+@track_program("sharded.row_mask")
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
 def _row_mask(n_padded: int, n_rows: int, sharding, dtype) -> jax.Array:
     idx = jnp.arange(n_padded)

@@ -1090,7 +1090,8 @@ class Incremental(ParallelPostFit):
             an estimator that has neither).
 
         One dict rebuilt per pass; its keys are also the root span's
-        attributes."""
+        attributes (``dispatches`` there is the span ledger's own count:
+        the same number, kept where the dispatch happens)."""
         from .observability import programs_enabled, programs_snapshot
 
         def program_calls():
@@ -1111,7 +1112,7 @@ class Incremental(ParallelPostFit):
         info["fit_dtype"] = getattr(est, "fit_dtype_", None)
         info["t_end"] = None if t1 is None else int(t1)
         self.pass_info_ = info
-        root.add(**info)
+        root.add(**{k: v for k, v in info.items() if k != "dispatches"})
         return est
 
     def _partial_fit_pass(self, est, X, y, block_size, rng, info,
