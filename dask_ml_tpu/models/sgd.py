@@ -25,6 +25,7 @@ cohort with the data block read from HBM once.
 from __future__ import annotations
 
 import collections
+import weakref
 from functools import partial
 
 import jax
@@ -1192,6 +1193,66 @@ def _grid_builders(mesh, B, S, dtype=None):
             plan_tracked("sgd.grid_y", jax.jit(grid_y, out_shardings=sh2)))
 
 
+def _epoch_grid_key(X, fit_dtype):
+    """What, beside the source array itself, decides an epoch grid of the
+    ShardedArray ``X``: ``(mesh, B, S, grid dtype)``."""
+    from ..config import mxu_dtype
+
+    return (X.mesh, *fused_blocks(X), mxu_dtype(fit_dtype))
+
+
+class _KeptGrid:
+    """ONE kept epoch grid: the X half ``Xr`` of ``_grid_builders``' grid, a
+    WEAK reference to the device array it was built from and its
+    ``_epoch_grid_key``. A jax array is immutable, so the same source
+    OBJECT is the same rows; the reference is held (never a bare ``id()``,
+    which is reused once the source is freed) and its callback drops the
+    grid with the source. ``Incremental`` owns one across its passes. It
+    pickles, deep-copies and clones as an empty holder. A kept grid is a
+    CACHE: ``drop_others`` empties every other holder of the process, so
+    that a wrapper whose headroom gate refuses takes back what older fitted
+    wrappers still hold before it falls to the block loop."""
+
+    __slots__ = ("Xr", "key", "_src", "__weakref__")
+    _live = weakref.WeakSet()    # the holders that keep a grid now
+
+    def __init__(self):
+        self.clear()
+
+    def clear(self):
+        self.Xr = self.key = self._src = None
+        self._live.discard(self)
+
+    @classmethod
+    def drop_others(cls, mine):
+        """Empty every holder but ``mine``; how many kept a grid."""
+        others = [h for h in list(cls._live) if h is not mine]
+        for holder in others:
+            holder.clear()
+        return len(others)
+
+    def get(self, src, key):
+        """The kept grid of exactly this source and key, else None."""
+        if self._src is not None and self._src() is src and self.key == key:
+            return self.Xr
+        return None
+
+    def keep(self, src, key, Xr):
+        me = weakref.ref(self)   # the callback must not keep the holder
+
+        def source_died(ref):
+            holder = me()
+            if holder is not None and holder._src is ref:
+                holder.clear()
+
+        self.Xr, self.key = Xr, key
+        self._src = weakref.ref(src, source_died)
+        self._live.add(self)
+
+    def __reduce__(self):
+        return (type(self), ())
+
+
 # what a cohort scan reads: the DISTINCT blocks ``Xr (B, S, d)`` in the
 # design dtype, their encoded targets ``yr (B, S)`` (f32) and each block's
 # count of valid leading rows ``NV (B,)``
@@ -1388,7 +1449,8 @@ class _SGDBase(BaseEstimator):
             sp.add(n_rows=X.n_rows, t_end=int(self._t))
         return self
 
-    def _fused_epoch(self, X, y, order, n_blocks=None, classes=None):
+    def _fused_epoch(self, X, y, order, n_blocks=None, classes=None,
+                     kept=None):
         """One full streaming epoch in ONE program (the Incremental
         wrapper's fast path for device data): the dataset is padded and
         reshaped once into its (B, S, d) contiguous block grid (axis 1
@@ -1397,16 +1459,24 @@ class _SGDBase(BaseEstimator):
         the blocks in ``order``. Semantically identical to ``order``
         partial_fit calls over the same contiguous blocks (same update,
         same lr clock, same masking), minus one dispatch round trip per
-        block. NOTE the grid is a second device copy of the dataset for
-        the epoch's duration — the wrapper falls back to the block loop
-        when HBM headroom is insufficient.
+        block. NOTE the grid is a second device copy of the dataset. Called
+        alone it lives for the epoch's duration. Given ``kept`` (a
+        ``_KeptGrid``; the wrapper hands its own) the X half outlives the
+        pass: a pass over the SAME device array under the same
+        ``_epoch_grid_key`` reads the kept grid and dispatches no
+        ``sgd.grid_x``; any other pass drops the kept grid BEFORE it builds
+        and keeps its own, so there are never two. The wrapper asks the HBM
+        headroom gate before a pass that has to build, and falls back to
+        the block loop when headroom is insufficient.
 
         Three spans, children of the caller's root (``Incremental.fit`` /
         ``partial_fit``): ``pass.validate`` (classes, ``as_sharded``, the
         label encoding with its one-scalar fetch), ``pass.grid`` (the
-        DISPATCH of ``sgd.grid_x`` / ``sgd.grid_y``: it does not wait for
-        them, so the grid's device time lands in ``pass.solve``, which
-        waits for everything) and ``pass.solve`` (``sgd.fused_epoch``
+        DISPATCH of ``sgd.grid_x`` — on a miss — and ``sgd.grid_y``: it
+        does not wait for them, so the grid's device time lands in
+        ``pass.solve``, which waits for everything; ``grid_hit`` and
+        ``grid_bytes``, the bytes of the grid the pass READS, are on it)
+        and ``pass.solve`` (``sgd.fused_epoch``
         through the weights on the host). ``solver_info_`` records what
         ran."""
         with span("pass.validate"):
@@ -1421,9 +1491,9 @@ class _SGDBase(BaseEstimator):
             X = as_sharded(X, dtype=np.float32)
             y_enc = as_sharded(self._encode_y(y), mesh=X.mesh,
                                dtype=np.float32)
-            mesh = X.mesh
             d = X.data.shape[1]
-            B, S = fused_blocks(X)
+            key = _epoch_grid_key(X, self.fit_dtype)
+            _, B, S, _ = key
             if n_blocks is not None and n_blocks != B:
                 # ``order`` indexes the caller's block partition; a
                 # mismatched one would silently train wrong minibatches
@@ -1439,19 +1509,26 @@ class _SGDBase(BaseEstimator):
                 )
             self._ensure_state(d)
             self._lr()  # validate the schedule name eagerly, like the loop
-        from ..config import mxu_dtype
-
         with span("pass.grid") as sp:
             # bf16 epoch grid: halves the grid's HBM (it's a second copy
             # of X) and the scan's matvecs ride the MXU at bf16 rate with
             # f32 accumulation; weights/targets/updates stay f32. Weight
             # parity vs f32 ~1e-2 relative (input rounding on the design
             # matrix)
-            fX, fy = _grid_builders(mesh, B, S, mxu_dtype(self.fit_dtype))
-            Xr = fX(X.data)
+            fX, fy = _grid_builders(*key)
+            if kept is None:
+                kept = _KeptGrid()   # called alone: gone with this call
+            Xr = kept.get(X.data, key)
+            grid_hit = Xr is not None
+            if not grid_hit:
+                kept.clear()         # never two grids alive
+                Xr = fX(X.data)
+                kept.keep(X.data, key, Xr)
+            # y may be new labels over the same X, and is a new encoded
+            # array every pass anyway
             yr = fy(y_enc.data)
             grid_bytes = int(Xr.nbytes) + int(yr.nbytes)
-            sp.add(grid_bytes=grid_bytes)
+            sp.add(grid_bytes=grid_bytes, grid_hit=grid_hit)
         with span("pass.solve") as sp:
             l2w, l1w = self._penalty_weights()
             W, _t = _sgd_epoch(
@@ -1471,6 +1548,7 @@ class _SGDBase(BaseEstimator):
             "path": "fused_epoch", "program": "sgd.fused_epoch",
             "blocks": int(B), "block_rows": int(S),
             "steps": int(len(order)), "grid_bytes": grid_bytes,
+            "grid_hit": grid_hit,
         }
         return self
 

@@ -1048,7 +1048,24 @@ def compiled_batch_fn(estimator, method="predict", device=None,
 
 class Incremental(ParallelPostFit):
     """Ref: dask_ml/wrappers.py::Incremental +
-    dask_ml/_partial.py::fit."""
+    dask_ml/_partial.py::fit.
+
+    What a fitted wrapper keeps on the device: on the fused path (a device
+    SGD estimator over a resident ``ShardedArray``) the X half of the epoch
+    grid — the (B, S, d) block grid of X in the fit dtype, half of X's own
+    bytes in bfloat16 — outlives the pass that built it, and the next
+    ``fit`` / ``partial_fit`` handed the SAME device array (the object, not
+    equal contents: a jax array is immutable) at the same mesh, blocking
+    and fit dtype reads it instead of casting and re-laying X again
+    (``pass_info_["grid_hit"]``). At most one grid a wrapper, and it goes
+    when a pass arrives with another array or key (before the new grid is
+    built), when the array it was built from is freed, or with the wrapper;
+    it is never pickled, deep-copied or cloned. Until then those bytes are
+    in use, and the headroom gate of ANOTHER wrapper's fused path sees
+    them: where it would refuse, the grids that other wrappers keep are
+    dropped first (they are caches; those wrappers' next pass rebuilds) and
+    the gate is asked again, and only then is the block loop taken. The
+    other three paths keep nothing."""
 
     def __init__(self, estimator=None, scoring=None, shuffle_blocks=True,
                  random_state=None, assume_equal_chunks=True,
@@ -1080,11 +1097,18 @@ class Incremental(ParallelPostFit):
             tracked-program calls in the pass (the registry's delta;
             None unless ``config.obs_programs`` is on);
         ``grid_bytes``
-            device bytes of the epoch grid (0 off the fused path);
+            device bytes of the epoch grid the pass read (0 off the fused
+            path);
+        ``grid_hit``
+            whether that grid's X half was the one kept from an earlier
+            pass over the same device array (then no ``sgd.grid_x`` ran);
         ``headroom``
             what the fused path's gate read (``needed`` / ``free`` bytes a
             device, ``fits``; ``free`` None where the backend reports no
-            memory stats; None where the gate was not asked);
+            memory stats; ``grids_dropped``, only where the gate refused,
+            other wrappers' kept grids were dropped and this is its second
+            reading; None where the gate was not asked: off the fused path,
+            and on a hit, which allocates nothing);
         ``fit_dtype``, ``t_end``
             the resolved fit dtype and the clock after the pass (None for
             an estimator that has neither).
@@ -1101,7 +1125,7 @@ class Incremental(ParallelPostFit):
         t0 = getattr(est, "_t", None)
         rng = np.random.RandomState(self.random_state)
         info = {"path": None, "blocks": 0, "grid_bytes": 0,
-                "headroom": None}
+                "grid_hit": False, "headroom": None}
         est = self._partial_fit_pass(est, X, y, self._block_size(X), rng,
                                      info, **fit_kwargs)
         t1 = getattr(est, "_t", None)
@@ -1127,7 +1151,7 @@ class Incremental(ParallelPostFit):
             # dataset never round-trips through host (VERDICT r2 #4 —
             # the reference's partial_fit chain runs on worker-resident
             # chunks the same way, SURVEY §3.6)
-            from .models.sgd import fused_blocks
+            from .models.sgd import _KeptGrid, _epoch_grid_key, fused_blocks
             from .parallel.sharded import take_rows
 
             ys = y if isinstance(y, ShardedArray) or y is None \
@@ -1145,17 +1169,36 @@ class Incremental(ParallelPostFit):
                 # fused-epoch fast path: the whole pass compiles into ONE
                 # scan program (same updates/order/lr clock as the block
                 # loop) — per-block dispatch round trips vanish. The
-                # grid is a second device copy of X for the epoch, hence
-                # the headroom gate (the loop gathers one block at a
-                # time and stays the fallback near HBM capacity).
-                info["headroom"] = _device_headroom(X.data.nbytes, X)
-                if info["headroom"]["fits"]:
+                # grid is a second device copy of X, kept from pass to
+                # pass while X is the same device array. A pass that has
+                # to BUILD one first drops the kept one, then asks the
+                # headroom gate (the loop gathers one block at a time,
+                # keeps nothing, and stays the fallback near HBM
+                # capacity); a hit allocates nothing and asks nothing.
+                # Kept grids are caches: where the gate refuses, those of
+                # OTHER wrappers go (their next pass rebuilds) and the
+                # gate is asked once more, before the loop is taken.
+                kept = getattr(self, "_epoch_grid", None)
+                if kept is None:
+                    kept = self._epoch_grid = _KeptGrid()
+                hit = kept.get(
+                    X.data, _epoch_grid_key(X, est.fit_dtype)) is not None
+                if not hit:
+                    kept.clear()
+                    info["headroom"] = _device_headroom(X.data.nbytes, X)
+                    if not info["headroom"]["fits"]:
+                        dropped = _KeptGrid.drop_others(kept)
+                        if dropped:
+                            info["headroom"] = {
+                                **_device_headroom(X.data.nbytes, X),
+                                "grids_dropped": dropped}
+                if hit or info["headroom"]["fits"]:
                     est._fused_epoch(
                         X, ys, order, n_blocks=B,
-                        classes=fit_kwargs.get("classes"),
+                        classes=fit_kwargs.get("classes"), kept=kept,
                     )
-                    info["path"] = est.solver_info_["path"]
-                    info["grid_bytes"] = est.solver_info_["grid_bytes"]
+                    info.update({k: est.solver_info_[k] for k in
+                                 ("path", "grid_bytes", "grid_hit")})
                     return est
             from .observability.live import publish_progress
 

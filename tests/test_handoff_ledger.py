@@ -151,7 +151,11 @@ def test_a_predict_is_one_dispatch_and_one_fetch_of_the_result_s_bytes():
     assert root["fetch_s"] <= root["wall_s"]
 
 
-def test_an_incremental_pass_counts_what_its_record_counts():
+@pytest.mark.parametrize("hit", [False, True], ids=["miss", "hit"])
+def test_an_incremental_pass_counts_what_its_record_counts(hit):
+    """A pass that builds the X half of its grid is three dispatches; one
+    that reads the grid kept from the pass before (the same device array)
+    makes two: no ``sgd.grid_x``."""
     from dask_ml_tpu.linear_model import SGDClassifier
     from dask_ml_tpu.parallel import as_sharded
     from dask_ml_tpu.wrappers import Incremental
@@ -161,6 +165,8 @@ def test_an_incremental_pass_counts_what_its_record_counts():
         Xs, ys = as_sharded(X), as_sharded(y.astype(np.float32))
         inc = Incremental(SGDClassifier(), random_state=3)
         inc.fit(Xs, ys, classes=[0, 1])
+        if not hit:
+            inc._epoch_grid.clear()
         obs.reset_recent_spans()
         before = _program_calls()
         inc.partial_fit(Xs, ys)
@@ -168,10 +174,13 @@ def test_an_incremental_pass_counts_what_its_record_counts():
         ring = obs.recent_spans()
     ((root, kids),) = _calls(ring, "partial_fit")
     assert inc.pass_info_["path"] == "fused_epoch"
+    assert inc.pass_info_["grid_hit"] is kids["pass.grid"]["grid_hit"] is hit
+    assert ran == {"sgd.grid_y": 1, "sgd.fused_epoch": 1,
+                   **({} if hit else {"sgd.grid_x": 1})}
     # the registry's delta, the pass record and the span's own ledger agree
-    assert root["dispatches"] == inc.pass_info_["dispatches"] == 3 \
-        == sum(ran.values())
-    assert kids["pass.grid"]["dispatches"] == 2
+    assert root["dispatches"] == inc.pass_info_["dispatches"] \
+        == (2 if hit else 3) == sum(ran.values())
+    assert kids["pass.grid"]["dispatches"] == (1 if hit else 2)
     assert kids["pass.solve"]["dispatches"] == 1
     # the label check's one scalar, the weights
     assert root["fetches"] == 2

@@ -91,7 +91,8 @@ def test_bfloat16_grid_matches_the_reference_at_the_stated_precision(chips):
     S = family.block_rows(4099, 8, chips)
     assert inc.estimator_.solver_info_ == {
         "path": "fused_epoch", "program": "sgd.fused_epoch", "blocks": 8,
-        "block_rows": S, "steps": 8, "grid_bytes": 8 * S * (D * 2 + 4)}
+        "block_rows": S, "steps": 8, "grid_bytes": 8 * S * (D * 2 + 4),
+        "grid_hit": True}
     assert stated <= TIGHT < f32 <= T.f32_band(family.block_rows(4099, 8, chips))
 
 
@@ -136,12 +137,19 @@ def test_the_gate_refusing_takes_the_block_loop(monkeypatch):
     X, y = _data(4099)
     with use_mesh(device_mesh(devices=jax.devices()[:1])):
         Xs, ys = as_sharded(X), as_sharded(y)
+        first = Incremental(SGDClassifier(loss="log_loss")).fit(
+            Xs, ys, classes=[0, 1])
         fused = _five_passes(Xs, ys)
         _refuse(monkeypatch)
         loop = _five_passes(Xs, ys)
-    assert fused.pass_info_["path"] == "fused_epoch"
-    assert fused.pass_info_["headroom"] == {
+    assert fused.pass_info_["path"] == first.pass_info_["path"] \
+        == "fused_epoch"
+    # the gate is asked by a pass that BUILDS its grid; the fifth pass over
+    # the same X reads the kept one and asks nothing
+    assert first.pass_info_["headroom"] == {
         "needed": Xs.data.nbytes, "free": None, "fits": True}
+    assert fused.pass_info_["headroom"] is None
+    assert fused.pass_info_["grid_hit"] and not first.pass_info_["grid_hit"]
     assert loop.pass_info_["path"] == "block_loop"
     assert loop.pass_info_["headroom"] == {
         "needed": Xs.data.nbytes, "free": 4096, "fits": False}
@@ -219,13 +227,17 @@ def test_spans_one_root_a_call_and_the_pass_record_on_it():
     obs.reset_recent_spans()
     assert [root["span"] for root, _ in calls] == [
         "fit", "partial_fit", "partial_fit", "predict"]
-    for (root, kids), info, delta in zip(calls, infos, deltas):
+    # the X half of the grid is built by the first pass and kept: the later
+    # passes dispatch no ``sgd.grid_x`` (tests/test_incremental_kept_grid.py)
+    for (root, kids), info, delta, hit in zip(calls, infos, deltas,
+                                              (False, True, True)):
         assert set(kids) == {"pass.validate", "pass.grid", "pass.solve"}
         assert root["component"] == "Incremental"
         assert root["estimator"] == "SGDClassifier"
         # the record, on the estimator and on the span
         assert {k: root[k] for k in info} == info
-        assert info["dispatches"] == delta == 3
+        assert info["dispatches"] == delta == (2 if hit else 3)
+        assert kids["pass.grid"]["grid_hit"] is info["grid_hit"] is hit
         assert kids["pass.grid"]["grid_bytes"] == info["grid_bytes"] > 0
         assert kids["pass.solve"]["t_end"] == info["t_end"]
         starts = [kids[k]["t_start_ns"] for k in
