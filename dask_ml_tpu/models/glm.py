@@ -7,8 +7,8 @@ solvers, with ``fit_intercept`` via an appended ones column and predict as
 blocked matvec. Same surface here; the solvers are the device-resident jax
 programs in ``solvers/solvers.py``. The intercept is the last entry of beta
 everywhere; whether it also costs X a column is the solver's affair
-(``_GLMBase._intercept_form``: lbfgs / gradient_descent / proximal_grad add
-it to eta as a scalar and leave X as wide as its features).
+(``_GLMBase._intercept_form``: lbfgs / gradient_descent / proximal_grad /
+admm add it to eta as a scalar and leave X as wide as its features).
 
 Regularization scaling: the objective is ``mean-NLL + lam * r(coef)`` with
 ``lam = 1 / (C * n_samples)`` and the intercept unpenalized, matching
@@ -254,7 +254,7 @@ def _prepare_fit(Xd, yd, mask, fit_intercept, to_bf16, encode):
     (cast, scan, eq, mul) that materializes an X-sized temporary per op.
 
     ``fit_intercept`` appends the ones COLUMN, and is passed true only
-    for a path whose mathematics index it (Newton, ADMM, the C grid; see
+    for a path whose mathematics index it (Newton, the C grid; see
     ``_GLMBase._intercept_form``). It is not free: on a TPU a 257-wide
     bf16 design is laid out column-major, so the column costs a
     transpose and a pad of X here and a transpose back in front of a
@@ -443,9 +443,10 @@ class _GLMBase(BaseEstimator):
         """Where a resident fit keeps the intercept, recorded as
         ``solver_info_["intercept"]``: ``"scalar"`` — the last entry of
         beta, added to eta by the loss, X as wide as the features (every
-        solver that touches X through ``_select_loss`` alone);
-        ``"column"`` — a ones column appended to X (Newton and ADMM,
-        whose Hessians index it; the ``stacked`` one-vs-rest and C-grid
+        solver that touches X through ``_select_loss`` alone, and ADMM,
+        whose local Newton step borders its Hessian itself);
+        ``"column"`` — a ones column appended to X (Newton, whose
+        Hessian indexes it; the ``stacked`` one-vs-rest and C-grid
         programs); ``"none"``."""
         if not self.fit_intercept:
             return "none"
@@ -676,9 +677,11 @@ class _GLMBase(BaseEstimator):
                 raise ValueError(f"Unknown penalty {self.penalty!r}")
             # bf16 design matrix: the _smooth_loss matvec rides the MXU
             # at bf16 rate with f32 accumulation; solver state / y / mask
-            # stay f32. Newton/ADMM are excluded — their Hessian matmuls
-            # would silently upcast (no speedup) and bf16 Hessians risk
-            # conditioning
+            # stay f32. Newton/ADMM are excluded: Newton's Hessian matmuls
+            # would silently upcast (no speedup), and ADMM's local step
+            # states f32 for eta and the gradient (its Gram alone runs at
+            # the MXU's default, ``solvers._newton_stats``) — an f32 X is
+            # read where it lies, with no copy at all
             from ..config import mxu_dtype
 
             use_bf16 = mxu_dtype(self.fit_dtype) is not None \
@@ -747,8 +750,10 @@ class _GLMBase(BaseEstimator):
                 log=log_steps, intercept=form == "scalar", **kwargs,
             )
             info["intercept"] = form
-            sp.add(**{k: info[k] for k in ("n_iter", "n_evals", "fused",
-                                           "intercept") if k in info})
+            sp.add(**{k: info[k] for k in (
+                "n_iter", "n_evals", "fused", "intercept", "local_steps",
+                "primal_residual", "dual_residual", "rho", "nnz",
+                "local_step") if k in info})
             if logger is not None and not log_steps:
                 logger.log(step=info.get("n_iter"), summary=True,
                            **{k: v for k, v in info.items()

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.scipy.linalg import cho_solve
 from jax.sharding import PartitionSpec as P
 
 from ...base import to_host
@@ -731,87 +732,268 @@ def newton(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
 # reference pays a gather-to-client + broadcast over TCP.
 # --------------------------------------------------------------------------
 
+# rows of X one step of the blocked statistics touches at a time: the
+# weighted copy ``x * w`` the Gram product reads is this large (32 MiB of
+# f32 at any width), never X-sized
+_NEWTON_BLOCK_BYTES = 32 * 1024 * 1024
+
+# residual balancing (Boyd et al. 3.4.1): rho doubles where the primal
+# residual exceeds ten times the dual one, halves in the opposite case
+ADMM_BALANCE_RATIO = 10.0
+ADMM_BALANCE_FACTOR = 2.0
+
+
+def _newton_block_rows(n, d, itemsize=4):
+    rows = max(_NEWTON_BLOCK_BYTES // (itemsize * max(d, 1)), 1024)
+    return n if n <= rows else rows - rows % 1024
+
+
+def _newton_stats(Xs, ys, ms, b, family, intercept):
+    """A local Newton step's ONE touch of the data, blocked over rows:
+    ``(X^T r, X^T W X)`` as SUMS over this shard's rows at ``b``, for the
+    ``(d + 1,)`` unknowns ``[coef, intercept]`` when ``intercept`` (the
+    intercept a scalar added to eta; its gradient entry Σ r and the
+    Hessian's border ``X^T w`` / Σ w accumulated apart and joined once,
+    after the loop: X keeps its width and no ones column exists), else
+    for X's own columns. eta, the residual products and the border run at
+    ``HIGHEST`` (f32 on the MXU, or the VPU's exact multiplies); the Gram
+    ``X^T W X`` is a curvature estimate and runs at the backend's default
+    (one bf16 pass on a TPU): its precision changes the path of the
+    iterates, not their fixed point. Each block is a dynamic slice of X,
+    so the only temporary is one block's weighted copy."""
+    fam = get_family(family)
+    n, d = Xs.shape
+    hi = jax.lax.Precision.HIGHEST
+    coef = b[:-1] if intercept else b
+    rows = _newton_block_rows(n, d, Xs.dtype.itemsize)
+
+    def block(Xb, yb, mb):
+        eta = jnp.dot(Xb, coef, precision=hi)
+        if intercept:
+            eta = eta + b[-1]
+        r = (fam.mean(eta) - yb) * mb
+        w = fam.hess_weight(eta, yb) * mb
+        # the Gram of ONE matrix, rows scaled by sqrt(w): whatever the
+        # MXU rounds, the sum stays symmetric positive semi-definite
+        xw = Xb * jnp.sqrt(w)[:, None]
+        return (jnp.dot(r, Xb, precision=hi), jnp.sum(r),
+                jnp.dot(xw.T, xw),
+                jnp.dot(w, Xb, precision=hi), jnp.sum(w))
+
+    def add(acc, new):
+        return tuple(a + v for a, v in zip(acc, new))
+
+    if rows == n:
+        acc = block(Xs, ys, ms)
+    else:
+        def body(i, acc):
+            cut = [jax.lax.dynamic_slice_in_dim(a, i * rows, rows)
+                   for a in (Xs, ys, ms)]
+            return add(acc, block(*cut))
+
+        zero = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(block, Xs[:rows], ys[:rows], ms[:rows]))
+        acc = jax.lax.fori_loop(0, n // rows, body, zero)
+        if n % rows:
+            lo = n - n % rows
+            acc = add(acc, block(Xs[lo:], ys[lo:], ms[lo:]))
+    g, gb, H, hb, hbb = acc
+    if not intercept:
+        return g, H
+    return (jnp.concatenate([g, gb[None]]),
+            jnp.block([[H, hb[:, None]], [hb[None, :], hbb[None, None]]]))
+
+
+def _label_sums(Xs, ys, ms, intercept):
+    """``[X^T y, Σ y]`` over this shard's valid rows (``X^T y`` alone
+    without ``intercept``), at ``HIGHEST``: the part of a Newton step's
+    gradient that no step changes, taken once a solve for the kernel
+    path, whose one pass over X reads no labels."""
+    ym = ys * ms
+    xty = jnp.dot(ym, Xs.astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST)
+    return jnp.concatenate([xty, jnp.sum(ym)[None]]) if intercept else xty
+
+
+def _newton_stats_pallas(Xs, ms, b, xty, family, intercept, interpret):
+    """``_newton_stats`` from ONE pass of the fused kernel
+    (``ops/pallas_fused.fused_glm_newton_stats``): X streams through VMEM
+    once a step, where the blocked XLA form reads every block for eta, for
+    the residual products and for the Gram. ``xty``: ``_label_sums``."""
+    from ...ops.pallas_fused import fused_glm_newton_stats
+
+    nv = jnp.sum(ms.astype(jnp.int32))      # padding is trailing per shard
+    s1, sp, H, hb, hbb = fused_glm_newton_stats(
+        Xs, nv, b[:-1] if intercept else b, b[-1] if intercept else 0.0,
+        family=family, interpret=interpret)
+    if not intercept:
+        return s1 - xty, H
+    return (jnp.concatenate([s1, sp[None]]) - xty,
+            jnp.block([[H, hb[:, None]], [hb[None, :], hbb[None, None]]]))
+
+
+def _resolve_admm_pallas(use_pallas, mesh, family, X):
+    """Auto gate of the fused Newton-statistics kernel for ADMM's local
+    step: where ``_resolve_pallas`` would pick a kernel, and a shard's rows
+    are whole row tiles of the kernel's VMEM budget (no padded copy of X is
+    ever made for it)."""
+    if use_pallas is not None:
+        return bool(use_pallas)
+    from ...ops.pallas_fused import glm_newton_tile
+
+    if not _resolve_pallas(None, mesh, family, X):
+        return False
+    n_local = X.shape[0] // mesh.shape[DATA_AXIS]
+    tile = glm_newton_tile(n_local, X.shape[1], X.dtype.itemsize)
+    return tile is not None and n_local % tile == 0
+
+
 @track_program("glm.admm")
 @partial(jax.jit, static_argnames=("family", "reg", "local_iter", "mesh",
-                                   "log"))
-def _admm_run(X, y, mask, n_rows, B, U, z, lam, pmask, l1_ratio, rho,
-              max_iter, abstol, family, reg, local_iter, mesh, log=False):
-    fam = get_family(family)
+                                   "log", "intercept", "use_pallas",
+                                   "interpret"))
+def _admm_run(X, y, mask, n_rows, beta0, lam, pmask, l1_ratio, rho,
+              max_iter, abstol, family, reg, local_iter, mesh,
+              log=False, intercept=False, use_pallas=False,
+              interpret=False):
+    """The whole consensus solve as one program. Returns ONE f32 vector
+    ``[*z, n_iter, local_steps, primal, dual, rho]``: what the host reads
+    of a solve is one fetch of it (``admm``)."""
     n_shards = mesh.shape[DATA_AXIS]
+    d1 = beta0.shape[0]
+    eye = jnp.eye(d1, dtype=beta0.dtype)
 
-    def shard_iter(Xs, ys, ms, b, u, z, rho):
+    def shard_iter(Xs, ys, ms, xty, b, u, z, rho):
         b, u = b[0], u[0]
         v = z - u  # local target
 
-        def local_newton(_, b):
-            eta = Xs @ b
-            resid = (jax.grad(lambda e: jnp.sum(fam.pointwise(e, ys) * ms))(eta))
-            g = Xs.T @ resid / n_rows + rho * (b - v)
-            w = fam.hess_weight(eta, ys) * ms
-            h = (Xs * w[:, None]).T @ Xs / n_rows + rho * jnp.eye(b.shape[0], dtype=b.dtype)
-            return b - jnp.linalg.solve(h, g)
+        # local solve of  f_i(b) + rho/2 ||b - v||^2  by Newton steps:
+        # at most ``local_iter``, ended after the step whose Newton
+        # decrement g.H^-1.g fell to ``abstol**2`` (quadratic
+        # convergence: the step after it would move b by far less, and
+        # the local solve is exact to well under the outer stop)
+        def newton_cond(c):
+            _, k, dec = c
+            return (k < local_iter) & (dec > abstol * abstol)
 
-        b = jax.lax.fori_loop(0, local_iter, local_newton, b)
+        def newton_step(c):
+            b, k, _ = c
+            if use_pallas:
+                gs, hs = _newton_stats_pallas(Xs, ms, b, xty[0], family,
+                                              intercept, interpret)
+            else:
+                gs, hs = _newton_stats(Xs, ys, ms, b, family, intercept)
+            g = gs / n_rows + rho * (b - v)
+            # rho > 0 makes h positive definite: a Cholesky solve (LU's
+            # pivot bookkeeping is a loop of d + 1 tiny steps on its own)
+            h = hs / n_rows + rho * eye
+            delta = cho_solve((jnp.linalg.cholesky(h), True), g)
+            return b - delta, k + 1, jnp.sum(g * delta)
+
+        b, steps, _ = jax.lax.while_loop(
+            newton_cond, newton_step,
+            (b, jnp.zeros((), jnp.int32),
+             jnp.asarray(jnp.inf, beta0.dtype)))
         bu_mean = jax.lax.pmean(b + u, DATA_AXIS)
         z_new = regularizers.prox(reg, bu_mean, lam, 1.0 / (rho * n_shards),
                                   pmask, l1_ratio)
         u = u + b - z_new
         primal = jax.lax.psum(jnp.sum((b - z_new) ** 2), DATA_AXIS)
-        return b[None], u[None], z_new, primal
+        # the slowest block's count: what the outer iteration waited for
+        steps = jax.lax.pmax(steps, DATA_AXIS)
+        return b[None], u[None], z_new, primal, steps
 
     shard_iter_sm = jax.shard_map(
         shard_iter,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS),
-                  P(DATA_AXIS, None), P(DATA_AXIS, None), P(), P()),
-        out_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None), P(), P()),
+                  P(DATA_AXIS, None), P(DATA_AXIS, None), P(DATA_AXIS, None),
+                  P(), P()),
+        out_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None), P(), P(), P()),
         check_vma=False,
     )
+    # the label sums of the kernel path, once a solve (one row a shard; a
+    # row of nothing where XLA's blocked statistics read the labels)
+    xty = jax.shard_map(
+        lambda Xs, ys, ms: _label_sums(Xs, ys, ms, intercept)[None],
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS)),
+        out_specs=P(DATA_AXIS, None), check_vma=False,
+    )(X, y, mask) if use_pallas else jnp.zeros((n_shards, 0), beta0.dtype)
 
     def cond(carry):
-        B, U, z, rho, it, primal, dual = carry
+        B, U, z, rho, it, steps, primal, dual = carry
         return (it < max_iter) & ((primal > abstol) | (dual > abstol))
 
     def body(carry):
-        B, U, z, rho, it, _, _ = carry
-        B, U, z_new, primal2 = shard_iter_sm(X, y, mask, B, U, z, rho)
-        dual = rho * jnp.sqrt(jnp.asarray(n_shards, z.dtype)) * jnp.linalg.norm(z_new - z)
+        B, U, z, rho, it, steps, _, _ = carry
+        B, U, z_new, primal2, k = shard_iter_sm(X, y, mask, xty, B, U, z,
+                                                rho)
+        dual = rho * jnp.sqrt(jnp.asarray(n_shards, z.dtype)) \
+            * jnp.linalg.norm(z_new - z)
         primal = jnp.sqrt(primal2)
-        # Boyd §3.4.1 residual balancing; U is the scaled dual, rescale on
-        # rho changes
+        # residual balancing; U is the scaled dual, rescaled on rho changes
         if log:
             emit_jit_step(it, primal_residual=primal, dual_residual=dual)
-        grow = primal > 10.0 * dual
-        shrink = dual > 10.0 * primal
-        scale = jnp.where(grow, 2.0, jnp.where(shrink, 0.5, 1.0)).astype(z.dtype)
-        return B, U / scale, z_new, rho * scale, it + 1, primal, dual
+        grow = primal > ADMM_BALANCE_RATIO * dual
+        shrink = dual > ADMM_BALANCE_RATIO * primal
+        scale = jnp.where(
+            grow, ADMM_BALANCE_FACTOR,
+            jnp.where(shrink, 1.0 / ADMM_BALANCE_FACTOR, 1.0)).astype(z.dtype)
+        return (B, U / scale, z_new, rho * scale, it + 1, steps + k, primal,
+                dual)
 
-    inf = jnp.asarray(jnp.inf, z.dtype)
-    B, U, z, rho, it, primal, dual = jax.lax.while_loop(
-        cond, body, (B, U, z, rho, 0, inf, inf)
+    inf = jnp.asarray(jnp.inf, beta0.dtype)
+    zero = jnp.zeros((), jnp.int32)
+    B = jnp.broadcast_to(beta0[None], (n_shards, d1))
+    _, _, z, rho, it, steps, primal, dual = jax.lax.while_loop(
+        cond, body, (B, jnp.zeros_like(B), beta0, rho, zero, zero, inf, inf)
     )
-    return z, it, primal, dual
+    tail = jnp.stack([it.astype(z.dtype), steps.astype(z.dtype), primal,
+                      dual, rho])
+    return jnp.concatenate([z, tail])
 
 
 def admm(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
-         max_iter=250, tol=1e-4, rho=1.0, local_iter=8, mesh=None, log=False,
-         **_):
+         max_iter=250, tol=1e-4, rho=1.0, local_iter=8, mesh=None,
+         log=False, intercept=False, use_pallas=None,
+         pallas_interpret=False, **_):
+    """Consensus ADMM over the mesh's row shards, one block a shard.
+
+    Departures from ``dask_glm.algorithms.admm``, each deliberate: the
+    local solves are Newton steps (upstream: scipy's L-BFGS-B to its own
+    ``pgtol``) — at most ``local_iter`` an outer iteration, ended early
+    once the Newton decrement is under ``tol**2`` (the local solve is
+    then exact to well under the outer stop); ``rho`` is rebalanced
+    (Boyd 3.4.1: x2 where primal > 10 dual, /2 where dual > 10 primal,
+    the scaled dual rescaled with it); the stop is ``primal <= tol and
+    dual <= tol`` with no relative term; the intercept is never
+    penalised. ``intercept``: the last entry of beta
+    is a scalar added to eta and X carries no ones column. A local step's
+    touch of the data is ``solver_info_["local_step"]``: the fused kernel
+    (``pallas_newton_stats``: one read of X a step) where
+    ``_resolve_admm_pallas`` picks it, XLA's blocked loop
+    (``xla_blocked``) elsewhere."""
     if reg == "none":
-        reg = "l2"
-        lam = jnp.asarray(0.0, beta0.dtype)
-    n_shards = mesh.shape[DATA_AXIS]
-    d = beta0.shape[0]
-    B = jnp.tile(beta0[None], (n_shards, 1))
-    U = jnp.zeros((n_shards, d), beta0.dtype)
-    z, it, primal, dual = _admm_run(
-        X, y, mask, n_rows, B, U, beta0, lam, pmask, l1_ratio,
-        jnp.asarray(rho, beta0.dtype), jnp.asarray(max_iter),
-        jnp.asarray(tol, beta0.dtype), family, reg, local_iter, mesh,
-        log=log,
+        reg, lam = "l2", 0.0
+    beta0 = _operand(beta0)
+    use_pallas = _resolve_admm_pallas(use_pallas, mesh, family, X)
+    result = _admm_run(
+        X, y, mask, n_rows, beta0, _operand(lam), _operand(pmask), l1_ratio,
+        _operand(rho), np.int32(max_iter), _operand(tol), family, reg,
+        int(local_iter), mesh, log=log, intercept=intercept,
+        use_pallas=use_pallas, interpret=pallas_interpret,
     )
-    it, primal, dual = _host_scalars(it, primal, dual)
-    return z, {"n_iter": int(it), "primal_residual": float(primal),
-               "dual_residual": float(dual)}
+    (result,) = _fetch(result)
+    z, (it, steps, primal, dual, rho_end) = result[:-5], result[-5:]
+    return z, {"n_iter": int(it), "local_steps": int(steps),
+               "primal_residual": float(primal),
+               "dual_residual": float(dual), "rho": float(rho_end),
+               "nnz": int(np.count_nonzero(z[:-1] if intercept else z)),
+               "local_step": ("pallas_newton_stats" if use_pallas
+                              else "xla_blocked"),
+               "fused": bool(use_pallas)}
 
 
 SOLVERS = {
@@ -824,9 +1006,12 @@ SOLVERS = {
 
 # solvers whose only touch of X is the loss from ``_select_loss``: they
 # take ``intercept=True`` (the last entry of beta, X without a ones
-# column). Newton's Hessian (``X.T W X``, the fused vgh kernel) and
-# ADMM's per-shard Newton index the intercept as a COLUMN of X.
-SCALAR_INTERCEPT_SOLVERS = ("lbfgs", "gradient_descent", "proximal_grad")
+# column) — and ADMM, whose local Newton step accumulates the intercept's
+# gradient entry and the Hessian's border apart (``_newton_stats``).
+# Newton's Hessian (``X.T W X``, the fused vgh kernel) indexes the
+# intercept as a COLUMN of X.
+SCALAR_INTERCEPT_SOLVERS = ("lbfgs", "gradient_descent", "proximal_grad",
+                            "admm")
 
 
 def solve(solver: str, **kwargs):

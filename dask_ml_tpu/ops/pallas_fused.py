@@ -39,7 +39,12 @@ real v5e chip):
   class codes, the ``*_stream`` and SGD kernels) still carry the column
   contract (``_row_dot`` / ``_tile_mask`` / ``_glm_eta_terms``): no
   benchmark cell runs them, so a conversion could not be measured; the row
-  helpers are written for them to move onto (ROADMAP S4);
+  helpers are written for them to move onto (ROADMAP S4).
+  ``fused_glm_newton_stats`` (PR 36, ADMM's local step, the cell
+  ``logreg_admm_l1``) is column form WITHOUT a label operand: what it sums
+  needs eta alone (Σ mean(eta) x, the Gram of ``x sqrt(w)``, the intercept's
+  border), the labels' part of the gradient is the caller's, once a solve —
+  so no ``(n, 1)`` array exists for it either;
 - the intercept is a SCALAR OPERAND, never a column of X: a ``(1, 1)`` f32
   block read as a scalar and added to eta, its gradient a ``(1, 1)``
   accumulator of its own. A 257th column costs a 256-wide bf16 design its
@@ -500,6 +505,86 @@ def fused_glm_value_grad_hess(x, n_valid, y, beta, family,
         interpret=interpret,
     )(x, y[:, None], nv, beta[None, :])
     return loss[0, 0], grad[0], hess
+
+
+def _glm_newton_stats_kernel(x_ref, nv_ref, b_ref, b0_ref, sp_ref, s1_ref,
+                             hess_ref, hb_ref, hbb_ref, *, tile, family):
+    """A local Newton step's statistics in ONE X pass, with NO label
+    operand: Σ mean(eta) x and Σ mean(eta) (the residual's sums less the
+    label's, ``X^T y`` and Σ y, which do not change from step to step and
+    are taken once a solve by the caller), the Gram Σ (x sqrt w)^T (x sqrt
+    w), and the intercept's border Σ w x, Σ w. COLUMN form: eta is the
+    VPU's exact f32 multiply-and-reduce (``_row_dot``), the per-row weights
+    scale x's rows as ``(tile, 1)`` columns, and the two vector sums are
+    VPU sublane reductions — f32 throughout. The Gram alone goes to the
+    MXU, in bf16 (one pass): a curvature estimate, and the Gram of ONE
+    matrix, so whatever the rounding it stays symmetric positive
+    semi-definite."""
+    i = pl.program_id(0)
+    x = x_ref[:].astype(jnp.float32)   # (tile, d)
+    from ..models.solvers.families import get_family
+
+    fam = get_family(family)
+    m = _tile_mask(x, nv_ref, i, tile)                  # (tile, 1)
+    eta = _row_dot(x, b_ref[:]) + b0_ref[0, 0]
+    mu = fam.mean(eta) * m
+    w = fam.hess_weight(eta, 0.0) * m
+
+    @pl.when(i == 0)
+    def _init():
+        for o in (sp_ref, s1_ref, hess_ref, hb_ref, hbb_ref):
+            o[:] = jnp.zeros_like(o)
+
+    s1_ref[:] += jnp.sum(x * mu, axis=0, keepdims=True)
+    sp_ref[:] += jnp.sum(mu, axis=0, keepdims=True)
+    hb_ref[:] += jnp.sum(x * w, axis=0, keepdims=True)
+    hbb_ref[:] += jnp.sum(w, axis=0, keepdims=True)
+    xw = (x * jnp.sqrt(w)).astype(jnp.bfloat16)
+    hess_ref[:] += jax.lax.dot_general(
+        xw, xw, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                   # (d, d)
+
+
+@functools.partial(jax.jit, static_argnames=("family", "interpret"))
+def fused_glm_newton_stats(x, n_valid, beta, intercept, family,
+                           interpret=False):
+    """(Σ mean(eta) x (d,), Σ mean(eta), Σ XᵀWX (d, d), Σ w x (d,), Σ w) of
+    one (per-device) block of rows in ONE pass over X, ``eta = x @ beta +
+    intercept`` — the statistics of a Newton step with the intercept a
+    scalar beside an ``(n, d)`` X (ADMM's local step; ``intercept`` is 0.0
+    for a model without one, whose caller drops the border). The gradient's
+    data term is ``Σ mean(eta) x - Xᵀy``: the label sums are the caller's,
+    once a solve. Row validity is the prefix count ``n_valid``. ``n`` must
+    be whole row tiles (``glm_newton_tile``): a padded copy of X is exactly
+    what this kernel exists to avoid."""
+    n, d = x.shape
+    tile = glm_newton_tile(n, d, x.dtype.itemsize)
+    if tile is None or n % tile:
+        raise ValueError(
+            f"the fused Newton-statistics kernel needs whole row tiles that "
+            f"fit its VMEM budget (n={n}, d={d}, tile={tile}); use the "
+            "blocked XLA statistics"
+        )
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    row = pl.BlockSpec((1, d), lambda i: (0, 0))
+    one = jax.ShapeDtypeStruct((1, 1), jnp.float32)
+    vec = jax.ShapeDtypeStruct((1, d), jnp.float32)
+    sp, s1, hess, hb, hbb = pl.pallas_call(
+        functools.partial(_glm_newton_stats_kernel, tile=tile,
+                          family=family),
+        grid=(n // tile,),
+        in_specs=[pl.BlockSpec((tile, d), lambda i: (i, 0)), scalar, row,
+                  scalar],
+        out_specs=[scalar, row, pl.BlockSpec((d, d), lambda i: (0, 0)), row,
+                   scalar],
+        out_shape=[one, vec, jax.ShapeDtypeStruct((d, d), jnp.float32), vec,
+                   one],
+        interpret=interpret,
+    )(x, jnp.asarray(n_valid, jnp.int32).reshape(1, 1),
+      beta.astype(jnp.float32)[None, :],
+      jnp.asarray(intercept, jnp.float32).reshape(1, 1))
+    return s1[0], sp[0, 0], hess, hb[0], hbb[0, 0]
 
 
 def _glm_multi_value_grad_kernel(x_ref, yc_ref, nv_ref, b_ref, loss_ref,

@@ -237,7 +237,7 @@ def test_scalar_intercept_matches_column(solver, family):
 
 @pytest.mark.parametrize("solver,form", [
     ("lbfgs", "scalar"), ("gradient_descent", "scalar"),
-    ("proximal_grad", "scalar"), ("newton", "column"), ("admm", "column")])
+    ("proximal_grad", "scalar"), ("newton", "column"), ("admm", "scalar")])
 @pytest.mark.parametrize("fit_intercept", [True, False])
 def test_solver_info_names_the_intercept_form(solver, form, fit_intercept):
     Est, X, y = _family_data("logistic", n=400, d=5)
