@@ -33,7 +33,6 @@ import numpy as np
 
 from ..base import BaseEstimator, ClusterMixin, TransformerMixin, to_host
 from ..ops.pairwise import euclidean_distances, euclidean_distances_sq
-from ..ops.reductions import masked_mean_var
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_array, check_is_fitted
 
@@ -141,6 +140,33 @@ def _labels_inertia(X, mask, centers):
     labels = jnp.argmin(d2, axis=1)
     inertia = jnp.sum(jnp.min(d2, axis=1) * mask)
     return labels, inertia
+
+
+@track_program("kmeans.tol_scale")
+@jax.jit
+def _tol_scale(x, mask, n_rows, tol):
+    """``tol`` times the mean per-feature population variance of x, in two
+    fused passes over x: plain f32 sums on the vector units (a
+    ``tensordot`` with the mask would round x to bf16 on a TPU's MXU), no
+    X-sized temporary. Two passes, not ``E[x^2] - E[x]^2``: in f32 that
+    loses the variance of a feature whose mean is large beside its
+    spread."""
+    m = mask[:, None]
+    mean = jnp.sum(x * m, axis=0) / n_rows
+    xc = (x - mean) * m
+    return tol * jnp.mean(jnp.sum(xc * xc, axis=0) / n_rows)
+
+
+def _lloyd_tol2(X: ShardedArray, mask, tol):
+    """(the Lloyd loop's stopping threshold on the squared centre shift,
+    the passes over X it took). scikit-learn's rule
+    (``sklearn.cluster._kmeans._tolerance``): ``tol`` scaled by the mean
+    per-feature variance, and at ``tol == 0`` an exact zero for which X is
+    not read."""
+    if tol == 0:
+        return jnp.asarray(0.0, X.dtype), 0
+    return _tol_scale(X.data, mask, np.float32(X.n_rows),
+                      np.float32(tol)), 2
 
 
 @jax.jit
@@ -807,7 +833,14 @@ def k_means(X, n_clusters, init="k-means||", max_iter=300, tol=1e-4,
 
 
 class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
-    """Ref: dask_ml/cluster/k_means.py::KMeans."""
+    """Ref: dask_ml/cluster/k_means.py::KMeans.
+
+    ``tol``: the Lloyd loop ends once the squared shift of the centres
+    falls to ``tol`` times the mean per-feature variance of X, as
+    scikit-learn scales it (a resident fit takes that variance in one
+    program of two passes over X, ``solver_info_["tol_scale_passes"]``).
+    ``tol=0`` computes no scale and reads X for none: the loop runs to
+    ``max_iter`` or to an exact fixed point."""
 
     def __init__(self, n_clusters=8, init="k-means||", oversampling_factor=2,
                  max_iter=300, tol=1e-4, precompute_distances="auto",
@@ -1065,12 +1098,11 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         root.add(n_rows=X.n_rows)
         with span("fit.init"):
             centers0 = self._init_centers(X)
-        with span("fit.tol_scale"):
-            # sklearn-style tol scaling: tol * mean per-feature variance
-            # (eager ops: dispatch only, the device works on into
-            # fit.solve)
-            _, var = masked_mean_var(X.data, mask, X.n_rows)
-            tol2 = jnp.asarray(self.tol, X.dtype) * jnp.mean(var)
+        with span("fit.tol_scale") as sp:
+            # dispatch only (none at tol == 0): the device works on into
+            # fit.solve
+            tol2, tol_passes = _lloyd_tol2(X, mask, self.tol)
+            sp.add(passes=tol_passes)
         from ..observability import active_logger, fit_logger
 
         with span("fit.solve", fused=bool(use_pallas)) as sp, \
@@ -1145,7 +1177,8 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             # what carried the fit: the resident twin of the GLMs'
             # solver_info_ ("fused": the Pallas Lloyd kernel ran)
             self.solver_info_ = {"n_iter": n_iter, "fused": bool(use_pallas),
-                                 "fit_dtype": self.fit_dtype_}
+                                 "fit_dtype": self.fit_dtype_,
+                                 "tol_scale_passes": tol_passes}
             self.n_features_in_ = X.shape[1]
             return self
 

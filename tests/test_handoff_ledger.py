@@ -240,18 +240,29 @@ def test_a_pca_fit_makes_five_fetches():
     assert root["dispatches"] == sum(ran.values())
 
 
-def test_a_kmeans_fit_counts_its_fetches_and_its_programs():
+@pytest.mark.parametrize("tol, scale_calls, host_operands",
+                         [(0.0, 0, 0), (1e-4, 1, 2)])
+def test_a_kmeans_fit_counts_its_fetches_and_its_programs(
+        tol, scale_calls, host_operands):
     from dask_ml_tpu.cluster import KMeans
 
     X = np.random.RandomState(0).randn(2048, 8).astype(np.float32)
     init = X[:4].copy()
     with config.set(obs_programs=True):
         before = _program_calls()
-        KMeans(n_clusters=4, init=init, max_iter=5, tol=0.0).fit(X)
+        KMeans(n_clusters=4, init=init, max_iter=5, tol=tol).fit(X)
         ran = _delta(before)
         ring = obs.recent_spans()
     ((root, kids),) = _calls(ring, "fit")
     assert root["dispatches"] == sum(ran.values())
+    # tol == 0: no scale is computed, X is not read for one; else ONE
+    # program, and n_rows and tol, four bytes each, ride in with its
+    # dispatch. Nothing else of a fit comes from the host
+    assert ran.get("kmeans.tol_scale", 0) == scale_calls
+    assert kids["fit.tol_scale"]["dispatches"] == scale_calls
+    assert kids["fit.tol_scale"]["host_operands"] == host_operands
+    assert root["host_operands"] == host_operands
+    assert root["host_operand_bytes"] == 4 * host_operands
     # n_iter; the two finite checks, the centres, the inertia
     assert kids["fit.solve"]["fetches"] == 1
     assert kids["fit.finish"]["fetches"] == 4

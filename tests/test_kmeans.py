@@ -165,5 +165,6 @@ def test_kmeans_records_what_carried_the_fit(blobs, use_pallas):
     km = KMeans(n_clusters=4, init=X.to_numpy()[:4].copy(), max_iter=7,
                 tol=0.0, use_pallas=use_pallas).fit(X)
     assert km.solver_info_ == {"n_iter": 7, "fused": use_pallas,
-                               "fit_dtype": "float32"}
+                               "fit_dtype": "float32",
+                               "tol_scale_passes": 0}
     assert km.n_iter_ == 7 and km.fit_dtype_ == "float32"
