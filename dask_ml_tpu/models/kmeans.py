@@ -39,8 +39,29 @@ from ..utils.validation import check_array, check_is_fitted
 
 # -- jitted kernels ---------------------------------------------------------
 
-from ..observability import emit_jit_step, span, track_program
+from ..observability import current_span, emit_jit_step, span, track_program
 from ..plans import tracked as plan_tracked
+
+
+class _AmbientPhase:
+    """Stands in for a phase's span where a resident fit runs INSIDE another
+    estimator's fit (``KMeans._fit_inner``): it opens nothing and keeps no
+    attribute; a wait is charged to the span the caller has open."""
+
+    def __init__(self, name=None, **attrs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def add(self, **attrs):
+        return self
+
+    def sync(self, value):
+        return current_span().sync(value)
 
 
 @track_program("kmeans.lloyd")
@@ -681,13 +702,42 @@ def _streamed_lloyd(stream, centers0, max_iter, tol2, logger=None,
     return centers, n_iter
 
 
+def _reduce_candidates(points, weights, n_clusters, random_state):
+    """k-means‖'s last step on the host: the ≤ (1 + l·rounds) weighted
+    candidates to ``n_clusters`` centres — scikit-learn's weighted k-means++
+    seeding, then weighted Lloyd iterations in numpy until no candidate
+    changes its centre (a centre that loses all its candidates stays).
+    Plain numpy on purpose: on a hundred points ``sklearn.cluster.KMeans``'s
+    OpenMP Lloyd loop and ``threadpoolctl``'s scan of the loaded libraries
+    cost the host 15 ms a call with the chip idle, and ten times that where
+    the host's cores are shared (PERF.md section 6); this is ~2 ms."""
+    from sklearn.cluster import kmeans_plusplus
+
+    points = np.asarray(points, np.float64)
+    weights = np.asarray(weights, np.float64)
+    centers, _ = kmeans_plusplus(points, n_clusters, sample_weight=weights,
+                                 random_state=random_state)
+    centers = centers.astype(np.float64)
+    labels = None
+    for _ in range(300):
+        d2 = ((points[:, None, :] - centers[None, :, :]) ** 2).sum(axis=-1)
+        new = d2.argmin(axis=1)
+        if labels is not None and np.array_equal(new, labels):
+            break
+        labels = new
+        for j in range(n_clusters):
+            mine = labels == j
+            if mine.any():
+                centers[j] = np.average(points[mine], axis=0,
+                                        weights=weights[mine])
+    return centers
+
+
 def init_scalable_streamed(stream, n_clusters, random_state, max_iter=None,
                            oversampling_factor=2):
     """k-means‖ over streamed blocks: the same fixed-budget Gumbel top-l
     rounds as ``init_scalable``, with each round's cost/sampling pass
     running block-by-block and merging exactly (see _block_weighted_topl)."""
-    from sklearn.cluster import KMeans as SkKMeans
-
     l = max(int(oversampling_factor * n_clusters), 1)
     key = jax.random.PRNGKey(0 if random_state is None else int(random_state))
     key, k0 = jax.random.split(key)
@@ -733,19 +783,15 @@ def init_scalable_streamed(stream, n_clusters, random_state, max_iter=None,
     # sampling above already pins PRNGKey(0) in that case, and under
     # multi-host every process must reduce the (identical) candidate set
     # to the IDENTICAL centers — an unseeded draw would diverge them
-    local = SkKMeans(
-        n_clusters=n_clusters, init="k-means++", n_init=1,
-        random_state=0 if random_state is None else int(random_state),
-    ).fit(cands_h, sample_weight=w_h)
-    return jnp.asarray(local.cluster_centers_, cands.dtype)
+    return jnp.asarray(_reduce_candidates(
+        cands_h, w_h, n_clusters,
+        0 if random_state is None else int(random_state)), cands.dtype)
 
 
 def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
                   oversampling_factor=2):
     """k-means‖ candidate harvesting; ref
     dask_ml/cluster/k_means.py::init_scalable."""
-    from sklearn.cluster import KMeans as SkKMeans
-
     data, mask = X.data, X.row_mask(X.dtype)
     n, d = X.shape
     n_pad = data.shape[0]
@@ -770,10 +816,12 @@ def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
 
     for r in range(rounds):
         dmin, phi = _cost_to_candidates(data, mask, cands, cand_valid)
-        if float(phi) <= 0.0:
-            break
+        # the draw is queued before the host asks for phi, so the chip sorts
+        # while the host waits (a round that ends the loop drops its draw)
         key, kr = jax.random.split(key)
         idx = _gumbel_top_l(dmin, kr, l)
+        if float(to_host(phi)) <= 0.0:
+            break
         rows = jnp.take(data, idx, axis=0)
         start = 1 + r * l
         cands = jax.lax.dynamic_update_slice(cands, rows, (start, 0))
@@ -787,11 +835,9 @@ def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
     w_h = to_host(weights)[valid_h]
     pts = cands_h[valid_h]
     w_h = np.where(w_h > 0, w_h, 1e-6)
-    local = SkKMeans(
-        n_clusters=n_clusters, init="k-means++", n_init=1,
-        random_state=None if random_state is None else int(random_state),
-    ).fit(pts, sample_weight=w_h)
-    return jnp.asarray(local.cluster_centers_, data.dtype)
+    return jnp.asarray(_reduce_candidates(
+        pts, w_h, n_clusters,
+        None if random_state is None else int(random_state)), data.dtype)
 
 
 def init_pp(X: ShardedArray, n_clusters, random_state):
@@ -1057,9 +1103,19 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         # are the phases, each ending where its host code ends
         with span("fit", component="KMeans",
                   n_clusters=self.n_clusters) as root:
-            return self._fit_resident(X, root)
+            return self._fit_resident(X, root, span)
 
-    def _fit_resident(self, X, root):
+    def _fit_inner(self, X):
+        """The resident fit of a row-sharded ``X`` for an estimator that
+        runs KMeans inside its OWN fit (``SpectralClustering``'s restarts):
+        the same phases as :meth:`fit` with no span opened, so the caller's
+        fit stays one root with its own flat children; every wait and fetch
+        lands on the span the caller has open."""
+        return self._fit_resident(X, _AmbientPhase(), _AmbientPhase)
+
+    def _fit_resident(self, X, root, span):
+        """``span``: what opens a phase — the tracer's ``span`` under
+        :meth:`fit`'s root, ``_AmbientPhase`` under :meth:`_fit_inner`."""
         from ..config import fit_dtype_info, mxu_dtype as _mxu_dtype
 
         with span("fit.validate"):

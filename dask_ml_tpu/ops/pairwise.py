@@ -13,6 +13,7 @@ padded row-sharded data — callers mask invalid rows on the results.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -75,9 +76,34 @@ def linear_kernel(x, y):
     return x @ y.T
 
 
-def rbf_kernel(x, y, gamma=None):
+def recentred_distances_sq(x, y, precision=jax.lax.Precision.HIGHEST):
+    """Squared euclidean distances (n, m) to the FEW rows ``y``, exact in
+    float32 where the expansion is not: with features of order one and 256
+    of them ``||x||^2`` is ~256 beside a squared distance of ~2, so the
+    expansion subtracts two large numbers, and a TPU's default cross term
+    (one bf16 pass) is off by a tenth of that distance. Distances do not
+    change when both sides are shifted by ``m = mean(y)``, so the expansion
+    is taken of ``x - m`` and ``y - m``, whose norms are of the order of the
+    distances themselves: ``||x - m||^2`` as a fused sum of squared
+    differences (no shifted copy of x is made) and the cross term as
+    ``x @ (y - m)^T - m @ (y - m)^T`` at ``precision``."""
+    m = jnp.mean(y, axis=0)
+    yc = y - m
+    cross = jnp.matmul(x, yc.T, precision=precision) \
+        - jnp.matmul(m, yc.T, precision=precision)[None, :]
+    d2 = (jnp.sum((x - m) ** 2, axis=-1)[:, None] - 2.0 * cross
+          + row_norms_sq(yc)[None, :])
+    return jnp.maximum(d2, 0.0)
+
+
+def rbf_kernel(x, y, gamma=None, precision=None):
+    """``exp(-gamma ||x - y||^2)`` by the expansion at the backend's
+    default precision; ``precision`` given, by :func:`recentred_distances_sq`
+    at that precision (what ``SpectralClustering`` asks for)."""
     if gamma is None:
         gamma = 1.0 / x.shape[-1]
+    if precision is not None:
+        return jnp.exp(-gamma * recentred_distances_sq(x, y, precision))
     return jnp.exp(-gamma * euclidean_distances_sq(x, y))
 
 
