@@ -33,6 +33,7 @@ import numpy as np
 
 from ..base import BaseEstimator, ClusterMixin, TransformerMixin, to_host
 from ..ops.pairwise import euclidean_distances, euclidean_distances_sq
+from ..ops.reductions import top_l_indices, top_l_path
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_array, check_is_fitted
 
@@ -207,9 +208,26 @@ def _gumbel_keys(weights, key):
 
 @partial(jax.jit, static_argnames=("l",))
 def _gumbel_top_l(weights, key, l):
-    """Indices of l draws without replacement with prob ∝ weights."""
-    _, idx = jax.lax.top_k(_gumbel_keys(weights, key), l)
-    return idx
+    """Indices of l draws without replacement with prob ∝ weights: exactly
+    ``lax.top_k``'s, without its full sort (``ops/reductions.py``)."""
+    return top_l_indices(_gumbel_keys(weights, key), l)
+
+
+def _draw(weights, key, l, draws):
+    """``_gumbel_top_l``, with the path it takes appended to ``draws`` (a
+    list, or None): counted at the host call site, static from the shapes."""
+    if draws is not None:
+        draws.append(top_l_path(weights.shape[0], l))
+    return _gumbel_top_l(weights, key, l)
+
+
+def _draw_summary(draws):
+    """{"draws": how many draws an init dispatched, "draw": the path they
+    took — ``"tiled"`` / ``"sort"``, ``"mixed"`` where they differ, ``"none"``
+    where there were none}."""
+    paths = set(draws)
+    path = paths.pop() if len(paths) == 1 else ("mixed" if paths else "none")
+    return {"draws": len(draws), "draw": path}
 
 
 @jax.jit
@@ -457,8 +475,9 @@ def _block_weighted_topl(X, weights, key, l):
     """Per-block Gumbel top-l: (keys, rows). Global weighted sampling
     without replacement = top-l of the per-block top-l keys (the Gumbel
     keys are independent across blocks), so blocks merge exactly."""
-    kv, idx = jax.lax.top_k(_gumbel_keys(weights, key), l)
-    return kv, jnp.take(X, idx, axis=0)
+    keys = _gumbel_keys(weights, key)
+    idx = top_l_indices(keys, l)
+    return keys[idx], jnp.take(X, idx, axis=0)
 
 
 def _proc_key(key, b):
@@ -789,9 +808,10 @@ def init_scalable_streamed(stream, n_clusters, random_state, max_iter=None,
 
 
 def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
-                  oversampling_factor=2):
+                  oversampling_factor=2, draws=None):
     """k-means‖ candidate harvesting; ref
-    dask_ml/cluster/k_means.py::init_scalable."""
+    dask_ml/cluster/k_means.py::init_scalable. ``draws``: a list that gets
+    the path of every weighted draw dispatched (see ``_draw``)."""
     data, mask = X.data, X.row_mask(X.dtype)
     n, d = X.shape
     n_pad = data.shape[0]
@@ -801,7 +821,7 @@ def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
 
     # step 1: one uniform-random valid row
     key, k0 = jax.random.split(key)
-    first = data[_gumbel_top_l(mask, k0, 1)[0]]
+    first = data[_draw(mask, k0, 1, draws)[0]]
 
     # candidate buffer with static shape (SURVEY.md §7 hard parts)
     if max_iter is None:
@@ -816,10 +836,10 @@ def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
 
     for r in range(rounds):
         dmin, phi = _cost_to_candidates(data, mask, cands, cand_valid)
-        # the draw is queued before the host asks for phi, so the chip sorts
+        # the draw is queued before the host asks for phi, so the chip draws
         # while the host waits (a round that ends the loop drops its draw)
         key, kr = jax.random.split(key)
-        idx = _gumbel_top_l(dmin, kr, l)
+        idx = _draw(dmin, kr, l, draws)
         if float(to_host(phi)) <= 0.0:
             break
         rows = jnp.take(data, idx, axis=0)
@@ -840,14 +860,14 @@ def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
         None if random_state is None else int(random_state)), data.dtype)
 
 
-def init_pp(X: ShardedArray, n_clusters, random_state):
+def init_pp(X: ShardedArray, n_clusters, random_state, draws=None):
     """k-means++ on a device-drawn uniform sample (ref ::init_pp)."""
     from sklearn.cluster import kmeans_plusplus
 
     data, mask = X.data, X.row_mask(X.dtype)
     m = min(X.n_rows, max(10 * n_clusters, 500), data.shape[0])
     key = jax.random.PRNGKey(1 if random_state is None else int(random_state))
-    idx = _gumbel_top_l(mask, key, m)
+    idx = _draw(mask, key, m, draws)
     sample = to_host(jnp.take(data, idx, axis=0))
     centers, _ = kmeans_plusplus(
         sample, n_clusters,
@@ -856,10 +876,10 @@ def init_pp(X: ShardedArray, n_clusters, random_state):
     return jnp.asarray(centers, data.dtype)
 
 
-def init_random(X: ShardedArray, n_clusters, random_state):
+def init_random(X: ShardedArray, n_clusters, random_state, draws=None):
     data, mask = X.data, X.row_mask(X.dtype)
     key = jax.random.PRNGKey(2 if random_state is None else int(random_state))
-    idx = _gumbel_top_l(mask, key, n_clusters)
+    idx = _draw(mask, key, n_clusters, draws)
     return jnp.take(data, idx, axis=0)
 
 
@@ -912,7 +932,9 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         # it); resolved choice lands on fit_dtype_
         self.fit_dtype = fit_dtype
 
-    def _init_centers(self, X: ShardedArray):
+    def _init_centers(self, X: ShardedArray, draws=None):
+        """The initial centres; ``draws`` gets the path of every weighted
+        draw dispatched."""
         if isinstance(self.init, np.ndarray) or isinstance(
             self.init, jnp.ndarray
         ):
@@ -925,11 +947,12 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             return centers
         if self.init == "k-means||":
             return init_scalable(X, self.n_clusters, self.random_state,
-                                 self.init_max_iter, self.oversampling_factor)
+                                 self.init_max_iter, self.oversampling_factor,
+                                 draws)
         if self.init == "k-means++":
-            return init_pp(X, self.n_clusters, self.random_state)
+            return init_pp(X, self.n_clusters, self.random_state, draws)
         if self.init == "random":
-            return init_random(X, self.n_clusters, self.random_state)
+            return init_random(X, self.n_clusters, self.random_state, draws)
         raise ValueError(f"Unknown init {self.init!r}")
 
     def _make_ckpt(self, X, n, d):
@@ -1152,8 +1175,11 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
                            "fit_dtype_source": "pallas-resident"}
             self.fit_dtype_ = dt_info["fit_dtype"]
         root.add(n_rows=X.n_rows)
-        with span("fit.init"):
-            centers0 = self._init_centers(X)
+        with span("fit.init") as sp:
+            draws = []
+            centers0 = self._init_centers(X, draws)
+            init_draw = _draw_summary(draws)
+            sp.add(**init_draw)
         with span("fit.tol_scale") as sp:
             # dispatch only (none at tol == 0): the device works on into
             # fit.solve
@@ -1234,7 +1260,8 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             # solver_info_ ("fused": the Pallas Lloyd kernel ran)
             self.solver_info_ = {"n_iter": n_iter, "fused": bool(use_pallas),
                                  "fit_dtype": self.fit_dtype_,
-                                 "tol_scale_passes": tol_passes}
+                                 "tol_scale_passes": tol_passes,
+                                 "init_draw": init_draw}
             self.n_features_in_ = X.shape[1]
             return self
 

@@ -52,6 +52,7 @@ import numpy as np
 from ..base import BaseEstimator, ClusterMixin
 from ..observability import span, track_program
 from ..ops import linalg, pairwise
+from ..ops.reductions import top_l_path
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_array
 from .kmeans import KMeans, _gumbel_top_l
@@ -213,7 +214,11 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
             base_seed = (0 if self.random_state is None
                          else int(self.random_state))
         root.add(n_rows=n, n_landmarks=c)
-        with span("fit.solve", embed="tsqr") as sp:
+        # the landmark draw runs inside the program: its path is counted
+        # here, static from the shapes, where the Python runs every fit
+        landmark_draw = top_l_path(X.data.shape[0], c)
+        with span("fit.solve", embed="tsqr",
+                  landmark_draw=landmark_draw) as sp:
             emb, s, idx, fell_back = _embed(
                 X.data, mask, np.uint32(base_seed % 2**32), c=c, k=k,
                 mesh=X.mesh, affinity=self.affinity,
@@ -243,8 +248,12 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
             n_iters = [km.n_iter_ for km in fits]
             winner = int(np.argmin(inertias))
             km = fits[winner]
+            # every restart draws at the same shapes, so by the same path
             sp.add(restarts=n_init, n_iters=n_iters, inertias=inertias,
-                   winner=winner, n_iter=sum(n_iters))
+                   winner=winner, n_iter=sum(n_iters),
+                   draws=sum(f.solver_info_["init_draw"]["draws"]
+                             for f in fits),
+                   draw=km.solver_info_["init_draw"]["draw"])
         root.add(n_iter=sum(n_iters))
         with span("fit.finish") as sp:
             s_h, idx_h, fb_h = _fetch(s, idx, fell_back)
@@ -260,6 +269,7 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
                 "lloyd_iters": sum(n_iters), "n_iters": n_iters,
                 "inertias": inertias, "winner": winner,
                 "assign_fused": bool(km.solver_info_["fused"]),
+                "landmark_draw": landmark_draw,
             }
             if self.persist_embedding:
                 # reference persists the embedding in cluster memory; the

@@ -12,7 +12,10 @@ All functions take the padded data plus a row mask (1 = logical row,
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
+from jax import lax
 
 
 def masked_sum(x, mask, axis=0):
@@ -50,3 +53,49 @@ def masked_count_nonzero(x, mask):
 
 def _expand(mask, x):
     return mask.reshape(mask.shape + (1,) * (x.ndim - 1)).astype(x.dtype)
+
+
+# -- exact top-l without a full sort ---------------------------------------
+# ``lax.top_k`` over n keys lowers to a FULL sort of n (key, index) pairs on
+# the TPU (6.2 ms at n = 4,194,304, PERF.md section 5), whatever l is.
+
+def top_l_tile(n, l):
+    """The row tile of :func:`top_l_indices` over ``n`` keys: a multiple of
+    128 near ``sqrt(n / l)``, which minimises the second stage's
+    ``n / T + l * T`` keys; ``None`` where one ``lax.top_k`` over all n is
+    no larger."""
+    t = 128 * max(1, round(math.sqrt(n / l) / 128))
+    return t if n > 2 * l * t else None
+
+
+def top_l_path(n, l):
+    """``"tiled"`` or ``"sort"``: which path :func:`top_l_indices` takes
+    over ``n`` keys, static from the shapes."""
+    return "sort" if top_l_tile(n, l) is None else "tiled"
+
+
+def top_l_indices(keys, l):
+    """``lax.top_k(keys, l)[1]`` for a 1-D float ``keys`` — the same
+    indices in the same order — without sorting all of ``keys``: a two-level
+    top-l over row tiles of T keys.
+
+    Order the keys by (key descending, index ascending). Every member of the
+    top l lies in one of the l tiles whose best key ranks highest in that
+    order (a tile below those has l better keys above its best).
+    ``lax.top_k`` breaks ties by the lower index, which orders contiguous
+    tiles by their best keys, and the chosen tiles are concatenated in index
+    order, so the second ``top_k`` breaks ties as the first would have —
+    ``-inf`` keys included (the padding ranks below every key). Under a
+    row-sharded ``keys`` the tile maxima are local and only the n / T maxima
+    and the l gathered tiles are sorted."""
+    n = keys.shape[0]
+    t = top_l_tile(n, l)
+    if t is None:
+        return lax.top_k(keys, l)[1]
+    n_t = -(-n // t)
+    tiles = jnp.pad(keys, (0, n_t * t - n),
+                    constant_values=-jnp.inf).reshape(n_t, t)
+    _, tile = lax.top_k(jnp.max(tiles, axis=1), l)
+    tile = jnp.sort(tile)
+    _, j = lax.top_k(jnp.take(tiles, tile, axis=0).reshape(-1), l)
+    return tile[j // t] * t + j % t

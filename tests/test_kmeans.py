@@ -1,13 +1,16 @@
 """KMeans tests (ref: tests/test_kmeans.py in the reference; sklearn is
 the oracle per SURVEY.md §4)."""
 
+import jax
 import numpy as np
 import pytest
 from sklearn.cluster import KMeans as SkKMeans
 from sklearn.metrics import adjusted_rand_score
 
+from dask_ml_tpu import config, observability as obs
 from dask_ml_tpu.cluster import KMeans
 from dask_ml_tpu.datasets import make_blobs
+from dask_ml_tpu.models import kmeans as KM
 
 
 @pytest.fixture(scope="module")
@@ -166,5 +169,53 @@ def test_kmeans_records_what_carried_the_fit(blobs, use_pallas):
                 tol=0.0, use_pallas=use_pallas).fit(X)
     assert km.solver_info_ == {"n_iter": 7, "fused": use_pallas,
                                "fit_dtype": "float32",
-                               "tol_scale_passes": 0}
+                               "tol_scale_passes": 0,
+                               "init_draw": {"draws": 0, "draw": "none"}}
     assert km.n_iter_ == 7 and km.fit_dtype_ == "float32"
+
+
+# -- the weighted draw without a full sort (PR 39) --------------------------
+
+def _plain_draw(weights, key, l):
+    """The draw as every fit took it until PR 39: a full ``lax.top_k``."""
+    return jax.lax.top_k(KM._gumbel_keys(weights, key), l)[1]
+
+
+@pytest.fixture(scope="module")
+def wide_blobs():
+    """Rows enough that every draw of k = 4 takes the tiled path (n_pad >
+    2 * l * 128 at l = 8)."""
+    X, _ = make_blobs(n_samples=6000, n_features=5, centers=4,
+                      random_state=1, cluster_std=2.0)
+    return X
+
+
+@pytest.mark.parametrize("init", ["k-means||", "random"])
+def test_the_tiled_draw_fits_what_the_plain_draw_fits(
+        monkeypatch, wide_blobs, init):
+    new = lambda: KMeans(n_clusters=4, init=init,  # noqa: E731
+                         random_state=3, max_iter=30)
+    tiled = new().fit(wide_blobs)
+    assert tiled.solver_info_["init_draw"]["draw"] == "tiled"
+    monkeypatch.setattr(KM, "_gumbel_top_l",
+                        jax.jit(_plain_draw, static_argnames=("l",)))
+    plain = new().fit(wide_blobs)
+    np.testing.assert_array_equal(tiled.cluster_centers_,
+                                  plain.cluster_centers_)
+    np.testing.assert_array_equal(tiled.labels_.to_numpy(),
+                                  plain.labels_.to_numpy())
+    assert tiled.inertia_ == plain.inertia_
+
+
+@pytest.mark.parametrize("n, draw", [(6000, "tiled"), (500, "mixed")])
+def test_the_init_span_counts_the_draws_and_their_path(n, draw):
+    """k-means‖ dispatches 1 + 5 draws; at 500 rows the first (l = 1) is
+    tiled and the rounds' (l = 8) sort."""
+    X, _ = make_blobs(n_samples=n, n_features=5, centers=4, random_state=1)
+    obs.reset_recent_spans()
+    with config.set(obs_programs=True):
+        km = KMeans(n_clusters=4, random_state=0, max_iter=5).fit(X)
+        ring = {r["span"]: r for r in obs.recent_spans()}
+    want = {"draws": 6, "draw": draw}
+    assert km.solver_info_["init_draw"] == want
+    assert {k: ring["fit.init"][k] for k in want} == want

@@ -1,5 +1,6 @@
 """SpectralClustering tests (ref: tests/test_spectral_clustering.py)."""
 
+import jax
 import numpy as np
 import pytest
 from sklearn.datasets import make_circles
@@ -7,6 +8,7 @@ from sklearn.metrics import adjusted_rand_score
 
 from dask_ml_tpu.cluster import KMeans, SpectralClustering
 from dask_ml_tpu.datasets import make_blobs
+from dask_ml_tpu.models import kmeans as KM, spectral
 
 
 @pytest.mark.slow
@@ -115,3 +117,62 @@ def test_spectral_persist_embedding_and_n_init():
     sc2 = SpectralClustering(n_clusters=2, n_components=40, n_init=1,
                              random_state=0).fit(X)
     assert not hasattr(sc2, "embedding_")
+
+
+# -- the weighted draw without a full sort (PR 39) --------------------------
+
+def _plain_draw(weights, key, l):
+    """The draw as every fit took it until PR 39: a full ``lax.top_k``."""
+    return jax.lax.top_k(KM._gumbel_keys(weights, key), l)[1]
+
+
+def _wide():
+    """Rows enough that the landmark draw (c = 20) and every restart's
+    draws (k = 3) take the tiled path: n > 2 * 20 * 128."""
+    X, _ = make_blobs(n_samples=6000, n_features=4, centers=3,
+                      random_state=5, cluster_std=1.5)
+    return X
+
+
+def _fit(X):
+    return SpectralClustering(n_clusters=3, n_components=20, n_init=2,
+                              gamma=0.2, random_state=4).fit(X)
+
+
+def test_the_tiled_draw_fits_what_the_plain_draw_fits():
+    X = _wide()
+    tiled = _fit(X)
+    assert tiled.solver_info_["landmark_draw"] == "tiled"
+    plain_jit = jax.jit(_plain_draw, static_argnames=("l",))
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(KM, "_gumbel_top_l", plain_jit)
+            mp.setattr(spectral, "_gumbel_top_l", plain_jit)
+            jax.clear_caches()      # spectral.embed traced the tiled draw
+            plain = _fit(X)
+    finally:
+        jax.clear_caches()          # ... and now the plain one
+    np.testing.assert_array_equal(tiled.landmarks_, plain.landmarks_)
+    np.testing.assert_array_equal(tiled.labels_.to_numpy(),
+                                  plain.labels_.to_numpy())
+    assert tiled.solver_info_["inertias"] == plain.solver_info_["inertias"]
+    np.testing.assert_array_equal(tiled.assign_labels_.cluster_centers_,
+                                  plain.assign_labels_.cluster_centers_)
+    assert tiled.assign_labels_.inertia_ == plain.assign_labels_.inertia_
+
+
+def test_the_spans_name_the_draw_path():
+    from dask_ml_tpu import config
+    from dask_ml_tpu.observability import recent_spans, reset_recent_spans
+
+    X = _wide()
+    reset_recent_spans()
+    with config.set(obs_programs=True):
+        est = _fit(X)
+        ring = {r["span"]: r for r in recent_spans()}
+    assert ring["fit.solve"]["landmark_draw"] == "tiled"
+    # two restarts of 1 + 5 draws each
+    assert ring["fit.assign"]["draws"] == 12
+    assert ring["fit.assign"]["draw"] == "tiled"
+    assert est.assign_labels_.solver_info_["init_draw"] == {
+        "draws": 6, "draw": "tiled"}
