@@ -33,7 +33,8 @@ import numpy as np
 
 from ..base import BaseEstimator, ClusterMixin, TransformerMixin, to_host
 from ..ops.pairwise import euclidean_distances, euclidean_distances_sq
-from ..ops.reductions import top_l_indices, top_l_path
+from ..ops.reductions import (small_segment_count, top_l_indices,
+                               top_l_path)
 from ..parallel.sharded import ShardedArray
 from ..utils.validation import check_array, check_is_fitted
 
@@ -232,10 +233,28 @@ def _draw_summary(draws):
 
 @jax.jit
 def _candidate_weights(X, mask, cands, cand_valid):
+    """The rows nearest each candidate: a one-hot count fused after the
+    argmin, not a scatter-add (``ops/reductions.py``)."""
     d2 = euclidean_distances_sq(X, cands)
     d2 = jnp.where(cand_valid[None, :] > 0, d2, jnp.inf)
     labels = jnp.argmin(d2, axis=1)
-    return jax.ops.segment_sum(mask, labels, num_segments=cands.shape[0])
+    return small_segment_count(labels, mask, cands.shape[0])
+
+
+def _weigh(X, mask, cands, cand_valid, passes):
+    """``_candidate_weights``, with the path of its count appended to
+    ``passes`` (a list, or None), as ``_draw`` counts the draws."""
+    if passes is not None:
+        passes.append("onehot")
+    return _candidate_weights(X, mask, cands, cand_valid)
+
+
+def _weight_summary(passes):
+    """{"weight_passes": how many candidate-weight passes an init
+    dispatched, "weights": the path of their count — ``"onehot"``, or
+    ``"none"`` where there were none}."""
+    return {"weight_passes": len(passes),
+            "weights": passes[0] if passes else "none"}
 
 
 # -- streamed (out-of-core) kernels ----------------------------------------
@@ -808,10 +827,11 @@ def init_scalable_streamed(stream, n_clusters, random_state, max_iter=None,
 
 
 def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
-                  oversampling_factor=2, draws=None):
+                  oversampling_factor=2, draws=None, weight_passes=None):
     """k-means‖ candidate harvesting; ref
     dask_ml/cluster/k_means.py::init_scalable. ``draws``: a list that gets
-    the path of every weighted draw dispatched (see ``_draw``)."""
+    the path of every weighted draw dispatched (see ``_draw``);
+    ``weight_passes`` the same of the candidate-weight pass (``_weigh``)."""
     data, mask = X.data, X.row_mask(X.dtype)
     n, d = X.shape
     n_pad = data.shape[0]
@@ -849,7 +869,7 @@ def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
             cand_valid, jnp.ones((l,), jnp.float32), (start,)
         )
 
-    weights = _candidate_weights(data, mask, cands, cand_valid)
+    weights = _weigh(data, mask, cands, cand_valid, weight_passes)
     cands_h = to_host(cands)
     valid_h = to_host(cand_valid) > 0
     w_h = to_host(weights)[valid_h]
@@ -932,9 +952,10 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         # it); resolved choice lands on fit_dtype_
         self.fit_dtype = fit_dtype
 
-    def _init_centers(self, X: ShardedArray, draws=None):
+    def _init_centers(self, X: ShardedArray, draws=None, weight_passes=None):
         """The initial centres; ``draws`` gets the path of every weighted
-        draw dispatched."""
+        draw dispatched, ``weight_passes`` that of every candidate-weight
+        pass."""
         if isinstance(self.init, np.ndarray) or isinstance(
             self.init, jnp.ndarray
         ):
@@ -948,7 +969,7 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         if self.init == "k-means||":
             return init_scalable(X, self.n_clusters, self.random_state,
                                  self.init_max_iter, self.oversampling_factor,
-                                 draws)
+                                 draws, weight_passes)
         if self.init == "k-means++":
             return init_pp(X, self.n_clusters, self.random_state, draws)
         if self.init == "random":
@@ -1176,10 +1197,11 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             self.fit_dtype_ = dt_info["fit_dtype"]
         root.add(n_rows=X.n_rows)
         with span("fit.init") as sp:
-            draws = []
-            centers0 = self._init_centers(X, draws)
+            draws, weight_passes = [], []
+            centers0 = self._init_centers(X, draws, weight_passes)
             init_draw = _draw_summary(draws)
-            sp.add(**init_draw)
+            init_weights = _weight_summary(weight_passes)
+            sp.add(**init_draw, **init_weights)
         with span("fit.tol_scale") as sp:
             # dispatch only (none at tol == 0): the device works on into
             # fit.solve
@@ -1261,7 +1283,8 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             self.solver_info_ = {"n_iter": n_iter, "fused": bool(use_pallas),
                                  "fit_dtype": self.fit_dtype_,
                                  "tol_scale_passes": tol_passes,
-                                 "init_draw": init_draw}
+                                 "init_draw": init_draw,
+                                 "init_weights": init_weights}
             self.n_features_in_ = X.shape[1]
             return self
 

@@ -248,12 +248,17 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
             n_iters = [km.n_iter_ for km in fits]
             winner = int(np.argmin(inertias))
             km = fits[winner]
-            # every restart draws at the same shapes, so by the same path
+            # every restart draws and weighs at the same shapes, so by the
+            # same paths
             sp.add(restarts=n_init, n_iters=n_iters, inertias=inertias,
                    winner=winner, n_iter=sum(n_iters),
                    draws=sum(f.solver_info_["init_draw"]["draws"]
                              for f in fits),
-                   draw=km.solver_info_["init_draw"]["draw"])
+                   draw=km.solver_info_["init_draw"]["draw"],
+                   weight_passes=sum(
+                       f.solver_info_["init_weights"]["weight_passes"]
+                       for f in fits),
+                   weights=km.solver_info_["init_weights"]["weights"])
         root.add(n_iter=sum(n_iters))
         with span("fit.finish") as sp:
             s_h, idx_h, fb_h = _fetch(s, idx, fell_back)

@@ -99,3 +99,23 @@ def top_l_indices(keys, l):
     tile = jnp.sort(tile)
     _, j = lax.top_k(jnp.take(tiles, tile, axis=0).reshape(-1), l)
     return tile[j // t] * t + j % t
+
+
+# -- counts into a few bins without a scatter -------------------------------
+# ``jax.ops.segment_sum`` lowers to a scatter-add; on the TPU one of n rows
+# into c << n bins collides on almost every update and runs near-serially
+# (36.7 ms for 4,194,304 rows into 81 bins, PERF.md section 5).
+
+def small_segment_count(labels, weights, c):
+    """``jax.ops.segment_sum(weights, labels, num_segments=c)`` for a small
+    ``c``, as a reduction over rows of the one-hot compare
+    ``labels[:, None] == arange(c)`` — XLA fuses the compare into the
+    reduction, so the (n, c) one-hot never reaches memory, and a label
+    outside ``[0, c)`` counts nowhere, as in the scatter. O(n c) vector
+    work, which a caller that took ``labels`` by an argmin over (n, c)
+    distances has already paid. Sums whole-number ``weights`` (a row mask)
+    exactly in any order below 2**24, so the counts equal the scatter's bit
+    for bit; under row-sharded ``labels`` the sum is per-shard partials and
+    one all-reduce of c values."""
+    hit = labels[:, None] == jnp.arange(c, dtype=labels.dtype)[None, :]
+    return jnp.sum(jnp.where(hit, weights[:, None], 0), axis=0)

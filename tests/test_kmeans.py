@@ -170,7 +170,9 @@ def test_kmeans_records_what_carried_the_fit(blobs, use_pallas):
     assert km.solver_info_ == {"n_iter": 7, "fused": use_pallas,
                                "fit_dtype": "float32",
                                "tol_scale_passes": 0,
-                               "init_draw": {"draws": 0, "draw": "none"}}
+                               "init_draw": {"draws": 0, "draw": "none"},
+                               "init_weights": {"weight_passes": 0,
+                                                "weights": "none"}}
     assert km.n_iter_ == 7 and km.fit_dtype_ == "float32"
 
 
@@ -219,3 +221,7 @@ def test_the_init_span_counts_the_draws_and_their_path(n, draw):
     want = {"draws": 6, "draw": draw}
     assert km.solver_info_["init_draw"] == want
     assert {k: ring["fit.init"][k] for k in want} == want
+    # and one candidate-weight pass, counted without a scatter (PR 40)
+    weighed = {"weight_passes": 1, "weights": "onehot"}
+    assert km.solver_info_["init_weights"] == weighed
+    assert {k: ring["fit.init"][k] for k in weighed} == weighed

@@ -2,8 +2,10 @@
 ``models/kmeans.py::_lloyd_tol2``): at ``tol == 0`` an exact zero for which
 X is not read — no launch, no temporary — and at ``tol > 0`` ONE tracked
 program, ``kmeans.tol_scale``, of two fused passes over X. XLA:CPU gives the
-counts, the values and what compiled; the one test that compiles for a
-described v5e holds the program's memory at the benchmark cell's shape."""
+counts, the values and what compiled; the tests that compile for a
+described v5e hold the programs' memory at the benchmark cells' shapes —
+this one and, since PR 40, k-means‖'s candidate-weight pass at
+``spectral_nystrom``'s (this file is the one that describes the chip)."""
 
 import logging
 import os
@@ -219,3 +221,17 @@ def test_at_the_cells_shape_the_chips_compiler_makes_two_passes_and_no_copy(
                if " fusion(" in line and "%x" in line.split("fusion(")[1]]
     assert len(reads_x) == 2
     assert all("f32[256]" in line.split("=")[1] for line in reads_x)
+
+
+def test_the_candidate_weights_keep_the_one_hot_out_of_memory(one_chip):
+    """``spectral_nystrom``'s k-means‖ weight pass, 4,194,304 x 8 rows
+    against 81 candidates on one v5e (PR 40): no scatter, and no temporary
+    as large as the int32 labels, let alone the (n, 81) one-hot (1.36 GB in
+    float32)."""
+    n, d, c = 4_194_304, 8, 81
+    A = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    compiled = KM._candidate_weights.lower(
+        A((n, d)), A((n,)), A((c, d)), A((c,))).compile()
+    assert " scatter(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n

@@ -174,5 +174,8 @@ def test_the_spans_name_the_draw_path():
     # two restarts of 1 + 5 draws each
     assert ring["fit.assign"]["draws"] == 12
     assert ring["fit.assign"]["draw"] == "tiled"
+    # and one candidate-weight pass each (PR 40)
+    assert ring["fit.assign"]["weight_passes"] == 2
+    assert ring["fit.assign"]["weights"] == "onehot"
     assert est.assign_labels_.solver_info_["init_draw"] == {
         "draws": 6, "draw": "tiled"}
