@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import collections
 import weakref
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from ..base import BaseEstimator, ClassifierMixin, RegressorMixin, to_host
 from ..metrics import accuracy_score, r2_score
 from ..observability import span, track_program
 from ..plans import tracked as plan_tracked, warmups as plan_warmups
+from ..plans.ladders import SlotRungLadder
 from ..parallel.sharded import ShardedArray, as_sharded
 from ..utils.validation import check_is_fitted
 from .glm import link_fetch, link_finish, predict_resident
@@ -79,65 +80,97 @@ def _design_matvec_bwd(Xd, ct):
 _design_matvec.defvjp(_design_matvec_fwd, _design_matvec_bwd)
 
 
+def _sgd_pointwise(eta, y, loss):
+    """The per-row SGD loss at decision value ``eta`` — the ONE definition
+    every autodiff reader of a block differentiates (the Pallas kernels'
+    twin, with its derivative, is ``ops/pallas_fused.py::
+    sgd_objective_terms``)."""
+    if loss == "log_loss":
+        return jax.nn.softplus(eta) - y * eta
+    if loss == "hinge":
+        margins = (2.0 * y - 1.0) * eta
+        return jnp.maximum(0.0, 1.0 - margins)
+    return 0.5 * (eta - y) ** 2  # squared_error
+
+
+def _dense_eta(X, mxu=None):
+    """``coef -> X @ coef`` for a dense block: the matvec runs at X's dtype
+    (``mxu`` casts it first) with f32 accumulation and an f32 gradient
+    (``_design_matvec``) — a bf16 block rides the MXU at bf16 rate; for
+    an f32 X this is exactly ``X @ coef``."""
+    return partial(_design_matvec, X if mxu is None else X.astype(mxu))
+
+
+def _sparse_eta(data, cols, rows, S):
+    """``coef -> X @ coef`` for a bucketed-nnz block of ``S`` rows, at nnz
+    cost (take, then segment-sum; its autodiff backward scatter-adds)."""
+    from ..ops.sparse_kernels import sparse_eta
+
+    return lambda coef: sparse_eta(data, cols, rows, coef, int(S))
+
+
+def _sgd_data_sum(w, y, eta_of, mask, iflag, loss, n_valid=None):
+    """The data term of weight vector ``w`` over one block read through
+    ``eta_of``: the masked sum of the per-row losses, or with ``n_valid``
+    their mean over that many rows. ``iflag=0`` zeroes the intercept's
+    contribution to eta, so grad[-1] is already 0 and the intercept stays
+    frozen at its init (0)."""
+    eta = eta_of(w[:-1]) + w[-1] * iflag
+    s = jnp.sum(_sgd_pointwise(eta, y, loss) * mask)
+    return s if n_valid is None else s / jnp.maximum(n_valid, 1.0)
+
+
 def _sgd_data_loss(w, y, X, mask, n_valid, iflag, loss, mxu=None):
-    """The minibatch data term — THE single definition shared by
+    """The minibatch data term of a dense block — shared by
     ``_sgd_update_one`` (which adds the l2 penalty inside its
     objective) and the grad-accum micro kernel (which normalizes by the
     accumulation GROUP's global valid-row count, so summing micro
     (value, grad) pairs over the group IS the group objective's
     value_and_grad; at A=1 single-process the traced expression is
     identical to the sequential step's)."""
-    # iflag=0 zeroes the intercept's contribution to eta, so grad[-1]
-    # is already 0 and the intercept stays frozen at its init (0).
-    # The matvec runs at X's dtype with f32 accumulation — a bf16
-    # block (config.dtype="bfloat16" epoch grids) rides the MXU at
-    # bf16 rate; for f32 X this is exactly `X @ w[:-1]`
-    Xd = X if mxu is None else X.astype(mxu)
-    eta = _design_matvec(Xd, w[:-1]) + w[-1] * iflag
-    if loss == "log_loss":
-        per = jax.nn.softplus(eta) - y * eta
-    elif loss == "hinge":
-        margins = (2.0 * y - 1.0) * eta
-        per = jnp.maximum(0.0, 1.0 - margins)
-    else:  # squared_error
-        per = 0.5 * (eta - y) ** 2
-    return jnp.sum(per * mask) / jnp.maximum(n_valid, 1.0)
+    return _sgd_data_sum(w, y, _dense_eta(X, mxu), mask, iflag, loss,
+                         n_valid)
 
 
-def _sgd_update_one(w, y, X, mask, n_valid, lr, alpha, l2w, l1w, iflag,
-                    loss, mxu=None):
-    """One minibatch-GD(+prox) update of one weight vector — the SINGLE
-    definition of the objective and update shared by the model-batched
-    and class-batched kernels (a divergence between them would silently
-    split binary and multiclass semantics). ``mxu`` (static dtype, e.g.
-    bf16 under config.dtype="auto" on TPU) casts ONLY the eta matvec's
-    operands; with None the trace is unchanged."""
+def _sgd_prox_update(w, data_loss, lr, alpha, l2w, l1w):
+    """One minibatch-GD(+prox) update of one weight vector whose
+    NORMALIZED data term is ``data_loss(w)``: the l2 penalty inside the
+    differentiated objective, the lr step, then the l1 soft-threshold
+    (intercept unpenalized). Returns (w2, objective value)."""
 
     def objective(w):
-        data_loss = _sgd_data_loss(w, y, X, mask, n_valid, iflag, loss,
-                                   mxu=mxu)
-        reg = 0.5 * alpha * l2w * jnp.sum(w[:-1] ** 2)
-        return data_loss + reg
+        return data_loss(w) + 0.5 * alpha * l2w * jnp.sum(w[:-1] ** 2)
 
     val, grad = jax.value_and_grad(objective)(w)
     w = w - lr * grad
-    # proximal soft-threshold for the l1 part (intercept unpenalized)
     thr = lr * alpha * l1w
     coef = jnp.sign(w[:-1]) * jnp.maximum(jnp.abs(w[:-1]) - thr, 0.0)
     return w.at[:-1].set(coef), val
 
 
+def _sgd_update_one(w, y, X, mask, n_valid, lr, alpha, l2w, l1w, iflag,
+                    loss, mxu=None):
+    """One update of one weight vector on a dense block — the SINGLE
+    definition shared by the model-batched and class-batched kernels (a
+    divergence between them would silently split binary and multiclass
+    semantics). ``mxu`` (static dtype, e.g. bf16 under
+    config.dtype="auto" on TPU) casts ONLY the eta matvec's operands."""
+    return _sgd_prox_update(
+        w, lambda v: _sgd_data_loss(v, y, X, mask, n_valid, iflag, loss,
+                                    mxu=mxu),
+        lr, alpha, l2w, l1w)
+
+
 def _sgd_many_update(W, loss_sums, grads, nv, lr, alpha, l2w, l1w,
                      iflag):
-    """The vectorized `_sgd_update_one` epilogue on RAW kernel sums for
-    an (N, d+1) weight stack — the ONE definition shared by the fused
-    multiclass step, the fused sharded multiclass step, and the fused
-    cohort scan (each copy independently remembering the thr broadcast
-    and the iflag fold is how flavors drift apart). Per-row
-    lr/alpha/penalty/iflag operands may be scalars (multiclass: one
-    setting for all C rows) or (N,) vectors (cohort: per-model);
-    broadcasting a scalar to a column changes no float op. Returns
-    (W2, per-row losses)."""
+    """The vectorized `_sgd_update_one` epilogue on RAW block sums for
+    an (N, d+1) weight stack, normalized by ``nv`` here — shared by the
+    streamed scan (``_sgd_stream_program``) and the fused resident cohort
+    scan. Per-row lr/alpha/penalty/iflag operands may be scalars (one
+    model: one setting for all its rows) or (N,) vectors (cohort:
+    per-model); broadcasting a scalar to a column changes no float op.
+    The intercept's gradient is multiplied by its flag (the kernels' raw
+    sums are flag-free). Returns (W2, per-row losses)."""
     def col(a):
         return jnp.reshape(
             jnp.broadcast_to(jnp.asarray(a, jnp.float32),
@@ -247,262 +280,6 @@ def _sgd_accum_apply(W, grad, lr, alpha, l2w, l1w):
     return W2.at[..., :-1].set(coef)
 
 
-@plan_tracked("superblock.sgd_scan")
-@partial(jax.jit, static_argnames=("loss", "n_out", "mxu"),
-         donate_argnums=(0,))
-def _sgd_sb_scan(W, Xs, ys, counts, lrs, alpha, l2w, l1w, iflag, loss,
-                 n_out, mxu=None):
-    """K streamed-block minibatch steps as ONE scan program over a
-    super-block stack (ISSUE 3): ``Xs (K, S, d)`` / ``ys (K, S)`` /
-    ``counts (K,)`` valid-row counts; the weight carry ``W`` is DONATED
-    so XLA advances it in place across the pass's dispatches. ``lrs``
-    carries the host-precomputed lr clock values (identical to the
-    per-block loop's ``_step_args`` sequence). All-padding slots
-    (``counts == 0``, the ragged final super-block) leave W untouched —
-    a masked-empty update would still apply the l2/prox terms."""
-    S = Xs.shape[1]
-    r = jnp.arange(S)
-
-    def step(W, Xb, yb, c, lr):
-        mask = (r < c).astype(jnp.float32)
-        nv = c.astype(jnp.float32)
-        if n_out is not None:
-            def one(w, cc):
-                yy = (yb == cc).astype(jnp.float32)
-                return _sgd_update_one(w, yy, Xb, mask, nv, lr, alpha,
-                                       l2w, l1w, iflag, loss, mxu=mxu)
-
-            W2, losses = jax.vmap(one)(
-                W, jnp.arange(n_out, dtype=jnp.float32)
-            )
-            loss_v = losses.sum()
-        else:
-            W2, loss_v = _sgd_update_one(W, yb, Xb, mask, nv, lr, alpha,
-                                         l2w, l1w, iflag, loss, mxu=mxu)
-        return jnp.where(c > 0, W2, W), loss_v
-
-    def scan_step(W, inp):
-        Xb, yb, c, lr = inp
-        return step(W, Xb, yb, c, lr)
-
-    return jax.lax.scan(scan_step, W, (Xs, ys, counts, lrs))
-
-
-@plan_tracked("pallas.sgd_step")
-@partial(jax.jit, static_argnames=("loss", "n_out", "mxu", "interpret"),
-         donate_argnums=(0,))
-def _sgd_sb_scan_pallas(W, Xs, ys, counts, lrs, alpha, l2w, l1w, iflag,
-                        loss, n_out=None, mxu=None, interpret=False):
-    """Pallas flavor of :func:`_sgd_sb_scan` (ISSUE 8 tentpole): each
-    block step is ONE fused VMEM pass — the ``fused_sgd_block_grad``
-    kernel (flat weights) or ``fused_sgd_many_block_grad`` (the C
-    one-vs-rest rows of a multiclass model, ISSUE 12: one (tile, C)
-    MXU matmul serves all classes) returns the objective and gradient
-    sums from a single X read where the XLA step reads X twice
-    (forward matvec + autodiff backward) — followed by the identical
-    O(d) lr/l2/prox epilogue in XLA. Selected by ``_SGDBase._sb_step``
-    with ``config.pallas_stream`` on (real TPU, or interpret mode via
-    ``pallas_stream_interpret``) and block shapes satisfying
-    ``sgd_stream_tile`` / ``sgd_many_stream_tile``; numerically within
-    float tolerance of the XLA flavor (tests/test_precision.py)."""
-    from ..ops.pallas_fused import (fused_sgd_block_grad,
-                                    fused_sgd_many_block_grad)
-
-    def step(W, Xb, yb, c, lr):
-        nv = jnp.maximum(c.astype(jnp.float32), 1.0)
-        if n_out is not None:
-            loss_sums, grads = fused_sgd_many_block_grad(
-                Xb, c, yb, W, iflag, loss, codes=True, mxu=mxu,
-                interpret=interpret,
-            )
-            W2, losses = _sgd_many_update(W, loss_sums, grads, nv, lr,
-                                          alpha, l2w, l1w, iflag)
-            return jnp.where(c > 0, W2, W), losses.sum()
-        loss_sum, grad = fused_sgd_block_grad(
-            Xb, c, yb, W, iflag, loss, mxu=mxu, interpret=interpret
-        )
-        # the exact `_sgd_update_one` epilogue on the kernel's raw sums
-        loss_v = loss_sum / nv + 0.5 * alpha * l2w * jnp.sum(W[:-1] ** 2)
-        g = grad / nv
-        g = g.at[:-1].add(alpha * l2w * W[:-1])
-        g = g.at[-1].mul(iflag)
-        W2 = W - lr * g
-        thr = lr * alpha * l1w
-        coef = jnp.sign(W2[:-1]) * jnp.maximum(
-            jnp.abs(W2[:-1]) - thr, 0.0
-        )
-        W2 = W2.at[:-1].set(coef)
-        return jnp.where(c > 0, W2, W), loss_v
-
-    def scan_step(W, inp):
-        Xb, yb, c, lr = inp
-        return step(W, Xb, yb, c, lr)
-
-    return jax.lax.scan(scan_step, W, (Xs, ys, counts, lrs))
-
-
-def _sgd_sparse_pointwise(eta, y, loss):
-    """The per-row loss switch on a precomputed eta — the sparse twin
-    of the expression inside ``_sgd_data_loss`` (kept textually
-    separate so the dense kernels' traced jaxprs stay byte-identical)."""
-    if loss == "log_loss":
-        return jax.nn.softplus(eta) - y * eta
-    if loss == "hinge":
-        margins = (2.0 * y - 1.0) * eta
-        return jnp.maximum(0.0, 1.0 - margins)
-    return 0.5 * (eta - y) ** 2  # squared_error
-
-
-def _sgd_update_one_sparse(w, y, data, cols, rows, S, mask, n_valid, lr,
-                           alpha, l2w, l1w, iflag, loss):
-    """``_sgd_update_one`` over one bucketed-nnz sparse block: the eta
-    matvec and its autodiff backward run at nnz cost (take →
-    scatter-add); objective normalization, l2 term and the l1 proximal
-    epilogue are the dense step's exactly."""
-    from ..ops.sparse_kernels import sparse_eta
-
-    def objective(w):
-        eta = sparse_eta(data, cols, rows, w[:-1], S) + w[-1] * iflag
-        data_loss = jnp.sum(_sgd_sparse_pointwise(eta, y, loss) * mask) \
-            / jnp.maximum(n_valid, 1.0)
-        return data_loss + 0.5 * alpha * l2w * jnp.sum(w[:-1] ** 2)
-
-    val, grad = jax.value_and_grad(objective)(w)
-    w = w - lr * grad
-    thr = lr * alpha * l1w
-    coef = jnp.sign(w[:-1]) * jnp.maximum(jnp.abs(w[:-1]) - thr, 0.0)
-    return w.at[:-1].set(coef), val
-
-
-import functools as _ft_sharded
-
-
-@_ft_sharded.lru_cache(maxsize=32)
-def _sgd_sb_scan_sparse(loss, n_out, S, mesh=None):
-    """Sparse flavor of :func:`_sgd_sb_scan` (ISSUE 13): K streamed
-    minibatch steps over bucketed-nnz COO stacks in ONE donated-carry
-    scan dispatch — same lr clock, same padding-slot pass-through, same
-    zero-compiles-after-pass-1 contract (the stream plan pads every
-    super-block of a fit to one nnz capacity). ``mesh`` selects the
-    shard_map data-parallel flavor: each shard's raw (loss, grad) sums
-    come from its own nnz segment/slab and psum ONCE per block step
-    before the identical lr/l2/prox epilogue — the dense sharded scan's
-    exact collective shape, tracked as
-    ``superblock.sparse.sgd_scan.psum``."""
-    from ..ops.sparse_kernels import sparse_eta
-
-    S = int(S)
-
-    if mesh is None:
-        @partial(jax.jit, donate_argnums=(0,))
-        def run(W, data, cols, rows, ys, counts, lrs, alpha, l2w, l1w,
-                iflag):
-            r = jnp.arange(S)
-
-            def step(W, db, cb, rb, yb, c, lr):
-                mask = (r < c).astype(jnp.float32)
-                nv = c.astype(jnp.float32)
-                if n_out is not None:
-                    def one(w, cc):
-                        yy = (yb == cc).astype(jnp.float32)
-                        return _sgd_update_one_sparse(
-                            w, yy, db, cb, rb, S, mask, nv, lr, alpha,
-                            l2w, l1w, iflag, loss,
-                        )
-
-                    W2, losses = jax.vmap(one)(
-                        W, jnp.arange(n_out, dtype=jnp.float32)
-                    )
-                    loss_v = losses.sum()
-                else:
-                    W2, loss_v = _sgd_update_one_sparse(
-                        W, yb, db, cb, rb, S, mask, nv, lr, alpha, l2w,
-                        l1w, iflag, loss,
-                    )
-                return jnp.where(c > 0, W2, W), loss_v
-
-            def scan_step(W, inp):
-                db, cb, rb, yb, c, lr = inp
-                return step(W, db, cb, rb, yb, c, lr)
-
-            return jax.lax.scan(scan_step, W,
-                                (data, cols, rows, ys, counts, lrs))
-
-        return plan_tracked("superblock.sparse.sgd_scan", run)
-
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import DATA_AXIS
-
-    def body(W, data, cols, rows, ys, shard_counts, counts, lrs, alpha,
-             l2w, l1w, iflag):
-        r = jnp.arange(S)               # LOCAL slab height
-        cts_local = shard_counts[0]
-
-        def step(W, db, cb, rb, yb, c_loc, c_glob, lr):
-            mask = (r < c_loc).astype(jnp.float32)
-            nv = jnp.maximum(c_glob.astype(jnp.float32), 1.0)
-
-            def one(w, y):
-                def local_sums(w):
-                    eta = sparse_eta(db, cb, rb, w[:-1], S) \
-                        + w[-1] * iflag
-                    return jnp.sum(
-                        _sgd_sparse_pointwise(eta, y, loss) * mask
-                    )
-
-                v, g = jax.value_and_grad(local_sums)(w)
-                loss_sum, grad = jax.lax.psum((v, g), DATA_AXIS)
-                loss_v = loss_sum / nv \
-                    + 0.5 * alpha * l2w * jnp.sum(w[:-1] ** 2)
-                g = grad / nv
-                g = g.at[:-1].add(alpha * l2w * w[:-1])
-                w2 = w - lr * g
-                thr = lr * alpha * l1w
-                coef = jnp.sign(w2[:-1]) * jnp.maximum(
-                    jnp.abs(w2[:-1]) - thr, 0.0
-                )
-                return w2.at[:-1].set(coef), loss_v
-
-            if n_out is not None:
-                def one_class(w, cc):
-                    return one(w, (yb == cc).astype(jnp.float32))
-
-                W2, losses = jax.vmap(one_class)(
-                    W, jnp.arange(n_out, dtype=jnp.float32)
-                )
-                loss_v = losses.sum()
-            else:
-                W2, loss_v = one(W, yb)
-            return jnp.where(c_glob > 0, W2, W), loss_v
-
-        def scan_step(W, inp):
-            db, cb, rb, yb, cl, cg, lr = inp
-            return step(W, db, cb, rb, yb, cl, cg, lr)
-
-        return jax.lax.scan(
-            scan_step, W,
-            (data, cols, rows, ys, cts_local, counts, lrs),
-        )
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def run(W, data, cols, rows, ys, shard_counts, counts, lrs, alpha,
-            l2w, l1w, iflag):
-        f = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), P(None, DATA_AXIS), P(None, DATA_AXIS),
-                      P(None, DATA_AXIS), P(None, DATA_AXIS),
-                      P(DATA_AXIS, None), P(), P(), P(), P(), P(),
-                      P()),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-        return f(W, data, cols, rows, ys, shard_counts, counts, lrs,
-                 alpha, l2w, l1w, iflag)
-
-    return plan_tracked("superblock.sparse.sgd_scan.psum", run)
-
-
 @plan_tracked("superblock.sparse.grad_accum_micro")
 @partial(jax.jit, static_argnames=("loss", "n_out", "S"))
 def _sgd_accum_micro_sparse(W, data, cols, rows, yb, mask, nv_group,
@@ -510,13 +287,10 @@ def _sgd_accum_micro_sparse(W, data, cols, rows, yb, mask, nv_group,
     """Sparse twin of :func:`_sgd_accum_micro` (the grad-accum flavor's
     per-micro-block value_and_grad, normalized by the GROUP's global
     valid-row count inside autodiff) over one bucketed-nnz block."""
-    from ..ops.sparse_kernels import sparse_eta
+    eta_of = _sparse_eta(data, cols, rows, S)
 
     def data_loss(w, y):
-        eta = sparse_eta(data, cols, rows, w[:-1], int(S)) \
-            + w[-1] * iflag
-        return jnp.sum(_sgd_sparse_pointwise(eta, y, loss) * mask) \
-            / jnp.maximum(nv_group, 1.0)
+        return _sgd_data_sum(w, y, eta_of, mask, iflag, loss, nv_group)
 
     if n_out is not None:
         def one(w, c):
@@ -528,145 +302,6 @@ def _sgd_accum_micro_sparse(W, data, cols, rows, yb, mask, nv_group,
         )
         return vals.sum(), grads
     return jax.value_and_grad(lambda w: data_loss(w, yb))(W)
-
-
-@_ft_sharded.lru_cache(maxsize=32)
-def _sgd_sb_scan_sharded(mesh, loss, n_out, mxu=None, fused=False,
-                         interpret=False):
-    """Data-parallel flavor of :func:`_sgd_sb_scan` (ISSUE 9): the K
-    block steps run under ``shard_map`` over the stream mesh's "data"
-    axis with a REPLICATED weight carry. SGD's update is sequential in
-    the blocks, so unlike the additive GLM/KMeans reducers it cannot
-    defer merging to one pass-end collective: each block step computes
-    its shard's raw (loss-sum, gradient-sum) from purely local rows and
-    pays ONE ``lax.psum`` over "data" before the identical lr/l2/prox
-    epilogue applies the GLOBAL gradient — the classic data-parallel
-    minibatch step, K psums per super-block dispatch. Counts split per
-    shard (``shard_counts``, local masks) with the global ``counts``
-    riding replicated for the normalizer and the padding-slot
-    pass-through; parity with the single-device scan is float-roundoff
-    only (per-shard partial sums reassociate the same additions).
-
-    ``fused=True`` (ISSUE 12): each shard's raw sums come from the
-    fused Pallas kernel running INSIDE the shard_map on its own slab
-    (tile selection sees the per-shard S/D height) — the per-step psum
-    and epilogue are unchanged, so the dispatch shape (K psums per
-    super-block) is identical and tracked as ``pallas.sgd_step.psum``.
-
-    Cached per (mesh, loss, n_out, mxu, fused, interpret) so every pass
-    of a fit reuses ONE jitted, donated-carry callable."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import DATA_AXIS, data_shard_spec as spec_of
-
-    if fused:
-        from ..ops.pallas_fused import (fused_sgd_block_grad,
-                                        fused_sgd_many_block_grad)
-
-    def body(W, Xs, ys, shard_counts, counts, lrs, alpha, l2w, l1w,
-             iflag):
-        S = Xs.shape[1]
-        r = jnp.arange(S)
-        cts_local = shard_counts[0]
-
-        def step(W, Xb, yb, c_loc, c_glob, lr):
-            mask = (r < c_loc).astype(jnp.float32)
-            nv = jnp.maximum(c_glob.astype(jnp.float32), 1.0)
-
-            if fused and n_out is not None:
-                # fused multiclass: one VMEM pass over this shard's
-                # slab serves all C one-vs-rest rows; psum the raw
-                # sums, then the identical vectorized epilogue
-                vs, gs = fused_sgd_many_block_grad(
-                    Xb, c_loc, yb, W, iflag, loss, codes=True,
-                    mxu=mxu, interpret=interpret,
-                )
-                vs, gs = jax.lax.psum((vs, gs), DATA_AXIS)
-                W2, losses = _sgd_many_update(W, vs, gs, nv, lr,
-                                              alpha, l2w, l1w, iflag)
-                return jnp.where(c_glob > 0, W2, W), losses.sum()
-
-            def one(w, y):
-                def local_sums(w):
-                    # the raw UNNORMALIZED data term over this shard's
-                    # rows — same eta/loss math as _sgd_update_one
-                    # (iflag rides inside eta, so grad[-1] is already 0
-                    # with the intercept off)
-                    Xd = Xb if mxu is None else Xb.astype(mxu)
-                    eta = jnp.matmul(Xd, w[:-1].astype(Xd.dtype),
-                                     preferred_element_type=jnp.float32
-                                     ) + w[-1] * iflag
-                    if loss == "log_loss":
-                        per = jax.nn.softplus(eta) - y * eta
-                    elif loss == "hinge":
-                        margins = (2.0 * y - 1.0) * eta
-                        per = jnp.maximum(0.0, 1.0 - margins)
-                    else:  # squared_error
-                        per = 0.5 * (eta - y) ** 2
-                    return jnp.sum(per * mask)
-
-                if fused:
-                    # ONE VMEM pass over this shard's slab for the
-                    # same raw sums the autodiff path computes twice
-                    v, g = fused_sgd_block_grad(
-                        Xb, c_loc, yb, w, iflag, loss, mxu=mxu,
-                        interpret=interpret,
-                    )
-                    # the kernel's raw intercept sum is iflag-free;
-                    # fold it here exactly like the XLA epilogue does
-                    g = g.at[-1].mul(iflag)
-                else:
-                    v, g = jax.value_and_grad(local_sums)(w)
-                # the data-parallel gradient psum INSIDE the scan: the
-                # next block step needs the GLOBAL update
-                loss_sum, grad = jax.lax.psum((v, g), DATA_AXIS)
-                loss_v = loss_sum / nv \
-                    + 0.5 * alpha * l2w * jnp.sum(w[:-1] ** 2)
-                g = grad / nv
-                g = g.at[:-1].add(alpha * l2w * w[:-1])
-                w2 = w - lr * g
-                thr = lr * alpha * l1w
-                coef = jnp.sign(w2[:-1]) * jnp.maximum(
-                    jnp.abs(w2[:-1]) - thr, 0.0
-                )
-                return w2.at[:-1].set(coef), loss_v
-
-            if n_out is not None:
-                def one_class(w, cc):
-                    return one(w, (yb == cc).astype(jnp.float32))
-
-                W2, losses = jax.vmap(one_class)(
-                    W, jnp.arange(n_out, dtype=jnp.float32)
-                )
-                loss_v = losses.sum()
-            else:
-                W2, loss_v = one(W, yb)
-            return jnp.where(c_glob > 0, W2, W), loss_v
-
-        def scan_step(W, inp):
-            Xb, yb, cl, cg, lr = inp
-            return step(W, Xb, yb, cl, cg, lr)
-
-        return jax.lax.scan(scan_step, W,
-                            (Xs, ys, cts_local, counts, lrs))
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def run(W, Xs, ys, shard_counts, counts, lrs, alpha, l2w, l1w,
-            iflag):
-        xs_spec = spec_of(Xs, 1)
-        ys_spec = spec_of(ys, 1)
-        f = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), xs_spec, ys_spec, P(DATA_AXIS, None), P(),
-                      P(), P(), P(), P(), P()),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-        return f(W, Xs, ys, shard_counts, counts, lrs, alpha, l2w,
-                 l1w, iflag)
-
-    name = "pallas.sgd_step.psum" if fused else "superblock.sgd_scan.psum"
-    return plan_tracked(name, run)
 
 
 @plan_tracked("sgd.fused_epoch")
@@ -789,41 +424,31 @@ def _sgd_cohort_scan_pallas(Xr, yr, NV, order, W, LRS, alphas, l2ws,
     return W, losses[-1]
 
 
-# -- streamed cohort superblock scans (ISSUE 14 tentpole) ---------------
-# The adaptive-search cohort as a CLIENT of the streamed superblock
-# plane: one BlockStream pass advances EVERY surviving candidate — each
-# super-block is ONE dispatch whose donated carry holds the stacked
-# (n_slots, d+1) cohort weights, so the round's data is read from
-# host/HBM once regardless of candidate count. Three mechanisms ride
-# the scan:
-#   - ``ACT (K, width)``: per-model STEP activity — heterogeneous
-#     rounds ({model_id: n_calls} with differing counts) run in the
-#     SAME scan, a model advancing only on its own window of block
-#     steps (the per-model ``iflags`` mechanism of the fused kernels
-#     generalized to the XLA scan);
+# -- the streamed super-block scans ----------------------------------------
+# Every streamed SGD dispatch is ONE program from ``_sgd_stream_program``:
+# one model or a search cohort's slot rung; dense, Pallas or bucketed-nnz
+# blocks; one device or a "data" mesh. The cohort is a CLIENT of the same
+# plane as a single model — one BlockStream pass advances EVERY surviving
+# candidate, each super-block ONE dispatch whose donated carry holds the
+# stacked (n_slots, d+1) cohort weights, so a round reads its data once
+# whatever the candidate count. Three mechanisms ride the cohort's scan:
+#   - ``act (K, width)``: per-model STEP activity — heterogeneous rounds
+#     ({model_id: n_calls} with differing counts) run in the SAME scan, a
+#     model advancing only on its own window of block steps;
 #   - ``idx (width,)``: the slot-rung gather — each dispatch pulls the
 #     union of its ACTIVE slots out of the full (n_slots, d+1) donated
-#     carry into the smallest compiled rung width (a geometric ladder,
-#     all rungs warmed in round 1), so compute scales with the LIVE
-#     bracket while bracket halving still reuses compiled scans via
-#     padded slots instead of recompiling at each surviving N;
-#   - padding block slots (``counts == 0``, the ragged final
-#     super-block) pass through exactly like the single-model scans,
-#     and padding SLOT columns (``ACT`` all-zero) pass their rows back
-#     unchanged through the ``.at[idx].set`` scatter.
+#     carry into the smallest rung width of the plans subsystem's
+#     SlotRungLadder (powers of two below the candidate count, then the
+#     full count). Every rung compiles during round 1 (warm dispatches
+#     recorded in the process-wide plans WarmupRegistry), so compute
+#     scales with the LIVE bracket while a shrinking bracket picks its
+#     rung at zero new compiles — and a second search over the same
+#     shapes skips the warm executions entirely;
+#   - padding block slots (``counts == 0``, the ragged final super-block)
+#     pass every row through, and padding SLOT columns (``act`` all-zero)
+#     pass their rows back unchanged through the ``.at[idx].set`` scatter.
 
-
-# the slot-width ladder a search's cohort dispatches draw from: the
-# plans subsystem's SlotRungLadder (ISSUE 15 — powers of two below the
-# candidate count, then the full count, near-duplicate top power
-# dropped). Every rung compiles during round 1 (warmup dispatches
-# recorded in the process-wide plans WarmupRegistry, which replaced the
-# old module-level _COHORT_WARMED set), so a shrinking bracket later
-# picks its rung at zero new compiles — and a second search over the
-# same shapes skips the warmup executions entirely.
-from ..plans.ladders import SlotRungLadder as _SlotRungLadder  # noqa: E402
-
-_COHORT_LADDER = _SlotRungLadder()
+_COHORT_LADDER = SlotRungLadder()
 
 
 def _cohort_rungs(n_slots):
@@ -834,290 +459,208 @@ def _cohort_rung_of(n_active, n_slots):
     return _COHORT_LADDER.rung_for(n_active, n_slots)
 
 
-def _cohort_gather(W, idx):
-    return jnp.take(W, idx, axis=0)
+# the program name of each (block reader, cohort carry); the
+# data-parallel program adds ".psum"
+_STREAM_PROGRAMS = {
+    ("xla", False): "superblock.sgd_scan",
+    ("pallas", False): "pallas.sgd_step",
+    ("sparse", False): "superblock.sparse.sgd_scan",
+    ("xla", True): "superblock.sgd_cohort",
+    ("pallas", True): "pallas.sgd_cohort",
+    ("sparse", True): "superblock.sparse.sgd_cohort",
+}
 
 
-def _cohort_scatter(W, idx, Wc):
-    return W.at[idx].set(Wc)
+def _stream_flavor(sb, rows, fit_dtype):
+    """``(source, mxu, interpret, reason)`` of one super-block — THE gate
+    of the streamed scans. ``source`` names the block reader: ``"sparse"``
+    for a bucketed-nnz slab; ``"pallas"`` when the fused kernels are opted
+    in (a real TPU, or interpret mode via
+    ``config.pallas_stream_interpret``) and the PER-SHARD slab height
+    (S/D rows, what each kernel instance sees) fits the kernel's tile;
+    else ``"xla"``. ``rows`` is None for one flat weight vector (the
+    one-row kernel) or the height of the stack a dispatch carries — a
+    cohort gates at its full padded slot count, so a tile that fits the
+    top rung fits every narrower one. ``mxu`` is the resolved compute
+    dtype (config.dtype="auto" → bf16 on TPU only), ``reason`` the gate
+    that refused the kernels (None when they engaged)."""
+    from ..config import mxu_dtype
+    from ..ops.pallas_fused import (sgd_many_stream_tile, sgd_stream_tile,
+                                    stream_kernel_mode, stream_mode_reason,
+                                    stream_tile_reason)
+    from ..parallel.sparse_stream import SparseSlab
+
+    if isinstance(sb.arrays[0], SparseSlab):
+        return "sparse", None, False, "sparse-stream"
+    mxu = mxu_dtype(fit_dtype)
+    reason = stream_mode_reason()
+    if reason is not None:
+        return "xla", mxu, False, reason
+    S, d = (int(v) for v in sb.arrays[0].shape[1:])
+    D = 1 if sb.shard_counts is None else int(sb.shard_counts.shape[0])
+    S_local = S // max(D, 1)
+    tile = (sgd_stream_tile(S_local, d) if rows is None
+            else sgd_many_stream_tile(S_local, d, int(rows)))
+    reason = stream_tile_reason(S_local, tile)
+    if reason is not None:
+        return "xla", mxu, False, reason
+    return "pallas", mxu, stream_kernel_mode()[1], None
 
 
-@plan_tracked("superblock.sgd_cohort", ladder="cohort-slots")
-@partial(jax.jit, static_argnames=("loss", "mxu"), donate_argnums=(0,))
-def _sgd_cohort_sb_scan(W, idx, Xs, ys, counts, LRS, ACT, alphas,
-                        l2ws, l1ws, iflags, loss, mxu=None):
-    """K streamed block steps of a search-cohort rung in ONE scan
-    program: ``W (n_slots, d+1)`` donated full carry, ``idx (width,)``
-    the dispatch's slot gather, ``Xs/ys/counts`` the super-block
-    operands of :func:`_sgd_sb_scan`, ``LRS``/``ACT`` ``(K, width)``
-    per-model lr clock values / step-activity masks. Each step runs
-    the SINGLE ``_sgd_update_one`` definition vmapped over the rung —
-    identical updates and lr clocks to the device-resident
-    ``_sgd_cohort_scan`` over the same minibatches — and an inactive
-    (masked or padding) slot passes its weights through untouched."""
-    S = Xs.shape[1]
-    r = jnp.arange(S)
-    Wc = _cohort_gather(W, idx)
+def _stream_operands(sb):
+    """``(mesh, block, S)`` of a super-block for ``_sgd_stream_program``:
+    the mesh its blocks are batch-sharded over (None on one device), the
+    block leaves — the dense ``(K, S, d)`` stack, or a sparse slab's
+    ``(data, cols, rows)`` — and a sparse slab's static per-shard row
+    count (None for a dense stack)."""
+    from ..parallel.sparse_stream import SparseSlab
 
-    def step(Wc, Xb, yb, c, lrs, act):
-        mask = (r < c).astype(jnp.float32)
+    mesh = None if sb.shard_counts is None else sb.shard_counts.sharding.mesh
+    slab = sb.arrays[0]
+    if isinstance(slab, SparseSlab):
+        return mesh, (slab.data, slab.cols, slab.rows), slab.n_rows
+    return mesh, (slab,), None
+
+
+def _pallas_block_sums(Xb, c, yb, W, iflag, loss, mxu, interpret, codes,
+                       one_row):
+    """Raw (loss sums (R,), gradient sums (R, d+1)) of the rows of ``W``
+    over a dense block in ONE fused VMEM pass: ``fused_sgd_block_grad``
+    for one flat model, ``fused_sgd_many_block_grad`` (one (tile, R) MXU
+    matmul) for a stack. The intercept sums are iflag-free;
+    ``_sgd_many_update`` folds the flag."""
+    from ..ops.pallas_fused import (fused_sgd_block_grad,
+                                    fused_sgd_many_block_grad)
+
+    if one_row:
+        v, g = fused_sgd_block_grad(Xb, c, yb, W[0], iflag, loss, mxu=mxu,
+                                    interpret=interpret)
+        return v[None], g[None]
+    return fused_sgd_many_block_grad(Xb, c, yb, W, iflag, loss,
+                                     codes=codes, mxu=mxu,
+                                     interpret=interpret)
+
+
+@lru_cache(maxsize=64)
+def _sgd_stream_program(mesh, source, loss, cohort, n_out=None, mxu=None,
+                        interpret=False, S=None):
+    """THE streamed SGD scan: the K block steps of one super-block in ONE
+    dispatch with the weight carry donated, for a ``(mesh, block reader,
+    loss, carry)`` — cached, so every pass of a fit reuses one jitted
+    callable, tracked under its ``_STREAM_PROGRAMS`` name.
+
+    ``run(W, block, ys, counts, lrs, alpha, l2w, l1w, iflag,
+    shard_counts=None, idx=None, act=None)``. The carry is one flat model
+    ``W (d+1,)``, a one-vs-rest model ``W (n_out, d+1)`` (row c trains on
+    ``ys == c``: ``ys`` holds class codes), or with ``cohort`` a search's
+    full slot stack ``W (n_slots, d+1)`` whose ``idx (width,)`` rung is
+    gathered, stepped and scattered back. A single model takes scalar
+    hyperparameters and ``lrs (K,)`` (the host-precomputed lr clock,
+    identical to the per-block loop's ``_step_args`` sequence); a cohort
+    takes ``(width,)`` vectors and ``lrs`` / ``act (K, width)``.
+    ``block`` is what ``source`` reads: ``(Xs (K, S, d),)`` for ``"xla"``
+    (autodiff through ``_design_matvec``) and ``"pallas"``
+    (``_pallas_block_sums``), a slab's ``(data, cols, rows)`` of ``S`` rows
+    for ``"sparse"`` (autodiff through ``sparse_eta``). ``counts (K,)``
+    are the blocks' valid-row prefix counts.
+
+    Each step computes every row's raw (loss sum, gradient sum) over the
+    block's valid rows, psums them over "data" under ``mesh`` — blocks
+    staged batch-sharded, ``shard_counts (D, K)`` the per-shard counts,
+    the carry replicated: SGD's update is sequential in the blocks, so
+    each step pays one psum — and applies ``_sgd_many_update``. Where no
+    psum stands between the sums and the update (one device, an autodiff
+    reader) the step normalizes inside autodiff instead,
+    ``_sgd_update_one``'s form: the single-device scan trains the per-block
+    loop's exact updates. A row advances where its step is active and the
+    block holds rows; padding block slots and inactive slots pass through
+    untouched (a masked-empty update would still apply the l2/prox
+    terms). Returns ``(W advanced, per-step losses)``: summed over a single
+    model's rows, per slot for a cohort."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import DATA_AXIS, data_shard_spec
+
+    hp_axis = 0 if cohort else None       # per-model or shared settings
+    t_axis = None if n_out is None else 0  # shared targets or class codes
+    fold = mesh is None and source != "pallas"
+
+    def target(yb, c):
+        return yb if c is None else (yb == c).astype(jnp.float32)
+
+    def step(Wc, r, classes, hp, inp):
+        blk, yb, c_loc, c, lr, act = inp
+        alpha, l2w, l1w, iflag = hp
+        mask = (r < c_loc).astype(jnp.float32)
         nv = c.astype(jnp.float32)
+        if source != "pallas":
+            eta_of = (_sparse_eta(*blk, S) if source == "sparse"
+                      else _dense_eta(blk[0], mxu))
+        if fold:
+            def one(w, cc, lr, a, l2, l1, ifl):
+                y = target(yb, cc)
+                return _sgd_prox_update(
+                    w, lambda v: _sgd_data_sum(v, y, eta_of, mask, ifl,
+                                               loss, nv),
+                    lr, a, l2, l1)
 
-        def one(w, lr, a, l2w, l1w, ifl):
-            return _sgd_update_one(w, yb, Xb, mask, nv, lr, a, l2w,
-                                   l1w, ifl, loss, mxu=mxu)
-
-        W2, losses = jax.vmap(one, in_axes=(0, 0, 0, 0, 0, 0))(
-            Wc, lrs, alphas, l2ws, l1ws, iflags
-        )
-        keep = (act > 0) & (c > 0)
-        return jnp.where(keep[:, None], W2, Wc), losses
-
-    def scan_step(Wc, inp):
-        Xb, yb, c, lrs, act = inp
-        return step(Wc, Xb, yb, c, lrs, act)
-
-    Wc, losses = jax.lax.scan(scan_step, Wc, (Xs, ys, counts, LRS, ACT))
-    return _cohort_scatter(W, idx, Wc), losses
-
-
-@plan_tracked("pallas.sgd_cohort", ladder="cohort-slots")
-@partial(jax.jit, static_argnames=("loss", "mxu", "interpret"),
-         donate_argnums=(0,))
-def _sgd_cohort_sb_scan_pallas(W, idx, Xs, ys, counts, LRS, ACT,
-                               alphas, l2ws, l1ws, iflags, loss,
-                               mxu=None, interpret=False):
-    """Fused flavor of :func:`_sgd_cohort_sb_scan`: each block step is
-    ONE ``fused_sgd_many_block_grad`` VMEM pass serving the whole rung
-    — the same kernel the device-resident fused cohort scan uses —
-    followed by the shared ``_sgd_many_update`` epilogue and the
-    step/slot pass-through mask."""
-    from ..ops.pallas_fused import fused_sgd_many_block_grad
-
-    Wc = _cohort_gather(W, idx)
-
-    def step(Wc, Xb, yb, c, lrs, act):
-        nv = jnp.maximum(c.astype(jnp.float32), 1.0)
-        loss_sums, grads = fused_sgd_many_block_grad(
-            Xb, c, yb, Wc, iflags, loss, codes=False, mxu=mxu,
-            interpret=interpret,
-        )
-        W2, losses = _sgd_many_update(Wc, loss_sums, grads, nv, lrs,
-                                      alphas, l2ws, l1ws, iflags)
-        keep = (act > 0) & (c > 0)
-        return jnp.where(keep[:, None], W2, Wc), losses
-
-    def scan_step(Wc, inp):
-        Xb, yb, c, lrs, act = inp
-        return step(Wc, Xb, yb, c, lrs, act)
-
-    Wc, losses = jax.lax.scan(scan_step, Wc, (Xs, ys, counts, LRS, ACT))
-    return _cohort_scatter(W, idx, Wc), losses
-
-
-@_ft_sharded.lru_cache(maxsize=32)
-def _sgd_cohort_sb_scan_sharded(mesh, loss, mxu=None, fused=False,
-                                interpret=False):
-    """Data-parallel flavor of :func:`_sgd_cohort_sb_scan`: the cohort
-    scan runs INSIDE ``shard_map`` over the stream mesh's "data" axis
-    with the slot stack replicated — each block step computes every
-    slot's raw (loss-sum, gradient-sum) from purely local rows and pays
-    exactly ONE ``lax.psum`` over "data" (the stacked analog of the
-    single-model sharded scan's collective shape) before the shared
-    ``_sgd_many_update`` epilogue applies the GLOBAL update. With
-    ``fused=True`` the local raw sums come from the
-    ``fused_sgd_many_block_grad`` Pallas kernel on each device's own
-    slab — the ``.psum`` twin of the fused cohort scan (ISSUE 14 after
-    the PR-12 pattern), tracked as ``pallas.sgd_cohort.psum``."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import DATA_AXIS, data_shard_spec as spec_of
-
-    if fused:
-        from ..ops.pallas_fused import fused_sgd_many_block_grad
-
-    def body(Wc, Xs, ys, shard_counts, counts, LRS, ACT, alphas, l2ws,
-             l1ws, iflags):
-        S = Xs.shape[1]
-        r = jnp.arange(S)
-        cts_local = shard_counts[0]
-
-        def step(W, Xb, yb, c_loc, c_glob, lrs, act):
-            mask = (r < c_loc).astype(jnp.float32)
-            nv = jnp.maximum(c_glob.astype(jnp.float32), 1.0)
-            if fused:
-                vs, gs = fused_sgd_many_block_grad(
-                    Xb, c_loc, yb, W, iflags, loss, codes=False,
-                    mxu=mxu, interpret=interpret,
-                )
+            W2, losses = jax.vmap(
+                one, in_axes=(0, t_axis) + (hp_axis,) * 5
+            )(Wc, classes, lr, alpha, l2w, l1w, iflag)
+        else:
+            if source == "pallas":
+                sums = _pallas_block_sums(
+                    blk[0], c_loc, yb, Wc, iflag, loss, mxu, interpret,
+                    n_out is not None, not cohort and n_out is None)
             else:
-                def local_sums(w, ifl):
-                    # the raw UNNORMALIZED data term over this shard's
-                    # rows — `_sgd_data_loss`'s eta/loss math with the
-                    # normalizer deferred past the psum
-                    Xd = Xb if mxu is None else Xb.astype(mxu)
-                    eta = jnp.matmul(
-                        Xd, w[:-1].astype(Xd.dtype),
-                        preferred_element_type=jnp.float32,
-                    ) + w[-1] * ifl
-                    if loss == "log_loss":
-                        per = jax.nn.softplus(eta) - yb * eta
-                    elif loss == "hinge":
-                        margins = (2.0 * yb - 1.0) * eta
-                        per = jnp.maximum(0.0, 1.0 - margins)
-                    else:  # squared_error
-                        per = 0.5 * (eta - yb) ** 2
-                    return jnp.sum(per * mask)
+                sums = jax.vmap(
+                    lambda w, cc, ifl: jax.value_and_grad(
+                        lambda v: _sgd_data_sum(v, target(yb, cc), eta_of,
+                                                mask, ifl, loss))(w),
+                    in_axes=(0, t_axis, hp_axis),
+                )(Wc, classes, iflag)
+            if mesh is not None:
+                sums = jax.lax.psum(sums, DATA_AXIS)
+            W2, losses = _sgd_many_update(Wc, *sums, jnp.maximum(nv, 1.0),
+                                          lr, alpha, l2w, l1w, iflag)
+        keep = c > 0 if act is None else (act > 0) & (c > 0)
+        return jnp.where(keep[..., None], W2, Wc), losses
 
-                vs, gs = jax.vmap(
-                    lambda w, ifl: jax.value_and_grad(
-                        lambda ww: local_sums(ww, ifl)
-                    )(w)
-                )(W, iflags)
-            vs, gs = jax.lax.psum((vs, gs), DATA_AXIS)
-            W2, losses = _sgd_many_update(W, vs, gs, nv, lrs, alphas,
-                                          l2ws, l1ws, iflags)
-            keep = (act > 0) & (c_glob > 0)
-            return jnp.where(keep[:, None], W2, W), losses
-
-        def scan_step(Wc, inp):
-            Xb, yb, cl, cg, lrs, act = inp
-            return step(Wc, Xb, yb, cl, cg, lrs, act)
-
-        return jax.lax.scan(scan_step, Wc,
-                            (Xs, ys, cts_local, counts, LRS, ACT))
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def run(W, idx, Xs, ys, shard_counts, counts, LRS, ACT, alphas,
-            l2ws, l1ws, iflags):
-        xs_spec = spec_of(Xs, 1)
-        ys_spec = spec_of(ys, 1)
-        f = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), xs_spec, ys_spec, P(DATA_AXIS, None), P(),
-                      P(), P(), P(), P(), P(), P()),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-        # the rung gather/scatter runs OUTSIDE the shard_map on the
-        # replicated full carry — the compact stack crosses in as P()
-        Wc, losses = f(_cohort_gather(W, idx), Xs, ys, shard_counts,
-                       counts, LRS, ACT, alphas, l2ws, l1ws, iflags)
-        return _cohort_scatter(W, idx, Wc), losses
-
-    name = "pallas.sgd_cohort.psum" if fused \
-        else "superblock.sgd_cohort.psum"
-    return plan_tracked(name, run, ladder="cohort-slots")
-
-
-@_ft_sharded.lru_cache(maxsize=32)
-def _sgd_cohort_sb_scan_sparse(loss, S, mesh=None):
-    """Sparse flavor of :func:`_sgd_cohort_sb_scan` (the search path's
-    densify finally ends — ROADMAP 4b): K cohort block steps over
-    bucketed-nnz COO stacks in ONE donated-carry dispatch, the
-    eta/gradient built from the ``ops/sparse_kernels`` take/segment_sum
-    primitives at nnz cost. Same step/slot masks and padding-slot
-    semantics as the dense cohort scan; ``mesh`` selects the shard_map
-    twin — per-shard raw sums, ONE psum per block step, the shared
-    ``_sgd_many_update`` epilogue — tracked as
-    ``superblock.sparse.sgd_cohort.psum``."""
-    from ..ops.sparse_kernels import sparse_eta
-
-    S = int(S)
-
-    if mesh is None:
-        @partial(jax.jit, donate_argnums=(0,))
-        def run(W, idx, data, cols, rows, ys, counts, LRS, ACT,
-                alphas, l2ws, l1ws, iflags):
-            r = jnp.arange(S)
-
-            def step(Wc, db, cb, rb, yb, c, lrs, act):
-                mask = (r < c).astype(jnp.float32)
-                nv = c.astype(jnp.float32)
-
-                def one(w, lr, a, l2w, l1w, ifl):
-                    return _sgd_update_one_sparse(
-                        w, yb, db, cb, rb, S, mask, nv, lr, a, l2w,
-                        l1w, ifl, loss,
-                    )
-
-                W2, losses = jax.vmap(one, in_axes=(0,) * 6)(
-                    Wc, lrs, alphas, l2ws, l1ws, iflags
-                )
-                keep = (act > 0) & (c > 0)
-                return jnp.where(keep[:, None], W2, Wc), losses
-
-            def scan_step(Wc, inp):
-                db, cb, rb, yb, c, lrs, act = inp
-                return step(Wc, db, cb, rb, yb, c, lrs, act)
-
-            Wc, losses = jax.lax.scan(
-                scan_step, _cohort_gather(W, idx),
-                (data, cols, rows, ys, counts, LRS, ACT),
-            )
-            return _cohort_scatter(W, idx, Wc), losses
-
-        return plan_tracked("superblock.sparse.sgd_cohort", run,
-                            ladder="cohort-slots")
-
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import DATA_AXIS
-
-    def body(Wc, data, cols, rows, ys, shard_counts, counts, LRS, ACT,
-             alphas, l2ws, l1ws, iflags):
-        r = jnp.arange(S)               # LOCAL slab height
-        cts_local = shard_counts[0]
-
-        def step(Wc, db, cb, rb, yb, c_loc, c_glob, lrs, act):
-            mask = (r < c_loc).astype(jnp.float32)
-            nv = jnp.maximum(c_glob.astype(jnp.float32), 1.0)
-
-            def local_sums(w, ifl):
-                eta = sparse_eta(db, cb, rb, w[:-1], S) + w[-1] * ifl
-                return jnp.sum(
-                    _sgd_sparse_pointwise(eta, yb, loss) * mask
-                )
-
-            vs, gs = jax.vmap(
-                lambda w, ifl: jax.value_and_grad(
-                    lambda ww: local_sums(ww, ifl)
-                )(w)
-            )(Wc, iflags)
-            vs, gs = jax.lax.psum((vs, gs), DATA_AXIS)
-            W2, losses = _sgd_many_update(Wc, vs, gs, nv, lrs, alphas,
-                                          l2ws, l1ws, iflags)
-            keep = (act > 0) & (c_glob > 0)
-            return jnp.where(keep[:, None], W2, Wc), losses
-
-        def scan_step(Wc, inp):
-            db, cb, rb, yb, cl, cg, lrs, act = inp
-            return step(Wc, db, cb, rb, yb, cl, cg, lrs, act)
-
+    def scan(Wc, blk, ys, shard_counts, counts, lrs, act, hp):
+        r = jnp.arange(S if source == "sparse" else blk[0].shape[1])
+        classes = (None if n_out is None
+                   else jnp.arange(n_out, dtype=jnp.float32))
+        c_loc = counts if shard_counts is None else shard_counts[0]
         return jax.lax.scan(
-            scan_step, Wc,
-            (data, cols, rows, ys, cts_local, counts, LRS, ACT),
-        )
+            lambda Wc, inp: step(Wc, r, classes, hp, inp), Wc,
+            (blk, ys, c_loc, counts, lrs, act))
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def run(W, idx, data, cols, rows, ys, shard_counts, counts, LRS,
-            ACT, alphas, l2ws, l1ws, iflags):
-        f = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), P(None, DATA_AXIS), P(None, DATA_AXIS),
-                      P(None, DATA_AXIS), P(None, DATA_AXIS),
-                      P(DATA_AXIS, None), P(), P(), P(), P(), P(),
-                      P(), P()),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-        Wc, losses = f(_cohort_gather(W, idx), data, cols, rows, ys,
-                       shard_counts, counts, LRS, ACT, alphas, l2ws,
-                       l1ws, iflags)
-        return _cohort_scatter(W, idx, Wc), losses
+    def run(W, blk, ys, counts, lrs, alpha, l2w, l1w, iflag,
+            shard_counts=None, idx=None, act=None):
+        # the rung gather/scatter runs OUTSIDE any shard_map, on the
+        # replicated full carry
+        Wc = jnp.take(W, idx, axis=0) if cohort else \
+            W.reshape(-1, W.shape[-1])
+        args = (Wc, blk, ys, shard_counts, counts, lrs, act,
+                (alpha, l2w, l1w, iflag))
+        if mesh is None:
+            Wc, losses = scan(*args)
+        else:
+            data = jax.tree.map(lambda a: data_shard_spec(a, 1), (blk, ys))
+            Wc, losses = jax.shard_map(
+                scan, mesh=mesh,
+                in_specs=(P(), *data, P(DATA_AXIS, None), P(), P(),
+                          None if act is None else P(), P()),
+                out_specs=(P(), P()), check_vma=False,
+            )(*args)
+        if cohort:
+            return W.at[idx].set(Wc), losses
+        return Wc.reshape(W.shape), losses.sum(axis=1)
 
-    return plan_tracked("superblock.sparse.sgd_cohort.psum", run,
-                        ladder="cohort-slots")
+    name = _STREAM_PROGRAMS[source, cohort] + \
+        ("" if mesh is None else ".psum")
+    return plan_tracked(name, jax.jit(run, donate_argnums=(0,)),
+                        ladder="cohort-slots" if cohort else None)
 
 
 @partial(jax.jit, static_argnames=("n_rows",))
@@ -1144,9 +687,6 @@ def _stack_cohort_weights(models, n_slots):
     return Wh
 
 
-import functools as _functools
-
-
 def fused_blocks(X) -> tuple[int, int]:
     """(n_blocks B, rows-per-block S) of the fused-epoch grid for a
     ShardedArray: CONTIGUOUS blocks of S = padded/D rows rounded up to a
@@ -1166,7 +706,7 @@ def fused_blocks(X) -> tuple[int, int]:
     return grid_partition(X.padded_shape[0], max(data_shards(X.mesh), 1))
 
 
-@_functools.lru_cache(maxsize=32)
+@lru_cache(maxsize=32)
 def _grid_builders(mesh, B, S, dtype=None):
     """Cached block-grid programs per (mesh, grid shape), tracked as
     ``sgd.grid_x`` / ``sgd.grid_y``: pad the (n_pad, d) row-sharded array
@@ -1259,7 +799,7 @@ class _KeptGrid:
 CohortGrid = collections.namedtuple("CohortGrid", "Xr yr NV")
 
 
-@_functools.lru_cache(maxsize=32)
+@lru_cache(maxsize=32)
 def _split_builders(mesh, B, S, T, dtype=None):
     """Cached programs of an adaptive search over a resident table, per
     (mesh, shapes), tracked as ``search.split_x`` / ``search.split_y``:
@@ -1765,35 +1305,6 @@ class _SGDBase(BaseEstimator):
     # -- streamed-cohort protocol (ISSUE 14 tentpole; consumed by
     # model_selection._incremental's _StreamCohortPlane) ----------------
     @classmethod
-    def _cohort_sb_flavor(cls, sb, n_slots, fit_dtype):
-        """(fused, mxu, interpret, reason) for the streamed cohort
-        scan: :meth:`_sb_scan_flavor`'s gate with the multi-weight tile
-        — the fused kernel's (tile, n_slots) MXU matmul must fit VMEM
-        for the PADDED slot stack, since that is what every dispatch
-        actually carries."""
-        from ..config import mxu_dtype
-        from ..ops.pallas_fused import (sgd_many_stream_tile,
-                                        stream_kernel_mode,
-                                        stream_mode_reason,
-                                        stream_tile_reason)
-
-        mxu = mxu_dtype(fit_dtype)
-        reason = stream_mode_reason()
-        if reason is not None:
-            return False, mxu, False, reason
-        _, interp = stream_kernel_mode()
-        Xs = sb.arrays[0]
-        S, d = Xs.shape[1:]
-        D = sb.shard_counts.shape[0] if sb.shard_counts is not None \
-            else 1
-        S_local = int(S) // max(int(D), 1)
-        tile = sgd_many_stream_tile(S_local, int(d), int(n_slots))
-        reason = stream_tile_reason(S_local, tile)
-        if reason is not None:
-            return False, mxu, False, reason
-        return True, mxu, interp, None
-
-    @classmethod
     def _streamed_cohort_round(cls, models, stream, order, act,
                                n_slots, warm=False):
         """Advance a (possibly heterogeneous) adaptive-search cohort
@@ -1818,16 +1329,15 @@ class _SGDBase(BaseEstimator):
         all-zero activity mask (a semantic no-op), so bracket halving
         later in the search picks any rung at zero new XLA compiles.
 
-        Flavor selection mirrors the single-model ``_sb_step``: sparse
-        slabs take the ``superblock.sparse.sgd_cohort[.psum]``
-        programs, a >1-shard stream mesh the ``.psum`` twins, and the
-        fused Pallas body (``pallas.sgd_cohort[.psum]``) engages under
-        the same tile/mode gates. Returns an engagement/dispatch info
-        dict for the search's telemetry."""
+        Each dispatch is the cohort carry of ``_sgd_stream_program``
+        through the reader ``_stream_flavor`` picks at the first
+        super-block, gated at the full slot stack
+        (``superblock[.sparse].sgd_cohort`` / ``pallas.sgd_cohort``,
+        ``.psum`` over a batch-sharded stream). Returns an
+        engagement/dispatch info dict for the search's telemetry."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..observability import record_superblock_donation
-        from ..parallel.sparse_stream import SparseSlab
 
         enc = models[0]
         N = len(models)
@@ -1856,63 +1366,26 @@ class _SGDBase(BaseEstimator):
         rep = NamedSharding(stream.mesh, P())
         W = jax.device_put(_stack_cohort_weights(models, n_slots), rep)
         loss_name = enc._loss()
-        sharded = stream.sb_sharded()
         info = {"streamed": True, "n_steps": int(S_total),
                 "shards": int(stream.sb_data_shards()),
                 "sparse": bool(stream.sb_sparse()),
                 "fused": False, "fused_reason": None,
                 "dispatches": 0, "warm_dispatches": 0}
         w_bytes = int(n_slots * (d + 1)) * 4
-        state = {"flavor": None}
+        flavor = None
+
+        def program(sb):
+            mesh, blk, S = _stream_operands(sb)
+            source, mxu, interp, _ = flavor
+            return _sgd_stream_program(mesh, source, loss_name, True,
+                                       None, mxu, interp, S), blk
 
         def dispatch(W, sb, idx, lr_k, act_k):
-            pars = tuple(jnp.asarray(args[idx, j]) for j in range(4))
-            idx_d = jnp.asarray(idx)
-            lr_d, act_d = jnp.asarray(lr_k), jnp.asarray(act_k)
-            slab = sb.arrays[0]
-            if isinstance(slab, SparseSlab):
-                info["fused_reason"] = "sparse-stream"
-                if sharded:
-                    run = _sgd_cohort_sb_scan_sparse(
-                        loss_name, slab.n_rows, mesh=stream.mesh
-                    )
-                    return run(W, idx_d, slab.data, slab.cols,
-                               slab.rows, sb.arrays[1],
-                               sb.shard_counts, sb.counts, lr_d,
-                               act_d, *pars)
-                run = _sgd_cohort_sb_scan_sparse(loss_name,
-                                                 slab.n_rows)
-                return run(W, idx_d, slab.data, slab.cols, slab.rows,
-                           sb.arrays[1], sb.counts, lr_d, act_d,
-                           *pars)
-            if state["flavor"] is None:
-                # gate once at the TOP rung (max VMEM footprint): if
-                # the fused tile fits the full slot stack it fits
-                # every smaller rung
-                state["flavor"] = cls._cohort_sb_flavor(
-                    sb, n_slots, enc.fit_dtype
-                )
-                info["fused"] = state["flavor"][0]
-                info["fused_reason"] = state["flavor"][3]
-            fused, mxu, interp, _ = state["flavor"]
-            if sharded:
-                run = _sgd_cohort_sb_scan_sharded(
-                    stream.mesh, loss_name, mxu, fused=fused,
-                    interpret=interp,
-                )
-                return run(W, idx_d, sb.arrays[0], sb.arrays[1],
-                           sb.shard_counts, sb.counts, lr_d, act_d,
-                           *pars)
-            if fused:
-                return _sgd_cohort_sb_scan_pallas(
-                    W, idx_d, sb.arrays[0], sb.arrays[1], sb.counts,
-                    lr_d, act_d, *pars, loss_name, mxu=mxu,
-                    interpret=interp,
-                )
-            return _sgd_cohort_sb_scan(
-                W, idx_d, sb.arrays[0], sb.arrays[1], sb.counts,
-                lr_d, act_d, *pars, loss_name, mxu=mxu,
-            )
+            run, blk = program(sb)
+            return run(W, blk, sb.arrays[1], sb.counts, jnp.asarray(lr_k),
+                       *(jnp.asarray(args[idx, j]) for j in range(4)),
+                       shard_counts=sb.shard_counts,
+                       idx=jnp.asarray(idx), act=jnp.asarray(act_k))
 
         all_slots = np.arange(n_slots)
         pos = 0
@@ -1925,41 +1398,26 @@ class _SGDBase(BaseEstimator):
             width = _cohort_rung_of(max(len(cols), 1), n_slots)
             spare = np.setdiff1d(all_slots, cols)[: width - len(cols)]
             idx = np.concatenate([cols, spare]).astype(np.int32)
+            if flavor is None:
+                flavor = _stream_flavor(sb, n_slots, enc.fit_dtype)
+                info["fused"] = flavor[0] == "pallas"
+                info["fused_reason"] = flavor[3]
             if warm and info["dispatches"] == 0:
                 # round-1 rung warmup: every OTHER ladder width runs
                 # once over this super-block with an all-zero activity
                 # mask (weights pass through bit-identically), so the
                 # whole ladder is compiled before bracket shrinks ask
                 # for a narrower rung. Once per PROCESS per shape via
-                # the plans WarmupRegistry (ISSUE 15): a later search
-                # over the same shapes finds the programs already
-                # compiled and skips the executions — and the plans
-                # table names the rungs that minted them
-                slab0 = sb.arrays[0]
-                if not isinstance(slab0, SparseSlab) \
-                        and state["flavor"] is None:
-                    state["flavor"] = cls._cohort_sb_flavor(
-                        sb, n_slots, enc.fit_dtype
-                    )
-                    info["fused"] = state["flavor"][0]
-                    info["fused_reason"] = state["flavor"][3]
-                fl = state["flavor"] or (False, None, False, None)
-                wkey = (cls.__name__, loss_name, stream.mesh, sharded,
-                        n_slots, d, K, int(stream.block_rows),
-                        slab0.cap if isinstance(slab0, SparseSlab)
-                        else None, fl[0], str(fl[1]), fl[2])
-                # attribute warm rungs to the flavor that actually
-                # dispatches (sparse / fused / psum variants have their
-                # own program rows) — a surprise recompile must name
-                # the program that minted it, not a sibling
-                if isinstance(slab0, SparseSlab):
-                    cohort_prog = "superblock.sparse.sgd_cohort"
-                elif fl[0]:
-                    cohort_prog = "pallas.sgd_cohort"
-                else:
-                    cohort_prog = "superblock.sgd_cohort"
-                if sharded:
-                    cohort_prog += ".psum"
+                # the plans WarmupRegistry: a later search over the same
+                # shapes finds the programs already compiled and skips
+                # the executions — and the plans table names the
+                # program and rungs that minted them
+                wkey = (cls.__name__, loss_name, stream.mesh,
+                        sb.shard_counts is not None, n_slots, d, K,
+                        int(stream.block_rows),
+                        getattr(sb.arrays[0], "cap", None),
+                        flavor[0], str(flavor[1]), flavor[2])
+                cohort_prog = program(sb)[0].program_name
                 for rw in _cohort_rungs(n_slots):
                     if rw == width \
                             or plan_warmups.warmed(("cohort", wkey, rw)):
@@ -2062,155 +1520,47 @@ class _SGDBase(BaseEstimator):
         self._w = W[0]
         self._last_loss = losses[0]
 
-    def _sb_scan_flavor(self, sb):
-        """(fused, mxu, interpret, reason) for one super-block: whether
-        the Pallas fused-step scan (``pallas.sgd_step`` single-device /
-        ``pallas.sgd_step.psum`` inside the shard_map flavor — one VMEM
-        pass per block) should carry it, when opted in (real TPU, or
-        interpret mode via ``config.pallas_stream_interpret``) and the
-        PER-SHARD slab height (S/D rows — what each kernel instance
-        actually sees) fits the 128-row grid; else the XLA scan, with
-        ``reason`` naming the gate that refused (None when fused
-        engaged). ``mxu`` is the resolved compute dtype
-        (config.dtype="auto" → bf16 on TPU only); both flavors honor
-        it, and with everything off/at-default the XLA program traces
-        byte-identically to the pre-feature one."""
-        from ..config import mxu_dtype
-        from ..ops.pallas_fused import (sgd_many_stream_tile,
-                                        sgd_stream_tile,
-                                        stream_kernel_mode,
-                                        stream_mode_reason,
-                                        stream_tile_reason)
-
-        mxu = mxu_dtype(self.fit_dtype)
-        reason = stream_mode_reason()
-        if reason is not None:
-            return False, mxu, False, reason
-        _, interp = stream_kernel_mode()
-        Xs = sb.arrays[0]
-        S, d = Xs.shape[1:]
-        D = sb.shard_counts.shape[0] if sb.shard_counts is not None \
-            else 1
-        S_local = int(S) // max(int(D), 1)
-        n_out = self._n_out()
-        tile = (sgd_many_stream_tile(S_local, int(d), n_out)
-                if n_out is not None
-                else sgd_stream_tile(S_local, int(d)))
-        reason = stream_tile_reason(S_local, tile)
-        if reason is not None:
-            return False, mxu, False, reason
-        return True, mxu, interp, None
-
     def _sb_step(self, sb):
         """Advance through one SuperBlock — K minibatch steps, ONE
-        dispatch, donated weight carry. The lr clock advances exactly as
-        K ``_step_args`` calls would (``_lr_schedule`` precomputes the
-        same host values); padding slots get a placeholder lr their
-        pass-through step never reads."""
+        ``_sgd_stream_program`` dispatch, donated weight carry, through
+        the block reader ``_stream_flavor`` picks. The lr clock advances
+        exactly as K ``_step_args`` calls would (``_lr_schedule``
+        precomputes the same host values); padding slots get a
+        placeholder lr their pass-through step never reads. Over a
+        batch-sharded super-block the carry is committed replicated ONCE,
+        so every dispatch of the fit hits the same executable (and
+        donation aliases in place)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         from ..observability import record_superblock_donation
 
         k = int(sb.counts.shape[0])
         lrs = np.ones(k, np.float32)
         lrs[:sb.n_blocks] = self._lr_schedule(sb.n_blocks)
         l2w, l1w = self._penalty_weights()
-        w_bytes = int(np.prod(self._w.shape)) * 4
-        from ..parallel.sparse_stream import SparseSlab
-
-        if isinstance(sb.arrays[0], SparseSlab):
-            return self._sb_step_sparse(sb, lrs, l2w, l1w, w_bytes)
-        fused, mxu, interp, reason = self._sb_scan_flavor(sb)
-        # on record for solver_info_ (the fused-engagement audit trail
+        n_out = self._n_out()
+        source, mxu, interp, reason = _stream_flavor(sb, n_out,
+                                                     self.fit_dtype)
+        # on record for solver_info_ (the engagement audit trail
         # tpu_smoke asserts on)
-        self._fused_stream = fused
+        self._fused_stream = source == "pallas"
         self._fused_stream_reason = reason
-        if sb.shard_counts is not None:
-            # data-parallel flavor (ISSUE 9): blocks staged batch-
-            # sharded over the stream mesh; the scan runs under
-            # shard_map with the weight carry replicated and one
-            # gradient psum per block step — the per-shard raw sums
-            # coming from the fused Pallas body when the flavor gate
-            # passes (ISSUE 12). The carry is committed replicated ONCE
-            # so every dispatch of the fit hits the same executable
-            # (and donation aliases in place)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            mesh = sb.shard_counts.sharding.mesh
+        if source == "sparse":
+            self._sparse_stream = True
+        mesh, blk, S = _stream_operands(sb)
+        if mesh is not None:
             rep = NamedSharding(mesh, P())
             if getattr(self._w, "sharding", None) != rep:
                 self._w = jax.device_put(self._w, rep)
-            run = _sgd_sb_scan_sharded(mesh, self._loss(),
-                                       self._n_out(), mxu,
-                                       fused=fused, interpret=interp)
-            W, losses = run(
-                self._w, sb.arrays[0], sb.arrays[1], sb.shard_counts,
-                sb.counts, jnp.asarray(lrs), jnp.float32(self.alpha),
-                jnp.float32(l2w), jnp.float32(l1w),
-                jnp.float32(1.0 if self.fit_intercept else 0.0),
-            )
-            record_superblock_donation(w_bytes)
-            self._w = W
-            self._t += sb.n_blocks
-            self._last_loss = losses[sb.n_blocks - 1]
-            return
-        if fused:
-            W, losses = _sgd_sb_scan_pallas(
-                self._w, sb.arrays[0], sb.arrays[1], sb.counts,
-                jnp.asarray(lrs), jnp.float32(self.alpha),
-                jnp.float32(l2w), jnp.float32(l1w),
-                jnp.float32(1.0 if self.fit_intercept else 0.0),
-                self._loss(), n_out=self._n_out(), mxu=mxu,
-                interpret=interp,
-            )
-        else:
-            W, losses = _sgd_sb_scan(
-                self._w, sb.arrays[0], sb.arrays[1], sb.counts,
-                jnp.asarray(lrs), jnp.float32(self.alpha),
-                jnp.float32(l2w), jnp.float32(l1w),
-                jnp.float32(1.0 if self.fit_intercept else 0.0),
-                self._loss(), self._n_out(), mxu=mxu,
-            )
-        record_superblock_donation(w_bytes)
-        self._w = W
-        self._t += sb.n_blocks
-        self._last_loss = losses[sb.n_blocks - 1]
-
-    def _sb_step_sparse(self, sb, lrs, l2w, l1w, w_bytes):
-        """The bucketed-nnz flavor of :meth:`_sb_step` (ISSUE 13): K
-        minibatch steps over the staged sparse slab in ONE donated-carry
-        scan — eta/gradient at nnz cost, same lr clock and padding-slot
-        semantics; one gradient psum per block step under the sharded
-        flavor (the dense sharded scan's exact collective shape)."""
-        from ..observability import record_superblock_donation
-
-        slab = sb.arrays[0]
-        self._fused_stream = False
-        self._fused_stream_reason = "sparse-stream"
-        self._sparse_stream = True
-        if sb.shard_counts is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            mesh = sb.shard_counts.sharding.mesh
-            rep = NamedSharding(mesh, P())
-            if getattr(self._w, "sharding", None) != rep:
-                self._w = jax.device_put(self._w, rep)
-            run = _sgd_sb_scan_sparse(self._loss(), self._n_out(),
-                                      slab.n_rows, mesh=mesh)
-            W, losses = run(
-                self._w, slab.data, slab.cols, slab.rows, sb.arrays[1],
-                sb.shard_counts, sb.counts, jnp.asarray(lrs),
-                jnp.float32(self.alpha), jnp.float32(l2w),
-                jnp.float32(l1w),
-                jnp.float32(1.0 if self.fit_intercept else 0.0),
-            )
-        else:
-            run = _sgd_sb_scan_sparse(self._loss(), self._n_out(),
-                                      slab.n_rows)
-            W, losses = run(
-                self._w, slab.data, slab.cols, slab.rows, sb.arrays[1],
-                sb.counts, jnp.asarray(lrs), jnp.float32(self.alpha),
-                jnp.float32(l2w), jnp.float32(l1w),
-                jnp.float32(1.0 if self.fit_intercept else 0.0),
-            )
+        w_bytes = int(np.prod(self._w.shape)) * 4
+        run = _sgd_stream_program(mesh, source, self._loss(), False, n_out,
+                                  mxu, interp, S)
+        W, losses = run(
+            self._w, blk, sb.arrays[1], sb.counts, jnp.asarray(lrs),
+            jnp.float32(self.alpha), jnp.float32(l2w), jnp.float32(l1w),
+            jnp.float32(1.0 if self.fit_intercept else 0.0),
+            shard_counts=sb.shard_counts,
+        )
         record_superblock_donation(w_bytes)
         self._w = W
         self._t += sb.n_blocks

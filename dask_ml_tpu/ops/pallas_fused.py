@@ -810,7 +810,7 @@ def _mxu_cast(a, mxu):
 def sgd_objective_terms(eta, yv, loss):
     """(pointwise loss, dloss/deta) for the SGD losses — the ONE
     definition shared by the fused step kernel and any epilogue, so the
-    Pallas and autodiff (models/sgd.py::_sgd_update_one) objectives
+    Pallas and autodiff (models/sgd.py::_sgd_pointwise) objectives
     cannot diverge. ``eta``/``yv`` rank-2."""
     if loss == "log_loss":
         per = jax.nn.softplus(eta) - yv * eta

@@ -191,14 +191,14 @@ class TestFusedShardedSGD:
         np.testing.assert_allclose(res[True][1], res[False][1], atol=1e-6)
 
     def test_sharded_scan_tracked_as_pallas_psum(self):
-        from dask_ml_tpu.models.sgd import _sgd_sb_scan_sharded
+        from dask_ml_tpu.models.sgd import _sgd_stream_program
         from dask_ml_tpu.parallel.mesh import stream_data_mesh
 
         mesh = stream_data_mesh()
-        fused = _sgd_sb_scan_sharded(mesh, "log_loss", None, None,
-                                     fused=True, interpret=True)
+        fused = _sgd_stream_program(mesh, "pallas", "log_loss", False,
+                                    interpret=True)
         assert fused.program_name == "pallas.sgd_step.psum"
-        plain = _sgd_sb_scan_sharded(mesh, "log_loss", None, None)
+        plain = _sgd_stream_program(mesh, "xla", "log_loss", False)
         assert plain.program_name == "superblock.sgd_scan.psum"
 
     def test_multiclass_fused_parity(self):

@@ -485,17 +485,18 @@ def test_live_plane_adds_nothing_when_port_unset():
     import jax
     import jax.numpy as jnp
 
-    from dask_ml_tpu.models.sgd import SGDClassifier, _sgd_sb_scan
+    from dask_ml_tpu.models.sgd import (SGDClassifier,
+                                        _sgd_stream_program)
     from dask_ml_tpu.observability import live
     from dask_ml_tpu.observability._programs import unwrap
     from dask_ml_tpu.observability._spans import _span_observers
 
     def scan_jaxpr():
-        body = unwrap(_sgd_sb_scan)
+        body = unwrap(_sgd_stream_program(None, "xla", "hinge", False))
         K, S, d = 2, 8, 3
         return str(jax.make_jaxpr(
             lambda W, Xs, ys, c, lrs: body(
-                W, Xs, ys, c, lrs, 1e-4, 1.0, 0.0, 1.0, "hinge", None
+                W, (Xs,), ys, c, lrs, 1e-4, 1.0, 0.0, 1.0
             )
         )(jnp.zeros(d + 1), jnp.zeros((K, S, d)), jnp.zeros((K, S)),
           jnp.zeros(K, jnp.int32), jnp.zeros(K)))
@@ -534,16 +535,17 @@ def test_drift_plane_adds_nothing_when_disabled():
     import jax
     import jax.numpy as jnp
 
-    from dask_ml_tpu.models.sgd import SGDClassifier, _sgd_sb_scan
+    from dask_ml_tpu.models.sgd import (SGDClassifier,
+                                        _sgd_stream_program)
     from dask_ml_tpu.observability import drift
     from dask_ml_tpu.observability._programs import unwrap
 
     def scan_jaxpr():
-        body = unwrap(_sgd_sb_scan)
+        body = unwrap(_sgd_stream_program(None, "xla", "hinge", False))
         K, S, d = 2, 8, 3
         return str(jax.make_jaxpr(
             lambda W, Xs, ys, c, lrs: body(
-                W, Xs, ys, c, lrs, 1e-4, 1.0, 0.0, 1.0, "hinge", None
+                W, (Xs,), ys, c, lrs, 1e-4, 1.0, 0.0, 1.0
             )
         )(jnp.zeros(d + 1), jnp.zeros((K, S, d)), jnp.zeros((K, S)),
           jnp.zeros(K, jnp.int32), jnp.zeros(K)))

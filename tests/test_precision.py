@@ -69,7 +69,7 @@ def _sb_fixture(K=3, S=256, d=8):
 
 @pytest.mark.parametrize("loss", ["log_loss", "hinge", "squared_error"])
 def test_pallas_sgd_scan_matches_xla(loss):
-    from dask_ml_tpu.models.sgd import _sgd_sb_scan, _sgd_sb_scan_pallas
+    from dask_ml_tpu.models.sgd import _sgd_stream_program
 
     Xs, ys, counts = _sb_fixture()
     K, _, d = Xs.shape
@@ -78,9 +78,11 @@ def test_pallas_sgd_scan_matches_xla(loss):
                      .randn(d + 1).astype(np.float32) * 0.1)
     args = (counts, lrs, jnp.float32(1e-3), jnp.float32(0.7),
             jnp.float32(0.3), jnp.float32(1.0))
-    Wx, lx = _sgd_sb_scan(jnp.array(w0), Xs, ys, *args, loss, None)
-    Wp, lp = _sgd_sb_scan_pallas(jnp.array(w0), Xs, ys, *args, loss,
+    xla = _sgd_stream_program(None, "xla", loss, False)
+    pallas = _sgd_stream_program(None, "pallas", loss, False,
                                  interpret=True)
+    Wx, lx = xla(jnp.array(w0), (Xs,), ys, *args)
+    Wp, lp = pallas(jnp.array(w0), (Xs,), ys, *args)
     np.testing.assert_allclose(Wp, Wx, atol=1e-5)
     np.testing.assert_allclose(lp, lx, rtol=1e-5, atol=1e-5)
 
@@ -152,7 +154,7 @@ def test_xla_flavor_selected_and_unchanged_on_cpu():
     pallas_stream off anywhere) the streamed programs are the plain XLA
     flavors — no pallas call, no bf16 casts — so the jaxpr is
     byte-identical to the pre-feature one."""
-    from dask_ml_tpu.models.sgd import SGDClassifier, _sgd_sb_scan
+    from dask_ml_tpu.models.sgd import _sgd_stream_program, _stream_flavor
     from dask_ml_tpu.observability._programs import unwrap
     from dask_ml_tpu.ops.pallas_fused import use_stream_kernels
 
@@ -161,28 +163,28 @@ def test_xla_flavor_selected_and_unchanged_on_cpu():
     with config.set(pallas_stream=False):
         assert not use_stream_kernels()
 
-    body = unwrap(_sgd_sb_scan)
+    body = unwrap(_sgd_stream_program(None, "xla", "log_loss", False))
     K, S, d = 2, 8, 3
     jaxpr = str(jax.make_jaxpr(
         lambda W, Xs, ys, c, lrs: body(
-            W, Xs, ys, c, lrs, 1e-4, 1.0, 0.0, 1.0, "log_loss", None
+            W, (Xs,), ys, c, lrs, 1e-4, 1.0, 0.0, 1.0
         )
     )(jnp.zeros(d + 1), jnp.zeros((K, S, d)), jnp.zeros((K, S)),
       jnp.zeros(K, jnp.int32), jnp.zeros(K)))
     assert "bf16" not in jaxpr and "pallas" not in jaxpr
 
-    # the estimator-level selector picks the XLA program on this backend
-    # and says why the fused flavor was gated off
+    # the streamed scans' gate picks the XLA reader on this backend and
+    # says why the fused flavor was gated off
     class _FakeSB:
         arrays = (jnp.zeros((2, 256, 8)), jnp.zeros((2, 256)))
         counts = jnp.zeros(2, jnp.int32)
         shard_counts = None
 
-    clf = SGDClassifier()
-    fused, mxu, interp, reason = clf._sb_scan_flavor(_FakeSB())
-    assert not fused and mxu is None and reason == "off-TPU"
+    source, mxu, interp, reason = _stream_flavor(_FakeSB(), None, None)
+    assert source == "xla" and mxu is None and reason == "off-TPU"
     with config.set(pallas_stream=False):
-        assert clf._sb_scan_flavor(_FakeSB())[3] == "pallas-stream-off"
+        assert _stream_flavor(_FakeSB(), None, None)[3] \
+            == "pallas-stream-off"
 
 
 # ---------------------------------------------------------------------------

@@ -242,15 +242,15 @@ class TestStagingHardening:
         jaxpr with the chaos plane armed (fault plan + quarantine +
         retries) is byte-identical to the default-config one — every
         site and policy is host-side."""
-        from dask_ml_tpu.models.sgd import _sgd_sb_scan
+        from dask_ml_tpu.models.sgd import _sgd_stream_program
         from dask_ml_tpu.observability._programs import unwrap
 
         def scan_jaxpr():
-            body = unwrap(_sgd_sb_scan)
+            body = unwrap(_sgd_stream_program(None, "xla", "hinge", False))
             K, S, d = 2, 8, 3
             return str(jax.make_jaxpr(
                 lambda W, Xs, ys, c, lrs: body(
-                    W, Xs, ys, c, lrs, 1e-4, 1.0, 0.0, 1.0, "hinge", None
+                    W, (Xs,), ys, c, lrs, 1e-4, 1.0, 0.0, 1.0
                 )
             )(jnp.zeros(d + 1), jnp.zeros((K, S, d)), jnp.zeros((K, S)),
               jnp.zeros(K, jnp.int32), jnp.zeros(K)))

@@ -296,8 +296,7 @@ class TestTrivialMeshJaxpr:
         whether the knob is set or left at default resolution semantics
         (the mesh feature adds nothing to the trace) and contains no
         psum; the sharded program's jaxpr does psum."""
-        from dask_ml_tpu.models.sgd import (_sgd_sb_scan,
-                                            _sgd_sb_scan_sharded)
+        from dask_ml_tpu.models.sgd import _sgd_stream_program
         from dask_ml_tpu.parallel.mesh import stream_data_mesh
 
         K, S, d = 2, 96, 4
@@ -309,10 +308,9 @@ class TestTrivialMeshJaxpr:
             counts = jnp.zeros((K,), jnp.int32)
             lrs = jnp.ones((K,), jnp.float32)
             z = jnp.float32(0.0)
+            run = _sgd_stream_program(None, "xla", "log_loss", False)
             return str(jax.make_jaxpr(
-                lambda *a: _sgd_sb_scan.__wrapped__(
-                    *a, loss="log_loss", n_out=None
-                )
+                lambda W, Xs, *a: run.__wrapped__(W, (Xs,), *a)
             )(W, Xs, ys, counts, lrs, z, z, z, z))
 
         baseline = trace_xla()
@@ -323,7 +321,7 @@ class TestTrivialMeshJaxpr:
         assert "psum" not in baseline
 
         mesh = stream_data_mesh()
-        run = _sgd_sb_scan_sharded(mesh, "log_loss", None, None)
+        run = _sgd_stream_program(mesh, "xla", "log_loss", False)
         W = jnp.zeros(d + 1, jnp.float32)
         Xs = jnp.zeros((K, S, d), jnp.float32)
         ys = jnp.zeros((K, S), jnp.float32)
@@ -331,9 +329,10 @@ class TestTrivialMeshJaxpr:
         counts = jnp.zeros((K,), jnp.int32)
         lrs = jnp.ones((K,), jnp.float32)
         z = jnp.float32(0.0)
-        sharded = str(jax.make_jaxpr(run.__wrapped__)(
-            W, Xs, ys, sc, counts, lrs, z, z, z, z
-        ))
+        sharded = str(jax.make_jaxpr(
+            lambda W, Xs, ys, sc, *a: run.__wrapped__(
+                W, (Xs,), ys, *a, shard_counts=sc)
+        )(W, Xs, ys, sc, counts, lrs, z, z, z, z))
         assert "psum" in sharded
 
     def test_trivial_mesh_fit_takes_original_program(self):
