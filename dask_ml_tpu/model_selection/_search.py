@@ -16,6 +16,17 @@ ONE task graph for the whole search with two key optimizations:
    the GLM regularization ``C`` — bare, multiclass, or as a Pipeline's
    last step — solves ALL candidates in ONE compiled joint L-BFGS
    program per fold (SURVEY.md §3.4 "combos batched when homogeneous").
+   Over a resident X split by ``KFold`` the folds are not copied at all:
+   one int32 fold id a row (``_FoldIds``) marks which rows each model
+   trains on, and every (fold, C) model is one block of ONE program over
+   the one design, scored by one more (``_BaseSearchCV._fit_stacked``).
+
+A search is one root ``fit`` span with flat children — ``fit.validate``,
+``fit.folds``, ``fit.prepare`` (stacked only), ``fit.solve``,
+``fit.score``, ``fit.refit``, ``fit.finish`` — and says what carried it
+in ``search_info_`` (``path``: ``"stacked-folds"``, ``"fold-copies"`` or
+``"general"``; ``n_models``; ``fold_copies``, the gathered copies of X's
+rows).
 
 Execution: candidates run as a host loop over jitted fits. Device
 estimators share XLA compile cache across candidates (same shapes), which
@@ -24,17 +35,23 @@ is the jit-level analog of dask's task de-dup.
 
 from __future__ import annotations
 
+import functools
 import numbers
 import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 from sklearn.model_selection import ParameterGrid, ParameterSampler
 
 from ..base import BaseEstimator, clone
 from ..metrics.scorer import check_scoring, get_scorer
-from ..parallel.mesh import device_mesh, resolve_mesh, use_mesh
+from ..observability import span, track_program
+from ..parallel.mesh import DATA_AXIS, device_mesh, resolve_mesh, use_mesh
 from ..parallel.sharded import ShardedArray, take_rows
 from ._normalize import estimator_token
 from ._split import KFold
@@ -129,6 +146,11 @@ class _CVCache:
         self._splits = list(cv.split(X, y))
         self._cache = {} if cache else None
         self.n_folds = len(self._splits)
+        # what the folds cost: gathered copies of X's rows (a fold's train
+        # and test half count one each), and the bytes of every array
+        # gathered, y's included
+        self.copies = 0
+        self.nbytes = 0
 
     def fold(self, fi):
         if self._cache is not None and fi in self._cache:
@@ -138,9 +160,129 @@ class _CVCache:
             _take(self._X, train_idx), _take(self._y, train_idx),
             _take(self._X, test_idx), _take(self._y, test_idx),
         )
+        self.copies += 2
+        self.nbytes += sum(_nbytes(a) for a in out)
         if self._cache is not None:
             self._cache[fi] = out
         return out
+
+
+def _nbytes(a):
+    if isinstance(a, ShardedArray):
+        return int(a.data.nbytes)
+    return int(getattr(a, "nbytes", 0) or 0)
+
+
+@track_program("search.fold_ids")
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _contiguous_fold_ids(n_padded, cuts, sharding):
+    """Row i's contiguous fold: how many of the folds' first rows
+    ``cuts`` it has passed (padding rows fall in the last fold; every
+    reader masks them)."""
+    row = jnp.arange(n_padded, dtype=jnp.int32)
+    ids = jnp.sum(row[:, None] >= cuts[None, :], axis=1, dtype=jnp.int32)
+    return jax.lax.with_sharding_constraint(ids, sharding)
+
+
+class _FoldIds:
+    """``KFold``'s folds over a resident X as ONE int32 id a row —
+    ``ids[i] = f`` for row i in test fold f, placed like X's rows — in
+    place of ``2 x n_splits`` gathered copies: a model of fold f trains on
+    the rows whose id is not f and is scored on those whose id is. The
+    contiguous folds of an unshuffled ``KFold`` are built on the device
+    from their boundaries (no host index array); shuffled ones are one
+    host array of ids from the splitter's own order. Built once a
+    search."""
+
+    def __init__(self, cv, X):
+        n = X.n_rows
+        self._starts, self._stops = cv._bounds(n)
+        self._order = cv._order(n) if cv.shuffle else None
+        self.n_folds = len(self._stops)
+        self.n_test = [int(v) for v in self._stops - self._starts]
+        self.n_train = [n - t for t in self.n_test]
+        if self._order is None:
+            self.ids = _contiguous_fold_ids(
+                X.padded_shape[0], np.asarray(self._stops[:-1], np.int32),
+                NamedSharding(X.mesh, P(DATA_AXIS)))
+        else:
+            ids = np.empty(n, np.int32)
+            for f, (lo, hi) in enumerate(zip(self._starts, self._stops)):
+                ids[self._order[lo:hi]] = f
+            self.ids = ShardedArray.from_array(ids, mesh=X.mesh).data
+        self.nbytes = int(self.ids.nbytes)
+
+    def rows(self, f):
+        """(train, test) host row indices of fold ``f``, as
+        ``KFold.split`` yields them."""
+        lo, hi = self._starts[f], self._stops[f]
+        idx = np.arange(self._stops[-1]) if self._order is None \
+            else self._order
+        return np.concatenate([idx[:lo], idx[hi:]]), idx[lo:hi]
+
+
+def _placement(mesh):
+    """Run on ``mesh`` where a multi-process search placed this process's
+    trials; else where the caller is."""
+    import contextlib
+
+    return use_mesh(mesh) if mesh is not None else contextlib.nullcontext()
+
+
+def _pure_C_grid(candidates, c_key, fit_params):
+    """The grid's ``C`` values where every candidate sets ``c_key`` alone
+    to a positive number (two or more of them, no fit params, one
+    process); else None."""
+    from ..parallel import distributed as _dist
+
+    if (fit_params or _dist.process_count() > 1 or len(candidates) < 2
+            or any(set(p) != {c_key} for p in candidates)):
+        return None
+    Cs = [p[c_key] for p in candidates]
+    if not all(isinstance(c, numbers.Real) and c > 0 for c in Cs):
+        return None
+    return Cs
+
+
+def _fast_path_failed(exc, info, copies, nbytes):
+    """A stacked C-grid path raised ``exc``. Out of memory, it raises on:
+    the per-candidate path would hold the same folds and more, so it is
+    no way out. Anything else falls back to the per-candidate fits, but
+    LOUDLY — a fast-path defect must be diagnosable, not hidden behind a
+    silent many-fold-cost refit — and on record in
+    ``info["fallbacks"]``."""
+    import warnings
+
+    if _exhausted(exc):
+        raise MemoryError(
+            f"the C-grid search ran out of memory with {copies} fold "
+            f"copies of X's rows made ({nbytes} bytes of fold arrays "
+            f"gathered): {type(exc).__name__}: {exc}") from exc
+    warnings.warn(
+        f"C-grid fast path failed ({type(exc).__name__}: {exc}); "
+        "falling back to per-candidate fits", RuntimeWarning,
+    )
+    info.setdefault("fallbacks", []).append(f"{type(exc).__name__}: {exc}")
+
+
+def _exhausted(exc):
+    """Whether ``exc`` is the device (or host) running out of memory."""
+    return isinstance(exc, MemoryError) or "RESOURCE_EXHAUSTED" in str(exc)
+
+
+def _accuracy_only(est, scorers):
+    """Whether every scorer is a binary classifier's accuracy — the
+    registry's ``accuracy`` or the default ``est.score`` of a
+    ``LogisticRegression`` — so that one program can score every stacked
+    model from its labels alone (``glm.grid_score``)."""
+    from ..metrics.scorer import SCORERS, _default_scorer
+    from ..models.glm import LogisticRegression
+
+    return isinstance(est, LogisticRegression) and all(
+        sc is SCORERS["accuracy"]
+        or (sc is _default_scorer
+            and type(est).score is LogisticRegression.score)
+        for sc in scorers.values())
 
 
 
@@ -274,10 +416,12 @@ class _BaseSearchCV(BaseEstimator):
             clear_host_fold_cache()
 
     def _try_C_grid_fast(self, candidates, cache, scorers, scores,
-                         train_scores, n_folds, fit_params, memo):
-        """True iff every (candidate, fold) score was filled by the
-        stacked C-grid solve; False leaves the grids NaN-reset for the
-        general path.
+                         train_scores, n_folds, fit_params, memo, info):
+        """True iff every (candidate, fold) score was filled by a
+        one-fold stacked C-grid solve over each fold's copies (the path
+        where ``_fit_stacked`` does not apply: a Pipeline, a splitter
+        whose test sets need not partition X, more than two classes);
+        False leaves the grids NaN-reset for the general path.
 
         Two eligible shapes: a bare GLM with a pure-``C`` grid, and a
         Pipeline whose LAST step is a GLM with a pure ``<last>__C``
@@ -299,8 +443,6 @@ class _BaseSearchCV(BaseEstimator):
         ``info["n_iter_per_candidate"]``), so convergence diagnostics
         distinguish fast candidates from the slowest one instead of all
         clones echoing the joint budget."""
-        from ..parallel import distributed as _dist
-
         from ..models.glm import _GLMBase
 
         est = self.estimator
@@ -325,11 +467,8 @@ class _BaseSearchCV(BaseEstimator):
             glm = est
         else:
             return False
-        if (fit_params or _dist.process_count() > 1 or len(candidates) < 2
-                or any(set(p) != {c_key} for p in candidates)):
-            return False
-        Cs = [p[c_key] for p in candidates]
-        if not all(isinstance(c, numbers.Real) and c > 0 for c in Cs):
+        Cs = _pure_C_grid(candidates, c_key, fit_params)
+        if Cs is None:
             return False
         def reset():
             for grid in (scores, train_scores or {}):
@@ -359,37 +498,251 @@ class _BaseSearchCV(BaseEstimator):
                         for name, sc in scorers.items():
                             train_scores[name][ci, fi] = sc(m, Xtr, ytr)
         except Exception as exc:
-            import warnings
-
-            # fall back, but LOUDLY: a genuine fast-path defect must be
-            # diagnosable, not hidden behind a silent 2x-cost refit
-            warnings.warn(
-                f"C-grid fast path failed ({type(exc).__name__}: {exc}); "
-                "falling back to per-candidate fits", RuntimeWarning,
-            )
-            self._c_grid_fallback_ = repr(exc)
+            _fast_path_failed(exc, info, cache.copies, cache.nbytes)
             reset()
             return False
         self._c_grid_vmapped_ = len(Cs)
         return True
 
+    def _fit_stacked(self, X, y, cv, candidates, scorers, multimetric,
+                     fit_params, root, info):
+        """The fold-stacked C grid over X placed once (``_FoldIds``): ONE
+        ``glm.prepare`` of X, ONE stacked program of every (fold, C)
+        model (``glm.lbfgs_lam_grid``), ONE scoring program and fetch for
+        an accuracy scorer (``glm.grid_score``) — other scorers score
+        clones on one gathered test fold at a time. True when it ran;
+        False (before any device work, or after the label scan finds
+        more than two classes: the one-vs-rest arm stays per fold) when
+        the shape is not its, with the reason in ``info["why"]``."""
+        from ..models.glm import _GLMBase, grid_hits
+        from ..parallel.streaming import _is_sparse_source, stream_plan
+        from ..utils.validation import check_X_y
+
+        est = self.estimator
+        Cs = _pure_C_grid(candidates, "C", fit_params)
+        why = None
+        if not isinstance(est, _GLMBase):
+            why = "not a bare GLM"
+        elif _is_sparse_source(X) or stream_plan(X) is not None:
+            why = "X is sparse or streams"
+        elif not isinstance(cv, KFold):
+            why = f"{type(cv).__name__}'s test sets need not partition X"
+        elif not est._grid_eligible():
+            why = "the estimator's solve is not the stacked lbfgs"
+        elif Cs is None:
+            why = "the grid is not C alone"
+        if why is not None:
+            info["why"] = why
+            return False
+        with span("fit.folds") as sp:
+            if not isinstance(X, ShardedArray):
+                # a host X is placed ONCE, as a fit would place it
+                X, y = check_X_y(X, y, mesh=resolve_mesh(None),
+                                 dtype=np.float32)
+            folds = _FoldIds(cv, X)
+            sp.add(fold_copies=0, fold_id_bytes=folds.nbytes,
+                   n_folds=folds.n_folds)
+        with span("fit.prepare") as sp:
+            prep = est._grid_prepare(X, y, binary_only=True)
+            if prep.multiclass:
+                info["why"] = "more than two classes"
+                return False
+        K, F = len(Cs), folds.n_folds
+        with span("fit.solve") as sp:
+            B, sinfo = est._grid_blocks(prep, Cs, folds.n_train, folds.ids)
+            prep.data = None         # the design is not read again
+            per = sinfo["n_iter_per_candidate"]
+            sp.add(n_iter=sinfo["n_iter"], n_evals=sinfo["n_evals"],
+                   n_models=K * F, n_iter_min=min(per), n_iter_max=max(per))
+        root.add(n_iter=sinfo["n_iter"])
+        shape = (F, K)
+        with span("fit.score") as sp:
+            if _accuracy_only(est, scorers):
+                hits = grid_hits(prep, B, folds.ids, F)
+                n_test = np.asarray(folds.n_test, np.float64)[:, None]
+                n_train = np.asarray(folds.n_train, np.float64)[:, None]
+                test = (hits[0].reshape(shape) / n_test).T        # (K, F)
+                scores = {name: test.copy() for name in scorers}
+                train_scores = {
+                    name: (hits[1].reshape(shape) / n_train).T
+                    for name in scorers} if self.return_train_score \
+                    else None
+                scored = "program"
+            else:
+                scores, train_scores = self._score_clones(
+                    X, y, est, Cs, B, sinfo, prep, folds, scorers, info)
+                scored = "scorer"
+            sp.add(scored=scored, fold_copies=info["fold_copies"])
+            self._publish(candidates, scores, train_scores, multimetric,
+                          F, scorers)
+        self._c_grid_vmapped_ = K
+        info.update(path="stacked-folds", n_iter=sinfo["n_iter"],
+                    n_evals=sinfo["n_evals"], n_iter_min=min(per),
+                    n_iter_max=max(per), fold_id_bytes=folds.nbytes,
+                    intercept=est._intercept_form(),
+                    fit_dtype=prep.fit_dtype, scored=scored,
+                    # every (candidate, fold) model, the intercept last
+                    betas=B.reshape(F, K, -1).transpose(1, 0, 2))
+        return True
+
+    def _score_clones(self, X, y, est, Cs, B, sinfo, prep, folds, scorers,
+                      info):
+        """Scores of a stacked grid by scorers that need a fitted model and
+        its rows: fold by fold, the fold's test rows (and train rows, where
+        asked) gathered once and dropped after its ``len(Cs)`` clones."""
+        K = len(Cs)
+        shape = (len(Cs), folds.n_folds)
+        scores = {name: np.full(shape, np.nan) for name in scorers}
+        train_scores = {name: np.full(shape, np.nan) for name in scorers} \
+            if self.return_train_score else None
+        finish = est._grid_finish(prep.classes, X.shape[1])
+        per = sinfo["n_iter_per_candidate"]
+        for fi in range(folds.n_folds):
+            rows = slice(fi * K, (fi + 1) * K)
+            models = est._grid_fitted(
+                Cs, B[rows], {**sinfo, "n_iter_per_candidate": per[rows]},
+                finish)
+            train_idx, test_idx = folds.rows(fi)
+            parts = [(scores, test_idx)]
+            if train_scores is not None:
+                parts.append((train_scores, train_idx))
+            for grid, idx in parts:
+                Xf, yf = _take(X, idx), _take(y, idx)
+                info["fold_copies"] += 1
+                for ci, m in enumerate(models):
+                    for name, sc in scorers.items():
+                        grid[name][ci, fi] = sc(m, Xf, yf)
+                del Xf, yf
+        return scores, train_scores
+
     def _fit(self, X, y=None, **fit_params):
         # per-fit diagnostics must not survive a re-fit that takes a
         # different path (same policy as _memo_stats, which is re-set)
-        for attr in ("_c_grid_vmapped_", "_c_grid_fallback_"):
-            if hasattr(self, attr):
-                delattr(self, attr)
-        candidates = list(self._candidates())
-        if not candidates:
-            raise ValueError("no parameter candidates")
-        cv = check_cv(self.cv)
-        scorers, multimetric = _resolve_scorers(
-            self.estimator, self.scoring, self.refit
-        )
-        cache = _CVCache(X, y, cv, cache=self.cache_cv)
-        memo = _PrefixMemo()
-        n_folds = cache.n_folds
+        if hasattr(self, "_c_grid_vmapped_"):
+            del self._c_grid_vmapped_
+        from ..parallel.streaming import _n_rows_of
 
+        with span("fit", component=type(self).__name__) as root:
+            with span("fit.validate"):
+                candidates = list(self._candidates())
+                if not candidates:
+                    raise ValueError("no parameter candidates")
+                cv = check_cv(self.cv)
+                self._resolve_execution(1)   # the knobs, whatever path
+                scorers, multimetric = _resolve_scorers(
+                    self.estimator, self.scoring, self.refit
+                )
+                n_rows = X.n_rows if isinstance(X, ShardedArray) \
+                    else _n_rows_of(X)
+            root.add(n_rows=n_rows)
+            info = {"path": "general", "fold_copies": 0}
+            memo = _PrefixMemo()
+            dist_mesh = None
+            try:
+                stacked = self._fit_stacked(X, y, cv, candidates, scorers,
+                                            multimetric, fit_params, root,
+                                            info)
+            except Exception as exc:
+                _fast_path_failed(exc, info, info["fold_copies"], 0)
+                stacked = False
+            if stacked:
+                n_folds = self.n_splits_
+            else:
+                with span("fit.folds") as sp:
+                    cache = _CVCache(X, y, cv, cache=self.cache_cv)
+                    n_folds = cache.n_folds
+                    sp.add(n_folds=n_folds)
+                with span("fit.solve") as sp:
+                    scores, train_scores, dist_mesh = self._fit_folds(
+                        X, y, candidates, cache, scorers, fit_params, memo,
+                        info)
+                    info["fold_copies"] = cache.copies
+                    sp.add(fold_copies=cache.copies)
+                with span("fit.score"):
+                    self._publish(candidates, scores, train_scores,
+                                  multimetric, n_folds, scorers)
+            info.update(n_candidates=len(candidates), n_folds=n_folds,
+                        n_models=len(candidates) * n_folds)
+            root.add(n_models=info["n_models"],
+                     fold_copies=info["fold_copies"], path=info["path"])
+            if self.refit:
+                # multi-process: every process refits identically on its
+                # local mesh (cv_results_ are identical everywhere, so
+                # best_params_ agree) — no cross-host program, consistent
+                # final state. The refit is the estimator's own public fit,
+                # its spans kept in this one's ring record (``fold_nested``:
+                # the ring's roots and their children stay the search's);
+                # a stacked search's design was released with its solve, so
+                # the refit prepares its own exactly as a plain fit does
+                with span("fit.refit", fold_nested=True), \
+                        _placement(dist_mesh):
+                    est = clone(self.estimator).set_params(
+                        **self.best_params_)
+                    est.fit(X, y, **fit_params)
+                self.best_estimator_ = est
+            with span("fit.finish"):
+                self._memo_stats = (memo.hits, memo.misses)
+                self.search_info_ = info
+        return self
+
+    def _publish(self, candidates, scores, train_scores, multimetric,
+                 n_folds, scorers):
+        """``cv_results_`` and the winner from the (candidate, fold)
+        score grids."""
+        results = {"params": candidates}
+        means = {}
+        for name, arr in scores.items():
+            suffix = name if multimetric else "score"
+            mean = arr.mean(axis=1)
+            means[name] = mean
+            order = np.argsort(-mean, kind="stable")
+            ranks = np.empty(len(candidates), np.int32)
+            ranks[order] = np.arange(1, len(candidates) + 1)
+            results[f"mean_test_{suffix}"] = mean
+            results[f"std_test_{suffix}"] = arr.std(axis=1)
+            results[f"rank_test_{suffix}"] = ranks
+            for fi in range(n_folds):
+                results[f"split{fi}_test_{suffix}"] = arr[:, fi]
+            if self.return_train_score:
+                tarr = train_scores[name]
+                results[f"mean_train_{suffix}"] = tarr.mean(axis=1)
+                results[f"std_train_{suffix}"] = tarr.std(axis=1)
+                for fi in range(n_folds):
+                    results[f"split{fi}_train_{suffix}"] = tarr[:, fi]
+        for key in sorted({k for p in candidates for k in p}):
+            results[f"param_{key}"] = np.ma.masked_all(
+                len(candidates), dtype=object
+            )
+            for ci, p in enumerate(candidates):
+                if key in p:
+                    results[f"param_{key}"][ci] = p[key]
+        self.cv_results_ = results
+        # selection metric: the single scorer, or the refit-named one
+        # (sklearn contract: multimetric + refit=False sets no best_*)
+        sel = self.refit if multimetric else "score"
+        if sel in means:
+            sel_mean = means[sel]
+            # near-tie deterministic winner (see class ``tie_tol`` note):
+            # earliest candidate within tie_tol of the best — identical
+            # across the stacked C-grid and per-candidate execution paths
+            # when their scores differ only by sub-solver-tol noise
+            best = np.nanmax(sel_mean) if np.isfinite(sel_mean).any() \
+                else np.nan
+            tied = np.flatnonzero(sel_mean >= best - float(self.tie_tol))
+            self.best_index_ = (int(tied[0]) if tied.size
+                                else int(np.argmax(sel_mean)))
+            self.best_score_ = float(sel_mean[self.best_index_])
+            self.best_params_ = candidates[self.best_index_]
+        self.n_splits_ = n_folds
+        self.scorer_ = scorers if multimetric else scorers["score"]
+        self.multimetric_ = multimetric
+
+    def _fit_folds(self, X, y, candidates, cache, scorers, fit_params, memo,
+                   info):
+        """Every (candidate, fold) score over fold copies: the one-fold C
+        grid where it applies, else the per-candidate fits. Returns
+        ``(scores, train_scores, dist_mesh)``."""
+        n_folds = cache.n_folds
         scores = {name: np.full((len(candidates), n_folds), np.nan)
                   for name in scorers}
         train_scores = (
@@ -425,12 +778,14 @@ class _BaseSearchCV(BaseEstimator):
         # when homogeneous'): a grid varying ONLY C over a device GLM
         # solves every candidate in ONE stacked-lam L-BFGS program per
         # fold — one X pass per iteration for the whole grid. Any
-        # failure (or ineligible shape) resets the score grid and falls
-        # back to the general per-candidate machinery, where
-        # error_score= applies.
+        # failure but running out of memory (or an ineligible shape)
+        # resets the score grid and falls back to the general
+        # per-candidate machinery, where error_score= applies.
         if self._try_C_grid_fast(candidates, cache, scorers, scores,
-                                 train_scores, n_folds, fit_params, memo):
+                                 train_scores, n_folds, fit_params, memo,
+                                 info):
             tasks = []
+            info["path"] = "fold-copies"
 
         # Multi-process distribution (SURVEY.md §3.5 'trials pinned to
         # hosts', §5 comm row): under a live jax.distributed runtime each
@@ -461,12 +816,6 @@ class _BaseSearchCV(BaseEstimator):
                 len(my_tasks), len(tasks), _dist.process_index(), n_proc
             )
 
-        def _placement():
-            import contextlib
-
-            return use_mesh(dist_mesh) if dist_mesh is not None \
-                else contextlib.nullcontext()
-
         def _sync_failures(exc):
             """Exchange failure state so an exception on ONE process fails
             ALL of them fast — peers must not block forever in the merge
@@ -495,7 +844,7 @@ class _BaseSearchCV(BaseEstimator):
             exc = None
 
             def __enter__(self):
-                self._cm = _placement()
+                self._cm = _placement(dist_mesh)
                 self._cm.__enter__()
                 return self
 
@@ -639,64 +988,7 @@ class _BaseSearchCV(BaseEstimator):
                 train_scores = {name: merge(a)
                                 for name, a in train_scores.items()}
 
-        results = {"params": candidates}
-        means = {}
-        for name, arr in scores.items():
-            suffix = name if multimetric else "score"
-            mean = arr.mean(axis=1)
-            means[name] = mean
-            order = np.argsort(-mean, kind="stable")
-            ranks = np.empty(len(candidates), np.int32)
-            ranks[order] = np.arange(1, len(candidates) + 1)
-            results[f"mean_test_{suffix}"] = mean
-            results[f"std_test_{suffix}"] = arr.std(axis=1)
-            results[f"rank_test_{suffix}"] = ranks
-            for fi in range(n_folds):
-                results[f"split{fi}_test_{suffix}"] = arr[:, fi]
-            if self.return_train_score:
-                tarr = train_scores[name]
-                results[f"mean_train_{suffix}"] = tarr.mean(axis=1)
-                results[f"std_train_{suffix}"] = tarr.std(axis=1)
-                for fi in range(n_folds):
-                    results[f"split{fi}_train_{suffix}"] = tarr[:, fi]
-        for key in sorted({k for p in candidates for k in p}):
-            results[f"param_{key}"] = np.ma.masked_all(
-                len(candidates), dtype=object
-            )
-            for ci, p in enumerate(candidates):
-                if key in p:
-                    results[f"param_{key}"][ci] = p[key]
-        self.cv_results_ = results
-        # selection metric: the single scorer, or the refit-named one
-        # (sklearn contract: multimetric + refit=False sets no best_*)
-        sel = self.refit if multimetric else "score"
-        if sel in means:
-            sel_mean = means[sel]
-            # near-tie deterministic winner (see class ``tie_tol`` note):
-            # earliest candidate within tie_tol of the best — identical
-            # across the stacked C-grid and per-candidate execution paths
-            # when their scores differ only by sub-solver-tol noise
-            best = np.nanmax(sel_mean) if np.isfinite(sel_mean).any() \
-                else np.nan
-            tied = np.flatnonzero(sel_mean >= best - float(self.tie_tol))
-            self.best_index_ = (int(tied[0]) if tied.size
-                                else int(np.argmax(sel_mean)))
-            self.best_score_ = float(sel_mean[self.best_index_])
-            self.best_params_ = candidates[self.best_index_]
-        self.n_splits_ = n_folds
-        self.scorer_ = scorers if multimetric else scorers["score"]
-        self.multimetric_ = multimetric
-        self._memo_stats = (memo.hits, memo.misses)
-
-        if self.refit:
-            # multi-process: every process refits identically on its local
-            # mesh (cv_results_ are identical everywhere, so best_params_
-            # agree) — no cross-host program, consistent final state
-            with _placement():
-                est = clone(self.estimator).set_params(**self.best_params_)
-                est.fit(X, y, **fit_params)
-            self.best_estimator_ = est
-        return self
+        return scores, train_scores, dist_mesh
 
     # -- delegation to best_estimator_ ------------------------------------
     def _check_refit(self, method):

@@ -253,21 +253,32 @@ class KFold:
         self.shuffle = shuffle
         self.random_state = random_state
 
-    def split(self, X, y=None, groups=None):
-        from ..parallel.streaming import _n_rows_of
+    def _order(self, n):
+        """The row order the folds are cut from: ``arange(n)``, shuffled
+        by ``random_state`` where asked."""
+        idx = np.arange(n)
+        if self.shuffle:
+            np.random.RandomState(self.random_state).shuffle(idx)
+        return idx
 
-        n = X.n_rows if isinstance(X, ShardedArray) else _n_rows_of(X)
+    def _bounds(self, n):
+        """(starts, stops) of the test folds in :meth:`_order`: the first
+        ``n % n_splits`` folds one row longer."""
         if self.n_splits > n:
             raise ValueError(
                 f"n_splits={self.n_splits} > n_samples={n}"
             )
-        idx = np.arange(n)
-        if self.shuffle:
-            np.random.RandomState(self.random_state).shuffle(idx)
         sizes = np.full(self.n_splits, n // self.n_splits)
         sizes[: n % self.n_splits] += 1
         stops = np.cumsum(sizes)
-        starts = stops - sizes
+        return stops - sizes, stops
+
+    def split(self, X, y=None, groups=None):
+        from ..parallel.streaming import _n_rows_of
+
+        n = X.n_rows if isinstance(X, ShardedArray) else _n_rows_of(X)
+        starts, stops = self._bounds(n)
+        idx = self._order(n)
         for lo, hi in zip(starts, stops):
             test = idx[lo:hi]
             train = np.concatenate([idx[:lo], idx[hi:]])

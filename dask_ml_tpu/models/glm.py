@@ -222,6 +222,44 @@ def predict_resident(est, X, link):
                                getattr(est, "classes_", None))
 
 
+@track_program("glm.grid_score")
+@_partial(jax.jit, static_argnames=("n_folds",))
+def _grid_hits(data, y_enc, mask, fold_id, B, n_folds):
+    """Right answers of every block of a fold-stacked C grid in ONE
+    program over the resident float32 X: ``(2, blocks)`` int32, the
+    block's hits on its fold's test rows and on its training rows. Block
+    ``j = f * k + c`` (``solvers._lam_grid_body``) is scored where
+    ``fold_id == f``; its answer is ``LogisticRegression.predict``'s —
+    ``sigmoid(eta) > 0.5`` picks ``classes_[1]`` — at predict's
+    precision, float32 products (``HIGHEST``: the ``(blocks, d)`` stack
+    rides the MXU, where one row's matvec is f32 multiplies); ``y_enc``
+    is ``glm.prepare``'s 0 / 1 encoding of y."""
+    d = data.shape[1]
+    eta = jax.lax.dot_general(
+        B[:, :d], data, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )                                                          # (m, n)
+    if B.shape[1] > d:
+        eta = eta + B[:, d:]
+    right = (LINK_TAILS["proba"](eta) > 0.5) == (y_enc > 0.5)[None, :]
+    fold = jnp.arange(B.shape[0]) // (B.shape[0] // n_folds)
+    rows = (mask > 0)[None, :]
+    test = (fold_id[None, :] == fold[:, None]) & rows
+    return jnp.stack([jnp.sum(right & test, axis=1, dtype=jnp.int32),
+                      jnp.sum(right & rows & ~test, axis=1,
+                              dtype=jnp.int32)])
+
+
+def grid_hits(prep, B, fold_id, n_folds):
+    """Host ``(2, blocks)`` hits of ``_grid_hits`` for the rows ``B`` of a
+    fold-stacked solve over ``prep`` (``_GLMBase._grid_prepare``): one
+    dispatch and one fetch, the wait on the open span."""
+    out = _grid_hits(prep.X.data, prep.y_data, prep.mask, fold_id,
+                     np.asarray(B, np.float32), n_folds=n_folds)
+    return to_host(current_span().sync(out))
+
+
 @jax.jit
 def _matvec_eta_multi(data, coef, intercept):
     """(n, C) decision values against stacked OvR coefficients (C, d)."""
@@ -330,39 +368,41 @@ class _GLMBase(BaseEstimator):
         family overrides it (other families have no multiclass fit)."""
         return None
 
-    def _run_C_grid(self, X, Cs, d, solve_fn, finish, **log_fields):
-        """Shared tail of BOTH C-grid arms: per-C (pmask, lam) through
-        _penalty_setup (the ONE place the regularization bookkeeping
-        lives), one logged stacked solve, then fitted clones in ``Cs``
-        order. ``solve_fn(lams, pmask) -> (B, info)``;
-        ``finish(est, B_i, info)`` publishes one candidate's result."""
-        from ..base import clone
+    def _run_C_grid(self, X, Cs, solve_fn, finish, form, **log_fields):
+        """Shared tail of BOTH one-fold C-grid arms: one logged stacked
+        solve, then fitted clones in ``Cs`` order (``_grid_fitted``).
+        ``solve_fn() -> (B, info)``; ``finish(est, B_i, info)`` publishes
+        one candidate's result; ``form`` is where the program kept the
+        intercept (``_intercept_form``)."""
         from ..observability import fit_logger
 
-        per_c = [clone(self).set_params(C=c)._penalty_setup(d, X.n_rows)
-                 for c in Cs]
-        pmask = per_c[0][0]
-        lams = [lam for _, lam in per_c]
         with span("fit", component=type(self).__name__, solver=self.solver,
                   n_rows=X.n_rows, lam_grid=len(Cs)) as sp, \
                 fit_logger(type(self).__name__, solver=self.solver,
                            n_rows=X.n_rows, lam_grid=len(Cs),
                            **log_fields) as logger:
-            B, info = solve_fn(lams, pmask)
-            sp.add(n_iter=info.get("n_iter"))
+            B, info = solve_fn()
+            sp.add(n_iter=info.get("n_iter"), n_evals=info.get("n_evals"))
             if logger is not None:
                 logger.log(step=info.get("n_iter"), summary=True,
                            **{k: v for k, v in info.items()
                               if isinstance(v, (int, float))})
+        info["intercept"] = form
+        return self._grid_fitted(Cs, B, info, finish)
+
+    def _grid_fitted(self, Cs, B, info, finish):
+        """Fitted clones in ``Cs`` order from the rows ``B`` of a stacked
+        solve, ``finish(est, B_i, info_i)`` publishing each."""
+        from ..base import clone
+        from ..config import mxu_dtype
+
         B = np.asarray(B, np.float64)
         per_cand = info.get("n_iter_per_candidate")
         # the C-grid design was prepared under the same rule as the
         # plain lbfgs fit (to_bf16 = resolved mxu dtype; the fast path
         # is lbfgs-only) — every fitted clone records the precision it
         # actually trained at
-        from ..config import mxu_dtype as _mxu
-
-        dt_label = "bfloat16" if _mxu(self.fit_dtype) is not None \
+        dt_label = "bfloat16" if mxu_dtype(self.fit_dtype) is not None \
             else "float32"
         fitted = []
         for i, c in enumerate(Cs):
@@ -374,7 +414,6 @@ class _GLMBase(BaseEstimator):
             # the joint budget stays readable as
             # max(solver_info_["n_iter_per_candidate"])
             info_i = dict(info)
-            info_i["intercept"] = self._intercept_form(stacked=True)
             if per_cand is not None:
                 info_i["n_iter"] = int(per_cand[i])
             # a sparse fold the fast path densified under the byte
@@ -446,8 +485,9 @@ class _GLMBase(BaseEstimator):
         solver that touches X through ``_select_loss`` alone, and ADMM,
         whose local Newton step borders its Hessian itself);
         ``"column"`` — a ones column appended to X (Newton, whose
-        Hessian indexes it; the ``stacked`` one-vs-rest and C-grid
-        programs); ``"none"``."""
+        Hessian indexes it; the ``stacked`` one-vs-rest programs, the
+        C grid's among them); ``"none"``. The binary C grid takes the
+        scalar form (``solvers._lam_grid_body``)."""
         if not self.fit_intercept:
             return "none"
         scalar = not stacked and self.solver in SCALAR_INTERCEPT_SOLVERS
@@ -579,23 +619,30 @@ class _GLMBase(BaseEstimator):
         self._last_stream_stats = getattr(stream, "stats", None)
         return self._finish_fit(beta, classes, info, d_feat)
 
-    def _fit_C_grid(self, X, y, Cs):
-        """Fit ``len(Cs)`` clones differing only in ``C`` as ONE
-        stacked-lam L-BFGS program over a shared design matrix
-        (GridSearchCV's homogeneous-trial fast path; SURVEY.md §3.4).
-        Returns the fitted clones in ``Cs`` order, or None when this fit
-        shape isn't eligible (caller falls back to per-candidate
-        fits)."""
-        from ..parallel.streaming import stream_plan
+    def _grid_eligible(self):
+        """Whether this estimator's C grid can run as the stacked lbfgs
+        program. class_weight != None is an ELIGIBILITY bail, not a
+        raise: the caller's general path re-runs est.fit(), which raises
+        the clean unsupported-param error instead of a fast-path
+        warning."""
+        return (self.solver == "lbfgs" and self.penalty in ("l2", "none")
+                and not self.solver_kwargs and not self.warm_start
+                and self.class_weight is None)
 
-        # class_weight != None is an ELIGIBILITY bail, not a raise: the
-        # caller's general path re-runs est.fit(), which raises the
-        # clean unsupported-param error instead of a fast-path warning
-        if (self.solver != "lbfgs" or self.penalty not in ("l2", "none")
-                or self.solver_kwargs or self.warm_start
-                or self.class_weight is not None):
-            return None
-        from ..parallel.streaming import _is_sparse_source
+    def _grid_prepare(self, X, y, binary_only=False):
+        """ONE ``check_X_y`` and ONE cast of ``X`` for a stacked C grid,
+        over every fold it will train: the design in the fit dtype at X's
+        own width (the intercept is each block's last beta entry,
+        ``_lam_grid_body``), after the label scan (``glm.prepare`` of the
+        labels alone) has encoded the labels. Returns a namespace (``X``,
+        ``y``, ``data``, ``y_data``, ``mask``, ``classes``, ``multiclass``,
+        ``fit_dtype``), or None where X streams or a sparse X is over the
+        densify budget. ``binary_only``: a target of more than two classes
+        returns with ``data`` None, X untouched."""
+        import types
+
+        from ..config import mxu_dtype
+        from ..parallel.streaming import _is_sparse_source, stream_plan
 
         self._c_grid_sparse_reason = None
         if _is_sparse_source(X):
@@ -616,44 +663,89 @@ class _GLMBase(BaseEstimator):
             return None
         mesh = resolve_mesh(getattr(X, "mesh", None))
         X, y = check_X_y(X, y, mesh=mesh, dtype=np.float32)
-        from ..config import mxu_dtype
-
         mask = X.row_mask(dtype=jnp.float32)
-        data, y_data, packed = _prepare_fit(
-            X.data, y.data, mask, fit_intercept=self.fit_intercept,
-            to_bf16=mxu_dtype(self.fit_dtype) is not None,
-            encode=self.family == "logistic",
-        )
-        if self.family == "poisson":
+        to_bf16 = mxu_dtype(self.fit_dtype) is not None
+        y_data, classes, multiclass = y.data, None, False
+        if self.family == "logistic":
+            _, y_data, packed = _prepare_fit(
+                None, y.data, mask, fit_intercept=False, to_bf16=False,
+                encode=True)
+            pk = to_host(packed)
+            # >2 classes (or one): the one-vs-rest arm
+            multiclass = not bool(pk[2]) or pk[0] == pk[1]
+            if not multiclass:
+                classes = np.asarray(pk[:2])
+        elif self.family == "poisson":
             _check_poisson_targets(
                 float(jnp.min(jnp.where(mask > 0, y_data, jnp.inf)))
             )
-        classes = None
-        if self.family == "logistic":
-            pk = to_host(packed)
-            if not bool(pk[2]) or pk[0] == pk[1]:
-                # >2 classes: the grid stacks k*C one-vs-rest blocks in
-                # one program (degenerate single-class keeps None — the
-                # general path raises the clean error)
-                return self._fit_C_grid_multiclass(X, y, data, mask, Cs)
-            classes = np.asarray(pk[:2])
-        d = data.shape[1]
+        ns = types.SimpleNamespace(
+            X=X, y=y, data=X.data, y_data=y_data, mask=mask, classes=classes,
+            multiclass=multiclass,
+            fit_dtype="bfloat16" if to_bf16 else "float32")
+        if multiclass and binary_only:
+            ns.data = None
+        elif to_bf16:          # else an f32 design: X as it is
+            ns.data = _prepare_fit(X.data, y_data, mask, fit_intercept=False,
+                                   to_bf16=True, encode=False)[0]
+        return ns
+
+    def _grid_blocks(self, prep, Cs, n_train, fold_id=None):
+        """The stacked solve of ``len(n_train) * len(Cs)`` blocks over the
+        prepared design, block ``f * len(Cs) + c`` candidate ``Cs[c]`` on
+        fold ``f`` (``fold_id``: ``_lam_grid_body``'s; None with one
+        fold of every row). Each block's ``lam = 1 / (C * n_train_f)``
+        comes from ``_penalty_setup``, the one place the regularization
+        bookkeeping lives. Returns ``((blocks, d [+ 1]) float64 betas,
+        info)``."""
+        from ..base import clone
         from .solvers.solvers import solve_lam_grid
 
-        def finish(est, Bi, info):
+        p = prep.data.shape[1] + int(self.fit_intercept)
+        pmask = self._penalty_setup(p, 1)[0]
+        lams = [clone(self).set_params(C=c)._penalty_setup(p, nt)[1]
+                for nt in n_train for c in Cs]
+        B, info = solve_lam_grid(
+            prep.data, prep.y_data, prep.mask, prep.X.n_rows, lams, pmask,
+            self.family, self.penalty, max_iter=self.max_iter, tol=self.tol,
+            fold_id=fold_id, n_train=n_train, intercept=self.fit_intercept,
+        )
+        return np.asarray(B, np.float64), info
+
+    def _fit_C_grid(self, X, y, Cs):
+        """Fit ``len(Cs)`` clones differing only in ``C`` as ONE
+        stacked-lam L-BFGS program over a shared design matrix — the
+        one-fold case of the stacked grid (GridSearchCV's homogeneous-
+        trial fast path over fold copies; SURVEY.md §3.4). Returns the
+        fitted clones in ``Cs`` order, or None when this fit shape isn't
+        eligible (caller falls back to per-candidate fits)."""
+        if not self._grid_eligible():
+            return None
+        prep = self._grid_prepare(X, y)
+        if prep is None:
+            return None
+        X = prep.X
+        if prep.multiclass:
+            # the one-vs-rest arm keeps the intercept as a column (degenerate
+            # single-class keeps None — the general path raises the clean
+            # error)
+            data = _append_intercept(prep.data, prep.mask) \
+                if self.fit_intercept else prep.data
+            return self._fit_C_grid_multiclass(X, prep.y, data, prep.mask,
+                                               Cs)
+        return self._run_C_grid(
+            X, Cs, lambda: self._grid_blocks(prep, Cs, [X.n_rows]),
+            self._grid_finish(prep.classes, X.shape[1]),
+            self._intercept_form())
+
+    def _grid_finish(self, classes, n_features):
+        """``finish`` of ``_grid_fitted`` for one binary (or regression)
+        block: the clone's coefficients, intercept and classes."""
+        def finish(est, beta, info):
             if classes is not None:
                 est.classes_ = classes
-            est._finish_fit(Bi, classes, dict(info),
-                            d - int(self.fit_intercept))
-
-        return self._run_C_grid(
-            X, Cs, d,
-            lambda lams, pmask: solve_lam_grid(
-                data, y_data, mask, X.n_rows, lams, pmask, self.family,
-                self.penalty, max_iter=self.max_iter, tol=self.tol,
-            ),
-            finish,
-        )
+            est._finish_fit(beta, classes, info, n_features)
+        return finish
 
     def fit(self, X, y):
         from ..parallel.streaming import stream_plan
@@ -908,18 +1000,23 @@ class LogisticRegression(_GLMBase):
             return None
         from .solvers.solvers import solve_lam_grid_multi
 
+        from ..base import clone
+
         Y = _onehot_targets(y.data, mask, jnp.asarray(classes, y.dtype))
         d = data.shape[1]
+        lams = [clone(self).set_params(C=c)._penalty_setup(d, X.n_rows)[1]
+                for c in Cs]
+        pmask = self._penalty_setup(d, X.n_rows)[0]
         return self._run_C_grid(
-            X, Cs, d,
-            lambda lams, pmask: solve_lam_grid_multi(
+            X, Cs,
+            lambda: solve_lam_grid_multi(
                 data, Y, mask, X.n_rows, lams, pmask, self.family,
                 self.penalty, max_iter=self.max_iter, tol=self.tol,
             ),
             lambda est, Bi, info: est._finish_fit_multi(
                 Bi, classes, dict(info), d - int(self.fit_intercept)
             ),
-            n_classes=len(classes),
+            self._intercept_form(stacked=True), n_classes=len(classes),
         )
 
     def _check_multi_class(self):

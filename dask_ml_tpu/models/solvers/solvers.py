@@ -289,6 +289,10 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
     The single-target carry is ``(beta, state, gnorm, it, n_evals)``:
     ``n_evals`` is an int32 sum of objective evaluations (one scalar add
     an iteration, always on — a static switch would make two programs).
+    ``gnorm`` is the gradient norm at the iterate the last body started
+    from: the loop stops on the first iterate whose gradient (free: the
+    last line search computed it) meets tol, with no line search from
+    it, and ``it`` counts the updates made.
     A one-element carry ``(beta0,)`` is a fresh start (``_fresh_carry``),
     a four-element one a state without its counters: both are completed
     here, in the traced function.
@@ -326,11 +330,11 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
         return (it < stop_it) & (gnorm > tol)
 
     def body(carry):
-        beta, state, _, it = carry[:4]
+        beta, state, _, it, n_evals = carry[:5]
         stored = state[-1].value     # what the last line search left
         value, grad = value_and_grad(beta, state=state)
         if track:
-            conv, frozen, cmask = carry[4:]
+            conv, frozen, cmask = carry[5:]
             # the gradient is evaluated at the CURRENT iterate: a block
             # whose norm just passed tol converged AT this iterate —
             # record it before the update moves on
@@ -338,62 +342,70 @@ def _lbfgs_loop(loss, carry, stop_it, tol, memory, log, n_blocks=None):
             frozen = jnp.where(cmask[:, None], frozen,
                                beta.reshape(n_blocks, -1))
             cmask = cmask | (norms <= tol)
-        updates, state = opt.update(
-            grad, state, beta, value=value, grad=grad, value_fn=loss
-        )
-        beta = optax.apply_updates(beta, updates)
-        if track:
             gnorm = jnp.max(norms)
             conv = jnp.where(norms > tol, it + 1, conv)
         else:
             gnorm = jnp.linalg.norm(grad)
-        if log:  # static: the silent trace has no callback at all
-            emit_jit_step(it, loss=value, grad_norm=gnorm)
-        if track:
-            return beta, state, gnorm, it + 1, conv, frozen, cmask
+
+        def step(_):
+            updates, new_state = opt.update(
+                grad, state, beta, value=value, grad=grad, value_fn=loss
+            )
+            if log:  # static: the silent trace has no callback at all
+                emit_jit_step(it, loss=value, grad_norm=gnorm)
+            return (optax.apply_updates(beta, updates), new_state,
+                    new_state[-1].info.num_linesearch_steps.astype(jnp.int32))
+
+        # an iterate that already meets tol is the answer: no line search
+        # from it (on the bf16 staircase one costs 1-20 evaluations that
+        # move nothing), and no iteration counted; the loop ends on it
+        done = gnorm <= tol
+        beta, state, steps = jax.lax.cond(
+            done, lambda _: (beta, state, jnp.zeros((), jnp.int32)), step,
+            None)
         # objective evaluations so far: value_and_grad_from_state ran the
         # loss only where no finite value was stored (the first
-        # iteration), the zoom line search once per step — and the state
-        # keeps only the last search's count
-        n_evals = (carry[4] + (~jnp.isfinite(stored)).astype(jnp.int32)
-                   + state[-1].info.num_linesearch_steps.astype(jnp.int32))
-        return beta, state, gnorm, it + 1, n_evals
+        # iteration), the zoom line search once per step
+        n_evals = n_evals + (~jnp.isfinite(stored)).astype(jnp.int32) + steps
+        it = jnp.where(done, it, it + 1)
+        if track:
+            return beta, state, gnorm, it, n_evals, conv, frozen, cmask
+        return beta, state, gnorm, it, n_evals
 
     if len(carry) == 1:
         carry = _fresh_carry(opt, carry[0])
-    if track and len(carry) == 4:
+    if len(carry) == 4:                  # a caller that starts from zero
+        carry = (*carry, jnp.zeros((), jnp.int32))
+    if track and len(carry) == 5:
         b0 = carry[0]
         carry = (*carry, jnp.zeros(n_blocks, jnp.int32),
                  b0.reshape(n_blocks, -1),
                  jnp.zeros(n_blocks, jnp.bool_))
-    elif not track and len(carry) == 4:   # a caller that starts from zero
-        carry = (*carry, jnp.zeros((), jnp.int32))
     out = jax.lax.while_loop(cond, body, carry)
     if track:
-        beta, state, gnorm, it, conv, frozen, cmask = out
+        beta, state, gnorm, it, n_evals, conv, frozen, cmask = out
         merged = jnp.where(cmask[:, None], frozen,
                            beta.reshape(n_blocks, -1)).reshape(beta.shape)
-        return merged, state, gnorm, it, conv
+        return merged, state, gnorm, it, n_evals, conv
     return out
 
 
 def _per_block_iters(conv, it_total):
     """Per-block iteration counts in the single-target ``n_iter``
-    convention: the confirming iteration that first observes a
-    below-tol gradient counts too (+1 over the tracker's last above-tol
-    iteration), clamped to the joint budget for blocks the cap cut
-    off. Guarantees max(per_block) == the joint program's n_iter."""
-    c = np.asarray(conv, np.int64) + 1
-    return np.minimum(c, int(it_total))
+    convention: the updates a block took before its first iterate whose
+    gradient norm passed tol (the tracker's count), clamped to the joint
+    budget for blocks the cap cut off. Guarantees max(per_block) == the
+    joint program's n_iter."""
+    return np.minimum(np.asarray(conv, np.int64), int(it_total))
 
 
 def _stacked_solve(chunk, *args, **kwargs):
     """One stacked L-BFGS program (``_lbfgs_loop`` with ``n_blocks``)
     from a fresh start, and what the host reads of it in one fetch:
-    ``(beta, n_iter, grad_norm, conv)`` as host values."""
-    beta, _state, gnorm, it, conv = chunk(*args, **kwargs)
-    beta, gnorm, it, conv = _fetch(beta, gnorm, it, conv)
-    return beta, int(it), float(gnorm), conv
+    ``(beta, n_iter, grad_norm, conv, n_evals)`` as host values."""
+    beta, _state, gnorm, it, n_evals, conv = chunk(*args, **kwargs)
+    beta, gnorm, it, conv, n_evals = _fetch(beta, gnorm, it, conv, n_evals)
+    return beta, int(it), float(gnorm), conv, int(n_evals)
 
 
 @track_program("glm.lbfgs_multi_pallas")
@@ -483,7 +495,9 @@ def lbfgs(X, y, mask, n_rows, beta0, family, reg, lam, pmask, l1_ratio=0.5,
             ))
             it, gnorm = int(carry[3]), float(carry[2])
             resumed_from = it
-        while it < max_iter and not (it > 0 and gnorm <= tol):
+        # a chunk whose start already meets tol returns it unmoved (and
+        # it unchanged): the norm alone says the solve is done
+        while it < max_iter and not gnorm <= tol:
             stop = min(it + int(checkpoint_every), max_iter)
             carry, result = run(carry=carry, stop_it=np.int32(stop))
             ckpt.save_pytree(checkpoint_path, tuple(carry))
@@ -1073,14 +1087,14 @@ def solve_multi(solver, X, Y, mask, n_rows, B0, family, reg, lam, pmask,
         if fits_vmem:
             _check_smooth(reg, solver)
             memory = int(kwargs.get("memory", 10))
-            beta, it, gnorm, conv = _stacked_solve(
+            beta, it, gnorm, conv, n_evals = _stacked_solve(
                 _lbfgs_multi_pallas_chunk, X, Y, mask, n_rows,
                 (_operand(B0).reshape(-1),), _operand(lam),
                 _operand(pmask), l1_ratio, np.int32(max_iter),
                 _operand(tol), family, reg, mesh, C, memory=memory,
                 interpret=pallas_interpret,
             )
-            info = {"n_iter": it, "grad_norm": gnorm,
+            info = {"n_iter": it, "grad_norm": gnorm, "n_evals": n_evals,
                     "n_iter_per_class":
                         _per_block_iters(conv, it).tolist(),
                     "fused_multi": True}
@@ -1103,13 +1117,13 @@ def solve_multi(solver, X, Y, mask, n_rows, B0, family, reg, lam, pmask,
         _check_smooth(reg, solver)
         memory = int(kwargs.pop("memory", 10))
         C, d = B0.shape
-        beta, it, gnorm, conv = _stacked_solve(
+        beta, it, gnorm, conv, n_evals = _stacked_solve(
             _multi_stacked_chunk, X, Y, mask, n_rows,
             (_operand(B0).reshape(-1),), _operand(lam), _operand(pmask),
             l1_ratio, np.int32(max_iter), _operand(tol), family, reg, C,
             memory=memory,
         )
-        info = {"n_iter": it, "grad_norm": gnorm,
+        info = {"n_iter": it, "grad_norm": gnorm, "n_evals": n_evals,
                 "n_iter_per_class": _per_block_iters(conv, it).tolist()}
         return check_finite_result(beta.reshape(C, d), info, solver)
     # per-class loop: forward the pallas knobs — the single-target
@@ -1160,34 +1174,50 @@ def _multi_stacked_body(X, Y, mask, n_rows, carry, lam, pmask, l1_ratio,
                        n_blocks=C)
 
 
-def _lam_grid_body(X, y, mask, n_rows, carry, lams, pmask, stop_it, tol,
-                   family, reg, k, memory=10):
-    """Joint L-BFGS over the FLAT (k*d,) stacked-lam vector: the k
-    forward matvecs batch into ONE (n,d)x(d,k) matmul (and the gradient
-    into one (d,n)x(n,k)) — real MXU contractions, unlike vmapping the
-    single-target while_loop, whose batched-loop lowering measured ~5x
-    slower PER LANE on XLA:CPU. The objective is separable across lams,
-    so the joint optimum equals the per-lam optima (same argument as the
-    multi-target OvR chunk above)."""
+def _lam_grid_body(X, y, mask, fold_id, n_train, carry, lams, pmask,
+                   stop_it, tol, family, reg, k, n_folds, intercept,
+                   memory=10):
+    """Joint L-BFGS over ``n_folds * k`` stacked blocks, block
+    ``j = f * k + c`` the model of candidate ``c`` trained on fold ``f``'s
+    training rows: one ``(m, d) x (d, n)`` product serves every block's
+    forward pass and one ``(m, n) x (n, d)`` their gradients, over the ONE
+    resident design. ``fold_id`` (``(n,)`` int32, or None for one fold of
+    every row) says which rows count: row i trains every block whose fold
+    is not ``fold_id[i]``; ``n_train`` (``(n_folds,)`` f32) is each fold's
+    training-row count, the mean's divisor, and ``lams`` already holds
+    each block's ``1 / (C * n_train)``. ``intercept`` (static): the last
+    entry of each block is added to eta in f32 (``_smooth_loss``'s
+    contract), X is as wide as its features. The objective is separable
+    across blocks, so the joint optimum is every block's own optimum, and
+    each block converges on its own gradient norm (``_lbfgs_loop``)."""
     d = X.shape[1]
-
+    m = n_folds * k
+    fold = jnp.arange(m) // k                       # block j's fold
+    inv_train = (1.0 / n_train)[fold]               # (m,)
     def loss(bflat):
-        B = bflat.reshape(k, d)
+        B = bflat.reshape(m, -1)
         eta = jax.lax.dot_general(
-            X, B.astype(X.dtype), (((1,), (1,)), ((), ())),
+            B[:, :d].astype(X.dtype), X, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                       # (n, k)
-        pw = get_family(family).pointwise(eta, y[:, None])
-        base = jnp.sum(pw * mask[:, None]) / n_rows
+        )                                                        # (m, n)
+        if intercept:
+            eta = eta + B[:, d:]
+        pw = get_family(family).pointwise(eta, y[None, :])
+        # each block's row weights as an expression of the fold ids, never
+        # an (m, n) array of their own: XLA fuses them into the reduction
+        # (and into the cotangent the gradient product reads)
+        weight = mask[None, :] if fold_id is None else jnp.where(
+            fold_id[None, :] == fold[:, None], 0.0, mask[None, :])
+        base = jnp.sum(jnp.sum(pw * weight, axis=1) * inv_train)
         if reg == "none":
             return base
         bp = B * pmask[None, :]
         return base + 0.5 * jnp.sum(lams * jnp.sum(bp * bp, axis=1))
 
-    # stop when EVERY candidate's block has converged to tol (max
-    # per-block norm) — identical criterion to per-candidate solves
+    # stop when EVERY block has converged to tol (max per-block norm) —
+    # identical criterion to per-candidate solves
     return _lbfgs_loop(loss, carry, stop_it, tol, memory, False,
-                       n_blocks=k)
+                       n_blocks=m)
 
 
 def _lam_grid_multi_body(X, Y, mask, n_rows, carry, lams, pmask, stop_it,
@@ -1231,7 +1261,8 @@ _multi_stacked_chunk = ProgramPlan(
 
 _lam_grid_chunk = ProgramPlan(
     name="glm.lbfgs_lam_grid", body=_lam_grid_body,
-    static_argnames=("family", "reg", "k", "memory"),
+    static_argnames=("family", "reg", "k", "n_folds", "intercept",
+                     "memory"),
     group="stacked-solve",
 ).build()
 
@@ -1252,7 +1283,7 @@ def solve_lam_grid_multi(X, Y, mask, n_rows, lams, pmask, family, reg,
     k = int(lams.shape[0])
     C = int(Y.shape[0])
     d = X.shape[1]
-    beta, it, gnorm, conv = _stacked_solve(
+    beta, it, gnorm, conv, n_evals = _stacked_solve(
         _lam_grid_multi_chunk, X, Y, mask, n_rows,
         (np.zeros((k * C * d,), np.float32),), lams, _operand(pmask),
         np.int32(max_iter), _operand(tol), family, reg, k, C,
@@ -1262,7 +1293,7 @@ def solve_lam_grid_multi(X, Y, mask, n_rows, lams, pmask, family, reg,
     # (the iteration count a standalone OvR fit of that candidate would
     # have reported)
     conv_kc = _per_block_iters(conv, it).reshape(k, C)
-    info = {"n_iter": it, "grad_norm": gnorm,
+    info = {"n_iter": it, "grad_norm": gnorm, "n_evals": n_evals,
             "lam_grid": k, "n_classes": C,
             "n_iter_per_candidate": conv_kc.max(axis=1).tolist(),
             "n_iter_per_block": conv_kc.tolist()}
@@ -1270,30 +1301,41 @@ def solve_lam_grid_multi(X, Y, mask, n_rows, lams, pmask, family, reg,
 
 
 def solve_lam_grid(X, y, mask, n_rows, lams, pmask, family, reg,
-                   max_iter=100, tol=1e-6, memory=10):
-    """k independent GLM solves differing ONLY in the l2 strength, as
-    ONE compiled program sharing the design matrix — a whole C grid
-    costs one X pass per iteration instead of k (SURVEY.md §3.4 'combos
-    batched when homogeneous'; the reference's analog is k separate
-    dask-glm solves). Returns ((k, d) betas, info); raises on
-    non-finite results (callers fall back to per-candidate fits where
-    error_score= applies individually).
+                   max_iter=100, tol=1e-6, memory=10, fold_id=None,
+                   n_train=None, intercept=False):
+    """GLM solves differing ONLY in the l2 strength and in which rows they
+    train on, as ONE compiled program sharing the design matrix — a whole
+    C grid over every cross-validation fold costs one X pass per
+    iteration instead of one per candidate and fold (SURVEY.md §3.4
+    'combos batched when homogeneous'; the reference's analog is a
+    dask-glm solve per candidate per fold). ``lams`` is ``n_folds * k``
+    long, fold-major (block ``f * k + c``); ``fold_id`` / ``n_train`` as
+    ``_lam_grid_body`` takes them (None / ``[n_rows]``: one fold of every
+    row, a plain C grid). Returns ``((n_folds * k, d [+ 1]) betas,
+    info)``; raises on non-finite results (callers fall back to
+    per-candidate fits where error_score= applies individually).
 
-    The k candidates share one iteration budget (see
-    :func:`solve_multi`): ``info["n_iter"]`` is the joint program's
-    iteration count (the slowest candidate's), and
-    ``info["n_iter_per_candidate"]`` each candidate's own convergence
-    point within the joint trajectory — the last iteration its
-    per-block gradient norm still exceeded tol."""
+    The blocks share one iteration budget (see :func:`solve_multi`):
+    ``info["n_iter"]`` is the joint program's iteration count (the
+    slowest block's), ``info["n_iter_per_candidate"]`` each block's own
+    convergence point within the joint trajectory — the last iteration
+    its gradient norm still exceeded tol — and ``info["n_evals"]`` how
+    often the stacked objective (one pass over X) ran."""
     _check_smooth(reg, "lbfgs")
     lams = _operand(lams)
-    k = int(lams.shape[0])
-    d = X.shape[1]
-    beta, it, gnorm, conv = _stacked_solve(
-        _lam_grid_chunk, X, y, mask, n_rows,
-        (np.zeros((k * d,), np.float32),), lams, _operand(pmask),
-        np.int32(max_iter), _operand(tol), family, reg, k, memory=memory,
+    m = int(lams.shape[0])
+    n_train = np.asarray([n_rows] if n_train is None else n_train,
+                         np.float32)
+    n_folds = int(n_train.shape[0])
+    k = m // n_folds
+    p = X.shape[1] + int(intercept)
+    beta, it, gnorm, conv, n_evals = _stacked_solve(
+        _lam_grid_chunk, X, y, mask, fold_id, n_train,
+        (np.zeros((m * p,), np.float32),), lams, _operand(pmask),
+        np.int32(max_iter), _operand(tol), family, reg, k, n_folds,
+        bool(intercept), memory=memory,
     )
-    info = {"n_iter": it, "grad_norm": gnorm, "lam_grid": k,
+    info = {"n_iter": it, "grad_norm": gnorm, "n_evals": n_evals,
+            "lam_grid": k, "n_folds": n_folds,
             "n_iter_per_candidate": _per_block_iters(conv, it).tolist()}
-    return check_finite_result(beta.reshape(k, d), info, "lbfgs")
+    return check_finite_result(beta.reshape(m, p), info, "lbfgs")

@@ -137,6 +137,9 @@ def remove_span_observer(fn) -> None:
 RING_SIZE = 4096
 _ring: collections.deque = collections.deque(maxlen=RING_SIZE)
 ANNOTATION_PREFIX = "dmt."
+# what a record folded into its ancestor's ring record leaves behind: its
+# ids and close stamps are the ancestor's business (``fold_nested``)
+_FOLDED_OUT = ("span_id", "parent_id", "root_id", "t_unix", "thread")
 
 
 def recent_spans():
@@ -299,13 +302,22 @@ class span:
     device barriers (``out = sp.sync(out)`` runs ``block_until_ready``
     and accumulates the stall into the record's ``sync_s``). With no
     sink configured the context yields the shared no-op span.
+
+    ``fold_nested=True`` (another estimator's public call inside one
+    phase: a search's refit) keeps the records of the spans opened under
+    it out of the in-memory ring, which holds them instead IN this span's
+    ring record, as the list ``nested`` (innermost first, without their
+    ids): there the call stays one phase of its root, whose children stay
+    flat. Observers and the sink see every such record as their own, and
+    the ledgers add up as always.
     """
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "root_id",
                  "sync_s", "_sink", "_t0", "_t0_ns", "_ctr0", "_tracked",
-                 "_annotation", "_ledger", "_gap0")
+                 "_annotation", "_ledger", "_gap0", "_fold", "_fold_into",
+                 "_nested")
 
-    def __init__(self, name, **attrs):
+    def __init__(self, name, fold_nested=False, **attrs):
         self.name = name
         self.attrs = attrs
         self.sync_s = 0.0
@@ -313,6 +325,9 @@ class span:
         self._tracked = False
         self._annotation = None
         self._ledger = None
+        self._fold = bool(fold_nested)
+        self._fold_into = None
+        self._nested = None
 
     @property
     def ledger(self):
@@ -397,6 +412,9 @@ class span:
         self.span_id = next(_ids)
         self.parent_id = st[-1].span_id if st else None
         self.root_id = st[0].root_id if st else self.span_id
+        if st:
+            parent = st[-1]
+            self._fold_into = parent if parent._fold else parent._fold_into
         st.append(self)
         with _open_lock:
             _open_spans[self.span_id] = {
@@ -504,8 +522,16 @@ class span:
         if armed:
             rec["t_start_ns"] = self._t0_ns
             rec["t_end_ns"] = t_end_ns
+        if armed and self._fold_into is not None:
+            # the ring keeps it in the folding ancestor's record
+            if self._fold_into._nested is None:
+                self._fold_into._nested = []
+            self._fold_into._nested.append(
+                {k: v for k, v in rec.items() if k not in _FOLDED_OUT})
+        elif armed:
             with _open_lock:
-                _ring.append(rec)
+                _ring.append(rec if self._nested is None
+                             else {**rec, "nested": self._nested})
         for fn in observers:
             # the live plane sees every closed span, recorded or not —
             # a failing observer must never surface into the fit

@@ -351,8 +351,9 @@ def test_multiclass_fit_appends_the_column_itself():
 
 @pytest.mark.parametrize("fit_intercept", [True, False])
 def test_C_grid_column_form_equals_scalar_fits(fit_intercept):
-    """The stacked C-grid program keeps the column; each of its clones
-    equals the estimator's own (scalar-form) fit at that C."""
+    """The stacked C-grid program takes the intercept as each block's
+    last beta entry, as the plain lbfgs fit does (no ones column); each
+    of its clones equals the estimator's own fit at that C."""
     Est, X, y = _family_data("logistic", n=900, d=6)
     Cs = [0.1, 1.0, 10.0]
     base = Est(solver="lbfgs", max_iter=300, tol=1e-7,
@@ -361,7 +362,7 @@ def test_C_grid_column_form_equals_scalar_fits(fit_intercept):
     assert fitted is not None and len(fitted) == 3
     for C, est in zip(Cs, fitted):
         assert est.solver_info_["intercept"] == (
-            "column" if fit_intercept else "none")
+            "scalar" if fit_intercept else "none")
         solo = Est(solver="lbfgs", max_iter=300, tol=1e-7, C=C,
                    fit_intercept=fit_intercept).fit(X, y)
         np.testing.assert_allclose(est.coef_, solo.coef_, atol=3e-3)

@@ -219,3 +219,28 @@ def test_multiclass_fit_solve_span_counts_one_fetch():
         rec = [r for r in obs.recent_spans() if r["span"] == "fit.solve"][-1]
     assert rec["fetches"] == 1 and rec["n_iter"] == clf.n_iter_
     assert clf.coef_.shape == (3, 8)
+
+
+@pytest.mark.parametrize("chunked", [False, True], ids=["whole", "chunks"])
+def test_a_converged_start_runs_no_line_search(chunked, tmp_path):
+    """The loop stops on the first iterate whose gradient meets tol: a fit
+    warm-started at its own answer evaluates the objective once, makes no
+    update and reports 0 iterations — also chunked, where a chunk that
+    moved nothing must still end the solve."""
+    from dask_ml_tpu.datasets import make_classification
+    from dask_ml_tpu.linear_model import LogisticRegression
+
+    X, y = make_classification(n_samples=2000, n_features=8, random_state=0)
+    done = LogisticRegression(solver="lbfgs", tol=1e-4, max_iter=100).fit(X, y)
+    assert done.solver_info_["n_evals"] >= done.n_iter_ + 1
+    kw = {"checkpoint_path": str(tmp_path / "ck"),
+          "checkpoint_every": 3} if chunked else None
+    again = LogisticRegression(solver="lbfgs", tol=1e-4, max_iter=100,
+                               warm_start=True, solver_kwargs=kw)
+    again.coef_, again.intercept_ = done.coef_, done.intercept_
+    again.fit(X, y)
+    assert again.n_iter_ == 0
+    assert again.solver_info_["grad_norm"] <= 1e-4
+    np.testing.assert_array_equal(again.coef_, done.coef_)
+    if not chunked:
+        assert again.solver_info_["n_evals"] == 1

@@ -264,9 +264,13 @@ def test_jaxpr_identity_stacked_c_grid_solves():
     tol = jnp.asarray(1e-6, jnp.float32)
     cases = [
         (S._lam_grid_chunk,
-         {"static_argnames": ("family", "reg", "k", "memory")},
-         (X, y, mask, n, carry_of(k * d), lams, pmask, stop_it, tol),
-         {"family": "logistic", "reg": "l2", "k": k}),
+         {"static_argnames": ("family", "reg", "k", "n_folds", "intercept",
+                              "memory")},
+         (X, y, mask, jnp.asarray(np.arange(n) % 2, jnp.int32),
+          jnp.asarray([n / 2, n / 2], jnp.float32), carry_of(2 * k * d),
+          jnp.tile(lams, 2), pmask, stop_it, tol),
+         {"family": "logistic", "reg": "l2", "k": k, "n_folds": 2,
+          "intercept": False}),
         (S._lam_grid_multi_chunk,
          {"static_argnames": ("family", "reg", "k", "C", "memory")},
          (X, Y, mask, n, carry_of(k * C * d), lams, pmask, stop_it,
