@@ -690,6 +690,11 @@ def kernel_cases(sizes):
         lambda a, i: pf.fused_lloyd_stats(a["Xb"], a["nv"], a["centers"],
                                           interpret=i),
         xla_stats)
+    add("fused_lloyd_stats_lanes",
+        lambda a, i: pf.fused_lloyd_stats_lanes(
+            pf.lloyd_lanes_operand(a["Xb"], a["centers"].shape[0]), a["nv"],
+            a["centers"], interpret=i),
+        xla_stats)
     add("fused_assign_update",
         lambda a, i: pf.fused_assign_update(a["Xb"], a["mask"], a["centers"],
                                             interpret=i)[2:],

@@ -110,13 +110,27 @@ def _lloyd_run_pallas(X, mask, centers0, max_iter, tol2, mesh,
                       interpret=False, log=False):
     """Lloyd loop where each iteration's data pass is the fused Pallas
     kernel (ops/pallas_fused.py): X streams through VMEM once per
-    iteration; sums/counts psum over ICI."""
+    iteration; sums/counts psum over ICI. Below 128 features
+    (``lloyd_layout``) the kernel reads X transposed, ``(d, n)``, made
+    once before the loop; from 128 up it reads X's own ``(n, d)`` rows."""
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.pallas_fused import fused_lloyd_stats
+    from ..ops.pallas_fused import (fused_lloyd_stats,
+                                    fused_lloyd_stats_lanes, lloyd_layout,
+                                    lloyd_lanes_operand)
     from ..parallel.mesh import DATA_AXIS
 
     k = centers0.shape[0]
+    x_spec = P(DATA_AXIS, None)
+    stats = fused_lloyd_stats
+    if lloyd_layout(X.shape[1]) == "lanes":
+        x_spec = P(None, DATA_AXIS)
+        stats = fused_lloyd_stats_lanes
+        X = jax.shard_map(
+            lambda xs: lloyd_lanes_operand(xs, k), mesh=mesh,
+            in_specs=P(DATA_AXIS, None), out_specs=x_spec,
+            check_vma=False,
+        )(X)
 
     def shard_step(xs, ms, c):
         # per-shard valid-row count (valid rows are a prefix of each
@@ -125,15 +139,13 @@ def _lloyd_run_pallas(X, mask, centers0, max_iter, tol2, mesh,
         # layout would pad 128x in HBM. Integer sum: an f32 accumulator
         # saturates at 2^24 rows, silently dropping rows past 16.7M
         nv = jnp.sum(ms.astype(jnp.int32))
-        sums, counts, _ = fused_lloyd_stats(
-            xs, nv, c, interpret=interpret
-        )
+        sums, counts, _ = stats(xs, nv, c, interpret=interpret)
         return (jax.lax.psum(sums, DATA_AXIS),
                 jax.lax.psum(counts, DATA_AXIS))
 
     step = jax.shard_map(
         shard_step, mesh=mesh,
-        in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P()),
+        in_specs=(x_spec, P(DATA_AXIS), P()),
         out_specs=(P(), P()),
         check_vma=False,
     )
@@ -1208,8 +1220,12 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             tol2, tol_passes = _lloyd_tol2(X, mask, self.tol)
             sp.add(passes=tol_passes)
         from ..observability import active_logger, fit_logger
+        from ..ops.pallas_fused import lloyd_layout
 
-        with span("fit.solve", fused=bool(use_pallas)) as sp, \
+        # which table form the Pallas kernel reads (None: XLA's loop)
+        layout = lloyd_layout(X.shape[1]) if use_pallas else None
+        with span("fit.solve", fused=bool(use_pallas),
+                  layout=layout) as sp, \
                 fit_logger("KMeans", n_rows=X.n_rows,
                            n_clusters=self.n_clusters) as logger, \
                 active_logger(logger):
@@ -1279,8 +1295,10 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             self.inertia_ = float(to_host(inertia))
             self.n_iter_ = n_iter
             # what carried the fit: the resident twin of the GLMs'
-            # solver_info_ ("fused": the Pallas Lloyd kernel ran)
+            # solver_info_ ("fused": the Pallas Lloyd kernel ran;
+            # "lloyd_layout": the table form it read)
             self.solver_info_ = {"n_iter": n_iter, "fused": bool(use_pallas),
+                                 "lloyd_layout": layout,
                                  "fit_dtype": self.fit_dtype_,
                                  "tol_scale_passes": tol_passes,
                                  "init_draw": init_draw,

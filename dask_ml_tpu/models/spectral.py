@@ -145,8 +145,10 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
     shard's CholeskyQR2 failed its guard and took Householder),
     ``restarts``, ``lloyd_iters`` (summed over the restarts; ``n_iters`` and
     ``inertias`` give each restart's, ``winner`` the index of the least
-    inertia) and ``assign_fused`` (the fused Lloyd kernel, not XLA's loop,
-    carried the ``n_clusters``-wide table)."""
+    inertia), ``assign_fused`` (the fused Lloyd kernel, not XLA's loop,
+    carried the ``n_clusters``-wide table) and ``assign_layout`` (the
+    winner's ``KMeans.solver_info_["lloyd_layout"]``: the table form that
+    kernel read, ``None`` on XLA's loop)."""
 
     def __init__(self, n_clusters=8, eigen_solver=None, random_state=None,
                  n_init=10, gamma=1.0, affinity="rbf", n_neighbors=10,
@@ -258,7 +260,8 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
                    weight_passes=sum(
                        f.solver_info_["init_weights"]["weight_passes"]
                        for f in fits),
-                   weights=km.solver_info_["init_weights"]["weights"])
+                   weights=km.solver_info_["init_weights"]["weights"],
+                   assign_layout=km.solver_info_["lloyd_layout"])
         root.add(n_iter=sum(n_iters))
         with span("fit.finish") as sp:
             s_h, idx_h, fb_h = _fetch(s, idx, fell_back)
@@ -274,6 +277,7 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
                 "lloyd_iters": sum(n_iters), "n_iters": n_iters,
                 "inertias": inertias, "winner": winner,
                 "assign_fused": bool(km.solver_info_["fused"]),
+                "assign_layout": km.solver_info_["lloyd_layout"],
                 "landmark_draw": landmark_draw,
             }
             if self.persist_embedding:

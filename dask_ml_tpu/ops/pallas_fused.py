@@ -1332,3 +1332,149 @@ def fused_assign_update(x, mask, centers, interpret=False):
     mind = mind[:n, 0]
     inertia = jnp.sum(mind)  # XLA fuses this with the kernel output
     return labels[:n, 0], mind, sums, counts[0], inertia
+
+
+# -- Lloyd statistics on a lane-dense table ----------------------------------
+# Below 128 features a ``(tile, d)`` row block pads d to 128 lanes: the
+# kernel's operand is a lane-padded copy of X (16x the bytes at d = 8) and
+# every iteration reads it. Laid out as ``(d, n)`` the same table is dense:
+# rows of the data ride along lanes, the features along sublanes.
+
+_LANE_CHUNK_BYTES = 1024 * 1024  # one (d, chunk) or (k, chunk) f32 value
+
+
+def lloyd_layout(d):
+    """Which table form the resident Pallas Lloyd loop reads at ``d``
+    features: ``"lanes"`` (``(d, n)``, :func:`fused_lloyd_stats_lanes`)
+    below 128, ``"rows"`` (``(n, d)``, :func:`fused_lloyd_stats`) from
+    128 up, where a row block fills its lanes."""
+    return "lanes" if d < 128 else "rows"
+
+
+def _round_up(n, m):
+    return -(-n // m) * m
+
+
+def lloyd_lane_tiling(n, d, k):
+    """(tile, chunk, n_pad) of the lane-dense kernel over ``n`` lanes: a
+    grid step moves a ``(d, tile)`` block of up to ``_GLM_TILE_BUDGET``
+    bytes and works through it ``chunk`` lanes at a time, so its
+    ``(d, chunk)`` and ``(k, chunk)`` values stay within
+    ``_LANE_CHUNK_BYTES`` whatever the block; ``n_pad`` (a multiple of
+    ``tile``) pads less than one chunk a grid step."""
+    rows = _round_up(d, 8)
+    widest = max(rows, _round_up(k, 8))
+    chunk = 128
+    while 2 * chunk * widest * 4 <= _LANE_CHUNK_BYTES:
+        chunk *= 2
+    chunk = min(chunk, _round_up(n, 128))
+    per_step = max(1, _GLM_TILE_BUDGET // (rows * chunk * 4))
+    chunks = -(-n // chunk)
+    steps = -(-chunks // per_step)
+    tile = -(-chunks // steps) * chunk
+    return tile, chunk, steps * tile
+
+
+def lloyd_lanes_operand(x, k):
+    """``x`` ``(n, d)`` as the lane-dense kernel reads it: ``(d, n_pad)``,
+    the padded lanes zero. Made once, before a loop of kernel calls."""
+    n, d = x.shape
+    _, _, n_pad = lloyd_lane_tiling(n, d, k)
+    xt = x.astype(jnp.float32).T
+    if n_pad != n:
+        xt = jnp.pad(xt, ((0, 0), (0, n_pad - n)))
+    return xt
+
+
+def _lloyd_lanes_kernel(xt_ref, nv_ref, c_ref, c2_ref, sums_ref, counts_ref,
+                        inertia_ref, *, tile, chunk):
+    """``_lloyd_stats_kernel`` transposed: the block is ``(d, tile)``, the
+    distances ``(k, chunk)``, the argmin over sublanes."""
+    i = pl.program_id(0)
+    c = c_ref[:]                       # (k, d)
+    c2 = c2_ref[:]                     # (k, 1)
+    k, d = c.shape
+
+    def chunk_stats(j, carry):
+        sums, counts, inertia = carry
+        start = pl.multiple_of(j * chunk, chunk)
+        x = xt_ref[:, pl.ds(start, chunk)]               # (d, chunk)
+        # lane validity from the GLOBAL lane index, as the row form's rows
+        lane_ids = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) \
+            + (i * tile + start)
+        m = (lane_ids < nv_ref[0, 0]).astype(jnp.float32)  # (1, chunk)
+        xc = jax.lax.dot_general(
+            c, x, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                # (k, chunk)
+        d2 = jnp.sum(x * x, axis=0, keepdims=True) - 2.0 * xc + c2
+        d2 = jnp.maximum(d2, 0.0)
+        mind = jnp.min(d2, axis=0, keepdims=True)        # (1, chunk)
+        iota = jax.lax.broadcasted_iota(
+            jnp.int32, (k, chunk), 0
+        ).astype(jnp.float32)
+        labf = jnp.min(jnp.where(d2 <= mind, iota, float(k)), axis=0,
+                       keepdims=True)
+        onehot = (iota == labf).astype(jnp.float32) * m
+        sums = sums + jax.lax.dot_general(
+            onehot, x, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                # (k, d)
+        counts = counts + jnp.sum(onehot, axis=1, keepdims=True)
+        inertia = inertia + jnp.sum(mind * m, axis=1, keepdims=True)
+        return sums, counts, inertia
+
+    zero = (jnp.zeros((k, d), jnp.float32), jnp.zeros((k, 1), jnp.float32),
+            jnp.zeros((1, 1), jnp.float32))
+    sums, counts, inertia = jax.lax.fori_loop(0, tile // chunk, chunk_stats,
+                                              zero)
+
+    @pl.when(i == 0)
+    def _init():
+        sums_ref[:] = jnp.zeros_like(sums_ref)
+        counts_ref[:] = jnp.zeros_like(counts_ref)
+        inertia_ref[:] = jnp.zeros_like(inertia_ref)
+
+    sums_ref[:] += sums
+    counts_ref[:] += counts
+    inertia_ref[:] += inertia
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fused_lloyd_stats_lanes(xt, n_valid, centers, interpret=False):
+    """:func:`fused_lloyd_stats` of the table ``xt`` laid out ``(d, n)``
+    (:func:`lloyd_lanes_operand`): the same statistics by the same
+    arithmetic, blocks of ``(d, tile)`` that hold no lane padding where d
+    is under 128. Returns (sums (k, d), counts (k,), inertia scalar); the
+    valid lanes are the first ``n_valid``."""
+    d, n = xt.shape
+    k = centers.shape[0]
+    xt = xt.astype(jnp.float32)
+    centers = centers.astype(jnp.float32)
+    tile, chunk, n_pad = lloyd_lane_tiling(n, d, k)
+    if n_pad != n:
+        xt = jnp.pad(xt, ((0, 0), (0, n_pad - n)))
+    c2 = jnp.sum(centers * centers, axis=1)[:, None]
+    nv = jnp.asarray(n_valid, jnp.int32).reshape(1, 1)
+    sums, counts, inertia = pl.pallas_call(
+        functools.partial(_lloyd_lanes_kernel, tile=tile, chunk=chunk),
+        grid=(n_pad // tile,),
+        in_specs=[
+            pl.BlockSpec((d, tile), lambda i: (0, i)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((k, d), lambda i: (0, 0)),
+            pl.BlockSpec((k, 1), lambda i: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((k, d), lambda i: (0, 0)),
+            pl.BlockSpec((k, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((k, d), jnp.float32),
+            jax.ShapeDtypeStruct((k, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(xt, nv, centers, c2)
+    return sums, counts[:, 0], inertia[0, 0]

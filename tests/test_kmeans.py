@@ -163,11 +163,14 @@ def test_kmeans_score_is_negative_inertia(blobs):
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_kmeans_records_what_carried_the_fit(blobs, use_pallas):
     """``solver_info_`` names the kernel flavour that ran (``fused``: the
-    Pallas Lloyd kernel), the iterations and the precision."""
+    Pallas Lloyd kernel; ``lloyd_layout``: the table form it read, lane-dense
+    at these 5 features), the iterations and the precision."""
     X, _ = blobs
     km = KMeans(n_clusters=4, init=X.to_numpy()[:4].copy(), max_iter=7,
                 tol=0.0, use_pallas=use_pallas).fit(X)
     assert km.solver_info_ == {"n_iter": 7, "fused": use_pallas,
+                               "lloyd_layout":
+                                   "lanes" if use_pallas else None,
                                "fit_dtype": "float32",
                                "tol_scale_passes": 0,
                                "init_draw": {"draws": 0, "draw": "none"},

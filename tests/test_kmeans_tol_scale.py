@@ -5,7 +5,8 @@ program, ``kmeans.tol_scale``, of two fused passes over X. XLA:CPU gives the
 counts, the values and what compiled; the tests that compile for a
 described v5e hold the programs' memory at the benchmark cells' shapes —
 this one and, since PR 40, k-means‖'s candidate-weight pass at
-``spectral_nystrom``'s (this file is the one that describes the chip)."""
+``spectral_nystrom``'s, and the Lloyd program at both cells' tables (this
+file is the one that describes the chip)."""
 
 import logging
 import os
@@ -235,3 +236,30 @@ def test_the_candidate_weights_keep_the_one_hot_out_of_memory(one_chip):
         A((n, d)), A((n,)), A((c, d)), A((c,))).compile()
     assert " scatter(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
+
+
+@pytest.mark.parametrize("d, k, kernel", [
+    (8, 8, "fused_lloyd_stats_lanes"), (256, 64, "fused_lloyd_stats")])
+def test_the_lloyd_program_reads_the_table_where_it_lies(one_chip, d, k,
+                                                         kernel):
+    """``kmeans.lloyd_pallas`` at the two cells' tables on one v5e:
+    ``spectral_nystrom``'s 8-wide E goes to the lane-dense kernel as the
+    column-major array it is (a bitcast, no lane-padded 2 GiB copy), and
+    ``kmeans_lloyd``'s 256-wide X to the row form, whose kernel keeps the
+    name the cell's roofline reader looks for."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    n = 4_194_304
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("data",))
+    A = lambda shape, spec, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa
+        shape, dt, sharding=NamedSharding(mesh, spec))
+    compiled = KM._lloyd_run_pallas.__wrapped_jit__.lower(
+        A((n, d), P("data", None)), A((n,), P("data")), A((k, d), P()),
+        A((), P(), jnp.int32), A((), P()), mesh=mesh, interpret=False,
+        log=False).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * 4
+    assert f"%{kernel}." in text and "custom-call(" in text
+    assert " copy(%X" not in text
+    if d < 128:
+        assert f"f32[{d},{n}]" in text.split(" bitcast(%X")[0].split("\n")[-1]
