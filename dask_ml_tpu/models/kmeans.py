@@ -17,7 +17,8 @@ TPU design (SURVEY.md §3.1 "boundary pattern" + §7 hard parts):
   per round via Gumbel top-l with weights ∝ d² (weighted sampling without
   replacement), writing into a static-shape candidate buffer — XLA-friendly
   static shapes instead of the reference's variable-size Bernoulli draws
-  (expected size l), same distribution in spirit.
+  (expected size l), same distribution in spirit. The rounds and the
+  candidates' weights are one program and one fetch a restart.
 - The final "cluster the candidates" step runs sklearn's k-means++ on the
   ≤(1 + l·rounds) weighted candidates on host, exactly the reference's
   pattern of running a local solver on the tiny candidate set.
@@ -253,12 +254,46 @@ def _candidate_weights(X, mask, cands, cand_valid):
     return small_segment_count(labels, mask, cands.shape[0])
 
 
-def _weigh(X, mask, cands, cand_valid, passes):
-    """``_candidate_weights``, with the path of its count appended to
-    ``passes`` (a list, or None), as ``_draw`` counts the draws."""
-    if passes is not None:
-        passes.append("onehot")
-    return _candidate_weights(X, mask, cands, cand_valid)
+@partial(jax.jit, static_argnames=("l", "rounds", "draw", "weigh"))
+def _init_scalable_run(X, mask, key, l, rounds, draw, weigh):
+    """k-means‖'s device part for one init, as ONE program: the first
+    uniform draw, the ``(1 + rounds * l, d)`` candidate buffer and its
+    validity, ``rounds`` rounds of {cost to the candidates, ``l`` draws ∝
+    that cost, the drawn rows into the buffer}, then the candidates'
+    weights. A round whose cost is not above 0 adds no candidate, and
+    neither does any round after it: the early exit as a mask. ``key``: an
+    operand, so a new seed compiles nothing. ``draw`` / ``weigh``: the draw
+    (``_gumbel_top_l``) and the weight pass (``_candidate_weights``),
+    static, so that another function is traced into a program of its own.
+    Returns one ``(c_max, d + 2)`` float32 array for one fetch: the
+    candidates, their validity, their weights. Not in the program
+    registry: the spectral cell's harness pins its fit's programs exactly,
+    so the fit's ``init_rounds`` says that this program ran."""
+    c_max = 1 + rounds * l
+    key, k0 = jax.random.split(key)
+    # a (1, d) take, not X[i]: a row-sharded X's scalar-index gather
+    # partitions as an all-gather of the whole table
+    cands = jnp.zeros((c_max, X.shape[1]), X.dtype).at[:1].set(
+        jnp.take(X, draw(mask, k0, 1), axis=0))
+    valid = jnp.zeros((c_max,), jnp.float32).at[0].set(1.0)
+
+    def one_round(r, carry):
+        cands, valid, key, alive = carry
+        dmin, phi = _cost_to_candidates(X, mask, cands, valid)
+        key, kr = jax.random.split(key)
+        idx = draw(dmin, kr, l)
+        alive = alive & ~(phi <= 0.0)
+        rows = jnp.where(alive, jnp.take(X, idx, axis=0), 0)
+        start = 1 + r * l
+        cands = jax.lax.dynamic_update_slice(cands, rows, (start, 0))
+        valid = jax.lax.dynamic_update_slice(
+            valid, jnp.full((l,), alive, jnp.float32), (start,))
+        return cands, valid, key, alive
+
+    cands, valid, _, _ = jax.lax.fori_loop(
+        0, rounds, one_round, (cands, valid, key, jnp.asarray(True)))
+    weights = weigh(X, mask, cands, valid)
+    return jnp.concatenate([cands, valid[:, None], weights[:, None]], axis=1)
 
 
 def _weight_summary(passes):
@@ -839,53 +874,41 @@ def init_scalable_streamed(stream, n_clusters, random_state, max_iter=None,
 
 
 def init_scalable(X: ShardedArray, n_clusters, random_state, max_iter=None,
-                  oversampling_factor=2, draws=None, weight_passes=None):
+                  oversampling_factor=2, draws=None, weight_passes=None,
+                  rounds_kept=None):
     """k-means‖ candidate harvesting; ref
-    dask_ml/cluster/k_means.py::init_scalable. ``draws``: a list that gets
-    the path of every weighted draw dispatched (see ``_draw``);
-    ``weight_passes`` the same of the candidate-weight pass (``_weigh``)."""
+    dask_ml/cluster/k_means.py::init_scalable. The rounds and the weight
+    pass are one program (``_init_scalable_run``) and one fetch; the
+    candidates' reduction runs on the host. ``draws``: a list that gets the
+    path of every weighted draw (as ``_draw`` counts them);
+    ``weight_passes`` the same of the candidate-weight pass;
+    ``rounds_kept`` the number of rounds that added candidates."""
     data, mask = X.data, X.row_mask(X.dtype)
-    n, d = X.shape
+    d = X.shape[1]
     n_pad = data.shape[0]
     # top_k needs l <= array length; tiny datasets clamp the oversample
     l = min(max(int(oversampling_factor * n_clusters), 1), n_pad)
     key = jax.random.PRNGKey(0 if random_state is None else int(random_state))
-
-    # step 1: one uniform-random valid row
-    key, k0 = jax.random.split(key)
-    first = data[_draw(mask, k0, 1, draws)[0]]
-
-    # candidate buffer with static shape (SURVEY.md §7 hard parts)
+    # a static budget of rounds (SURVEY.md §7 hard parts)
     if max_iter is None:
         # rounds ≈ log(phi); phi ≤ n * max_dist² — 5 is the practical
         # regime for sane data, matching the reference's few-round behavior
         rounds = 5
     else:
         rounds = max(int(max_iter), 1)
-    c_max = 1 + rounds * l
-    cands = jnp.zeros((c_max, d), data.dtype).at[0].set(first)
-    cand_valid = jnp.zeros((c_max,), jnp.float32).at[0].set(1.0)
-
-    for r in range(rounds):
-        dmin, phi = _cost_to_candidates(data, mask, cands, cand_valid)
-        # the draw is queued before the host asks for phi, so the chip draws
-        # while the host waits (a round that ends the loop drops its draw)
-        key, kr = jax.random.split(key)
-        idx = _draw(dmin, kr, l, draws)
-        if float(to_host(phi)) <= 0.0:
-            break
-        rows = jnp.take(data, idx, axis=0)
-        start = 1 + r * l
-        cands = jax.lax.dynamic_update_slice(cands, rows, (start, 0))
-        cand_valid = jax.lax.dynamic_update_slice(
-            cand_valid, jnp.ones((l,), jnp.float32), (start,)
-        )
-
-    weights = _weigh(data, mask, cands, cand_valid, weight_passes)
-    cands_h = to_host(cands)
-    valid_h = to_host(cand_valid) > 0
-    w_h = to_host(weights)[valid_h]
-    pts = cands_h[valid_h]
+    if draws is not None:
+        draws += [top_l_path(n_pad, 1)] + [top_l_path(n_pad, l)] * rounds
+    if weight_passes is not None:
+        weight_passes.append("onehot")
+    packed = to_host(_init_scalable_run(
+        data, mask, key, l=l, rounds=rounds, draw=_gumbel_top_l,
+        weigh=_candidate_weights))
+    valid_h = packed[:, d] > 0
+    if rounds_kept is not None:
+        # every kept round fills its l slots, the first draw slot 0
+        rounds_kept.append((int(valid_h.sum()) - 1) // l)
+    pts = packed[valid_h, :d]
+    w_h = packed[valid_h, d + 1]
     w_h = np.where(w_h > 0, w_h, 1e-6)
     return jnp.asarray(_reduce_candidates(
         pts, w_h, n_clusters,
@@ -964,10 +987,11 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         # it); resolved choice lands on fit_dtype_
         self.fit_dtype = fit_dtype
 
-    def _init_centers(self, X: ShardedArray, draws=None, weight_passes=None):
+    def _init_centers(self, X: ShardedArray, draws=None, weight_passes=None,
+                      rounds_kept=None):
         """The initial centres; ``draws`` gets the path of every weighted
         draw dispatched, ``weight_passes`` that of every candidate-weight
-        pass."""
+        pass, ``rounds_kept`` the k-means‖ rounds that added candidates."""
         if isinstance(self.init, np.ndarray) or isinstance(
             self.init, jnp.ndarray
         ):
@@ -981,7 +1005,7 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
         if self.init == "k-means||":
             return init_scalable(X, self.n_clusters, self.random_state,
                                  self.init_max_iter, self.oversampling_factor,
-                                 draws, weight_passes)
+                                 draws, weight_passes, rounds_kept)
         if self.init == "k-means++":
             return init_pp(X, self.n_clusters, self.random_state, draws)
         if self.init == "random":
@@ -1209,11 +1233,13 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
             self.fit_dtype_ = dt_info["fit_dtype"]
         root.add(n_rows=X.n_rows)
         with span("fit.init") as sp:
-            draws, weight_passes = [], []
-            centers0 = self._init_centers(X, draws, weight_passes)
+            draws, weight_passes, rounds_kept = [], [], []
+            centers0 = self._init_centers(X, draws, weight_passes,
+                                          rounds_kept)
             init_draw = _draw_summary(draws)
             init_weights = _weight_summary(weight_passes)
-            sp.add(**init_draw, **init_weights)
+            init_rounds = sum(rounds_kept)
+            sp.add(**init_draw, **init_weights, init_rounds=init_rounds)
         with span("fit.tol_scale") as sp:
             # dispatch only (none at tol == 0): the device works on into
             # fit.solve
@@ -1302,7 +1328,8 @@ class KMeans(TransformerMixin, ClusterMixin, BaseEstimator):
                                  "fit_dtype": self.fit_dtype_,
                                  "tol_scale_passes": tol_passes,
                                  "init_draw": init_draw,
-                                 "init_weights": init_weights}
+                                 "init_weights": init_weights,
+                                 "init_rounds": init_rounds}
             self.n_features_in_ = X.shape[1]
             return self
 

@@ -261,6 +261,8 @@ class SpectralClustering(ClusterMixin, BaseEstimator):
                        f.solver_info_["init_weights"]["weight_passes"]
                        for f in fits),
                    weights=km.solver_info_["init_weights"]["weights"],
+                   init_rounds=sum(f.solver_info_["init_rounds"]
+                                   for f in fits),
                    assign_layout=km.solver_info_["lloyd_layout"])
         root.add(n_iter=sum(n_iters))
         with span("fit.finish") as sp:

@@ -130,8 +130,19 @@ def test_row_sharded_over_four_devices(kind):
 
 @pytest.fixture
 def with_the_scatter(monkeypatch):
-    """Put the scatter formula back where ``init_scalable`` calls it."""
-    return lambda: monkeypatch.setattr(KM, "_candidate_weights", _scatter)
+    """Put the scatter formula back where ``init_scalable`` calls it; the
+    list it returns gets the candidate count of every trace of it."""
+    traced = []
+
+    def scatter(X, mask, cands, cand_valid):
+        traced.append(cands.shape[0])
+        return _scatter_weights(X, mask, cands, cand_valid)
+
+    def swap():
+        monkeypatch.setattr(KM, "_candidate_weights", jax.jit(scatter))
+        return traced
+
+    return swap
 
 
 @pytest.mark.parametrize("n, k", [(3_001, 4), (6_000, 7)])
@@ -143,8 +154,10 @@ def test_kmeans_fits_what_the_scatter_fitted(with_the_scatter, n, k):
     onehot = new().fit(X)
     assert onehot.solver_info_["init_weights"] == {"weight_passes": 1,
                                                    "weights": "onehot"}
-    with_the_scatter()
+    traced = with_the_scatter()
     scatter = new().fit(X)
+    # the swap reached k-means‖'s program: it was traced anew with it
+    assert traced == [1 + 5 * 2 * k]
     np.testing.assert_array_equal(onehot.cluster_centers_,
                                   scatter.cluster_centers_)
     np.testing.assert_array_equal(onehot.labels_.to_numpy(),
@@ -164,8 +177,9 @@ def test_spectral_at_the_cells_parameters_fits_what_the_scatter_fitted(
         n_init=10, assign_labels="kmeans", persist_embedding=True,
         random_state=7)
     onehot = new().fit(X)
-    with_the_scatter()
+    traced = with_the_scatter()
     scatter = new().fit(X)
+    assert traced == [1 + 5 * 2 * 8]      # one trace, ten restarts
     np.testing.assert_array_equal(onehot.labels_.to_numpy(),
                                   scatter.labels_.to_numpy())
     np.testing.assert_array_equal(onehot.assign_labels_.cluster_centers_,

@@ -175,7 +175,8 @@ def test_kmeans_records_what_carried_the_fit(blobs, use_pallas):
                                "tol_scale_passes": 0,
                                "init_draw": {"draws": 0, "draw": "none"},
                                "init_weights": {"weight_passes": 0,
-                                                "weights": "none"}}
+                                                "weights": "none"},
+                               "init_rounds": 0}
     assert km.n_iter_ == 7 and km.fit_dtype_ == "float32"
 
 
@@ -202,9 +203,18 @@ def test_the_tiled_draw_fits_what_the_plain_draw_fits(
                          random_state=3, max_iter=30)
     tiled = new().fit(wide_blobs)
     assert tiled.solver_info_["init_draw"]["draw"] == "tiled"
+    traced = []
+
+    def plain_draw(weights, key, l):
+        traced.append(l)
+        return _plain_draw(weights, key, l)
+
     monkeypatch.setattr(KM, "_gumbel_top_l",
-                        jax.jit(_plain_draw, static_argnames=("l",)))
+                        jax.jit(plain_draw, static_argnames=("l",)))
     plain = new().fit(wide_blobs)
+    # the swap reached the draws: k-means‖'s program (its first draw and
+    # its rounds') is traced anew with it, as is the random init's draw
+    assert sorted(set(traced)) == ([1, 8] if init == "k-means||" else [4])
     np.testing.assert_array_equal(tiled.cluster_centers_,
                                   plain.cluster_centers_)
     np.testing.assert_array_equal(tiled.labels_.to_numpy(),
